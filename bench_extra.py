@@ -17,7 +17,9 @@ Writes BENCH_extra.json:
 
 Each entry: rows, device p50 ms + rows/s (pipelined where the engine
 overlaps round trips), host-numpy p50 ms + rows/s, speedup. Segments
-build once under ./bench_data_extra (git-ignored).
+build once under ./bench_data_extra (git-ignored). The file names the
+device it ran on; without a TPU the run refuses (bench.require_chip), and
+a config that fails ends the run with its error, not an "error" entry.
 """
 from __future__ import annotations
 
@@ -260,26 +262,26 @@ def config5_startree():
 
 
 def main():
+    import bench
+    device = bench.require_chip()
     os.makedirs(DATA, exist_ok=True)
-    out = {}
+    out = {"device": device}
     for key, fn in [("baseball_sum", config1_baseball),
                     ("ssb_q1", config2_ssb_q1),
                     ("ssb_groupby", config3_ssb_groupby),
                     ("distinct_percentile", config4_distinct_percentile),
                     ("startree", config5_startree)]:
         t0 = time.time()
-        try:
-            out[key] = fn()
-        except Exception as e:  # noqa: BLE001 — record, keep measuring
-            out[key] = {"error": f"{type(e).__name__}: {e}"}
+        out[key] = fn()
         out[key]["measure_s"] = round(time.time() - t0, 1)
         print(f"{key}: {json.dumps(out[key])}", file=sys.stderr)
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "BENCH_extra.json")
     with open(path, "w") as f:
         json.dump(out, f, indent=1)
-    print(json.dumps({"metric": "bench_extra_configs", "value": len(out),
-                      "unit": "configs", "vs_baseline": 1.0}))
+    print(json.dumps({"metric": "bench_extra_configs",
+                      "value": len(out) - 1, "unit": "configs",
+                      "vs_baseline": 1.0, "device": device}))
 
 
 if __name__ == "__main__":

@@ -28,7 +28,7 @@ from pinot_tpu.controller.coordination import CoordinationClient
 log = logging.getLogger(__name__)
 
 
-def _start_admin(cfg, key: str, roles) -> Optional[object]:
+def _start_admin(cfg, key: str, roles, routes=None) -> Optional[object]:
     """Per-role /metrics + /debug surface (trace_store.DebugHttpServer)
     for roles without an HTTP edge. Knob semantics: 0 = ephemeral port,
     >0 = fixed, <0 = disabled."""
@@ -40,7 +40,7 @@ def _start_admin(cfg, key: str, roles) -> Optional[object]:
         return None
     from pinot_tpu.utils.trace_store import DebugHttpServer
     try:
-        srv = DebugHttpServer(roles, port=port)
+        srv = DebugHttpServer(roles, port=port, routes=routes)
         srv.start()
     except OSError as e:
         # a debug-only surface must never take the data-serving role
@@ -340,9 +340,17 @@ class ServerRole:
     RT_PARTITION_TTL_S = 30.0
 
     def start(self) -> None:
+        if self.executor.use_tpu:
+            # the device is claimed and named HERE, not at the first
+            # query: a server that cannot reach its chip fails to start
+            # (JAX_PLATFORMS=tpu makes JAX raise instead of handing back
+            # CPU devices), and one that can says what it got
+            from pinot_tpu.ops.device import device_line
+            print(device_line(self.executor.device_report()), flush=True)
         self.transport.start()
         self.admin_http = _start_admin(
-            self.config, "pinot.server.admin.port", ["server"])
+            self.config, "pinot.server.admin.port", ["server"],
+            routes={"/debug/device": self.executor.device_report})
         if self.admin_http is not None:
             log.info("server %s admin http on %s:%s", self.instance_id,
                      self.admin_http.host, self.admin_http.port)

@@ -1,4 +1,4 @@
-"""Device execution backend: JAX/Pallas kernels for the query hot path.
+"""Device execution backend: jit'd JAX (XLA) kernels for the query hot path.
 
 This is the TPU-native rewrite of pinot-core's per-segment operator chain
 (SURVEY.md §3.2): instead of BlockDocIdSet iterators + per-block

@@ -43,15 +43,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
 from pinot_tpu.ops import kernels
 from pinot_tpu.ops.kernels import note_trace, plan_fingerprint
 from pinot_tpu.ops.plan_ir import DevicePlan
-
-try:
-    from jax import shard_map  # jax >= 0.8
-except ImportError:  # pragma: no cover — older jax
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def _merged_plan(plan: DevicePlan) -> DevicePlan:

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from pinot_tpu.ops import clp_device
 from pinot_tpu.ops import collective
+from pinot_tpu.ops import device as device_mod
 from pinot_tpu.ops import dispatch as dispatch_mod
 from pinot_tpu.ops import kernels
 from pinot_tpu.ops import startree_device
@@ -83,6 +84,7 @@ class TpuOperatorExecutor:
         through; None reads env/defaults).
         metrics_labels: labels for the dispatcher's metrics (the server
         passes its instance id)."""
+        device_mod.configure_compile_cache()
         self._doc_axis = 1
         #: collective broker merge engages only on an EXPLICIT mesh: the
         #: implicit >1-device segments mesh below keeps per-segment
@@ -153,8 +155,8 @@ class TpuOperatorExecutor:
             devices=self.devices)
         #: staging lock only: cache mutation (plan/stage/evict) serializes,
         #: but kernel dispatch + result fetch run OUTSIDE it so concurrent
-        #: queries overlap their device round trips (the host<->TPU link
-        #: costs ~100ms per sync; overlapped, N queries share that latency).
+        #: queries overlap their device round trips (every result fetch is
+        #: one host<->device sync; overlapped, N queries share its latency).
         #: Eviction drops cache references WITHOUT .delete(): the staging
         #: query itself and any concurrently dispatched kernels hold the
         #: block as an input, and JAX refcounting frees the HBM as soon as
@@ -1227,7 +1229,7 @@ class TpuOperatorExecutor:
         caches); the launch rides the dispatch ring, which coalesces
         fingerprint-equal concurrent queries into one batched kernel and
         fetches results off-ring — N server threads overlap their device
-        round trips instead of serializing behind one ~100ms sync each.
+        round trips instead of serializing behind one sync each.
         cancel_check: polled while the launch waits in the ring (a
         cancelled/deadline-expired query leaves its batch before launch).
         """
@@ -2455,7 +2457,7 @@ class TpuOperatorExecutor:
           HBM (ops/residency.py) — a changed batch (pruning picked a
           different subset, a new segment sealed) uploads ONLY rows the
           device has never seen, instead of re-shipping every column
-          over the ~100ms link.
+          from the host.
         * ASSEMBLED level, per (batch, column): the [S, D] block the
           kernel consumes, built ON-DEVICE from resident rows
           (kernels.compiled_row_assembler) — steady state is zero
@@ -2602,11 +2604,8 @@ class TpuOperatorExecutor:
     def _dev_label(arr) -> str:
         """`platform:id` label of the device holding a committed row —
         the key the per-chip residency ledger and `device=` gauges use."""
-        try:
-            d = next(iter(arr.devices()))
-            return f"{d.platform}:{d.id}"
-        except Exception:  # pragma: no cover — non-array stand-ins
-            return "cpu:0"
+        d = next(iter(arr.devices()))
+        return f"{d.platform}:{d.id}"
 
     def _reshard_block(self, dev):
         """Move an assembled single-device block onto the mesh sharding

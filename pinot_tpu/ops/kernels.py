@@ -304,8 +304,13 @@ def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
 
         k_chunks = keys[:, :main].reshape(S, nchunk, _ONEHOT_CHUNK).swapaxes(0, 1)
         c_chunks = contrib[:, :main].reshape(S, nchunk, _ONEHOT_CHUNK).swapaxes(0, 1)
-        out, _ = jax.lax.scan(body, jnp.zeros((S, num_groups), contrib.dtype),
-                              (k_chunks, c_chunks))
+        init = jnp.zeros((S, num_groups), contrib.dtype)
+        # under shard_map the chunks vary over the mesh axes, and scan
+        # requires its carry to enter with the type it leaves with
+        vma = tuple(jax.typeof(keys).vma | jax.typeof(contrib).vma)
+        if vma:
+            init = jax.lax.pcast(init, vma, to="varying")
+        out, _ = jax.lax.scan(body, init, (k_chunks, c_chunks))
         if main < D:
             out = _vmap_scatter(out, keys[:, main:], contrib[:, main:], "add")
         return out
@@ -541,8 +546,8 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
     params:  dict of per-leaf predicate arrays ('leaf<i>:lo/hi/idx/lut')
     num_docs: int32 [S] actual docs per segment (for the padding mask).
 
-    Returns ONE packed array — a single device->host fetch matters
-    because the host<->TPU link can cost O(100ms) per round trip:
+    Returns ONE packed array — every separate device->host fetch is a
+    sync the query would wait out in turn:
       no group-by: [S, 1 + n_slots]  (col 0 = matched doc count)
       group-by:    [S, G, n_slots]   (matched derived from the count
                                       slot host-side)
@@ -721,10 +726,7 @@ def make_sharded_kernel(plan: DevicePlan, mesh):
     mis-mask padding).
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map  # type: ignore
+    from jax import shard_map
 
     doc_shards = dict(zip(mesh.axis_names, mesh.devices.shape)).get("docs", 1)
     fp = plan_fingerprint(plan)
@@ -903,10 +905,7 @@ def make_batched_sharded_kernel(plan: DevicePlan, mesh, B: int,
     host platforms hold the CPU-collective lock once per BATCH.
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map  # jax >= 0.8
-    except ImportError:  # pragma: no cover — older jax
-        from jax.experimental.shard_map import shard_map  # type: ignore
+    from jax import shard_map
 
     doc_shards = dict(zip(mesh.axis_names, mesh.devices.shape)).get("docs", 1)
     fp = plan_fingerprint(plan)

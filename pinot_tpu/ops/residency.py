@@ -3,7 +3,7 @@
 The engine's device tier used to cache only whole stacked blocks keyed by
 the exact segment-batch tuple — a different pruned subset, or one newly
 sealed segment joining the batch, missed the device tier entirely and
-re-shipped EVERY column over the ~100ms host<->TPU link. This module
+re-shipped EVERY column from the host to the device. This module
 holds the unit that actually survives batch recomposition: one padded
 device row per (segment object, column kind), assembled into kernel-ready
 [S, D] blocks ON DEVICE (ops/kernels.compiled_row_assembler), so a new

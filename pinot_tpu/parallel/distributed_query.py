@@ -9,7 +9,7 @@ ops/kernels.py: column blocks [S, D] are sharded over BOTH mesh axes
   * psum over `segments` — combines per-segment partials into the final
     aggregate (replacing combine/BaseCombineOperator's merge +
     BrokerReduceService for the single-table case)
-via jax.experimental.shard_map, so the collectives are explicit and
+via jax.shard_map, so the collectives are explicit and
 compile to ICI all-reduces rather than relying on GSPMD inference.
 """
 from __future__ import annotations
@@ -19,12 +19,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-try:  # moved out of experimental in newer jax
-    from jax import shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map  # type: ignore
 
 
 def distributed_query_step(mesh: Mesh):

@@ -234,6 +234,17 @@ class ServerQueryExecutor:
                         "instance": self.data_manager.instance_id})
             return self._engine
 
+    def device_report(self) -> dict:
+        """The device the engine runs on, as JAX reports it (the server's
+        start-up line and its /debug/device route); builds the engine —
+        and with it the JAX backend — on first call. Empty when the
+        device path is off."""
+        engine = self._shared_engine()
+        if engine is None:
+            return {}
+        from pinot_tpu.ops.device import device_report
+        return device_report(engine.devices)
+
     def residency_report(self) -> dict:
         """Per-physical-table HBM-resident bytes this server can
         advertise in its heartbeat (the instance-sweep residency

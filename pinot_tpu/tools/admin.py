@@ -401,8 +401,18 @@ def cmd_add_table(args) -> int:
 def cmd_upload_segment(args) -> int:
     from pinot_tpu.controller.coordination import CoordinationClient
     client = CoordinationClient(args.coordinator)
-    r = client.upload_segment(args.table, args.segment_dir,
-                              table_type=args.table_type)
+    store_uri = client.get_state().get("deep_store_uri")
+    if store_uri:
+        # the controller has a deep store: push the tar there and
+        # register its URI, so servers download instead of sharing the
+        # build directory
+        from pinot_tpu.segment.fs import SegmentDeepStore
+        r = client.upload_segment_to_store(
+            args.table, args.segment_dir, SegmentDeepStore(store_uri),
+            table_type=args.table_type)
+    else:
+        r = client.upload_segment(args.table, args.segment_dir,
+                                  table_type=args.table_type)
     client.close()
     print(f"assigned to {r['segment']['instances']}")
     return 0

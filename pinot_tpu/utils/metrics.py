@@ -234,10 +234,10 @@ class MetricsRegistry:
         with self._lock:
             for (name, labels), v in sorted(self._meters.items()):
                 type_line(f"{prefix}{name}", "counter", name)
-                out.append(f"{prefix}{name}{_fmt(labels)} {v:g}")
+                out.append(f"{prefix}{name}{_fmt(labels)} {_num(v)}")
             for (name, labels), v in sorted(self._gauges.items()):
                 type_line(f"{prefix}{name}", "gauge", name)
-                out.append(f"{prefix}{name}{_fmt(labels)} {v:g}")
+                out.append(f"{prefix}{name}{_fmt(labels)} {_num(v)}")
             for (name, labels), t in sorted(self._timers.items()):
                 base = f"{prefix}{name}"
                 type_line(base, "summary", name)
@@ -268,6 +268,13 @@ def _escape_help(v: str) -> str:
     """HELP-text escaping per the exposition spec: backslash, newline
     (quotes stay literal in HELP lines)."""
     return str(v).replace("\\", "\\\\").replace("\n", "\\n")
+
+
+def _num(v: float) -> str:
+    """Exact sample value: `:g` keeps six digits, which hides a
+    kilobyte re-upload behind a gigabyte byte counter."""
+    f = float(v)
+    return str(int(f)) if f.is_integer() else repr(f)
 
 
 def _fmt(labels: Tuple[Tuple[str, str], ...]) -> str:

@@ -225,12 +225,14 @@ def debug_payload(role: str, path: str) -> Optional[Any]:
 class DebugHttpServer:
     """Tiny ops surface for roles without an HTTP edge (server, minion,
     cache server): /health, /metrics (exposition over the role's
-    registries), /debug/traces[/id], /debug/queries."""
+    registries), /debug/traces[/id], /debug/queries, plus the owner's
+    own JSON `routes` (path -> zero-arg callable)."""
 
     def __init__(self, roles: Sequence[str], host: str = "127.0.0.1",
-                 port: int = 0):
+                 port: int = 0, routes=None):
         roles = list(roles)
         primary = roles[0] if roles else "server"
+        routes = dict(routes or {})
 
         class _Handler(BaseHTTPRequestHandler):
             def log_message(self, *args):  # quiet
@@ -247,7 +249,8 @@ class DebugHttpServer:
                         for r in roles)
                     ctype = "text/plain"
                 else:
-                    payload = debug_payload(primary, path)
+                    payload = routes[path]() if path in routes \
+                        else debug_payload(primary, path)
                     if payload is None:
                         self.send_response(404)
                         self.end_headers()

@@ -208,7 +208,7 @@ class TestRetraceGuard:
         (compile odometer) nor ship ONE column byte host->device
         (transfer odometer): columns are resident, blocks are assembled
         and cached, params are plan-keyed. Either regression silently
-        re-pays the ~100ms link or a recompile per query in production."""
+        re-pays the host->device upload or a recompile per query in production."""
         from pinot_tpu.ops import residency
         eng = make_engine()
         ctxs = [QueryContext.from_sql(

@@ -11,11 +11,6 @@ import pytest
 
 import jax
 
-if not hasattr(jax, "enable_x64"):
-    # older jax: the context manager lives in jax.experimental
-    from jax.experimental import enable_x64 as _enable_x64
-    jax.enable_x64 = _enable_x64
-
 from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
                               TableConfig, TableType)
 from pinot_tpu.query.executor import QueryExecutor

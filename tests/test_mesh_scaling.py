@@ -351,6 +351,39 @@ class TestMeshCollectiveFailpoint:
         assert b"true" in j1, "the 0.5 coin never fired in 4 queries"
 
 
+class TestShardMapOneHotScan:
+    """G <= ONEHOT_MAX_GROUPS with >= _ONEHOT_CHUNK docs per doc shard
+    takes _scatter_sum's chunked one-hot `lax.scan` INSIDE shard_map —
+    the parity segments above (700 docs) never reach it, so only a bench
+    smoke leg used to."""
+
+    @pytest.mark.parametrize("merge", [True, False])
+    def test_small_group_by_over_scan_chunk(self, tmp_path, merge):
+        if len(jax.devices()) < 4:
+            pytest.skip("needs 4 virtual devices")
+        docs = 2 * kernels._ONEHOT_CHUNK - 91  # pads to 4096 per doc shard
+        segs = build_segments(
+            tmp_path, synthetic_schema(), synthetic_table_config(),
+            [synthetic_columns(docs, seed=977 + i) for i in range(2)])
+        labels = {"leg": f"onehot-scan-{merge}"}
+        engine = _mesh_engine(
+            4, 2, labels=labels,
+            **{"pinot.server.mesh.collective.merge": merge})
+        sql = ("SELECT groupCol, COUNT(*), SUM(intCol), MAX(rawIntCol) "
+               "FROM testTable WHERE intCol < 900 GROUP BY groupCol "
+               "ORDER BY groupCol LIMIT 50")
+        before = sum(1 for e in kernels.trace_log()
+                     if e["kind"] in ("sharded", "merged"))
+        _assert_parity(
+            QueryExecutor(segs, use_tpu=True, engine=engine).execute(sql),
+            QueryExecutor(segs, use_tpu=False).execute(sql))
+        traced = [e for e in kernels.trace_log()
+                  if e["kind"] in ("sharded", "merged")][before:]
+        assert traced, "group-by never reached a shard_map kernel"
+        reg = engine._dispatcher._metrics
+        assert (reg.meter("mesh_merge_served", labels=labels) > 0) == merge
+
+
 # tier-1 smoke of the acceptance driver
 class TestMeshBenchSmoke:
     def test_mesh_bench_smoke(self, tmp_path):
