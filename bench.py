@@ -305,185 +305,6 @@ def deadline_overhead_main():
         f"deadline checks cost {overhead_pct:.2f}% p50 (>{2.0}%)"
 
 
-def trace_overhead_main(smoke: bool = False):
-    """--trace-overhead [--smoke]: tracing-off must stay free (ISSUE 12).
-
-    Two paired A/B legs over identical MiniClusters in one process,
-    strictly interleaved so ambient drift hits both sides equally:
-
-    * off leg — pinot.trace.enabled=false (NO trace machinery: the
-      pre-PR request path) vs the default config with trace=false
-      (shadow span collection + tail capture armed). Asserts the shadow
-      machinery adds <2% p50.
-    * on leg — trace=false vs trace=true on the default cluster: the
-      full stitched cross-process tree (server trees shipped in every
-      response, per-op scopes, store retention). Reported and asserted
-      BOUNDED (<25% or <5ms absolute) — trace=true is a debugging mode,
-      not the hot path, but it must stay usable under load.
-
-    Writes BENCH_tracing.json; the smoke leg is tier-1 via
-    tests/test_tracing.py.
-    """
-    import statistics as stats
-    import tempfile
-
-    import numpy as np
-
-    from pinot_tpu.cluster.mini import MiniCluster
-    from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
-                                  TableConfig, TableType)
-    from pinot_tpu.segment.creator import SegmentCreator
-    from pinot_tpu.segment.loader import load_segment
-    from pinot_tpu.utils.config import PinotConfiguration
-
-    num_segments = 8 if smoke else 32
-    docs = 5_000 if smoke else 20_000
-    iters = 16 if smoke else 40
-    query = ("SELECT SUM(v), COUNT(*) FROM t "
-             "WHERE k BETWEEN 100 AND 800 OPTION(skipCache=true)")
-    traced_query = ("SET trace = true; " + query)
-
-    schema = Schema("t", [
-        FieldSpec("k", DataType.INT, FieldType.DIMENSION),
-        FieldSpec("v", DataType.INT, FieldType.METRIC),
-    ])
-    creator = SegmentCreator(TableConfig("t", TableType.OFFLINE), schema)
-    tmp = tempfile.mkdtemp(prefix="bench_tracing_")
-    segments = []
-    for i in range(num_segments):
-        rng = np.random.default_rng(i)
-        d = os.path.join(tmp, f"seg_{i}")
-        creator.build({"k": rng.integers(0, 1000, docs).astype(np.int32),
-                       "v": rng.integers(0, 100, docs).astype(np.int32)},
-                      d, f"t_{i}")
-        segments.append(load_segment(d))
-
-    def make_cluster(cfg):
-        c = MiniCluster(num_servers=2, config=cfg)
-        c.start()
-        c.add_table("t")
-        for i, seg in enumerate(segments):
-            c.add_segment("t", seg, server_idx=i % 2)
-        return c
-
-    off_cfg = PinotConfiguration(
-        overrides={"pinot.trace.enabled": False})
-    on_cfg = PinotConfiguration()  # defaults: shadow tracing armed
-    c_off = make_cluster(off_cfg)
-    c_on = make_cluster(on_cfg)
-
-    def one(c, q):
-        t0 = time.perf_counter()
-        resp = c.query(q)
-        assert not resp.exceptions, resp.exceptions
-        return (time.perf_counter() - t0) * 1e3
-
-    def paired_pct(run_a, run_b, n):
-        """Median of per-pair ratios, back-to-back A/B per iteration
-        with ALTERNATING order (a,b / b,a) — ambient drift cancels per
-        pair and a fixed-order bias (the second call riding the first's
-        cache/scheduler warmth) cancels across pairs."""
-        ratios, deltas, a_lat, b_lat = [], [], [], []
-        for i in range(n):
-            if i % 2 == 0:
-                a = run_a()
-                b = run_b()
-            else:
-                b = run_b()
-                a = run_a()
-            a_lat.append(a)
-            b_lat.append(b)
-            ratios.append(b / a)
-            deltas.append(b - a)
-        return ((stats.median(ratios) - 1.0) * 100.0,
-                stats.median(deltas),
-                stats.median(a_lat), stats.median(b_lat))
-
-    try:
-        # warm both clusters (JIT, routing, sockets, thread pools)
-        for _ in range(8):
-            one(c_off, query), one(c_on, query)
-        # A/A noise floor: the same cluster against itself — whatever
-        # "overhead" this shows is measurement noise, and the real
-        # assertions must clear it, not just the 2% target. BOTH
-        # clusters stay equally exercised during the floor pass: an
-        # idle cluster cools (scheduler/socket warmth) and would bias
-        # leg 1 against it.
-        noise_pct, _, _, _ = paired_pct(
-            lambda: one(c_off, query),
-            lambda: (one(c_on, query), one(c_off, query))[1], iters)
-        noise_pct = abs(noise_pct)
-
-        # -- leg 1: machinery off vs shadow-on, trace=false both sides
-        shadow_pct, shadow_delta_ms, p50_off, p50_shadow = paired_pct(
-            lambda: one(c_off, query), lambda: one(c_on, query), iters)
-
-        # -- leg 2: trace=false vs trace=true on the shadow cluster
-        for _ in range(3):
-            one(c_on, traced_query)
-        traced_pct, traced_delta_ms, p50_plain, p50_traced = paired_pct(
-            lambda: one(c_on, query), lambda: one(c_on, traced_query),
-            iters)
-        resp = c_on.query(traced_query)
-        assert resp.trace is not None, "trace=true returned no traceInfo"
-        assert any(ch.get("operator") == "ServerScatter"
-                   for ch in resp.trace.get("children", ())), resp.trace
-    finally:
-        c_off.stop()
-        c_on.stop()
-
-    out = {
-        "metric": "tracing_off_overhead_pct",
-        "value": round(shadow_pct, 3),
-        "unit": "%",
-        "p50_off_ms": round(p50_off, 3),
-        "p50_shadow_ms": round(p50_shadow, 3),
-        "p50_traced_ms": round(p50_traced, 3),
-        "traced_overhead_pct": round(traced_pct, 3),
-        "shadow_paired_delta_ms": round(shadow_delta_ms, 3),
-        "traced_paired_delta_ms": round(traced_delta_ms, 3),
-        "aa_noise_floor_pct": round(noise_pct, 3),
-        "num_segments": num_segments,
-        "docs_per_segment": docs,
-        "iters": iters,
-        "smoke": smoke,
-        "asserted_max_pct": 2.0,
-        "asserted_traced_max_pct": 25.0,
-    }
-    if not smoke:
-        with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                               "BENCH_tracing.json"), "w") as f:
-            json.dump(out, f, indent=2)
-    print(json.dumps(out))
-    # the A/A floor + absolute epsilon absorb shared-box scheduler noise
-    # (paired ratios already cancel drift; what's left is jitter) — the
-    # shadow cost itself is a handful of dict/list ops per query, far
-    # below either bound. The traced bound is deliberately loose (debug
-    # mode): it exists to catch accidental O(rows) work on the span path.
-    # the smoke leg runs inside tier-1 on whatever box CI gives it, and
-    # a loaded 2-core host shows A/A floors of 3-8% — it simply cannot
-    # resolve a 2% delta (the floor itself is one noisy draw). The
-    # STRICT <2% bar belongs to the full run (the committed
-    # BENCH_tracing.json); smoke asserts the qualitative contract (the
-    # stitched trace exists, tracing-off is not MULTI-ms/tens-of-percent
-    # more expensive) so a real O(ms) regression on the shadow path
-    # still fails tier-1 without the noise flaking it
-    if smoke:
-        shadow_bound = max(25.0, 2.0 * noise_pct + 5.0)
-        shadow_eps_ms = max(2.0, 0.10 * p50_off)
-    else:
-        shadow_bound = max(2.0, noise_pct + 1.0)
-        shadow_eps_ms = 0.5
-    assert shadow_pct < shadow_bound or shadow_delta_ms < shadow_eps_ms, \
-        (f"shadow tracing costs {shadow_pct:.2f}% p50 "
-         f"({shadow_delta_ms:.3f}ms paired; bound {shadow_bound:.2f}%, "
-         f"A/A floor {noise_pct:.2f}%)")
-    traced_eps_ms = max(5.0, 0.25 * p50_plain) if smoke else 5.0
-    assert traced_pct < max(25.0, 2.0 * noise_pct + 25.0) \
-        or traced_delta_ms < traced_eps_ms, \
-        f"trace=true costs {traced_pct:.2f}% p50 (>25%)"
-
-
 def concurrency_main(smoke: bool = False):
     """--concurrency [--smoke]: A/B the dispatch pipeline (ISSUE 4).
 
@@ -2830,7 +2651,7 @@ def health_main(smoke: bool = False, out_path: "str | None" = None):
     """--health [--smoke]: the fleet health plane must be ~free (ISSUE 14).
 
     Two overhead legs over identical MiniClusters in one process, with
-    an A/A noise floor like --trace-overhead:
+    an A/A noise floor:
 
     * accounting leg — pinot.workload.accounting.enabled=false (no
       ChargeSlips, no WorkloadStats rollup) vs on (the default):
@@ -3010,7 +2831,7 @@ def health_main(smoke: bool = False, out_path: "str | None" = None):
         with open(out_path, "w") as f:
             json.dump(out, f, indent=2)
     print(json.dumps(out))
-    # bounds mirror --trace-overhead: the STRICT <2% bar belongs to the
+    # the STRICT <2% bar belongs to the
     # full run (the committed BENCH_health.json); smoke runs inside
     # tier-1 on a loaded CI box whose A/A floor alone can be 3-8%, so it
     # asserts the qualitative contract (no multi-ms / tens-of-percent
@@ -5077,8 +4898,6 @@ def timeseries_main(smoke: bool = False, out_path: str = None):
 if __name__ == "__main__":
     if "--deadline-overhead" in sys.argv:
         deadline_overhead_main()
-    elif "--trace-overhead" in sys.argv:
-        trace_overhead_main(smoke="--smoke" in sys.argv)
     elif "--concurrency" in sys.argv:
         concurrency_main(smoke="--smoke" in sys.argv)
     elif "--residency" in sys.argv:

@@ -32,7 +32,23 @@ the hot path here is ONE device program per query, not N segment tasks:
     pool OFF the ring, so the next launch overlaps the previous fetch;
     `execute_async` staging runs on a staging pool so host-side padding
     + `jax.device_put` for query N+1 proceed while query N's kernel
-    occupies the device (`staging_overlap_ms` measures exactly that).
+    occupies the device.
+
+Every wait of a traced launch is measured where it happens and lands on
+its `DeviceDispatch` span: `queueWaitMs` (submit -> popped off the ring;
+inline: submit -> launch; `submitMs` before it, staged -> submitted;
+on the ring `dispatchMs` after it, popped -> the launch call starts),
+then `launchMs` (the kernel call returning:
+trace + compile on a first shape, else the asynchronous enqueue),
+`deviceWaitMs` (launch returned -> `jax.block_until_ready`: a HOST
+clock, an upper bound on device time, never kernel time) and `d2hMs`
+(`np.asarray`: the device->host copy), with the wall-clock stamps
+`launchNs` / `readyNs` round launch + device wait, and last `handoffMs`
+(copy landed -> the caller's thread holds the result). `kernelMs`/`fetchMs`
+keep their older, coarser meaning (see `_submit_serialized`). The same
+phases go to the jax profiler's host plane as `pinot:<phase>` trace
+annotations (`phase_annotation`), so an xprof view shows them above the
+device ops.
 
 Deadline/cancel checks are honored while a launch waits in the ring: a
 cancelled query's future fails and the query leaves its batch before
@@ -194,6 +210,29 @@ def wait_result(future: Future, cancel_check=None,
                     f"(dispatcher wedged?)") from None
 
 
+def phase_annotation(phase: str, span):
+    """`jax.profiler.TraceAnnotation("pinot:<phase>")` round one host
+    phase of a TRACED launch (span = its DeviceDispatch handle): level
+    1, which a profiler started with host_tracer_level >= 1 records on
+    this thread's line, and a few hundred ns when none runs. Untraced
+    launches (span None: pinot.trace.enabled=false) make no call."""
+    if span is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation("pinot:" + phase,
+                                        trace_id=span.trace_id or "")
+
+
+def start_copy(out) -> None:
+    """Queue the device->host copy of a just-launched result behind its
+    kernel, as `np.asarray` on the unready array used to: the explicit
+    wait for the device that follows (so that device wait and copy each
+    get a clock) then costs no second round trip to the device. Kernel
+    stand-ins that return host arrays have nothing to start."""
+    start = getattr(out, "copy_to_host_async", None)
+    if start is not None:
+        start()
+
+
 def split_packed(arr: np.ndarray, n: int) -> List[np.ndarray]:
     """Zero-copy per-member split of a batched result fetch (ROADMAP
     item): the N coalesced callers receive VIEWS into the ONE packed
@@ -244,6 +283,77 @@ def split_charge(live: List["Launch"], kernel_ms: float) -> None:
             pass
 
 
+class _LaunchClock:
+    """The clock reads round one TRACED kernel launch, turned into its
+    DeviceDispatch span attrs; an untraced launch (span None) makes none
+    of them. Monotonic seconds time the phases, `time.time_ns()` stamps
+    launch and ready on the wall clock the spans' `startNs` share."""
+
+    __slots__ = ("traces0", "t0", "launch_ns", "attrs")
+
+    def __init__(self, popped: Optional[float] = None):
+        self.traces0 = kernels.trace_count()
+        self.t0 = time.monotonic()
+        self.launch_ns = time.time_ns()
+        self.attrs: Dict[str, Any] = {}
+        if popped is not None:
+            # ring path: popped off the ring -> the launch call starts
+            # (the batch's inputs put together, the launch pool's
+            # hand-off); queueWaitMs ends where this begins
+            self.attrs["dispatchMs"] = round((self.t0 - popped) * 1e3, 3)
+
+    def launched(self) -> None:
+        """The kernel call returned. A call that traced a kernel says
+        so: `retraceEvents` (best-effort: a concurrent launch's trace
+        may land in the window) and `compileMs`, the call's own time —
+        trace + lower + compile or cache load, an upper bound on the
+        compile."""
+        self.t0, t0 = time.monotonic(), self.t0
+        launch_ms = round((self.t0 - t0) * 1e3, 3)
+        self.attrs.update(launchNs=self.launch_ns, launchMs=launch_ms)
+        retraces = kernels.trace_count() - self.traces0
+        if retraces > 0:
+            self.attrs.update(retraceEvents=retraces, compileMs=launch_ms)
+
+    def ready(self) -> None:
+        """jax.block_until_ready returned. deviceWaitMs is a HOST clock
+        from the launch call returning: queueing behind other launches
+        on the device and the wake-up are in it, so it bounds device
+        time from above and is never kernel time."""
+        self.t0, t0 = time.monotonic(), self.t0
+        self.attrs.update(readyNs=time.time_ns(),
+                          deviceWaitMs=round((self.t0 - t0) * 1e3, 3))
+
+    def copied(self, live: List["Launch"]) -> None:
+        """np.asarray returned: the device->host copy. Each member's
+        hand-over to its caller starts here (Launch.end_span)."""
+        self.t0, t0 = time.monotonic(), self.t0
+        self.attrs["d2hMs"] = round((self.t0 - t0) * 1e3, 3)
+        for it in live:
+            it.done_ts = self.t0
+
+
+class _NoClock:
+    """An untraced launch's clock: reads nothing, holds no attrs."""
+
+    attrs: Dict[str, Any] = {}
+
+    def launched(self) -> None:
+        pass
+
+    ready = launched
+
+    def copied(self, live) -> None:
+        pass
+
+
+_NO_CLOCK = _NoClock()
+
+
+def _clock_for(span, popped: Optional[float] = None):
+    return _NO_CLOCK if span is None else _LaunchClock(popped)
+
+
 class Launch:
     """One staged device launch waiting in the ring.
 
@@ -264,7 +374,7 @@ class Launch:
     __slots__ = ("call", "plan", "cols", "params", "num_docs", "D", "G",
                  "batch_key", "cols_key", "factory", "dedup_factory",
                  "collective", "cancel_check", "site_ctx", "future",
-                 "span", "enq_ts", "slip", "docs")
+                 "span", "enq_ts", "staged_ts", "done_ts", "slip", "docs")
 
     def __init__(self, call: Callable[[], Any], plan=None, cols=None,
                  params=None, num_docs=None, D: int = 0, G: int = 0,
@@ -275,7 +385,8 @@ class Launch:
                  collective: bool = False,
                  cancel_check: Optional[Callable[[], None]] = None,
                  site_ctx: Optional[Dict[str, Any]] = None,
-                 span=None, slip=None, docs: int = 0):
+                 span=None, slip=None, docs: int = 0,
+                 staged_ts: float = 0.0):
         self.call = call
         self.plan = plan
         self.cols = cols
@@ -305,7 +416,36 @@ class Launch:
         self.slip = slip
         #: real docs staged for this member (the cost-split weight)
         self.docs = int(docs)
+        #: time.monotonic() of a TRACED launch's two hand-overs (0.0 =
+        #: not taken): staging done (the engine's, under its lock) and
+        #: the result's copy landed (the dispatcher's); with enq_ts they
+        #: give submitMs and handoffMs
+        self.staged_ts = staged_ts
+        self.done_ts = 0.0
         self.enq_ts = 0.0
+
+    def queue_attrs(self, now: float) -> Dict[str, Any]:
+        """Span attrs of the way into the ring, at `now` (popped for
+        launch): submitMs (staged -> submitted: coalesce key, this
+        object, the ring's lock) and queueWaitMs (submitted -> now)."""
+        attrs = {"queueWaitMs": round((now - self.enq_ts) * 1e3, 3)
+                 if self.enq_ts else 0.0}
+        if self.staged_ts and self.enq_ts:
+            attrs["submitMs"] = round(
+                (self.enq_ts - self.staged_ts) * 1e3, 3)
+        return attrs
+
+    def end_span(self) -> None:
+        """End the DeviceDispatch span on the thread that now holds the
+        result: handoffMs runs from the copy landed to here (the
+        dispatcher's bookkeeping, the future's wake-up, the GIL)."""
+        if self.span is None:
+            return
+        if self.done_ts:
+            self.span.end(handoffMs=round(
+                (time.monotonic() - self.done_ts) * 1e3, 3))
+        else:
+            self.span.end()
 
 
 class KernelDispatcher:
@@ -354,12 +494,10 @@ class KernelDispatcher:
         #: the batching window only waits when >1 (a lone client never
         #: pays window latency for a batch that cannot form)
         self._active = 0
-        # device-busy clock: wall time with >=1 launch in flight, so
-        # staging can measure how much of itself overlapped compute
+        #: launches in flight (launched, result not yet fetched): a lone
+        #: submit takes the inline fast path only while this is 0
         self._busy_lock = threading.Lock()
         self._inflight = 0
-        self._busy_accum = 0.0
-        self._busy_since = 0.0
         self._trace_seen = kernels.trace_count()
         self._trace_seen_by_plan = kernels.trace_count_by_plan()
         self._trace_meter_lock = threading.Lock()
@@ -383,26 +521,14 @@ class KernelDispatcher:
             self._active = max(0, self._active - 1)
             self._cv.notify_all()
 
-    # -- device-busy clock --------------------------------------------
+    # -- in-flight count ----------------------------------------------
     def _busy_begin(self) -> None:
         with self._busy_lock:
-            if self._inflight == 0:
-                self._busy_since = time.monotonic()
             self._inflight += 1
 
     def _busy_end(self) -> None:
         with self._busy_lock:
             self._inflight -= 1
-            if self._inflight == 0:
-                self._busy_accum += time.monotonic() - self._busy_since
-
-    def busy_ms(self) -> float:
-        """Cumulative wall-ms during which >=1 launch was in flight."""
-        with self._busy_lock:
-            total = self._busy_accum
-            if self._inflight > 0:
-                total += time.monotonic() - self._busy_since
-        return total * 1e3
 
     # -- metrics helpers ----------------------------------------------
     def observe(self, name: str, value: float) -> None:
@@ -523,24 +649,36 @@ class KernelDispatcher:
                 launch.cancel_check()
             guard = _CPU_COLLECTIVE_LOCK if launch.collective \
                 else contextlib.nullcontext()
+            span = launch.span
             self._busy_begin()
+            clock = _clock_for(span)
             t0 = time.monotonic()
             try:
                 with guard:
-                    packed = np.asarray(launch.call())
+                    with phase_annotation("launch", span):
+                        out = launch.call()
+                        start_copy(out)
+                    clock.launched()
+                    with phase_annotation("device_wait", span):
+                        jax.block_until_ready(out)
+                    clock.ready()
+                    with phase_annotation("d2h", span):
+                        packed = np.asarray(out)
+                    clock.copied([launch])
             finally:
                 self._busy_end()
                 self._meter_traces()
             kernel_ms = (time.monotonic() - t0) * 1e3
-            if launch.span is not None:
-                # inline path: kernel + fetch are one sync round trip
-                launch.span.set(
-                    queueWaitMs=round(
-                        (t0 - launch.enq_ts) * 1e3, 3)
-                    if launch.enq_ts else 0.0,
+            if span is not None:
+                # inline path: kernelMs is the whole sync round trip
+                # (launch + device wait + copy) and fetchMs 0 — the
+                # older, coarser pair the accounting and the benchmark's
+                # launch_fetch_ms read; launchMs/deviceWaitMs/d2hMs are
+                # its three parts
+                span.set(
                     batchSize=1, variant="inline",
                     kernelMs=round(kernel_ms, 3),
-                    fetchMs=0.0)
+                    fetchMs=0.0, **launch.queue_attrs(t0), **clock.attrs)
             split_charge([launch], kernel_ms)
             launch.future.set_result(packed)
         except BaseException as e:  # noqa: BLE001 — future carries it
@@ -624,10 +762,7 @@ class KernelDispatcher:
             if it.span is not None:
                 # each coalesced member reports into its OWN trace: the
                 # shared launch's facts land on N distinct span trees
-                it.span.set(
-                    queueWaitMs=round((now - it.enq_ts) * 1e3, 3)
-                    if it.enq_ts else 0.0,
-                    batchSize=len(live))
+                it.span.set(batchSize=len(live), **it.queue_attrs(now))
         batched = len(live) > 1
         if batched:
             # pad to the batch-size bucket with replicated leader inputs
@@ -705,23 +840,31 @@ class KernelDispatcher:
             # process-wide; block on the ring (compute completion), then
             # hand the ready buffers to the fetch pool so the NEXT
             # launch overlaps this result's host assembly
+            span = self._lead_span(live)
+            clock = _clock_for(span, now)
             self._busy_begin()
             t0 = time.monotonic()
             try:
                 with _CPU_COLLECTIVE_LOCK:
-                    out = call()
-                    jax.block_until_ready(out)
+                    with phase_annotation("launch", span):
+                        out = call()
+                        start_copy(out)
+                    clock.launched()
+                    with phase_annotation("device_wait", span):
+                        jax.block_until_ready(out)
+                    clock.ready()
             except BaseException as e:  # noqa: BLE001
                 self._busy_end()
                 for it in live:
                     it.future.set_exception(e)
                 return
             fetch_pool().submit(self._finish, live, out, batched,
-                                (time.monotonic() - t0) * 1e3)
+                                (time.monotonic() - t0) * 1e3, clock)
         else:
             # fully concurrent submission (real accelerators order their
             # own queue; non-partitioned host programs don't rendezvous)
-            launch_pool().submit(self._run_and_finish, live, call, batched)
+            launch_pool().submit(self._run_and_finish, live, call, batched,
+                                 now)
 
     def _coalesce(self, leader: Launch) -> List[Launch]:
         """Collect fingerprint-equal launches behind the leader, waiting
@@ -750,40 +893,57 @@ class KernelDispatcher:
             self._set_depth_locked()
         return batch
 
-    def _run_and_finish(self, live: List[Launch], call, batched: bool) -> None:
+    @staticmethod
+    def _lead_span(live: List[Launch]):
+        """The span a shared launch's profiler annotations are tagged
+        with: the first traced member's (its trace id names the batch)."""
+        return next((it.span for it in live if it.span is not None), None)
+
+    def _run_and_finish(self, live: List[Launch], call, batched: bool,
+                        popped: float) -> None:
+        span = self._lead_span(live)
+        clock = _clock_for(span, popped)
         self._busy_begin()
         t0 = time.monotonic()
-        traces0 = kernels.trace_count()
         try:
-            out = call()
+            with phase_annotation("launch", span):
+                out = call()
+                start_copy(out)
         except BaseException as e:  # noqa: BLE001
             self._busy_end()
             for it in live:
                 it.future.set_exception(e)
             self._meter_traces()
             return
-        kernel_ms = (time.monotonic() - t0) * 1e3
-        # best-effort retrace attribution: a concurrent launch's trace
-        # could land in this window, but a retrace on the steady path is
-        # a bug worth a loud mark either way
-        retraces = kernels.trace_count() - traces0
-        if retraces > 0:
-            for it in live:
-                if it.span is not None:
-                    it.span.set(retraceEvents=retraces)
-        self._finish(live, out, batched, kernel_ms)
+        clock.launched()
+        self._finish(live, out, batched, (time.monotonic() - t0) * 1e3,
+                     clock)
 
     def _finish(self, live: List[Launch], out, batched: bool,
-                kernel_ms: Optional[float] = None) -> None:
-        """Fetch (device->host) + split per caller; runs OFF the ring.
-        The busy interval (opened at launch) closes when the fetch lands
-        — and BEFORE the futures resolve: a caller woken by its result
-        must observe an idle dispatcher, or its next lone submit would
-        race the busy bookkeeping and needlessly take the ring path
-        (the inline fast path is what keeps lone p50 at the floor)."""
+                kernel_ms: float, clock) -> None:
+        """Wait for the device, fetch (device->host) + split per caller;
+        runs OFF the ring. `clock` holds a traced launch's reads so far
+        (launched, and ready where the ring already waited under the
+        collective lock); coalesced members each get the batch's values,
+        as they do kernelMs (ring path: the launch call, plus the wait
+        on the collective path, where d2hMs also holds the hand-off
+        to the fetch pool) and fetchMs (the rest: device wait + copy).
+        The busy interval (opened at launch) closes when the
+        fetch lands — and BEFORE the futures resolve: a caller woken by
+        its result must observe an idle dispatcher, or its next lone
+        submit would race the busy bookkeeping and needlessly take the
+        ring path (the inline fast path is what keeps lone p50 at the
+        floor)."""
+        span = self._lead_span(live)
         t0 = time.monotonic()
         try:
-            arr = np.asarray(out)
+            if "readyNs" not in clock.attrs:
+                with phase_annotation("device_wait", span):
+                    jax.block_until_ready(out)
+                clock.ready()
+            with phase_annotation("d2h", span):
+                arr = np.asarray(out)
+            clock.copied(live)
         except BaseException as e:  # noqa: BLE001
             self._busy_end()
             self._meter_traces()
@@ -796,11 +956,9 @@ class KernelDispatcher:
         fetch_ms = (time.monotonic() - t0) * 1e3
         for it in live:
             if it.span is not None:
-                it.span.set(fetchMs=round(fetch_ms, 3),
-                            **({"kernelMs": round(kernel_ms, 3)}
-                               if kernel_ms is not None else {}))
-        if kernel_ms is not None:
-            split_charge(live, kernel_ms)
+                it.span.set(kernelMs=round(kernel_ms, 3),
+                            fetchMs=round(fetch_ms, 3), **clock.attrs)
+        split_charge(live, kernel_ms)
         try:
             if batched:
                 for member, it in zip(split_packed(arr, len(live)), live):

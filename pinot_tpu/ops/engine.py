@@ -19,6 +19,7 @@ Responsibilities:
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 from collections import OrderedDict
@@ -169,6 +170,12 @@ class TpuOperatorExecutor:
         #: bounded LRU (hot filter parameters survive cache pressure
         #: instead of a wholesale clear dropping them all at once)
         self._params_cache: "OrderedDict[tuple, Any]" = OrderedDict()
+        #: `_put` calls so far, and the running staging pass's parameter
+        #: part (seconds, puts): written under the engine lock, read by
+        #: `_staging_attrs` into paramsMs / paramPuts
+        self._puts = 0
+        self._params_s = 0.0
+        self._param_puts = 0
         #: pipelined dispatch stage: ring + micro-batching + fetch
         #: overlap (ops/dispatch.py); owns NO engine state — staging
         #: stays under the engine lock, launches ride the ring
@@ -395,16 +402,14 @@ class TpuOperatorExecutor:
         """Plan + stage under the engine lock (they mutate the block
         caches), then wrap the launch for the dispatch ring. Returns
         (plan, slots_of_fn, S_real, Launch), or None -> host fallback.
-        The staging_overlap_ms histogram records how much of this staging
-        ran while another query's kernel occupied the device — the
-        pipeline's third leg (staging/compute overlap).
 
         parent_span: explicit tracing.SpanHandle for callers off the
         request thread (execute_async stages on the staging pool, where
         the trace contextvar doesn't flow); sync callers inherit the
-        contextvar. The DeviceDispatch child span carries staging ms,
-        residency hit/miss counts, and host->device transfer bytes —
-        exact per query because staging holds the engine lock."""
+        contextvar. The DeviceDispatch child span carries the wait for
+        the engine lock, staging ms split into plan / block look-ups /
+        parameter puts, and host->device transfer bytes — exact per
+        query because staging holds the engine lock (_staging_lock)."""
         if parent_span is None:
             parent_span = tracing.capture()
         dsp = None
@@ -412,15 +417,13 @@ class TpuOperatorExecutor:
             dsp = parent_span.child("DeviceDispatch", table=ctx.table,
                                     mode="agg")
         from pinot_tpu.ops import residency as residency_mod
-        busy0 = self._dispatcher.busy_ms()
-        with self._engine_lock:
-            # snapshot INSIDE the lock: the diff must cover exactly this
-            # query's staging, not a concurrent stager's (the transfer
-            # odometer diff below is exact per query for the same reason)
+        with self._staging_lock(dsp) as stage_info:
+            # odometer read INSIDE the lock: the diff must cover exactly
+            # this query's staging, not a concurrent stager's
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            stage_info = self._staging_snapshot(dsp)
             plan_info = self._plan(segments, ctx)
             if plan_info is None:
+                self.scan_fallback("plan")
                 if dsp is not None:
                     dsp.end(outcome="hostFallback")
                 return None
@@ -445,15 +448,17 @@ class TpuOperatorExecutor:
                 dedup_factory = (lambda B, U, _p=plan:
                                  kernels.compiled_batched_dedup_kernel(
                                      _p, B, U))
+            t_plan = time.perf_counter()
             try:
                 cols, params, num_docs, S_real, D, G = self._stage(
                     segments, ctx, plan, batchable=batchable)
             except _NotStageable:
+                self.scan_fallback("staging")
                 if dsp is not None:
                     dsp.end(outcome="hostFallback")
                 return None
-            self._staging_attrs(dsp, stage_info, S=int(num_docs.shape[0]),
-                                D=D, G=G)
+            staged_ts = self._staging_attrs(
+                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D, G=G)
             # collective broker merge (ops/collective.py): fold the
             # per-segment partials on device — one psum/pmin/pmax over
             # the whole mesh — instead of shipping [S, ...] rows to the
@@ -484,9 +489,7 @@ class TpuOperatorExecutor:
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
-        overlap = self._dispatcher.busy_ms() - busy0
-        if overlap > 0:
-            self._dispatcher.observe("staging_overlap_ms", overlap)
+        self._meter("scan_served")
         G_eff = G
         if minfo is not None:
             self._meter("mesh_merge_served")
@@ -523,7 +526,8 @@ class TpuOperatorExecutor:
             collective=self._needs_cpu_ordering(kernel),
             cancel_check=cancel_check,
             site_ctx={"table": ctx.table, "mode": "agg"}, span=dsp,
-            slip=slip, docs=sum(s.num_docs for s in segments))
+            slip=slip, docs=sum(s.num_docs for s in segments),
+            staged_ts=staged_ts)
         return plan, slots_of_fn, S_real, launch, minfo
 
     # ------------------------------------------------------------------
@@ -538,11 +542,7 @@ class TpuOperatorExecutor:
     def _merge_fallback(self, reason: str) -> None:
         """mesh_merge_fallback{reason=}: why an eligible mesh launch kept
         the host IndexedTable fold (labeled like startree_fallback)."""
-        if self._metrics is None:
-            return
-        labels = dict(self._labels or {})
-        labels["reason"] = reason
-        self._metrics.add_meter("mesh_merge_fallback", 1, labels=labels)
+        self._meter("mesh_merge_fallback", reason=reason)
 
     def _merged_prepare(self, segments, plan: DevicePlan, params,
                         S_real: int, S: int, G_local: int):
@@ -684,24 +684,26 @@ class TpuOperatorExecutor:
                 return False
         return True
 
+    def scan_fallback(self, reason: str) -> None:
+        """scan_fallback{reason=}: why a query the device could have
+        scanned ran on the host instead — `unsupported` (supports() said
+        no: QueryExecutor meters it beside that call), `plan` (no
+        DevicePlan for these segments), `staging` (a column or literal
+        would not stage). With `scan_served` the plain leg's routing
+        shows in /metrics without a trace, as the star-tree, CLP, vector
+        and mesh legs' does."""
+        self._meter("scan_fallback", reason=reason)
+
     def _st_fallback(self, reason: str) -> None:
         """startree_fallback{reason=}: why a tree-carrying batch went to
         the scan path (labeled like server_admission_rejected)."""
-        if self._metrics is None:
-            return
-        labels = dict(self._labels or {})
-        labels["reason"] = reason
-        self._metrics.add_meter("startree_fallback", labels=labels)
+        self._meter("startree_fallback", reason=reason)
 
     def _clp_fallback(self, reason: str) -> None:
         """clp_fallback{reason=}: why a LIKE/regex over a CLP column left
         the device path (pattern outside the pushable subset, slot caps,
         staging failure, ...) — vocabulary in clp_device.FALLBACK_REASONS."""
-        if self._metrics is None:
-            return
-        labels = dict(self._labels or {})
-        labels["reason"] = reason
-        self._metrics.add_meter("clp_fallback", labels=labels)
+        self._meter("clp_fallback", reason=reason)
 
     def _clp_leaf(self, e: Function, segments, col: str):
         """'clp' DeviceLeaf for a LIKE/regexp_like predicate over a
@@ -730,11 +732,7 @@ class TpuOperatorExecutor:
         """vector_fallback{reason=}: why a vector_similarity query left
         the device path for the host index search — vocabulary in
         vector_device.FALLBACK_REASONS."""
-        if self._metrics is None:
-            return
-        labels = dict(self._labels or {})
-        labels["reason"] = reason
-        self._metrics.add_meter("vector_fallback", labels=labels)
+        self._meter("vector_fallback", reason=reason)
 
     def _plan_vector(self, segments, ctx: QueryContext):
         """(VectorPlan, (vector fn, qvec, k), residual ctx) when the ANN
@@ -810,6 +808,7 @@ class TpuOperatorExecutor:
                 (lambda seg: vector_device.cell_row(
                     seg, plan.col, _pow2(seg.num_docs))),
                 np.int32, tuple(_pow2(s.num_docs) for s in segments))
+        pmark = self._params_begin()
         pkey = (_batch_id(segments), plan, fn, "__vec__", S)
         cached = self._params_cache.get(pkey)
         if cached is not None:
@@ -817,6 +816,7 @@ class TpuOperatorExecutor:
             if all(a is b for a, b in zip(csegs, segments)):
                 self._params_cache.move_to_end(pkey)
                 params.update(cparams)
+                self._params_end(pmark)
                 return cols, params, num_docs, S_real, D
         qp = vector_device.query_params(segments, plan, qvec, k, S)
         vparams = {key: self._put(arr) for key, arr in qp.items()}
@@ -825,6 +825,7 @@ class TpuOperatorExecutor:
         self._params_cache.move_to_end(pkey)
         while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
             self._params_cache.popitem(last=False)
+        self._params_end(pmark)
         return cols, params, num_docs, S_real, D
 
     def _vec_block_locked(self, segments, S, W, col, leg, fetch, dtype,
@@ -906,9 +907,8 @@ class TpuOperatorExecutor:
         if parent_span is not None:
             dsp = parent_span.child("DeviceDispatch", table=ctx.table,
                                     mode="vector")
-        with self._engine_lock:
+        with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            stage_info = self._staging_snapshot(dsp)
             plan, qinfo, rctx = self._plan_vector(segments, ctx)
             if plan is None:
                 self._vector_fallback(qinfo)
@@ -918,6 +918,7 @@ class TpuOperatorExecutor:
             fn, qvec, k = qinfo
             kernel = vector_device.compiled_vector_kernel(plan)
             batchable = isinstance(kernel, jax.stages.Wrapped)
+            t_plan = time.perf_counter()
             try:
                 cols, params, num_docs, S_real, D = \
                     self._stage_vector_locked(segments, rctx, plan, fn,
@@ -927,8 +928,8 @@ class TpuOperatorExecutor:
                 if dsp is not None:
                     dsp.end(outcome="hostFallback", reason="staging")
                 return None
-            self._staging_attrs(dsp, stage_info, S=int(num_docs.shape[0]),
-                                D=D)
+            staged_ts = self._staging_attrs(
+                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D)
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
@@ -953,7 +954,8 @@ class TpuOperatorExecutor:
             collective=self._needs_cpu_ordering(kernel),
             cancel_check=cancel_check,
             site_ctx={"table": ctx.table, "mode": "vector"}, span=dsp,
-            slip=slip, docs=sum(s.num_docs for s in segments))
+            slip=slip, docs=sum(s.num_docs for s in segments),
+            staged_ts=staged_ts)
         return plan, S_real, launch
 
     def _execute_vector(self, segments, ctx: QueryContext,
@@ -975,10 +977,12 @@ class TpuOperatorExecutor:
                     self._dispatcher.submit(launch), launch.cancel_check,
                     max_wait_s=self.LAUNCH_WAIT_CAP_S)
             finally:
-                if launch.span is not None:
-                    launch.span.end()
-        return vector_device.assemble(segments, ctx, plan,
-                                      np.asarray(packed), S_real), []
+                launch.end_span()
+        t_asm = time.perf_counter()
+        results = vector_device.assemble(segments, ctx, plan,
+                                         np.asarray(packed), S_real)
+        self._note_assemble(launch, t_asm)
+        return results, []
 
     def _prepare_startree(self, segments: List[ImmutableSegment],
                           ctx: QueryContext, cancel_check=None,
@@ -999,10 +1003,8 @@ class TpuOperatorExecutor:
             dsp = parent_span.child("DeviceDispatch", table=ctx.table,
                                     mode="startree", starTree=True)
         from pinot_tpu.ops import residency as residency_mod
-        busy0 = self._dispatcher.busy_ms()
-        with self._engine_lock:
+        with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            stage_info = self._staging_snapshot(dsp)
             plan, needed, fits, reason = startree_device.plan_startree(
                 segments, ctx)
             if plan is None:
@@ -1015,6 +1017,7 @@ class TpuOperatorExecutor:
             factory = (lambda B, stacked, _p=plan:
                        startree_device.compiled_batched_startree_kernel(
                            _p, B, stacked))
+            t_plan = time.perf_counter()
             try:
                 cols, params, num_docs, S_real, D = self._stage_startree_locked(
                     segments, ctx, plan, fits, batchable=batchable)
@@ -1023,14 +1026,12 @@ class TpuOperatorExecutor:
                 if dsp is not None:
                     dsp.end(outcome="scanFallback", reason="staging")
                 return None
-            self._staging_attrs(dsp, stage_info, S=int(num_docs.shape[0]),
-                                D=D, G=plan.num_groups)
+            staged_ts = self._staging_attrs(
+                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D,
+                G=plan.num_groups)
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
-        overlap = self._dispatcher.busy_ms() - busy0
-        if overlap > 0:
-            self._dispatcher.observe("staging_overlap_ms", overlap)
         self._meter("startree_served")
         batch_key = None
         if batchable and self._dispatcher.batch_max > 1:
@@ -1058,7 +1059,8 @@ class TpuOperatorExecutor:
             collective=self._needs_cpu_ordering(kernel),
             cancel_check=cancel_check,
             site_ctx={"table": ctx.table, "mode": "startree"}, span=dsp,
-            slip=slip, docs=sum(s.num_docs for s in segments))
+            slip=slip, docs=sum(s.num_docs for s in segments),
+            staged_ts=staged_ts)
         return plan, needed, fits, S_real, launch
 
     def _stage_startree_locked(self, segments, ctx: QueryContext, plan, fits,
@@ -1092,12 +1094,14 @@ class TpuOperatorExecutor:
         # re-traverses nothing and uploads nothing. The fitted tree
         # indexes are deterministic in (segments, plan, filter), so the
         # scan-path key form is sufficient here too.
+        pmark = self._params_begin()
         pkey = (_batch_id(segments), plan, ctx.filter, "__startree__", S, D)
         cached = self._params_cache.get(pkey)
         if cached is not None:
             csegs, cparams, cnum_docs = cached
             if all(a is b for a, b in zip(csegs, segments)):
                 self._params_cache.move_to_end(pkey)
+                self._params_end(pmark)
                 return cols, dict(cparams), cnum_docs, S_real, D
         sel = startree_device.selection_mask(fits, S, D)
         params = {"sel": self._put(sel, block=True)}
@@ -1109,6 +1113,7 @@ class TpuOperatorExecutor:
         self._params_cache.move_to_end(pkey)
         while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
             self._params_cache.popitem(last=False)
+        self._params_end(pmark)
         return cols, params, num_docs_dev, S_real, D
 
     def _st_block_locked(self, segments, fits, S, D, ckey, form, dtype):
@@ -1189,36 +1194,64 @@ class TpuOperatorExecutor:
         return dev
 
     # -- staging trace attrs -------------------------------------------
-    def _staging_snapshot(self, dsp):
-        """Counters to diff across a traced staging pass (None span ->
-        no snapshot cost). Exact per query: _stage runs under the engine
-        lock, so no other query's staging interleaves."""
+    @contextlib.contextmanager
+    def _staging_lock(self, dsp):
+        """The engine lock round one query's plan + stage, the wait for
+        it measured where it happens: `lockWaitMs` runs from the
+        DeviceDispatch span's opening to the lock acquired, so under N
+        clients the queue for staging has its own number and is no part
+        of `stagingMs`. Yields the snapshot `_staging_attrs` diffs
+        (None span -> no snapshot, no clock read, no annotation). The
+        same two phases go to the profiler's host plane as
+        `pinot:lock_wait` / `pinot:staging`."""
         if dsp is None:
-            return None
-        from pinot_tpu.ops import residency as residency_mod
-        hits = misses = 0.0
-        if self._metrics is not None:
-            hits = self._metrics.meter("hbm_block_hit", labels=self._labels)
-            misses = self._metrics.meter("hbm_block_miss",
-                                         labels=self._labels)
-        return (time.perf_counter(), residency_mod.transfer_bytes(),
-                hits, misses)
-
-    def _staging_attrs(self, dsp, snap, **dims) -> None:
-        if dsp is None or snap is None:
+            with self._engine_lock:
+                yield None
             return
         from pinot_tpu.ops import residency as residency_mod
-        t0, xfer0, hits0, misses0 = snap
-        attrs = dict(
-            stagingMs=round((time.perf_counter() - t0) * 1e3, 3),
+        with dispatch_mod.phase_annotation("lock_wait", dsp):
+            self._engine_lock.acquire()
+        try:
+            with dispatch_mod.phase_annotation("staging", dsp):
+                self._params_s = 0.0
+                self._param_puts = 0
+                yield (time.perf_counter(), residency_mod.transfer_bytes())
+        finally:
+            self._engine_lock.release()
+
+    def _params_begin(self):
+        """Mark the start of a staging pass's parameter part (resolve
+        literals, build and `_put` the tiny arrays); `_params_end` adds
+        it to the pass's paramsMs / paramPuts. Caller holds the engine
+        lock."""
+        return time.perf_counter(), self._puts
+
+    def _params_end(self, mark) -> None:
+        self._params_s += time.perf_counter() - mark[0]
+        self._param_puts += self._puts - mark[1]
+
+    def _staging_attrs(self, dsp, snap, t_plan: float, **dims) -> float:
+        """Returns the Launch's `staged_ts` (0.0 untraced), having set
+        stagingMs (lock acquired -> staged) and its three parts:
+        planMs (plan + kernel look-up, to `t_plan`), paramsMs (the
+        `_params_begin/_end` sections, with their `_put` count
+        paramPuts) and blocksMs (the rest of the stage call: the
+        block-cache look-ups and, on a miss, row fetch + upload)."""
+        if dsp is None or snap is None:
+            return 0.0
+        from pinot_tpu.ops import residency as residency_mod
+        t0, xfer0 = snap
+        now = time.perf_counter()
+        dsp.set(
+            lockWaitMs=round(t0 * 1e3 - dsp.node.start_ms, 3),
+            stagingMs=round((now - t0) * 1e3, 3),
+            planMs=round((t_plan - t0) * 1e3, 3),
+            blocksMs=round((now - t_plan - self._params_s) * 1e3, 3),
+            paramsMs=round(self._params_s * 1e3, 3),
+            paramPuts=self._param_puts,
             transferBytes=int(residency_mod.transfer_bytes() - xfer0),
             **dims)
-        if self._metrics is not None:
-            attrs["hbmBlockHits"] = int(self._metrics.meter(
-                "hbm_block_hit", labels=self._labels) - hits0)
-            attrs["hbmBlockMisses"] = int(self._metrics.meter(
-                "hbm_block_miss", labels=self._labels) - misses0)
-        dsp.set(**attrs)
+        return time.monotonic()
 
     def execute(self, segments: List[ImmutableSegment], ctx: QueryContext,
                 cancel_check=None
@@ -1261,16 +1294,34 @@ class TpuOperatorExecutor:
                     self._dispatcher.submit(launch), launch.cancel_check,
                     max_wait_s=self.LAUNCH_WAIT_CAP_S)
             finally:
-                if launch.span is not None:
-                    launch.span.end()
+                launch.end_span()
+        t_asm = time.perf_counter()
         if st is not None:
-            return startree_device.assemble(segments, ctx, st_plan, needed,
-                                            fits, packed), []
-        if minfo is not None:
-            return self._assemble_merged(segments, ctx, plan, packed,
-                                         S_real, slots_of_fn, minfo), []
-        results = self._assemble(segments, ctx, plan, packed, S_real, slots_of_fn)
+            results = startree_device.assemble(segments, ctx, st_plan,
+                                               needed, fits, packed)
+        elif minfo is not None:
+            results = self._assemble_merged(segments, ctx, plan, packed,
+                                            S_real, slots_of_fn, minfo)
+        else:
+            results = self._assemble(segments, ctx, plan, packed, S_real,
+                                     slots_of_fn)
+        self._note_assemble(launch, t_asm)
         return results, []
+
+    @staticmethod
+    def _note_assemble(launch: Launch, t0: float, parent=None) -> None:
+        """`assembleMs` on the span the DeviceDispatch hangs under (the
+        server's ServerRequest): packed device result -> per-segment
+        results, from the dispatch span's end (`t0`) to now; summed over
+        a request's launches. parent: that span's handle where the
+        caller is off the request thread."""
+        if launch.span is None:
+            return
+        parent = parent or tracing.capture()
+        if parent is not None:
+            parent.set(assembleMs=round(
+                parent.get("assembleMs", 0.0)
+                + (time.perf_counter() - t0) * 1e3, 3))
 
     def execute_async(self, segments: List[ImmutableSegment],
                       ctx: QueryContext, cancel_check=None):
@@ -1310,17 +1361,18 @@ class TpuOperatorExecutor:
                     lfut = self._dispatcher.submit(launch)
 
                     def finish_st(f):
+                        launch.end_span()
+                        t_asm = time.perf_counter()
                         try:
                             # lint: hang(done-callback: f is already resolved)
                             packed = f.result()
-                            out.set_result((startree_device.assemble(
+                            results = startree_device.assemble(
                                 segments, ctx, st_plan, needed, fits,
-                                packed), []))
+                                packed)
+                            self._note_assemble(launch, t_asm, parent_span)
+                            out.set_result((results, []))
                         except BaseException as e:  # noqa: BLE001
                             out.set_exception(e)
-                        finally:
-                            if launch.span is not None:
-                                launch.span.end()
 
                     lfut.add_done_callback(finish_st)
                     return
@@ -1334,22 +1386,23 @@ class TpuOperatorExecutor:
                 lfut = self._dispatcher.submit(launch)
 
                 def finish(f):
+                    launch.end_span()
+                    t_asm = time.perf_counter()
                     try:
                         # lint: hang(done-callback: f is already resolved)
                         packed = f.result()
                         if minfo is not None:
-                            out.set_result((self._assemble_merged(
+                            results = self._assemble_merged(
                                 segments, ctx, plan, packed, S_real,
-                                slots_of_fn, minfo), []))
-                            return
-                        out.set_result((self._assemble(
-                            segments, ctx, plan, packed, S_real,
-                            slots_of_fn), []))
+                                slots_of_fn, minfo)
+                        else:
+                            results = self._assemble(
+                                segments, ctx, plan, packed, S_real,
+                                slots_of_fn)
+                        self._note_assemble(launch, t_asm, parent_span)
+                        out.set_result((results, []))
                     except BaseException as e:  # noqa: BLE001
                         out.set_exception(e)
-                    finally:
-                        if launch.span is not None:
-                            launch.span.end()
 
                 lfut.add_done_callback(finish)
             except BaseException as e:  # noqa: BLE001
@@ -1397,28 +1450,31 @@ class TpuOperatorExecutor:
         if parent_span is not None:
             dsp = parent_span.child("DeviceDispatch", table=ctx.table,
                                     mode=mode)
-        with self._engine_lock:
+        with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            stage_info = self._staging_snapshot(dsp)
             plan = self._plan_topn(segments, ctx)
             if plan is None:
+                self.scan_fallback("plan")
                 if dsp is not None:
                     dsp.end(outcome="hostFallback")
                 return None
             kernel = kernels.compiled_topn_kernel(plan)
             batchable = isinstance(kernel, jax.stages.Wrapped)
+            t_plan = time.perf_counter()
             try:
                 cols, params, num_docs, S_real, D, _G = self._stage(
                     segments, ctx, plan, batchable=batchable)
             except _NotStageable:
+                self.scan_fallback("staging")
                 if dsp is not None:
                     dsp.end(outcome="hostFallback")
                 return None
-            self._staging_attrs(dsp, stage_info, S=int(num_docs.shape[0]),
-                                D=D)
+            staged_ts = self._staging_attrs(
+                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D)
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
+        self._meter("scan_served")
         batch_key = None
         if batchable and self._dispatcher.batch_max > 1:
             if self._cross_table and D <= self._doc_bucket_max:
@@ -1438,7 +1494,8 @@ class TpuOperatorExecutor:
             collective=self._needs_cpu_ordering(kernel),
             cancel_check=cancel_check,
             site_ctx={"table": ctx.table, "mode": mode}, span=dsp,
-            slip=slip, docs=sum(s.num_docs for s in segments))
+            slip=slip, docs=sum(s.num_docs for s in segments),
+            staged_ts=staged_ts)
         return S_real, launch
 
     def _execute_topn(self, segments, ctx: QueryContext, cancel_check=None):
@@ -1446,7 +1503,8 @@ class TpuOperatorExecutor:
                 and vector_device.contains_vector(ctx.filter):
             return self._execute_vector(segments, ctx, cancel_check)
         if self._doc_axis > 1:
-            return [], segments  # top-K across doc shards: host path
+            self.scan_fallback("unsupported")  # top-K across doc shards
+            return [], segments
         prep = self._prepare_topn(segments, ctx, cancel_check, "topn")
         if prep is None:
             return [], segments
@@ -1457,9 +1515,11 @@ class TpuOperatorExecutor:
                     self._dispatcher.submit(launch), launch.cancel_check,
                     max_wait_s=self.LAUNCH_WAIT_CAP_S)
             finally:
-                if launch.span is not None:
-                    launch.span.end()
-        return self._assemble_topn(segments, ctx, packed, S_real), []
+                launch.end_span()
+        t_asm = time.perf_counter()
+        results = self._assemble_topn(segments, ctx, packed, S_real)
+        self._note_assemble(launch, t_asm)
+        return results, []
 
     # ------------------------------------------------------------------
     @staticmethod
@@ -1777,8 +1837,7 @@ class TpuOperatorExecutor:
                     self._dispatcher.submit(launch), launch.cancel_check,
                     max_wait_s=self.LAUNCH_WAIT_CAP_S)
             finally:
-                if launch.span is not None:
-                    launch.span.end()
+                launch.end_span()
         out = []
         for s, seg in enumerate(segments[:S_real]):
             matched = int(packed[s, 0])
@@ -2103,6 +2162,7 @@ class TpuOperatorExecutor:
         # expression trees, so they key the resolved literals exactly;
         # the entry also carries hist slot bounds — they depend only on
         # (segments, plan), so a repeat query uploads NOTHING)
+        pmark = self._params_begin()
         pkey = (_batch_id(segments), plan, ctx.filter,
                 tuple(ctx.agg_filters), S,
                 tuple(ctx.group_by) if plan.tbucket else None)
@@ -2116,6 +2176,7 @@ class TpuOperatorExecutor:
                     self._meter("clp_served")
                 if plan.tbucket:
                     self._meter("timeseries_leaf_device")
+                self._params_end(pmark)
                 return cols, params, cnum_docs, S_real, D, G
         if plan.tbucket:
             # fused time-bucket cells: start's (hi, lo) planes + step +
@@ -2244,6 +2305,7 @@ class TpuOperatorExecutor:
             self._meter("clp_served")
         if plan.tbucket:
             self._meter("timeseries_leaf_device")
+        self._params_end(pmark)
         return cols, params, num_docs_dev, S_real, D, G
 
     # ------------------------------------------------------------------
@@ -2617,9 +2679,15 @@ class TpuOperatorExecutor:
             else P("segments", None)
         return jax.device_put(dev, NamedSharding(self._mesh, spec))
 
-    def _meter(self, name: str, value: float = 1) -> None:
-        if self._metrics is not None:
-            self._metrics.add_meter(name, value, labels=self._labels)
+    def _meter(self, name: str, value: float = 1,
+               reason: Optional[str] = None) -> None:
+        """reason: the `reason=` label of a `*_fallback` meter."""
+        if self._metrics is None:
+            return
+        labels = self._labels
+        if reason is not None:
+            labels = dict(labels or {}, reason=reason)
+        self._metrics.add_meter(name, value, labels=labels)
 
     def _refresh_tier_gauges(self) -> None:
         if self._metrics is None:
@@ -2870,6 +2938,7 @@ class TpuOperatorExecutor:
         from pinot_tpu.ops import residency as residency_mod
         residency_mod.note_transfer(arr.nbytes, column=block)
         self._meter("hbm_transfer_bytes", arr.nbytes)
+        self._puts += 1
         if self._mesh is None:
             return jnp.asarray(arr)
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -92,6 +92,16 @@ def plan_fingerprint(plan: DevicePlan) -> str:
     return hashlib.sha1(repr(plan).encode()).hexdigest()[:12]
 
 
+def _named(fn, name: str):
+    """Name `fn` before jax.jit: the XLA module, and with it the device
+    trace's `XLA Modules` line and every op under it, reads
+    `jit_<name>`. Names are note_trace's kind + the plan fingerprint
+    (`agg_<fp>`, `batched_b4_stacked_<fp>`, ...), so the /debug trace
+    log, `kernel_retrace_by_plan` and a profile agree on one name."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def _value_dtype() -> jnp.dtype:
     return jnp.float64 if jax.config.read("jax_enable_x64") else jnp.float32
 
@@ -118,7 +128,7 @@ def compiled_row_assembler(S: int, D: int, row_lens: Tuple[int, ...],
             out = jax.lax.dynamic_update_slice(out, r[None, :], (i, 0))
         return out
 
-    return jax.jit(assemble)
+    return jax.jit(_named(assemble, f"assemble_s{S}"))
 
 
 # ---------------------------------------------------------------------------
@@ -476,66 +486,70 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
     ([(op, [S]- or [S, G]-array)], matched_count [S] or None).
     G: group count for compact-key plans (plan.num_groups is 0 there)."""
     dt = _value_dtype()
-    if plan.filter_ir is not None:
-        mask = _eval_filter(plan.filter_ir, plan, cols, params)
-    else:
-        mask = jnp.ones(valid.shape, dtype=bool)
-    # per-aggregation FILTER (WHERE ...) masks AND into the main mask
-    # per slot (ref FilteredAggregationOperator)
-    agg_masks = [_eval_filter(ir, plan, cols, params)
-                 for ir in plan.agg_filter_irs]
+    with jax.named_scope("filter"):
+        if plan.filter_ir is not None:
+            mask = _eval_filter(plan.filter_ir, plan, cols, params)
+        else:
+            mask = jnp.ones(valid.shape, dtype=bool)
+        # per-aggregation FILTER (WHERE ...) masks AND into the main mask
+        # per slot (ref FilteredAggregationOperator)
+        agg_masks = [_eval_filter(ir, plan, cols, params)
+                     for ir in plan.agg_filter_irs]
 
     values = []
-    for ir in plan.value_irs:
-        values.append(None if ir is None else _eval_value(ir, cols, params))
+    with jax.named_scope("values"):
+        for ir in plan.value_irs:
+            values.append(None if ir is None
+                          else _eval_value(ir, cols, params))
 
     slots = []
     num_groups = plan.num_groups or G
     if num_groups:
-        if plan.group_compact:
-            keys = cols["gkey"]
-        else:
-            keys = jnp.zeros(valid.shape, dtype=jnp.int32)
-            for col, stride in zip(plan.group_cols, plan.group_strides):
-                keys = keys + cols["ids:" + col] * jnp.int32(stride)
-        if plan.tbucket:
-            # fused time bucket: floor((t - start) / step) from the
-            # (hi, lo) raw64 planes becomes the key's lowest digit;
-            # out-of-window rows gate out of every slot (their wrapped
-            # deltas never reach the scatter)
-            tcol, count_pad = plan.tbucket
-            b, tgate = timeseries_device.bucket_ids(
-                cols["valhi:" + tcol], cols["vallo:" + tcol],
-                params["tb:shi"], params["tb:slo"],
-                params["tb:step"], params["tb:count"], count_pad)
-            keys = keys + b
-            mask = mask & tgate
+        with jax.named_scope("group_keys"):
+            if plan.group_compact:
+                keys = cols["gkey"]
+            else:
+                keys = jnp.zeros(valid.shape, dtype=jnp.int32)
+                for col, stride in zip(plan.group_cols, plan.group_strides):
+                    keys = keys + cols["ids:" + col] * jnp.int32(stride)
+            if plan.tbucket:
+                # fused time bucket: floor((t - start) / step) from the
+                # (hi, lo) raw64 planes becomes the key's lowest digit;
+                # out-of-window rows gate out of every slot (their wrapped
+                # deltas never reach the scatter)
+                tcol, count_pad = plan.tbucket
+                b, tgate = timeseries_device.bucket_ids(
+                    cols["valhi:" + tcol], cols["vallo:" + tcol],
+                    params["tb:shi"], params["tb:slo"],
+                    params["tb:step"], params["tb:count"], count_pad)
+                keys = keys + b
+                mask = mask & tgate
         for op, vidx, fidx in plan.agg_ops:
-            vals = None if vidx is None else values[vidx]
-            m = mask if fidx is None else mask & agg_masks[fidx]
-            slots.append((op, _grouped_reduce(op, vals, keys, m, valid,
-                                              num_groups)))
+            with jax.named_scope("reduce:" + op):
+                vals = None if vidx is None else values[vidx]
+                m = mask if fidx is None else mask & agg_masks[fidx]
+                slots.append((op, _grouped_reduce(op, vals, keys, m, valid,
+                                                  num_groups)))
         return slots, None
-    matched = jnp.sum(mask & valid, axis=1).astype(dt)
+    with jax.named_scope("reduce:matched"):
+        matched = jnp.sum(mask & valid, axis=1).astype(dt)
     for j, (op, vidx, fidx) in enumerate(plan.agg_ops):
-        m = mask if fidx is None else mask & agg_masks[fidx]
-        if op.startswith("hll:"):
-            slots.append((op, _hll_slot(op, cols, m & valid)))
-            continue
-        if op.startswith("hist:"):
-            slots.append((op, _hist_slot(op, j, values[vidx], params,
-                                         m & valid)))
-            continue
-        if op == "isum":
-            vi = _eval_value_int(plan.value_irs[vidx], cols)
-            slots.append((op, _isum_slot(vi, m & valid)))
-            continue
-        if op.startswith("isum:u"):
-            vi = _eval_value_int(plan.value_irs[vidx], cols)
-            slots.append((op, _isum_u_slot(op, vi, m & valid)))
-            continue
-        vals = None if vidx is None else values[vidx]
-        slots.append((op, _masked_reduce(op, vals, m, valid)))
+        with jax.named_scope("reduce:" + op):
+            m = mask if fidx is None else mask & agg_masks[fidx]
+            if op.startswith("hll:"):
+                slot = _hll_slot(op, cols, m & valid)
+            elif op.startswith("hist:"):
+                slot = _hist_slot(op, j, values[vidx], params, m & valid)
+            elif op == "isum":
+                vi = _eval_value_int(plan.value_irs[vidx], cols)
+                slot = _isum_slot(vi, m & valid)
+            elif op.startswith("isum:u"):
+                vi = _eval_value_int(plan.value_irs[vidx], cols)
+                slot = _isum_u_slot(op, vi, m & valid)
+            else:
+                vals = None if vidx is None else values[vidx]
+                slot = _masked_reduce(op, vals, m, valid)
+            slots.append((op, slot))
     return slots, matched
 
 
@@ -569,9 +583,10 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
             # mirroring the host executor's `mask &= valid.to_mask()`
             valid = valid & cols["vmask"]
         slots, matched = _compute_slots(plan, cols, params, valid, G)
-        if plan.num_groups or G:
-            return jnp.stack([s for _, s in slots], axis=-1)
-        return _pack_flat(matched, slots)
+        with jax.named_scope("pack"):
+            if plan.num_groups or G:
+                return jnp.stack([s for _, s in slots], axis=-1)
+            return _pack_flat(matched, slots)
 
     return kernel
 
@@ -607,41 +622,50 @@ def make_topn_kernel(plan: DevicePlan, kind: str = "topn",
         valid = jnp.arange(D, dtype=jnp.int32)[None, :] < num_docs[:, None]
         if plan.valid_mask:
             valid = valid & cols["vmask"]
-        if plan.filter_ir is not None:
-            mask = _eval_filter(plan.filter_ir, plan, cols, params) & valid
-        else:
-            mask = valid
+        with jax.named_scope("filter"):
+            if plan.filter_ir is not None:
+                mask = _eval_filter(plan.filter_ir, plan, cols, params) \
+                    & valid
+            else:
+                mask = valid
         dt = _value_dtype()
-        if plan.value_irs:
-            v = _eval_value(plan.value_irs[0], cols, params).astype(dt)
-            score = -v if plan.topn_asc else v
-            # tie-break toward lower doc ids so results are stable
-        else:
-            score = jnp.broadcast_to(
-                -jnp.arange(D, dtype=dt)[None, :], mask.shape)
-        # clamp matched scores to the finite range so a legitimate -inf
-        # score (f32 overflow of huge values, or a real +/-inf column
-        # value under ASC negation) still outranks every unmatched doc's
-        # -inf sentinel; validity then reads the MASK at the winning docs
-        fin = jnp.finfo(dt)
-        # NaN order values sort LAST (host sort parity: numpy puts NaN at
-        # the end) — clip passes NaN through and top_k would rank it first,
-        # so map it to the finite minimum among matched docs
-        score = jnp.where(jnp.isnan(score), fin.min, score)
-        score = jnp.where(mask, jnp.clip(score, fin.min, fin.max), -jnp.inf)
-        k = min(plan.topn_k, D)
-        _top_vals, top_idx = jax.lax.top_k(score, k)
-        found = jnp.take_along_axis(mask, top_idx, axis=1)
-        idx_out = jnp.where(found, top_idx, -1).astype(jnp.int32)
-        matched = jnp.sum(mask, axis=1).astype(jnp.int32)
-        return jnp.concatenate([matched[:, None], idx_out], axis=1)
+        with jax.named_scope("values"):
+            if plan.value_irs:
+                v = _eval_value(plan.value_irs[0], cols, params).astype(dt)
+                score = -v if plan.topn_asc else v
+                # tie-break toward lower doc ids so results are stable
+            else:
+                score = jnp.broadcast_to(
+                    -jnp.arange(D, dtype=dt)[None, :], mask.shape)
+        with jax.named_scope("reduce:topk"):
+            # clamp matched scores to the finite range so a legitimate
+            # -inf score (f32 overflow of huge values, or a real +/-inf
+            # column value under ASC negation) still outranks every
+            # unmatched doc's -inf sentinel; validity then reads the MASK
+            # at the winning docs
+            fin = jnp.finfo(dt)
+            # NaN order values sort LAST (host sort parity: numpy puts NaN
+            # at the end) — clip passes NaN through and top_k would rank
+            # it first, so map it to the finite minimum among matched docs
+            score = jnp.where(jnp.isnan(score), fin.min, score)
+            score = jnp.where(mask, jnp.clip(score, fin.min, fin.max),
+                              -jnp.inf)
+            k = min(plan.topn_k, D)
+            _top_vals, top_idx = jax.lax.top_k(score, k)
+        with jax.named_scope("pack"):
+            found = jnp.take_along_axis(mask, top_idx, axis=1)
+            idx_out = jnp.where(found, top_idx, -1).astype(jnp.int32)
+            matched = jnp.sum(mask, axis=1).astype(jnp.int32)
+            return jnp.concatenate([matched[:, None], idx_out], axis=1)
 
     return kernel
 
 
 @functools.lru_cache(maxsize=256)
 def compiled_topn_kernel(plan: DevicePlan):
-    return jax.jit(make_topn_kernel(plan), static_argnames=("D",))
+    return jax.jit(_named(make_topn_kernel(plan),
+                          "topn_" + plan_fingerprint(plan)),
+                   static_argnames=("D",))
 
 
 @functools.lru_cache(maxsize=256)
@@ -651,7 +675,9 @@ def compiled_kernel(plan: DevicePlan):
     COUNT(*) stages no columns to infer it from; G is the compact-key
     group count — data-dependent, hence a static arg rather than plan
     state)."""
-    return jax.jit(make_kernel(plan), static_argnames=("D", "G"))
+    return jax.jit(_named(make_kernel(plan),
+                          "agg_" + plan_fingerprint(plan)),
+                   static_argnames=("D", "G"))
 
 
 # ---------------------------------------------------------------------------
@@ -761,7 +787,7 @@ def make_sharded_kernel(plan: DevicePlan, mesh):
         )
         return sm(cols, params, num_docs)
 
-    return jax.jit(fn, static_argnames=("D", "G"))
+    return jax.jit(_named(fn, "sharded_" + fp), static_argnames=("D", "G"))
 
 
 @functools.lru_cache(maxsize=256)
@@ -792,6 +818,12 @@ def compiled_sharded_kernel(plan: DevicePlan, mesh):
 # replicated leader inputs, so jit's shape cache only ever sees bucketed
 # batch sizes — steady state is zero retraces.
 
+def _batched_name(prefix: str, B: int, stacked: bool,
+                  plan: DevicePlan) -> str:
+    return (f"{prefix}_b{B}{'_stacked' if stacked else ''}_"
+            f"{plan_fingerprint(plan)}")
+
+
 def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
     kind = "batched_stacked" if stacked else "batched"
     base = make_kernel(plan, kind=kind, extra=(B,))
@@ -812,7 +844,8 @@ def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
             return jax.vmap(
                 lambda p, _i: base(cols, p, num_docs, D=D, G=G))(ps, idx)
 
-    return jax.jit(fn, static_argnames=("D", "G"))
+    return jax.jit(_named(fn, _batched_name("batched", B, stacked, plan)),
+                   static_argnames=("D", "G"))
 
 
 @functools.lru_cache(maxsize=256)
@@ -847,7 +880,9 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
                 jax.tree_util.tree_map(lambda c: c[i], cs), p, ns[i],
                 D=D, G=G))(ps, idx)
 
-    return jax.jit(fn, static_argnames=("D", "G"))
+    return jax.jit(_named(fn, f"batched_b{B}_dedup{U}_"
+                              f"{plan_fingerprint(plan)}"),
+                   static_argnames=("D", "G"))
 
 
 @functools.lru_cache(maxsize=256)
@@ -883,7 +918,9 @@ def make_batched_topn_kernel(plan: DevicePlan, B: int,
             return jax.vmap(
                 lambda p, _i: base(cols, p, num_docs, D=D))(ps, idx)
 
-    return jax.jit(fn, static_argnames=("D", "G"))
+    return jax.jit(_named(fn, _batched_name("topn_batched", B, stacked,
+                                            plan)),
+                   static_argnames=("D", "G"))
 
 
 @functools.lru_cache(maxsize=256)
@@ -953,7 +990,9 @@ def make_batched_sharded_kernel(plan: DevicePlan, mesh, B: int,
         )
         return sm(cs, ps, ns)
 
-    return jax.jit(fn, static_argnames=("D", "G"))
+    return jax.jit(_named(fn, _batched_name("sharded_batched", B, stacked,
+                                            plan)),
+                   static_argnames=("D", "G"))
 
 
 @functools.lru_cache(maxsize=256)

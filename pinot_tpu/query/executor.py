@@ -115,7 +115,9 @@ class QueryExecutor:
             if self._cancel_check is not None:
                 self._cancel_check()
             engine = self.tpu_engine
-            if engine is not None and engine.supports(ctx):
+            if engine is not None and not engine.supports(ctx):
+                engine.scan_fallback("unsupported")
+            elif engine is not None:
                 if host_only:
                     # staging + launch ride the engine's dispatch
                     # pipeline; the future resolves off-thread, so this

@@ -26,7 +26,7 @@ from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.executor import QueryExecutor
 from pinot_tpu.server import datatable
 from pinot_tpu.server.data_manager import InstanceDataManager, TableDataManager
-from pinot_tpu.utils import errorcodes
+from pinot_tpu.utils import errorcodes, tracing
 from pinot_tpu.utils.accounting import (BrokerTimeoutError,
                                         QueryCancelledError,
                                         ResourceAccountant,
@@ -321,7 +321,6 @@ class ServerQueryExecutor:
         appends the tree to the response bytes so the broker stitches
         one cross-process trace. Slow requests (and sampled ones) are
         retained in the server's trace store."""
-        from pinot_tpu.utils import tracing
         from pinot_tpu.utils import trace_store
         tc = tracing.TraceContext.from_wire(trace_ctx)
         if tc is None or not self._trace_enabled:
@@ -442,8 +441,10 @@ class ServerQueryExecutor:
                 # engine staging (transfer bytes), the dispatch ring
                 # (kernel ms, batch-split), and the tier-2 cache
                 # (hit/miss bytes) all charge this query through it
+                t_exec = time.perf_counter()
                 with charging(slip):
                     results, prune_stats = ex.execute_context(ctx)
+                t_done = time.perf_counter()
                 if slip is not None:
                     rows = sum(r.stats.num_docs_scanned for r in results)
                     entries = sum(r.stats.num_entries_scanned_in_filter
@@ -452,8 +453,17 @@ class ServerQueryExecutor:
                     # bytes: dict-encoded scan entries are int32 ids —
                     # 4 bytes per entry is the storage-traffic cost
                     slip.add(rows_scanned=rows, bytes_scanned=4 * entries)
-                return datatable.serialize_results(results,
-                                                   extra_stats=prune_stats)
+                payload = datatable.serialize_results(
+                    results, extra_stats=prune_stats)
+                # the request's two ends on its ServerRequest span (no-op
+                # untraced): parse + segment acquire before the executor
+                # runs, DataTable bytes after it; the engine's phases on
+                # DeviceDispatch and assembleMs lie between them
+                tracing.annotate(
+                    parseMs=round((t_exec - slo_t0) * 1e3, 3),
+                    serializeMs=round(
+                        (time.perf_counter() - t_done) * 1e3, 3))
+                return payload
             finally:
                 TableDataManager.release_all(sdms)
         except (QueryCancelledError, BrokerTimeoutError) as e:

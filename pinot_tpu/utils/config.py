@@ -302,9 +302,9 @@ KEYS: Dict[str, Any] = {
     "pinot.minion.executor.concurrency": 2,
     # -- distributed tracing (utils/tracing.py + utils/trace_store.py) --
     # master switch: off = NO trace machinery at all (no RequestTrace,
-    # no wire context, no tail capture) — the bench.py --trace-overhead
-    # A-side. On = shadow span collection per query (stitched trees kept
-    # only for trace=true responses and slow-query tail capture).
+    # no wire context, no tail capture, no clock stamp, no profiler
+    # annotation). On = shadow span collection per query (stitched trees
+    # kept only for trace=true responses and slow-query tail capture).
     "pinot.trace.enabled": True,
     # bounded per-role in-memory trace retention behind /debug/traces
     "pinot.trace.store.capacity": 256,

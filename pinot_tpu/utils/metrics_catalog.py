@@ -70,11 +70,15 @@ METRICS: Dict[str, str] = {
         "batch members coalesced across tables (stacked/dedup variants)",
     "dispatch_batch_dedup":
         "batch members sharing a stack entry via same-cols grouping",
-    "staging_overlap_ms":
-        "staging wall time overlapped with another query's kernel (ms)",
     "kernel_retrace": "kernel retraces (steady-state retraces are bugs)",
     "kernel_retrace_by_plan":
         "kernel retraces attributed per plan fingerprint",
+    "scan_served":
+        "queries staged for the device scan leg (agg, group-by, top-N, "
+        "DISTINCT)",
+    "scan_fallback":
+        "queries the scan leg handed to the host path (label reason="
+        "unsupported|plan|staging)",
     "startree_served":
         "queries answered by the device star-tree pre-agg leg",
     "startree_fallback":

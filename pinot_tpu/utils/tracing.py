@@ -22,6 +22,14 @@ crosses them:
 All tree mutation goes through one module lock: span operations are rare
 (tens per query) relative to the work they time, so a coarse lock is
 cheaper than per-node locks and makes cross-thread appends race-free.
+
+Two clocks a span: ``perf_counter`` times it (``durationMs``), and
+``time.time_ns()`` at its opening PLACES it (``startNs``, epoch ns).
+CLOCK_REALTIME is what the load generator, the broker, the server and
+the jax profiler's xplane all stamp with on one machine, so that one
+integer lays client, broker, server and device on one axis. Stamps
+inside a span (``launchNs``/``readyNs`` on DeviceDispatch) are the same
+clock.
 """
 from __future__ import annotations
 
@@ -80,6 +88,8 @@ class TraceNode:
     operator: str
     start_ms: float = 0.0
     duration_ms: float = 0.0
+    #: wall-clock opening (time.time_ns()); 0 = never opened
+    start_ns: int = 0
     attrs: Dict[str, Any] = field(default_factory=dict)
     children: List["TraceNode"] = field(default_factory=list)
 
@@ -90,6 +100,7 @@ class TraceNode:
     def _to_dict_locked(self) -> dict:
         return {"operator": self.operator,
                 "durationMs": round(self.duration_ms, 3),
+                **({"startNs": self.start_ns} if self.start_ns else {}),
                 **self.attrs,
                 **({"children": [c._to_dict_locked()
                                  for c in self.children]}
@@ -100,9 +111,11 @@ class TraceNode:
         """Inverse of to_dict — rebuilds a remote side's shipped tree so
         the broker can graft it into its own."""
         attrs = {k: v for k, v in d.items()
-                 if k not in ("operator", "durationMs", "children")}
+                 if k not in ("operator", "durationMs", "startNs",
+                              "children")}
         node = cls(operator=str(d.get("operator", "?")),
                    duration_ms=float(d.get("durationMs", 0.0) or 0.0),
+                   start_ns=int(d.get("startNs", 0) or 0),
                    attrs=attrs)
         node.children = [cls.from_dict(c) for c in d.get("children", ())]
         return node
@@ -113,18 +126,23 @@ class SpanHandle:
     API for code paths where contextvars don't flow (the dispatch ring's
     pools, broker fan-out threads, MSE stage threads)."""
 
-    __slots__ = ("node",)
+    __slots__ = ("node", "trace_id")
 
-    def __init__(self, node: TraceNode):
+    def __init__(self, node: TraceNode, trace_id: Optional[str] = None):
         self.node = node
+        #: the enclosing request's trace id, for code off the request
+        #: thread that tags side channels with it (the dispatch ring's
+        #: profiler annotations); children inherit it
+        self.trace_id = trace_id
 
     def child(self, operator: str, **attrs) -> "SpanHandle":
         """Open a child span (timing starts now); end it with .end()."""
         n = TraceNode(operator, attrs=dict(attrs))
         n.start_ms = time.perf_counter() * 1000.0
+        n.start_ns = time.time_ns()
         with _tree_lock:
             self.node.children.append(n)
-        return SpanHandle(n)
+        return SpanHandle(n, self.trace_id)
 
     def end(self, **attrs) -> None:
         with _tree_lock:
@@ -192,6 +210,7 @@ class Scope:
             self._token = _current.set(self.node)
             self._active = True
             self.node.start_ms = time.perf_counter() * 1000.0
+            self.node.start_ns = time.time_ns()
         return self
 
     def set(self, **attrs) -> None:
@@ -226,6 +245,7 @@ class RequestTrace:
 
     def __enter__(self) -> "RequestTrace":
         self.root.start_ms = time.perf_counter() * 1000.0
+        self.root.start_ns = time.time_ns()
         self._token = _current.set(self.root)
         self._req_token = _request.set(self)
         return self
@@ -237,7 +257,7 @@ class RequestTrace:
         _request.reset(self._req_token)
 
     def handle(self) -> SpanHandle:
-        return SpanHandle(self.root)
+        return SpanHandle(self.root, self.trace_id)
 
     def wire_context(self) -> dict:
         """The TraceContext dict shipped on outgoing hops."""
@@ -256,7 +276,7 @@ def capture() -> Optional[SpanHandle]:
     """Thread-safe handle on the CURRENT span (None when tracing is off)
     — capture on the request thread, attach from any thread later."""
     node = _current.get()
-    return None if node is None else SpanHandle(node)
+    return None if node is None else SpanHandle(node, current_trace_id())
 
 
 def current_trace_id() -> Optional[str]:
