@@ -219,7 +219,8 @@ MAIN_QUERIES = [
      "SELECT lo_discount, COUNT(*), SUM(lo_extendedprice) FROM ssb "
      "WHERE lo_quantity < 25 GROUP BY lo_discount "
      "ORDER BY lo_discount LIMIT 20"),
-    ("groupby_scatter", "group-by G=2352, scatter add/min/max",
+    ("groupby_scatter",
+     "group-by G=2352: COUNT factored one-hot, MIN/MAX scatter",
      "SELECT lo_orderdate, COUNT(*), MIN(lo_extendedprice), "
      "MAX(lo_extendedprice) FROM ssb GROUP BY lo_orderdate "
      "ORDER BY lo_orderdate LIMIT 3000"),

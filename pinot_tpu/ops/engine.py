@@ -20,6 +20,7 @@ Responsibilities:
 from __future__ import annotations
 
 import contextlib
+import math
 import threading
 import time
 from collections import OrderedDict
@@ -491,14 +492,24 @@ class TpuOperatorExecutor:
                     residency_mod.transfer_bytes() - xfer0))
         self._meter("scan_served")
         G_eff = G
+        num_groups = plan.num_groups or G
         if minfo is not None:
             self._meter("mesh_merge_served")
-            G_eff = minfo["G"]
+            G_eff = num_groups = minfo["G"]
             kernel = collective.compiled_merged_kernel(plan, self._mesh)
             factory = (lambda B, stacked, _p=plan, _m=self._mesh:
                        collective.compiled_batched_merged_kernel(
                            _p, _m, B, stacked))
             dedup_factory = None  # merged in_specs are per-member
+        if num_groups:
+            # which way the additive slots of this GROUP BY run: the
+            # kernel builder's own decision, asked with one shard's shapes
+            path = kernels.group_path(
+                num_groups, D // self._doc_axis, kernels._value_dtype(),
+                finite=not plan.nonfinite)
+            self._meter("group_path", path=path)
+            if dsp is not None:
+                dsp.set(groupPath=path)
         # the mesh shape rides the coalesce key: launches never pair
         # across differently-sharded engines (or merged with unmerged)
         mesh_sig = ("mesh", self._mesh, self._doc_axis, minfo is not None)
@@ -1776,6 +1787,17 @@ class TpuOperatorExecutor:
                 slot_index[("count", None, None)] = len(agg_ops)
                 agg_ops.append(("count", None, None))
 
+        # a grouped sum that may add an Inf or a NaN keeps the scatter
+        def adds_nonfinite(op, vidx) -> bool:
+            power = kernels._ADDITIVE.get(op, 0)  # count, min, max: 0
+            if not power:
+                return False
+            bits = self._ir_bits(seg0, value_irs[vidx])
+            return bits is None or power * bits > 127
+
+        nonfinite = bool(ctx.group_by) and any(
+            adds_nonfinite(op, vidx) for op, vidx, _f in agg_ops)
+
         raw64 = {lf.column for lf in leaves
                  if lf.kind == "vrange64"} | hll_cols
         if tbucket:
@@ -1805,6 +1827,7 @@ class TpuOperatorExecutor:
             clp_cols=clp_device.staged_cols(leaves),
             valid_mask=self._needs_valid_mask(segments),
             tbucket=tbucket,
+            nonfinite=nonfinite,
         )
         return plan, slots_of_fn
 
@@ -2679,15 +2702,14 @@ class TpuOperatorExecutor:
             else P("segments", None)
         return jax.device_put(dev, NamedSharding(self._mesh, spec))
 
-    def _meter(self, name: str, value: float = 1,
-               reason: Optional[str] = None) -> None:
-        """reason: the `reason=` label of a `*_fallback` meter."""
+    def _meter(self, name: str, value: float = 1, **labels: str) -> None:
+        """labels: the `reason=` of a `*_fallback` meter, the `path=` of
+        `group_path`."""
         if self._metrics is None:
             return
-        labels = self._labels
-        if reason is not None:
-            labels = dict(labels or {}, reason=reason)
-        self._metrics.add_meter(name, value, labels=labels)
+        if labels:
+            labels = dict(self._labels or {}, **labels)
+        self._metrics.add_meter(name, value, labels=labels or self._labels)
 
     def _refresh_tier_gauges(self) -> None:
         if self._metrics is None:
@@ -2899,6 +2921,27 @@ class TpuOperatorExecutor:
             return bounds if -LIM <= bounds[0] and bounds[1] <= LIM else None
 
         return rec(ir)
+
+    @staticmethod
+    def _ir_bits(seg0, ir) -> Optional[int]:
+        """b with |value| <= 2^b for a value IR, from its columns' TYPES
+        (INT 31, LONG 63) and its literals, or None where it can hold an
+        Inf or a NaN whatever the data's range: a FLOAT/DOUBLE column, a
+        `div`, a literal that is one. A slot of power p (kernels.
+        _ADDITIVE) then adds finite f32s iff p * b <= 127."""
+        op = ir[0]
+        if op == "col":
+            dtype = seg0.metadata.columns[ir[1]].data_type.np_dtype
+            return 8 * dtype.itemsize - 1 if dtype.kind in "iu" else None
+        if op == "lit":
+            v = float(ir[1])
+            return max(math.frexp(v)[1], 0) if math.isfinite(v) else None
+        if op not in ("neg", "add", "sub", "mul"):
+            return None
+        bits = [TpuOperatorExecutor._ir_bits(seg0, c) for c in ir[1:]]
+        if None in bits:
+            return None
+        return sum(bits) if op == "mul" else max(bits) + (op != "neg")
 
     @staticmethod
     def _hist_bounds(segments, col: str) -> Tuple[float, float]:

@@ -3,8 +3,11 @@
 The kernel computes, for stacked segment blocks [S, D]:
   mask  = filter tree over dictId compares / LUT gathers     (VPU, fused)
   vals  = dictionary-value gathers + arithmetic              (fused)
-  out   = masked reductions (sum/min/max/count/sumsq) or
-          group-keyed scatter-add / one-hot matmul partials  (MXU for matmul)
+  out   = masked reductions (sum/min/max/count/sumsq) or per-group
+          partials: additive slots as a one-hot matmul on the MXU (one
+          level to ONEHOT_MAX_GROUPS groups, factored hi x lo above, all
+          slots in one pass), min/max and whatever `group_path` sends
+          there as an XLA scatter
 returning per-segment partials — the host (or a psum over the mesh) merges.
 
 Everything is shape-static: jit re-specializes per (S, D, C, G) bucket and
@@ -21,14 +24,34 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from pinot_tpu.ops import clp_device, timeseries_device
 from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan
 
-# group-by cardinality below which the one-hot matmul path (MXU-friendly)
-# is used instead of scatter-add
+# group-by cardinality up to which a grouped sum is ONE one-hot matmul a
+# slot (f32 `one_hot` x einsum over chunks of docs); `group_path` decides
 ONEHOT_MAX_GROUPS = 1024
 _ONEHOT_CHUNK = 4096
+# above it the key is factored, k = hi * _ONEHOT2_LANES + lo, and all
+# additive slots of a plan ride one bf16 [P*H, T] @ [T, L] product a tile
+# of docs (`_onehot2_sums`). One-hot work grows with G and the scatter's
+# does not, so past ONEHOT2_MAX_GROUPS the scatter wins again. The sweep
+# (one v5e, 16 x 8M rows, COUNT + SUM = 4 planes, ms a pass, PR 28):
+#   G        2,048  7,000 16,384 32,768 65,536 131,072 262,144 524,288
+#   onehot2   24.4   52.3  105.2  199.7  388.9   767.8 1,529.8 3,049.7
+#   scatter 2,072.8 1,873.5 1,868.0 1,861.1 1,857.9 1,856.4 3,106.2 3,106.2
+# (the scatter at 1,048,576: 3,106.7). The pass is linear in G, 41.7 of
+# its 52.3 ms at G = 7,000 in the kernel (about 90% of the MXU's bf16 peak),
+# and crosses the scatter near 530,000. The bound sits where it still
+# wins 2x, with room for a plan of more planes: its work grows with P,
+# the scatter's with slots.
+ONEHOT2_MAX_GROUPS = 1 << 18
+_ONEHOT2_LANES = 128
+_ONEHOT2_CHUNK = 8192          # docs a segment under which: scatter
+_ONEHOT2_TILE_ELEMS = 1 << 21  # left-operand elements of one matmul
+_ONEHOT2_VMEM_BYTES = 64 << 20
 
 # ---------------------------------------------------------------------------
 # trace (recompile) accounting: kernel bodies run at TRACE time only, so a
@@ -89,7 +112,9 @@ def plan_fingerprint(plan: DevicePlan) -> str:
     """Short stable id of a plan STRUCTURE (not its literals): the label
     kernels compile under, and the `plan` label on the kernel_retrace
     meter. repr() of the frozen dataclass is deterministic and total."""
-    return hashlib.sha1(repr(plan).encode()).hexdigest()[:12]
+    text = repr(plan) + ("+nonfinite" if getattr(plan, "nonfinite", False)
+                         else "")
+    return hashlib.sha1(text.encode()).hexdigest()[:12]
 
 
 def _named(fn, name: str):
@@ -259,29 +284,58 @@ def _masked_reduce(op: str, vals: Optional[jnp.ndarray], mask: jnp.ndarray,
     raise ValueError(f"unknown reduction {op}")
 
 
+#: additive slot ops -> the power of the value a row contributes
+_ADDITIVE = {"count": 0, "sum": 1, "sumsq": 2, "sum3": 3, "sum4": 4}
+
+
+def group_path(num_groups: int, docs: int, dtype, finite: bool) -> str:
+    """How a grouped sum over `docs` docs a segment into `num_groups`
+    groups runs — 'onehot' | 'onehot2' | 'scatter' — from static shapes
+    alone. The ONE place that decides: `_compute_slots` and
+    `_scatter_sum` build what it says, and the engine writes the same
+    word on the DeviceDispatch span (`groupPath`), so they cannot drift.
+
+    dtype: the contributions' (the factored path splits an f32 into
+    three bf16 terms; under jax_enable_x64 the f64 sums keep the
+    scatter). finite: no contribution can be Inf/NaN — in a one-hot
+    product 0 * Inf = NaN reaches every group of the tile, where a
+    scatter touches only its own (`DevicePlan.nonfinite`). docs under
+    the chunk (a small doc shard) keep the scatter, as ever."""
+    if num_groups <= ONEHOT_MAX_GROUPS:
+        return "onehot" if docs >= _ONEHOT_CHUNK else "scatter"
+    if num_groups <= ONEHOT2_MAX_GROUPS and docs >= _ONEHOT2_CHUNK \
+            and finite and jnp.dtype(dtype) == jnp.float32:
+        return "onehot2"
+    return "scatter"
+
+
+def _contribution(op: str, vals: Optional[jnp.ndarray],
+                  m: jnp.ndarray) -> jnp.ndarray:
+    """What each row adds to an additive slot, 0 where masked out. A
+    count's stays bool: one exact bf16 plane on the factored path."""
+    if op == "count":
+        return m
+    assert vals is not None
+    if op == "sum":
+        v = vals
+    elif op == "sumsq":
+        v = vals * vals
+    elif op == "sum3":
+        v = vals * vals * vals
+    else:
+        v2 = vals * vals
+        v = v2 * v2
+    return jnp.where(m, v, 0).astype(_value_dtype())
+
+
 def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
                     mask: jnp.ndarray, valid: jnp.ndarray,
                     num_groups: int) -> jnp.ndarray:
-    """[S, D] + keys [S, D] -> [S, G] per-group partials."""
+    """[S, D] + keys [S, D] -> [S, G] per-group partials, one slot."""
     m = mask & valid
-    dt = _value_dtype()
     safe_keys = jnp.where(m, keys, 0)
-    if op == "count":
-        contrib = m.astype(dt)
-        return _scatter_sum(contrib, safe_keys, num_groups)
-    assert vals is not None
-    if op == "sum":
-        contrib = jnp.where(m, vals, 0).astype(dt)
-        return _scatter_sum(contrib, safe_keys, num_groups)
-    if op == "sumsq":
-        contrib = jnp.where(m, vals * vals, 0).astype(dt)
-        return _scatter_sum(contrib, safe_keys, num_groups)
-    if op == "sum3":
-        contrib = jnp.where(m, vals * vals * vals, 0).astype(dt)
-        return _scatter_sum(contrib, safe_keys, num_groups)
-    if op == "sum4":
-        v2 = vals * vals
-        contrib = jnp.where(m, v2 * v2, 0).astype(dt)
+    if op in _ADDITIVE:
+        contrib = _contribution(op, vals, m).astype(_value_dtype())
         return _scatter_sum(contrib, safe_keys, num_groups)
     if op == "min":
         init = jnp.full((vals.shape[0], num_groups), jnp.inf, dtype=vals.dtype)
@@ -296,14 +350,19 @@ def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
 
 def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
                  num_groups: int) -> jnp.ndarray:
-    """Sum contributions per group key.
-
-    Small key spaces ride the MXU as a chunked one-hot matmul
-    (SURVEY.md §7: group-bys become one-hot/segment-sum scatter-adds);
-    large ones fall back to XLA scatter-add.
-    """
+    """Sum one slot's contributions per group key, by `group_path`: a
+    chunked one-hot matmul to ONEHOT_MAX_GROUPS groups (SURVEY.md §7:
+    group-bys become one-hot/segment-sum scatter-adds), the factored
+    one above it for a bool `contrib` (a count: finite whatever the
+    columns hold), else XLA's scatter-add."""
     S, D = contrib.shape
-    if num_groups <= ONEHOT_MAX_GROUPS and D >= _ONEHOT_CHUNK:
+    dt = _value_dtype()
+    path = group_path(num_groups, D, dt, finite=contrib.dtype == jnp.bool_)
+    if path == "onehot2":
+        with jax.named_scope("onehot2"):
+            return _onehot2_sums([contrib], keys, num_groups)[0]
+    contrib = contrib.astype(dt)
+    if path == "onehot":
         nchunk = D // _ONEHOT_CHUNK
         main = nchunk * _ONEHOT_CHUNK
 
@@ -326,6 +385,146 @@ def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
         return out
     return _vmap_scatter(jnp.zeros((S, num_groups), contrib.dtype), keys,
                          contrib, "add")
+
+
+def _bf16_terms(c: jnp.ndarray) -> List[jnp.ndarray]:
+    """A contribution as bf16 planes whose sum is exactly `c`: a bool
+    (count) is one; an f32 is its three-term split, 3 x 8 = 24 mantissa
+    bits. Each term is rounded by `reduce_precision`, which XLA may not
+    elide: an f32 -> bf16 -> f32 round trip it folds away on the TPU
+    (`xla_allow_excess_precision`), and the split with it (measured:
+    4.5e-4 off). Inf/NaN do not survive it (Inf - Inf): `group_path`
+    keeps such plans away."""
+    if c.dtype == jnp.bool_:
+        return [c.astype(jnp.bfloat16)]
+    terms = []
+    for _ in range(3):
+        t = jax.lax.reduce_precision(c, exponent_bits=8, mantissa_bits=7)
+        terms.append(t.astype(jnp.bfloat16))
+        c = c - t
+    return terms
+
+
+def _onehot2_tile(k: jnp.ndarray, planes: jnp.ndarray, H: int) -> jnp.ndarray:
+    """One tile of one segment's docs, k (1, T) int32 and planes (P, T)
+    bf16, to its (P*H, L) f32 addend. Docs lie on the lanes of both
+    operands, so neither one-hot is built transposed: left[p*H + h, d] =
+    plane_p[d] where hi[d] == h, right[l, d] = [lo[d] == l], left @
+    right^T."""
+    L = _ONEHOT2_LANES
+    P, T = planes.shape
+    hot_hi = (k >> (L.bit_length() - 1)) == jax.lax.broadcasted_iota(
+        jnp.int32, (H, T), 0)
+    # selects in f32 (the v5e's VPU has no bf16), one cast for all
+    planes = planes.astype(jnp.float32)
+    left = jnp.concatenate(
+        [jnp.where(hot_hi, planes[p:p + 1, :], 0.0) for p in range(P)],
+        axis=0).astype(jnp.bfloat16)                           # (P*H, T)
+    hot_lo = (k & (L - 1)) == jax.lax.broadcasted_iota(jnp.int32, (L, T), 0)
+    right = jnp.where(hot_lo, 1.0, 0.0).astype(jnp.bfloat16)   # (L, T)
+    return jax.lax.dot_general(
+        left, right, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
+def _onehot2_kernel(keys_ref, planes_ref, out_ref, *, H: int):
+    """Pallas body: out_ref (P*H, L) stays resident over one segment's
+    tiles (the grid's last axis) and starts from zero at the first."""
+    out_ref[...] = jnp.where(pl.program_id(1) == 0, 0.0, out_ref[...]) \
+        + _onehot2_tile(keys_ref[...], planes_ref[...], H)
+
+
+def _onehot2_sums(contribs: List[jnp.ndarray], keys: jnp.ndarray,
+                  num_groups: int) -> List[jnp.ndarray]:
+    """Per-group sums of several contributions in ONE pass over the docs:
+    a factored one-hot matmul on the MXU.
+
+    contribs: [S, D] each, bool (a count) or f32, 0 where masked out;
+    keys [S, D] int32 (one outside [0, num_groups) matches no group).
+    The key splits as k = hi * L + lo (L = _ONEHOT2_LANES, H = ceil(G /
+    L) rounded up to 8) — the key, not the group columns, so both
+    factors are wide whatever the columns' cardinalities — and a tile of
+    docs contributes
+
+        out[s, p, h, l] += sum_d [hi == h] * plane_p[s, d] * [lo == l]
+
+    a [P*H, T] @ [T, L] product, bf16 operands, f32 accumulation
+    (`_onehot2_tile`). The one-hots are 0/1 and the planes the exact
+    bf16 terms of the contributions (`_bf16_terms`), so every product is
+    exact and only the f32 accumulation rounds, as in the scatter.
+    Counts are exact to 2^24 docs a segment. Returns one [S, G] f32
+    array a contribution.
+
+    On the TPU the tiles run as ONE Pallas kernel (`onehot2` in the
+    device trace; both one-hots live and die in VMEM). Left to XLA the
+    same loop is a dozen ops a tile: 2.4 M device events in the
+    benchmark's 12 s traced window, which the profiler could not write
+    out in 150 s, and three quarters slower (G = 7,000: 91 ms against 52).
+    Every other backend takes that loop: Pallas's interpreter cannot
+    type a kernel under shard_map."""
+    S, D = keys.shape
+    L = _ONEHOT2_LANES
+    H = -(-num_groups // (8 * L)) * 8  # f32 sublanes: the P pieces align
+    widths = [1 if c.dtype == jnp.bool_ else 3 for c in contribs]
+    P = sum(widths)
+    # docs a tile: the left operand stays under 8 MB of f32 in VMEM, so
+    # a wide key space takes short tiles (its rows amortise the step)
+    T = max(128, min(_ONEHOT2_CHUNK,
+                     _pow2_floor(_ONEHOT2_TILE_ELEMS // (P * H))))
+    # [S, P, D] by a select chain over the plane axis, not a stack: XLA
+    # then writes the planes in ONE fusion, straight into the kernel's
+    # layout; a stack held every term in HBM beside them and cost a
+    # relayout copy a plane (G = 7,000: 66 -> 59 ms a pass)
+    terms = [t[:, None, :] for c in contribs for t in _bf16_terms(c)]
+    p_ids = jax.lax.broadcasted_iota(jnp.int32, (1, P, 1), 1)
+    planes = terms[-1]
+    for p in reversed(range(P - 1)):
+        planes = jnp.where(p_ids == p, terms[p], planes)
+    keys = keys[:, None, :]
+    tail = -D % _ONEHOT2_CHUNK
+    if tail:  # zero planes add nothing
+        pad = ((0, 0), (0, 0), (0, tail))
+        planes, keys = jnp.pad(planes, pad), jnp.pad(keys, pad)
+    n = (D + tail) // T
+    # under shard_map the result varies over the mesh as its inputs do
+    vma = jax.typeof(keys).vma | jax.typeof(planes).vma
+    if jax.default_backend() == "tpu":
+        acc = pl.pallas_call(
+            functools.partial(_onehot2_kernel, H=H),
+            out_shape=jax.ShapeDtypeStruct((S, P * H, L), jnp.float32,
+                                           vma=vma),
+            grid=(S, n),
+            in_specs=[pl.BlockSpec((None, 1, T), lambda s, t: (s, 0, t)),
+                      pl.BlockSpec((None, P, T), lambda s, t: (s, 0, t))],
+            out_specs=pl.BlockSpec((None, P * H, L), lambda s, t: (s, 0, 0)),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=_ONEHOT2_VMEM_BYTES),
+            name="onehot2",
+        )(keys, planes)
+    else:
+        def tiles(x):  # [S, R, n * T] -> [n, S, R, T]
+            return x.reshape(S, -1, n, T).transpose(2, 0, 1, 3)
+
+        acc = jnp.zeros((S, P * H, L), jnp.float32)
+        if vma:  # scan's carry must enter with the type it leaves with
+            acc = jax.lax.pcast(acc, tuple(vma), to="varying")
+        add = jax.vmap(functools.partial(_onehot2_tile, H=H))
+        acc, _ = jax.lax.scan(lambda a, kp: (a + add(*kp), None), acc,
+                              (tiles(keys), tiles(planes)))
+    sums = acc.reshape(S, P, H * L)[:, :, :num_groups]
+    out, p = [], 0
+    for w in widths:
+        # smallest terms first
+        out.append(sums[:, p] if w == 1
+                   else sums[:, p + 2] + sums[:, p + 1] + sums[:, p])
+        p += w
+    return out
+
+
+def _pow2_floor(n: int) -> int:
+    return 1 << (max(n, 1).bit_length() - 1)
 
 
 def _vmap_scatter(init: jnp.ndarray, keys: jnp.ndarray, vals: jnp.ndarray,
@@ -465,15 +664,14 @@ def _hll_slot(op: str, cols, mask) -> jnp.ndarray:
 
 def _hist_slot(op: str, j: int, vals, params, mask) -> jnp.ndarray:
     """Fixed-bucket histogram partials [S, B] over the value block:
-    bucket = clip((v - lo) * scale) then masked scatter-add (feeds
+    bucket = clip((v - lo) * scale) then a masked count a bucket (feeds
     TDigest centroids host-side, ref PercentileTDigestAggregationFunction)."""
     B = int(op.split(":")[1])
     lo = params[f"slot{j}:hlo"][:, None]
     scale = params[f"slot{j}:hscale"][:, None]
     bucket = jnp.clip((vals - lo) * scale, 0, B - 1).astype(jnp.int32)
     bucket = jnp.where(mask, bucket, 0)
-    contrib = mask.astype(_value_dtype())
-    return _scatter_sum(contrib, bucket, B)
+    return _scatter_sum(mask, bucket, B)
 
 
 # ---------------------------------------------------------------------------
@@ -524,12 +722,35 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
                     params["tb:step"], params["tb:count"], count_pad)
                 keys = keys + b
                 mask = mask & tgate
-        for op, vidx, fidx in plan.agg_ops:
+        def slot_mask(fidx):
+            return mask if fidx is None else mask & agg_masks[fidx]
+
+        sums = {}
+        if group_path(num_groups, valid.shape[1], dt,
+                      finite=not plan.nonfinite) == "onehot2":
+            # every additive slot in one pass: the `lo` one-hot is built
+            # once a chunk, and the planes stack into one left operand
+            additive = [j for j, a in enumerate(plan.agg_ops)
+                        if a[0] in _ADDITIVE]
+            scope = "+".join(plan.agg_ops[j][0] for j in additive)
+            with jax.named_scope("reduce:" + scope), \
+                    jax.named_scope("onehot2"):
+                contribs = [
+                    _contribution(op, None if vidx is None else values[vidx],
+                                  slot_mask(fidx) & valid)
+                    for op, vidx, fidx in (plan.agg_ops[j] for j in additive)]
+                # keys as they are: a masked row adds zeros wherever it
+                # lands, and a key outside [0, G) matches no group
+                sums = dict(zip(additive, _onehot2_sums(
+                    contribs, keys, num_groups)))
+        for j, (op, vidx, fidx) in enumerate(plan.agg_ops):
+            if j in sums:
+                slots.append((op, sums[j]))
+                continue
             with jax.named_scope("reduce:" + op):
                 vals = None if vidx is None else values[vidx]
-                m = mask if fidx is None else mask & agg_masks[fidx]
-                slots.append((op, _grouped_reduce(op, vals, keys, m, valid,
-                                                  num_groups)))
+                slots.append((op, _grouped_reduce(
+                    op, vals, keys, slot_mask(fidx), valid, num_groups)))
         return slots, None
     with jax.named_scope("reduce:matched"):
         matched = jnp.sum(mask & valid, axis=1).astype(dt)

@@ -100,3 +100,10 @@ class DevicePlan:
     #: the plan, so a dashboard's sliding refresh window re-stages four
     #: scalar rows instead of retracing the kernel.
     tbucket: Tuple = ()
+    #: True: an additive slot of this GROUP BY may add an Inf or a NaN (a
+    #: FLOAT/DOUBLE column, a `div`, or a power that can overflow f32).
+    #: In a one-hot product 0 * Inf = NaN would reach every group of the
+    #: tile, so such a plan keeps the scatter (kernels.group_path). Out of
+    #: the repr, and in the fingerprint only when set: plans without it
+    #: compile under the names they always had.
+    nonfinite: bool = field(default=False, repr=False)

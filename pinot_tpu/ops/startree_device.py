@@ -117,8 +117,7 @@ def make_startree_kernel(plan: StarTreePlan, kind: str = "startree",
             keys = jnp.zeros(valid.shape, dtype=jnp.int32)
             for dim, stride in zip(plan.group_dims, plan.group_strides):
                 keys = keys + cols["stid:" + dim] * jnp.int32(stride)
-            outs = [kernels._scatter_sum(m.astype(dt),
-                                         jnp.where(m, keys, 0), ng)]
+            outs = [kernels._scatter_sum(m, jnp.where(m, keys, 0), ng)]
             for op, name in plan.slots:
                 if op == "usum":
                     outs.extend(_grouped_usum(cols["sthi:" + name], keys,

@@ -73,6 +73,9 @@ METRICS: Dict[str, str] = {
     "kernel_retrace": "kernel retraces (steady-state retraces are bugs)",
     "kernel_retrace_by_plan":
         "kernel retraces attributed per plan fingerprint",
+    "group_path":
+        "device GROUP BYs by the way their additive slots run (label "
+        "path=onehot|onehot2|scatter: kernels.group_path)",
     "scan_served":
         "queries staged for the device scan leg (agg, group-by, top-N, "
         "DISTINCT)",

@@ -1,0 +1,380 @@
+"""Grouped SUM/COUNT above ONEHOT_MAX_GROUPS groups as a factored one-hot
+matmul (ISSUE 28).
+
+  * `kernels.group_path` is the one place that decides: its edges
+  * `_onehot2_sums` through `make_kernel` against numpy: COUNT exact, SUM
+    of integers and of non-integer f32 within 5e-7, several additive
+    slots in one pass, MIN beside them on the scatter, rows masked out,
+    padding docs, a tail past the last chunk
+  * the same pass under `vmap` (the batched kernel) and inside
+    `shard_map` (the sharded kernel)
+  * a served GROUP BY: an INT sum takes the pass, a FLOAT sum whose
+    groups hold Inf, -Inf and NaN keeps the scatter and answers as the
+    host does in every group
+
+The suite runs with x64 ON, where the device sums are f64 and keep the
+scatter; what one chip runs (x64 off, f32) is entered with
+`jax.enable_x64(False)`.
+"""
+import math
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
+                              TableConfig, TableType)
+from pinot_tpu.ops import kernels
+from pinot_tpu.ops.engine import TpuOperatorExecutor
+from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan
+from pinot_tpu.parallel import make_mesh
+from pinot_tpu.query.context import QueryContext
+from pinot_tpu.query.executor import QueryExecutor
+from tests.queries.harness import build_segments
+
+CH = kernels._ONEHOT2_CHUNK
+RTOL = 5e-7
+
+
+# -- the path function's edges -------------------------------------------------
+@pytest.mark.parametrize("G,D,dtype,finite,want", [
+    (kernels.ONEHOT_MAX_GROUPS, CH, jnp.float32, True, "onehot"),
+    (kernels.ONEHOT_MAX_GROUPS, CH, jnp.float64, False, "onehot"),
+    (11, kernels._ONEHOT_CHUNK - 1, jnp.float32, True, "scatter"),
+    (kernels.ONEHOT_MAX_GROUPS + 1, CH, jnp.float32, True, "onehot2"),
+    (7000, 1 << 23, jnp.float32, True, "onehot2"),
+    (kernels.ONEHOT2_MAX_GROUPS, CH, jnp.float32, True, "onehot2"),
+    (kernels.ONEHOT2_MAX_GROUPS + 1, CH, jnp.float32, True, "scatter"),
+    (1 << 20, 1 << 23, jnp.float32, True, "scatter"),
+    (7000, CH, jnp.float64, True, "scatter"),
+    (7000, CH - 1, jnp.float32, True, "scatter"),
+    (7000, CH, jnp.float32, False, "scatter"),
+])
+def test_group_path_edges(G, D, dtype, finite, want):
+    assert kernels.group_path(G, D, dtype, finite=finite) == want
+
+
+# -- the pass against numpy ----------------------------------------------------
+def _plan(G, ops=("sum", "count", "sumsq", "min"), filtered=False):
+    """GROUP BY one id column `g` of cardinality G over raw columns `v`
+    (slot values) and `f` (the filter's)."""
+    return DevicePlan(
+        filter_ir=("leaf", 0) if filtered else None,
+        leaves=(DeviceLeaf("vrange", "f"),) if filtered else (),
+        value_irs=(("col", "v"),),
+        agg_ops=tuple((op, None if op == "count" else 0, None)
+                      for op in ops),
+        group_cols=("g",), group_strides=(1,), num_groups=G,
+        raw_cols=("f", "v") if filtered else ("v",))
+
+
+def _data(S, D, G, seed, integers):
+    rng = np.random.default_rng(seed)
+    keys = rng.integers(0, G, (S, D)).astype(np.int32)
+    keys[:, :3] = [0, G - 1, G // 2]  # both ends of the key space are hit
+    if integers:
+        vals = rng.integers(-(1 << 24) + 1, 1 << 24, (S, D))
+    else:
+        vals = rng.normal(size=(S, D)) * np.exp(rng.normal(size=(S, D)) * 3)
+    num_docs = np.array([D - 37 * (s + 1) for s in range(S)], np.int32)
+    filt = rng.random((S, D)).astype(np.float32)
+    return keys, vals.astype(np.float32), num_docs, filt
+
+
+def _reference(keys, vals, m, G, ops):
+    """numpy, f64, a row at a time: [S, G, n_slots] and, for the sums'
+    tolerance, the per-group sums of |contribution|."""
+    S = keys.shape[0]
+    out = np.zeros((S, G, len(ops)))
+    scale = np.ones((S, G, len(ops)))
+    v = vals.astype(np.float64)
+    for j, op in enumerate(ops):
+        for s in range(S):
+            k, sel = keys[s][m[s]], v[s][m[s]]
+            if op == "min":
+                out[s, :, j] = np.inf
+                np.minimum.at(out[s, :, j], k, sel)
+                continue
+            c = sel ** kernels._ADDITIVE[op]
+            np.add.at(out[s, :, j], k, c)
+            scale[s, :, j] = 0
+            np.add.at(scale[s, :, j], k, np.abs(c))
+    return out, np.maximum(scale, 1.0)
+
+
+def _check(got, keys, vals, m, G, ops):
+    want, scale = _reference(keys, vals, m, G, ops)
+    got = np.asarray(got, np.float64)
+    assert got.shape == want.shape
+    for j, op in enumerate(ops):
+        if op in ("count", "min"):
+            assert np.array_equal(got[..., j], want[..., j]), op
+        else:
+            err = np.abs(got[..., j] - want[..., j]) / scale[..., j]
+            assert err.max() <= RTOL, (op, err.max())
+
+
+@pytest.mark.parametrize("integers", [True, False],
+                         ids=["int<2^24", "f32"])
+@pytest.mark.parametrize("tail", [0, 200], ids=["whole", "tail"])
+@pytest.mark.parametrize("G", [1025, 7000, 8192, 65536])
+def test_onehot2_pass_against_numpy(G, tail, integers):
+    S, D = 2, CH + tail
+    ops = ("sum", "count", "sumsq", "min") if G < 65536 else ("sum", "count")
+    keys, vals, num_docs, _f = _data(S, D, G, G + tail, integers)
+    with jax.enable_x64(False):
+        assert kernels.group_path(G, D, kernels._value_dtype(),
+                                  finite=True) == "onehot2"
+        kernel = jax.jit(kernels.make_kernel(_plan(G, ops)),
+                         static_argnames=("D", "G"))
+        cols = {"ids:g": jnp.asarray(keys), "val:v": jnp.asarray(vals)}
+        # the additive slots share ONE pass; only MIN is left to scatter
+        jaxpr = str(jax.make_jaxpr(kernel, static_argnums=(3,))(
+            cols, {}, jnp.asarray(num_docs), D))
+        assert jaxpr.count("dot_general") == 1
+        assert jaxpr.count("scatter_dims_to_operand_dims") == ("min" in ops)
+        got = kernel(cols, {}, jnp.asarray(num_docs), D=D)
+        assert got.dtype == jnp.float32
+    valid = np.arange(D)[None, :] < num_docs[:, None]
+    _check(got, keys, vals, valid, G, ops)
+
+
+def test_onehot2_under_vmap():
+    """The batched kernel: B = 2 queries that differ in a filter literal
+    share the columns; the pass is vmapped over the contributions."""
+    G, S, D, ops = 7000, 2, CH + 128, ("count", "sum", "sum3")
+    keys, vals, num_docs, filt = _data(S, D, G, 5, integers=False)
+    his = (0.25, 0.8)
+    with jax.enable_x64(False):
+        kernel = kernels.make_batched_kernel(_plan(G, ops, filtered=True), 2)
+        cols = {"ids:g": jnp.asarray(keys), "val:v": jnp.asarray(vals),
+                "val:f": jnp.asarray(filt)}
+        plist = [{"leaf0:lo": jnp.zeros(S, jnp.float32),
+                  "leaf0:hi": jnp.full(S, hi, jnp.float32)} for hi in his]
+        got = np.asarray(kernel(cols, plist, jnp.asarray(num_docs), D=D))
+    assert got.shape == (2, S, G, len(ops))
+    valid = np.arange(D)[None, :] < num_docs[:, None]
+    for b, hi in enumerate(his):
+        _check(got[b], keys, vals, valid & (filt <= np.float32(hi)), G, ops)
+    assert got[0][..., 0].sum() < got[1][..., 0].sum()
+
+
+def test_onehot2_inside_shard_map():
+    """The sharded kernel over a (segments=2, docs=2) mesh: each doc
+    shard holds one chunk, so the scan runs INSIDE shard_map and its
+    carry must enter as varying as it leaves."""
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    G, S, D, ops = 1500, 2, 2 * CH, ("sum", "count", "max")
+    keys, vals, num_docs, _f = _data(S, D, G, 9, integers=True)
+    mesh = make_mesh(jax.devices()[:4], doc_axis=2)
+    with jax.enable_x64(False):
+        assert kernels.group_path(G, D // 2, kernels._value_dtype(),
+                                  finite=True) == "onehot2"
+        kernel = kernels.make_sharded_kernel(_plan(G, ops), mesh)
+        got = np.asarray(kernel(
+            {"ids:g": jnp.asarray(keys), "val:v": jnp.asarray(vals)}, {},
+            jnp.asarray(num_docs), D=D))
+    valid = np.arange(D)[None, :] < num_docs[:, None]
+    want, scale = _reference(keys, vals, valid, G, ("sum", "count"))
+    assert np.array_equal(got[..., 1], want[..., 1])
+    assert (np.abs(got[..., 0] - want[..., 0]) / scale[..., 0]).max() <= RTOL
+    ref_max = np.full((S, G), -np.inf)
+    for s in range(S):
+        np.maximum.at(ref_max[s], keys[s][valid[s]], vals[s][valid[s]])
+    assert np.array_equal(got[..., 2], ref_max)
+
+
+# -- the TPU's driver: the same tiles as one Pallas kernel -----------------------
+def _sums(G, keys, mask, vals):
+    return jnp.stack(kernels._onehot2_sums(
+        [mask, jnp.where(mask, vals, 0)], jnp.where(mask, keys, 0), G), -1)
+
+
+@pytest.mark.parametrize("G,tail", [(1025, 0), (7000, 200), (70000, 0)])
+def test_pallas_driver_equals_the_xla_loop(G, tail, monkeypatch):
+    """Off the TPU `_onehot2_sums` runs its tiles as an XLA loop; here the
+    Pallas driver runs the same tiles through Pallas's TPU interpreter,
+    and must give the same bits."""
+    from jax.experimental.pallas import tpu as pltpu
+    S, D = 2, CH + tail
+    keys, vals, num_docs, _f = _data(S, D, G, 3 * G, integers=False)
+    mask = np.arange(D)[None, :] < num_docs[:, None]
+    with jax.enable_x64(False):
+        loop = np.asarray(jax.jit(_sums, static_argnums=0)(
+            G, keys, mask, vals))
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        with pltpu.force_tpu_interpret_mode():
+            jaxpr = str(jax.make_jaxpr(_sums, static_argnums=0)(
+                G, keys, mask, vals))
+            assert jaxpr.count("pallas_call[") == 1
+            assert "name=onehot2" in jaxpr and "scan" not in jaxpr
+            kernel = np.asarray(jax.jit(_sums, static_argnums=0)(
+                G, keys, mask, vals))
+    assert kernel.tobytes() == loop.tobytes()
+    _check(kernel, keys, vals, mask, G, ("count", "sum"))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("G,ops", [
+    (7000, ("sum", "count")),                       # ssb2_q2_c1: 4 planes
+    (8192, ("count",)),                             # the t-digest histogram
+    (kernels.ONEHOT2_MAX_GROUPS, ("sum", "count", "sumsq", "sum3", "sum4")),
+])
+def test_pallas_driver_compiles_for_the_v5e(G, ops, one_chip, monkeypatch):
+    """The chip's compiler takes the kernel at the benchmark's widths
+    (16 segments of 8M docs) and at the widest plan the path function
+    admits: tiling, VMEM and all. Nothing runs."""
+    from jax.experimental.compilation_cache import compilation_cache
+    S, D = 16, 1 << 23
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.enable_x64(False):
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            kernel = jax.jit(kernels.make_kernel(_plan(G, ops)),
+                             static_argnames=("D", "G"))
+            compiled = kernel.lower(
+                {"ids:g": jax.ShapeDtypeStruct((S, D), jnp.int32,
+                                               sharding=one_chip),
+                 "val:v": jax.ShapeDtypeStruct((S, D), jnp.float32,
+                                               sharding=one_chip)},
+                {}, jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip),
+                D=D).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 1 and "onehot2" in hlo
+    assert "scatter" not in hlo
+    # what a launch holds beside the table (planes, keys, contributions)
+    # stays under half the chip
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+# -- a served GROUP BY ---------------------------------------------------------
+CARD = 1500
+DOCS = CH + 500
+
+
+@pytest.fixture(scope="module")
+def wide_segs(tmp_path_factory):
+    """GROUP BY dim has 1,500 groups; `fval` holds +Inf and -Inf in
+    group 7 (their sum is NaN), +Inf alone in group 8 and a NaN in
+    group 9 (which segment creation may or may not keep: the host path
+    is the judge)."""
+    schema = Schema("wide", [
+        FieldSpec("dim", DataType.INT, FieldType.DIMENSION),
+        FieldSpec("ival", DataType.INT, FieldType.METRIC),
+        FieldSpec("fval", DataType.FLOAT, FieldType.METRIC),
+    ])
+    tc = TableConfig("wide", TableType.OFFLINE)
+    tc.indexing.no_dictionary_columns = ["ival", "fval"]
+    segs = []
+    for i in range(2):
+        rng = np.random.default_rng(40 + i)
+        dim = rng.integers(0, CARD, DOCS).astype(np.int32)
+        dim[:CARD] = np.arange(CARD)  # every group in every segment
+        fval = (rng.random(DOCS) * 100).astype(np.float32)
+        if i == 0:
+            fval[7], fval[CARD + 1] = np.inf, -np.inf
+            dim[CARD + 1] = 7
+            fval[8], fval[9] = np.inf, np.nan
+        segs.append({"dim": dim, "fval": fval,
+                     "ival": rng.integers(0, 1 << 20, DOCS).astype(np.int32)})
+    return build_segments(tmp_path_factory.mktemp("wide"), schema, tc, segs)
+
+
+def _dispatches(tree):
+    out = [tree] if tree.get("operator") == "DeviceDispatch" else []
+    for c in tree.get("children", ()):
+        out += _dispatches(c)
+    return out
+
+
+def _run(segs, sql, labels):
+    with jax.enable_x64(False):
+        engine = TpuOperatorExecutor(metrics_labels=labels)
+        got = QueryExecutor(segs, use_tpu=True, engine=engine).execute(
+            "SET trace = true; " + sql)
+        want = QueryExecutor(segs, use_tpu=False).execute(sql)
+        plan, _slots = engine._plan(segs, QueryContext.from_sql(sql))
+    assert not got.exceptions and not want.exceptions
+    span, = _dispatches(got.trace)
+    assert "outcome" not in span, "fell back to the host"
+    meters = {p: engine._metrics.meter("group_path",
+                                       labels=dict(labels, path=p))
+              for p in ("onehot", "onehot2", "scatter")}
+    return got.result_table.rows, want.result_table.rows, plan, span, meters
+
+
+def test_int_sum_is_served_by_the_pass(wide_segs):
+    sql = ("SELECT dim, COUNT(*), SUM(ival), AVG(ival) FROM wide "
+           "WHERE ival > 5000 GROUP BY dim ORDER BY dim LIMIT 2000")
+    got, want, plan, span, meters = _run(wide_segs, sql, {"t": "int"})
+    assert not plan.nonfinite
+    assert span["groupPath"] == "onehot2" and span["G"] == 0
+    assert meters == {"onehot": 0, "onehot2": 1, "scatter": 0}
+    assert len(got) == len(want) == CARD
+    for g, w in zip(got, want):
+        assert g[:2] == w[:2]
+        assert g[2] == pytest.approx(w[2], rel=RTOL)
+        assert g[3] == pytest.approx(w[3], rel=2 * RTOL)
+
+
+def test_float_sum_with_inf_and_nan_keeps_the_scatter(wide_segs):
+    """0 * Inf = NaN would reach every group of a one-hot tile: the plan
+    knows `fval` is a FLOAT and stays on the scatter, whose answer is the
+    host's in EVERY group: NaN in 7, Inf in 8, finite past 9."""
+    sql = ("SELECT dim, SUM(fval), COUNT(*) FROM wide GROUP BY dim "
+           "ORDER BY dim LIMIT 2000")
+    got, want, plan, span, meters = _run(wide_segs, sql, {"t": "float"})
+    assert plan.nonfinite
+    assert kernels.plan_fingerprint(plan) != kernels.plan_fingerprint(
+        DevicePlan(**{**plan.__dict__, "nonfinite": False}))
+    assert span["groupPath"] == "scatter"
+    assert meters == {"onehot": 0, "onehot2": 0, "scatter": 1}
+    assert len(got) == len(want) == CARD
+    assert math.isnan(got[7][1]) and math.isnan(want[7][1])
+    assert got[8][1] == want[8][1] == math.inf
+    for g, w in zip(got, want):
+        assert g[0] == w[0] and g[2] == w[2]
+        assert g[1] == pytest.approx(w[1], rel=1e-5, nan_ok=True)
+        assert math.isfinite(g[1]) or g[0] in (7, 8, 9)
+
+
+@pytest.mark.parametrize("expr,nonfinite", [
+    ("SUM(ival)", False), ("SUM(ival * ival)", False),
+    ("SUM(ival * ival * ival * 2)", False), ("MAX(fval)", False),
+    ("SUM(fval)", True), ("SUM(ival / 2)", True), ("SUM(ival + fval)", True),
+    ("VAR_POP(ival)", False), ("KURTOSIS(ival)", False),
+    ("KURTOSIS(ival * ival)", True),
+])
+def test_plan_knows_which_sums_can_hold_an_inf(wide_segs, expr, nonfinite):
+    """From the columns' types alone: INT is 31 bits, a product adds
+    them, a slot of power p must stay under 2^127."""
+    ctx = QueryContext.from_sql(
+        f"SELECT dim, {expr} FROM wide GROUP BY dim LIMIT 10")
+    with jax.enable_x64(False):
+        engine = TpuOperatorExecutor()
+        if not engine.supports(ctx):
+            pytest.skip(f"{expr}: not a device aggregation here")
+        planned = engine._plan(wide_segs, ctx)
+    assert planned is not None
+    assert planned[0].nonfinite == nonfinite
+    flat = engine._plan(wide_segs, QueryContext.from_sql(
+        f"SELECT {expr} FROM wide"))
+    assert flat is None or not flat[0].nonfinite  # no GROUP BY: no one-hot

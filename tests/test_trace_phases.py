@@ -12,6 +12,8 @@
     inside; scopes change no bit of an answer
   * with no trace open nothing of this runs: no span, stamp, annotation
   * the plain leg's `scan_served` / `scan_fallback{reason=}` meters
+  * a GROUP BY's span and the server's `/metrics` say which way its sums
+    ran (`groupPath`, `group_path{path=}`: ISSUE 28); a scan says nothing
 """
 import contextlib
 import statistics
@@ -159,6 +161,10 @@ def test_inline_dispatch_carries_every_wait(legs, leg):
             _check_dispatch(d, ring=False)
             assert d["mode"] == {"groupby": "agg", "distinct": "agg"}.get(
                 leg, leg)
+            # 11 groups (DISTINCT groups too) over 2,048 docs: under the
+            # one-hot's chunk
+            assert d.get("groupPath") == (
+                "scatter" if leg in ("groupby", "distinct") else None)
 
 
 @pytest.mark.parametrize("leg", ["agg", "groupby", "topn", "startree"])
@@ -502,6 +508,27 @@ def test_scan_leg_meters_served_and_fallback(scan_segs, reason,
     else:
         assert served == 0
         assert fallen == {r: float(r == reason) for r in fallen}
+
+
+# -- which way a GROUP BY's sums ran (ISSUE 28) --------------------------------
+def test_served_group_by_names_its_path_and_a_scan_does_not(cluster):
+    from pinot_tpu.utils.metrics import get_registry
+    page = get_registry("server").prometheus_text
+
+    def scatters():
+        return sum(float(line.rsplit(" ", 1)[1])
+                   for line in page().splitlines()
+                   if line.startswith("pinot_tpu_server_group_path{")
+                   and 'path="scatter"' in line)
+    before = scatters()
+    scan, = _served(_cluster_trace(cluster, "agg", 41))
+    assert "groupPath" not in scan and scatters() == before
+    grouped, = _served(_cluster_trace(cluster, "groupby", 41))
+    dims = {k: grouped[k] for k in ("G", "D", "groupPath")}
+    assert dims["groupPath"] == kernels.group_path(
+        11, dims["D"], kernels._value_dtype(), finite=True) == "scatter"
+    assert scatters() == before + 1
+    assert "# HELP pinot_tpu_server_group_path " in page()
 
 
 # -- the same phases on the profiler's clock ----------------------------------
