@@ -430,20 +430,11 @@ def spans(node, name: str) -> list:
     return found
 
 
-#: per-engine budgets the catalog fixes for ONE 16 GB chip; an N-chip
-#: server needs them N times over (they are constants, not derived from
-#: the devices — ROADMAP), or its resident tier fills and admission sheds
-CHIP_BUDGET_KNOBS = ("pinot.server.hbm.cache.bytes",
-                     "pinot.server.hbm.resident.bytes",
-                     "pinot.server.host.row.cache.bytes")
-
-
 class Cluster:
     """The smoke's processes; every one it starts, it stops."""
 
-    def __init__(self, work: str, rehearsal: bool, chips: int):
+    def __init__(self, work: str, rehearsal: bool):
         self.work = work
-        self.chips = chips
         self.procs = {}
         self.env = dict(os.environ)
         self.env["PYTHONPATH"] = REPO + os.pathsep + \
@@ -514,17 +505,11 @@ class Cluster:
     def start_server(self) -> dict:
         """Start the one server; returns its /debug/device report."""
         from pinot_tpu.controller.coordination import CoordinationClient
-        from pinot_tpu.utils.config import PinotConfiguration
-        args = ["StartServer", "--instance-id", "server_0",
-                "--coordinator", self.coordinator, "--tpu"]
-        if self.chips > 1:
-            defaults = PinotConfiguration()
-            props = os.path.join(self.work, "server.properties")
-            with open(props, "w") as f:
-                for knob in CHIP_BUDGET_KNOBS:
-                    f.write(f"{knob}={defaults.get_int(knob) * self.chips}\n")
-            args += ["--config", props]
-        self.spawn("server", args, self.server_env)
+        # no configuration file: the HBM budgets are per chip, and the
+        # engine multiplies them by the chips it holds
+        self.spawn("server", ["StartServer", "--instance-id", "server_0",
+                              "--coordinator", self.coordinator, "--tpu"],
+                   self.server_env)
 
         def admin_url():
             client = CoordinationClient(self.coordinator)
@@ -623,7 +608,7 @@ def run(args) -> dict:
     os.environ["TMPDIR"] = os.path.join(work, "tmp")
     native = native_library()  # before anything here imports the loader
 
-    cluster = Cluster(work, args.cpu_rehearsal, args.chips)
+    cluster = Cluster(work, args.cpu_rehearsal)
     try:
         return drive(args, cluster, work, native, t_start)
     finally:

@@ -107,6 +107,14 @@ class TpuOperatorExecutor:
             if len(self.devices) > 1:
                 from jax.sharding import Mesh
                 self._mesh = Mesh(np.array(self.devices), ("segments",))
+        #: [segments-shard][its devices], in mesh order: segment slot i
+        #: of an [S, ...] block lives on shard i // (S / shards), and a
+        #: resident row is put on that shard's first device
+        self._shards = self._segment_shards(self._mesh) \
+            if self._mesh is not None else []
+        #: bytes of resident rows copied chip to chip at block assembly
+        #: (a row found on another chip than its slab's) since start-up
+        self._cross_chip_bytes = 0
         #: ASSEMBLED device blocks, LRU-evicted under a byte budget: the
         #: exact [S, D] arrays kernels consume, keyed by the segment
         #: batch identity (id+name pairs guard against id() reuse). A
@@ -129,11 +137,15 @@ class TpuOperatorExecutor:
 
         from pinot_tpu.utils.config import PinotConfiguration
         _cfg = config or PinotConfiguration()
-        # legacy short env names still win for compatibility
+        # legacy short env names still win for compatibility. The host
+        # row cache is this process's memory, one budget a server; the
+        # two HBM knobs are bytes PER CHIP, and the engine's pools are
+        # the knob times the chips it holds
+        chips = max(len(self.devices), 1)
         self.host_budget_bytes = int(_os.environ.get(
             "PINOT_TPU_HOST_ROW_CACHE_BYTES",
             _cfg.get_int("pinot.server.host.row.cache.bytes")))
-        self.cache_budget_bytes = int(_os.environ.get(
+        self.cache_budget_bytes = chips * int(_os.environ.get(
             "PINOT_TPU_HBM_CACHE_BYTES",
             _cfg.get_int("pinot.server.hbm.cache.bytes")))
         #: per-(segment, column) device-resident rows (ops/residency.py):
@@ -141,7 +153,7 @@ class TpuOperatorExecutor:
         #: subset or a newly sealed segment uploads only rows the device
         #: has never seen; everything else assembles on-device
         from pinot_tpu.ops.residency import ResidencyManager
-        resident_bytes = int(_os.environ.get(
+        resident_bytes = chips * int(_os.environ.get(
             "PINOT_TPU_HBM_RESIDENT_BYTES",
             _cfg.get_int("pinot.server.hbm.resident.bytes")))
         if not _cfg.get_bool("pinot.server.hbm.resident.enabled", True):
@@ -236,11 +248,6 @@ class TpuOperatorExecutor:
         #: host-factorized global group-key remap params per
         #: (segment batch, plan) — built once, re-used across queries
         self._gmap_cache: "OrderedDict[tuple, Any]" = OrderedDict()
-        #: round-robin upload target over the mesh devices: resident
-        #: rows spread across every chip's HBM instead of pooling on
-        #: device 0 (per-chip budgets in ops/residency.py account them)
-        import itertools as _itertools
-        self._row_rr = _itertools.count()
         self._metrics = self._dispatcher._metrics
         self._residency._metrics = self._metrics
 
@@ -869,27 +876,13 @@ class TpuOperatorExecutor:
                 host_rows = [self._host_row(
                     segments[i], name, "vector", fetch, dtype,
                     pad_to=row_lens[i]) for i in missing]
-                if len(host_rows) > 1 and sum(
-                        a.nbytes for a in host_rows
-                ) >= self.UPLOAD_FANOUT_BYTES:
-                    futs = [dispatch_mod.upload_pool().submit(
-                        self._put_row, a) for a in host_rows]
-                    uploaded = [dispatch_mod.wait_result(
-                        f, max_wait_s=self.LAUNCH_WAIT_CAP_S)
-                        for f in futs]
-                else:
-                    uploaded = [self._put_row(a) for a in host_rows]
+                uploaded = self._upload_rows(host_rows, missing, S)
                 for i, arr, dev in zip(missing, host_rows, uploaded):
                     self._residency.admit(segments[i], "vector", name,
                                           dtype_str, dev, arr.nbytes,
                                           device=self._dev_label(dev))
                     dev_rows[i] = dev
-            if self._mesh is not None and len(self.devices) > 1:
-                anchor = self.devices[0]
-                dev_rows = [jax.device_put(r, anchor) for r in dev_rows]
-            assembler = kernels.compiled_row_assembler(
-                S, W, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
-            dev = self._reshard_block(assembler(tuple(dev_rows)))
+            dev = self._assemble_rows(dev_rows, S, W, dtype_str)
             nbytes = S * W * np.dtype(dtype).itemsize
         else:
             rows = [self._host_row(seg, name, "vector", fetch, dtype,
@@ -1169,28 +1162,14 @@ class TpuOperatorExecutor:
                     segments[i], names[i], "startree", fetchers[i], dtype,
                     pad_to=_pow2(int(fits[i].tree.meta.num_records)))
                     for i in missing]
-                if len(host_rows) > 1 and sum(
-                        a.nbytes for a in host_rows
-                ) >= self.UPLOAD_FANOUT_BYTES:
-                    futs = [dispatch_mod.upload_pool().submit(
-                        self._put_row, a) for a in host_rows]
-                    uploaded = [dispatch_mod.wait_result(
-                        f, max_wait_s=self.LAUNCH_WAIT_CAP_S)
-                        for f in futs]
-                else:
-                    uploaded = [self._put_row(a) for a in host_rows]
+                uploaded = self._upload_rows(host_rows, missing, S)
                 for i, arr, dev in zip(missing, host_rows, uploaded):
                     self._residency.admit(segments[i], "startree",
                                           names[i], dtype_str, dev,
                                           arr.nbytes,
                                           device=self._dev_label(dev))
                     dev_rows[i] = dev
-            if self._mesh is not None and len(self.devices) > 1:
-                anchor = self.devices[0]
-                dev_rows = [jax.device_put(r, anchor) for r in dev_rows]
-            assembler = kernels.compiled_row_assembler(
-                S, D, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
-            dev = self._reshard_block(assembler(tuple(dev_rows)))
+            dev = self._assemble_rows(dev_rows, S, D, dtype_str)
             nbytes = S * D * np.dtype(dtype).itemsize
         else:
             rows = [self._host_row(seg, name, "startree", fetch, dtype,
@@ -1229,6 +1208,22 @@ class TpuOperatorExecutor:
                 yield (time.perf_counter(), residency_mod.transfer_bytes())
         finally:
             self._engine_lock.release()
+            self._chip_attrs(dsp)
+
+    def _chip_attrs(self, dsp) -> None:
+        """A mesh engine's traced DeviceDispatch: how many devices the
+        launch spans and what each chip's allocator holds and has held
+        at most (`memory_stats()`, None where the backend keeps none),
+        read once the engine lock is released, so no other query waits
+        for it; plus the bytes moved chip to chip at block assembly
+        since start-up. One device: nothing is set."""
+        if len(self.devices) < 2:
+            return
+        stats = [d.memory_stats() or {} for d in self.devices]
+        dsp.set(meshDevices=len(self.devices),
+                chipBytesInUse=[m.get("bytes_in_use") for m in stats],
+                chipPeakBytes=[m.get("peak_bytes_in_use") for m in stats],
+                crossChipBytes=self._cross_chip_bytes)
 
     def _params_begin(self):
         """Mark the start of a staging pass's parameter part (resolve
@@ -2426,18 +2421,13 @@ class TpuOperatorExecutor:
                     self._host_bytes -= _entry_nbytes(payload)
                 arr = self._host_row(seg, "__valid__",
                                      f"vmask:{stamps[i]}", fetch_row, bool)
-                dev = self._put_row(arr)
+                dev = self._put_row(arr, self._slot_device(i, S))
                 self._residency.admit(seg, f"vmask:{stamps[i]}",
                                       "__valid__", dtype_str, dev,
                                       arr.nbytes,
                                       device=self._dev_label(dev))
                 dev_rows[i] = dev
-            if self._mesh is not None and len(self.devices) > 1:
-                anchor = self.devices[0]
-                dev_rows = [jax.device_put(r, anchor) for r in dev_rows]
-            assembler = kernels.compiled_row_assembler(
-                S, D, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
-            dev = self._reshard_block(assembler(tuple(dev_rows)))
+            dev = self._assemble_rows(dev_rows, S, D, dtype_str)
             nbytes = S * D
         else:
             rows = [self._host_row(seg, "__valid__", f"vmask:{st}",
@@ -2612,35 +2602,98 @@ class TpuOperatorExecutor:
             host_rows = [self._host_row(segments[i], col, kind, fetch_row,
                                         dtype, host_cache)
                          for i in missing]
-            if len(host_rows) > 1 and sum(
-                    a.nbytes for a in host_rows) >= self.UPLOAD_FANOUT_BYTES:
-                # double-buffer big bursts: row N+1's transfer overlaps
-                # row N's (and, under execute_async, the previous
-                # query's kernel). Small rows stay inline — thread
-                # handoff costs more than the copy
-                futs = [dispatch_mod.upload_pool().submit(self._put_row, a)
-                        for a in host_rows]
-                # pool-executed device_puts always complete; the cap
-                # bounds a wedged-device-link hang (no query deadline
-                # here — staging also runs under warmup/prestage)
-                uploaded = [dispatch_mod.wait_result(
-                    f, max_wait_s=self.LAUNCH_WAIT_CAP_S) for f in futs]
-            else:
-                uploaded = [self._put_row(a) for a in host_rows]
+            uploaded = self._upload_rows(host_rows, missing, S)
             for i, arr, dev in zip(missing, host_rows, uploaded):
                 self._residency.admit(segments[i], kind, col, dtype_str,
                                       dev, arr.nbytes,
                                       device=self._dev_label(dev))
                 dev_rows[i] = dev
-        if self._mesh is not None and len(self.devices) > 1:
-            # resident rows round-robin across chips; the jit'd
-            # assembler needs colocated inputs, so anchor the stack on
-            # device 0 (chip-to-chip copies — never the host link)
-            anchor = self.devices[0]
-            dev_rows = [jax.device_put(r, anchor) for r in dev_rows]
-        assembler = kernels.compiled_row_assembler(
-            S, D, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
-        return self._reshard_block(assembler(tuple(dev_rows)))
+        return self._assemble_rows(dev_rows, S, D, dtype_str)
+
+    def _upload_rows(self, host_rows, slots, S: int) -> list:
+        """One `_put_row` a host row, each to the device that owns its
+        segment slot of an [S, ...] block."""
+        targets = [self._slot_device(i, S) for i in slots]
+        if len(host_rows) > 1 and sum(
+                a.nbytes for a in host_rows) >= self.UPLOAD_FANOUT_BYTES:
+            # double-buffer big bursts: row N+1's transfer overlaps
+            # row N's (and, under execute_async, the previous
+            # query's kernel). Small rows stay inline — thread
+            # handoff costs more than the copy
+            futs = [dispatch_mod.upload_pool().submit(self._put_row, a, d)
+                    for a, d in zip(host_rows, targets)]
+            # pool-executed device_puts always complete; the cap
+            # bounds a wedged-device-link hang (no query deadline
+            # here — staging also runs under warmup/prestage)
+            return [dispatch_mod.wait_result(
+                f, max_wait_s=self.LAUNCH_WAIT_CAP_S) for f in futs]
+        return [self._put_row(a, d) for a, d in zip(host_rows, targets)]
+
+    @staticmethod
+    def _segment_shards(mesh) -> List[list]:
+        """[segments-shard][its devices] of a mesh, in mesh order (one
+        shard of every device where the mesh has no segments axis)."""
+        devs = mesh.devices
+        if "segments" not in mesh.axis_names:
+            return [list(devs.flat)]
+        devs = np.moveaxis(devs, mesh.axis_names.index("segments"), 0)
+        return [list(d) for d in devs.reshape(devs.shape[0], -1)]
+
+    def _slot_device(self, slot: int, S: int):
+        """The device that owns segment slot `slot` of an [S, ...] block
+        (S a multiple of the segments axis, `_padded_S`): the first
+        device of the slot's segments-shard. None without a mesh."""
+        if self._mesh is None:
+            return None
+        return self._shards[slot // (S // len(self._shards))][0]
+
+    def _assemble_rows(self, dev_rows, S: int, D: int, dtype_str: str):
+        """The kernel-ready [S, D] block from one resident row a segment
+        slot (fewer rows than S leave zero slots at the end), stacked
+        on-device (kernels.compiled_row_assembler). On a mesh every
+        segments-shard's [S / shards, D] slab is stacked on the device
+        that owns it (`_slot_device`), from the rows `_put_row` already
+        placed there, and the global array is made from the slabs: no
+        device holds more than its shard of the block beside its own
+        resident rows. A row found on another chip (a batch recomposed
+        after pruning) is copied chip to chip to its slab's device
+        only, never over the host link, and metered as
+        `hbm_cross_chip_bytes`. On a (segments, docs) mesh the slab is
+        then split over `docs` among its own shard's devices."""
+        if self._mesh is None:
+            assembler = kernels.compiled_row_assembler(
+                S, D, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
+            return assembler(tuple(dev_rows))
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        sharding = NamedSharding(
+            self._mesh, P("segments", "docs") if self._doc_axis > 1
+            else P("segments", None))
+        # which docs of its slab each device of a shard holds
+        index = sharding.devices_indices_map((S, D)) \
+            if len(self._shards[0]) > 1 else None
+        per = S // len(self._shards)
+        pieces = []
+        for j, shard in enumerate(self._shards):
+            home = shard[0]
+            rows = []
+            for r in dev_rows[j * per:(j + 1) * per]:
+                if home not in r.devices():
+                    self._cross_chip_bytes += r.nbytes
+                    self._meter("hbm_cross_chip_bytes", r.nbytes)
+                    r = jax.device_put(r, home)
+                rows.append(r)
+            assembler = kernels.compiled_row_assembler(
+                per, D, tuple(int(r.shape[0]) for r in rows), dtype_str)
+            if rows:
+                slab = assembler(tuple(rows))
+            else:  # a slab of padding only: no input says where it lives
+                with jax.default_device(home):
+                    slab = assembler(())
+            pieces += [slab if len(shard) == 1
+                       else jax.device_put(slab[:, index[d][1]], d)
+                       for d in shard]
+        return jax.make_array_from_single_device_arrays(
+            (S, D), sharding, pieces)
 
     def _host_row(self, seg, col, kind, fetch_row, dtype,
                   cache: bool = True, pad_to: Optional[int] = None):
@@ -2669,21 +2722,20 @@ class TpuOperatorExecutor:
             self._refresh_tier_gauges()
         return arr
 
-    def _put_row(self, arr: np.ndarray):
-        """Upload ONE residency row. On a multi-chip mesh rows
-        round-robin across the mesh devices so resident bytes (and the
-        per-chip admission pressure they feed) spread instead of piling
-        onto device 0; the assembled block is resharded over the mesh
-        regardless of where its rows live. Runs on upload-pool threads
-        for multi-row bursts — the shared round-robin counter is the
-        only engine state touched (itertools.count is atomic)."""
+    def _put_row(self, arr: np.ndarray, device=None):
+        """Upload ONE residency row to `device`, the chip that owns its
+        segment slot (`_slot_device`): a segment's rows live where its
+        shard of every block lives, so blocks assemble per shard with
+        no chip-to-chip copy and the per-chip budgets
+        (ops/residency.py) fill evenly. None (no mesh): the default
+        device. Runs on upload-pool threads for multi-row bursts and
+        touches no engine state."""
         from pinot_tpu.ops import residency as residency_mod
         residency_mod.note_transfer(arr.nbytes, column=True)
         self._meter("hbm_transfer_bytes", arr.nbytes)
-        if self._mesh is not None and len(self.devices) > 1:
-            dev = self.devices[next(self._row_rr) % len(self.devices)]
-            return jax.device_put(arr, dev)
-        return jnp.asarray(arr)
+        if device is None:
+            return jnp.asarray(arr)
+        return jax.device_put(arr, device)
 
     @staticmethod
     def _dev_label(arr) -> str:
@@ -2691,16 +2743,6 @@ class TpuOperatorExecutor:
         the key the per-chip residency ledger and `device=` gauges use."""
         d = next(iter(arr.devices()))
         return f"{d.platform}:{d.id}"
-
-    def _reshard_block(self, dev):
-        """Move an assembled single-device block onto the mesh sharding
-        kernels expect (device-to-device; never the host link)."""
-        if self._mesh is None:
-            return dev
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        spec = P("segments", "docs") if self._doc_axis > 1 \
-            else P("segments", None)
-        return jax.device_put(dev, NamedSharding(self._mesh, spec))
 
     def _meter(self, name: str, value: float = 1, **labels: str) -> None:
         """labels: the `reason=` of a `*_fallback` meter, the `path=` of

@@ -105,9 +105,10 @@ class ResidencyManager:
         self.admission = bool(admission)
         self.sample_window = max(64, int(sample_window))
         #: mesh devices (multi-chip engines): a resident row commits
-        #: whole to ONE chip, so the pooled budget splits evenly into
-        #: per-chip shares and eviction/pressure watch the most-loaded
-        #: chip — one hot chip OOMs alone long before the pool looks full
+        #: whole to ONE chip, so the pool (the engine passes the
+        #: per-chip knob times its chips) splits evenly into per-chip
+        #: shares and eviction/pressure watch the most-loaded chip —
+        #: one hot chip OOMs alone long before the pool looks full
         self.devices = list(devices) if devices else []
         n = len(self.devices)
         self.device_budget_bytes = \

@@ -125,6 +125,11 @@ METRICS: Dict[str, str] = {
     "hbm_admission_rejected": "rows the TinyLFU admission duel rejected",
     "hbm_evicted": "rows evicted from the resident tier",
     "hbm_transfer_bytes": "host->device bytes shipped by residency",
+    "hbm_cross_chip_bytes":
+        "bytes of resident rows copied chip to chip at block assembly (a "
+        "row found on another chip than its slab's: a batch recomposed "
+        "after pruning); 0 while every batch is the one its rows were "
+        "uploaded for",
     "host_row_cache_bytes": "host padded-row cache bytes",
     "host_row_hit": "host row-cache hits",
     "host_row_miss": "host row-cache misses",

@@ -72,6 +72,12 @@ def test_cpu_rehearsal_reports_every_field_and_is_not_a_pass(tmp_path):
     after = sorted(os.listdir(default_cache)) \
         if os.path.isdir(default_cache) else None
     assert after == before
+    # the HBM budgets are per chip and the engine multiplies them by the
+    # chips it holds: the smoke hands no server a configuration file,
+    # with --chips 4 neither (ISSUE 29)
+    with open(SMOKE) as f:
+        source = f.read()
+    assert "--config" not in source and ".properties" not in source
 
 
 @pytest.mark.skipif(bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")),

@@ -332,8 +332,11 @@ class TestWarmupSeeding:
 class TestParamsCacheBounded:
     def test_params_evict_with_last_block(self, segs):
         # budget fits ONE batch's blocks (~295KB each), so staging batch
-        # B evicts batch A's blocks — and with them A's params entries
-        eng = make_engine(**{"pinot.server.hbm.cache.bytes": 500_000})
+        # B evicts batch A's blocks — and with them A's params entries.
+        # The knob is bytes PER CHIP: the engine's pool is knob x devices
+        # (the conftest forces 8), so 62,500 a chip is the 500,000 pool
+        eng = make_engine(**{"pinot.server.hbm.cache.bytes": 62_500})
+        assert eng.cache_budget_bytes == 62_500 * len(eng.devices) == 500_000
         ctx = QueryContext.from_sql(SQL)
         eng.execute(segs[:2], ctx)
         key_a = _batch_id(segs[:2])
