@@ -89,13 +89,14 @@ def measure_device_kernel(ex, segments, iters: int = 20):
         if plan_info is None:
             return None, None
         plan, _slots = plan_info
-        cols, params, num_docs, _S, D, G = eng._stage(segments, ctx, plan)
+        cols, params, _S, _S_real, D, G = eng._stage(segments, ctx, plan)
         kern = _k.compiled_kernel(plan)
-    jax.block_until_ready(kern(cols, params, num_docs, D=D, G=G))
+    # num_docs rides the packed parameters (plan_ir.PACK)
+    jax.block_until_ready(kern(cols, params, None, D=D, G=G))
     t0 = time.perf_counter()
     out = None
     for _ in range(iters):
-        out = kern(cols, params, num_docs, D=D, G=G)
+        out = kern(cols, params, None, D=D, G=G)
     jax.block_until_ready(out)
     dt = (time.perf_counter() - t0) / iters
     nbytes = sum(v.nbytes for v in cols.values())

@@ -204,6 +204,7 @@ def make_merged_kernel(plan: DevicePlan, mesh):
     col_spec = P("segments", "docs") if has_docs else P("segments", None)
 
     def fn(cols, params, num_docs, D, G=0):
+        params, num_docs = kernels.unpack_params(plan, params, num_docs)
         in_specs = (
             {k: col_spec for k in cols},
             {k: P("segments", *([None] * (v.ndim - 1)))
@@ -258,15 +259,14 @@ def make_batched_merged_kernel(plan: DevicePlan, mesh, B: int,
         return jnp.concatenate([flat, tail.astype(flat.dtype)], axis=-1)
 
     def fn(cols, plist, num_docs, D, G=0):
-        ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+        ps, ns = kernels.unpack_batch(plan, plist, num_docs, stacked)
         if stacked:
-            cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cols)
-            ns = jnp.stack(num_docs)
+            cs = kernels.stack_members(cols)
             col_spec = P(None, "segments", "docs") if has_docs \
                 else P(None, "segments", None)
             nd_spec = P(None, "segments")
         else:
-            cs, ns = cols, num_docs
+            cs = cols
             col_spec = P("segments", "docs") if has_docs \
                 else P("segments", None)
             nd_spec = P("segments")

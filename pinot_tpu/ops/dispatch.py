@@ -28,6 +28,23 @@ the hot path here is ONE device program per query, not N segment tasks:
     (plan, pow2 batch bucket, variant) — a cross-query retrace is a
     bug, and `kernels.trace_count()` / `kernels.trace_log()` / the
     per-plan-labelled `kernel_retrace` meter make one loud.
+  * hold behind a busy device — on the launch pool's path (every real
+    accelerator launch) the ring hands a batchable launch over only
+    while fewer than `_HOLD_DEPTH` launches are in flight. A launch
+    popped behind a busy device would wait on the device's queue
+    anyway, alone, re-reading the columns; held in the ring instead it
+    keeps collecting key-equal launches up to `batch_max` and leaves as
+    ONE batch the moment `_busy_end` frees the slot (through `_cv`, not
+    polled). In flight is counted where the ring decides, at hand-off
+    to the launch pool. With nothing in flight the window and the
+    callers target apply unchanged; the lone-caller inline path, a
+    launch that cannot batch, and XLA:CPU's collective branch (one
+    launch at a time under `_CPU_COLLECTIVE_LOCK` already) are as they
+    were. `heldMs` on the span and the `dispatch_held` meter say how
+    often it engages. The pow2 buckets a held batch grows into compile
+    on their first launch like any other, nothing precompiles them: a
+    warm-up has to meet them (the benchmark's does: eight clients
+    starting at once pile up behind a round's first launches).
   * staging/compute overlap — device->host result fetch runs on a fetch
     pool OFF the ring, so the next launch overlaps the previous fetch;
     `execute_async` staging runs on a staging pool so host-side padding
@@ -35,9 +52,11 @@ the hot path here is ONE device program per query, not N segment tasks:
     occupies the device.
 
 Every wait of a traced launch is measured where it happens and lands on
-its `DeviceDispatch` span: `queueWaitMs` (submit -> popped off the ring;
-inline: submit -> launch; `submitMs` before it, staged -> submitted;
-on the ring `dispatchMs` after it, popped -> the launch call starts),
+its `DeviceDispatch` span: `queueWaitMs` (submit -> handed over for
+launch; inline: submit -> launch; `submitMs` before it, staged ->
+submitted; on the ring `heldMs` is its part spent held for an in-flight
+slot, 0.0 where not held, and `dispatchMs` comes after it, handed over ->
+the launch call starts),
 then `launchMs` (the kernel call returning:
 trace + compile on a first shape, else the asynchronous enqueue),
 `deviceWaitMs` (launch returned -> `jax.block_until_ready`: a HOST
@@ -149,6 +168,16 @@ def upload_pool() -> ThreadPoolExecutor:
                 max_workers=_UPLOAD_THREADS,
                 thread_name_prefix="residency-upload")
         return _upload_pool
+
+
+#: launches the ring lets onto the device at once (the non-collective
+#: path): a batch popped while this many are in flight is HELD in the
+#: ring, where it keeps growing, and launched the moment one lands. A
+#: launch behind a busy device waits either way: on the device's queue
+#: alone, or here, joining a batch that reads the columns once.
+_HOLD_DEPTH = 2
+#: a held batch's members' cancel checks run this often (s)
+_HOLD_POLL_S = 0.05
 
 
 def _pow2(n: int) -> int:
@@ -494,9 +523,10 @@ class KernelDispatcher:
         #: the batching window only waits when >1 (a lone client never
         #: pays window latency for a batch that cannot form)
         self._active = 0
-        #: launches in flight (launched, result not yet fetched): a lone
-        #: submit takes the inline fast path only while this is 0
-        self._busy_lock = threading.Lock()
+        #: launches in flight (handed to the device, result not yet
+        #: fetched), under _cv: a lone submit takes the inline fast path
+        #: only while this is 0, and the ring holds a batch while it is
+        #: _HOLD_DEPTH or more (_busy_end wakes it)
         self._inflight = 0
         self._trace_seen = kernels.trace_count()
         self._trace_seen_by_plan = kernels.trace_count_by_plan()
@@ -523,12 +553,13 @@ class KernelDispatcher:
 
     # -- in-flight count ----------------------------------------------
     def _busy_begin(self) -> None:
-        with self._busy_lock:
+        with self._cv:
             self._inflight += 1
 
     def _busy_end(self) -> None:
-        with self._busy_lock:
+        with self._cv:
             self._inflight -= 1
+            self._cv.notify_all()
 
     # -- metrics helpers ----------------------------------------------
     def observe(self, name: str, value: float) -> None:
@@ -665,10 +696,10 @@ class KernelDispatcher:
                     with phase_annotation("d2h", span):
                         packed = np.asarray(out)
                     clock.copied([launch])
+                kernel_ms = (time.monotonic() - t0) * 1e3
             finally:
                 self._busy_end()
                 self._meter_traces()
-            kernel_ms = (time.monotonic() - t0) * 1e3
             if span is not None:
                 # inline path: kernelMs is the whole sync round trip
                 # (launch + device wait + copy) and fetchMs 0 — the
@@ -729,14 +760,13 @@ class KernelDispatcher:
             # here also widens the coalescing window, which is exactly
             # what a chaos test wants to provoke batching determinism)
             fire("server.dispatch.before", **leader.site_ctx)
-            batch = self._coalesce(leader)
-            self._dispatch_batch(batch)
+            held_at = self._coalesce(leader, batch)
+            self._dispatch_batch(batch, held_at)
         except BaseException as e:  # noqa: BLE001 — futures carry it
-            for it in batch:
-                if not it.future.done():
-                    it.future.set_exception(e)
+            self._fail(batch, e)
 
-    def _dispatch_batch(self, batch: List[Launch]) -> None:
+    def _dispatch_batch(self, batch: List[Launch],
+                        held_at: Optional[float] = None) -> None:
         # deadline/cancel checks honored while queued: a cancelled query
         # leaves the batch before launch. The `server.dispatch.batch`
         # failpoint fires PER MEMBER inside the coalesced path: an
@@ -757,12 +787,19 @@ class KernelDispatcher:
         if not live:
             return
         self.observe("dispatch_batch_size", float(len(live)))
+        if held_at is not None:
+            self._metrics.add_meter("dispatch_held", 1, labels=self._labels)
         now = time.monotonic()
         for it in live:
             if it.span is not None:
                 # each coalesced member reports into its OWN trace: the
-                # shared launch's facts land on N distinct span trees
-                it.span.set(batchSize=len(live), **it.queue_attrs(now))
+                # shared launch's facts land on N distinct span trees.
+                # heldMs: the part of queueWaitMs this member spent
+                # waiting for an in-flight slot (0 where not held)
+                held = 0.0 if held_at is None \
+                    else (now - max(held_at, it.enq_ts)) * 1e3
+                it.span.set(batchSize=len(live), heldMs=round(held, 3),
+                            **it.queue_attrs(now))
         batched = len(live) > 1
         if batched:
             # pad to the batch-size bucket with replicated leader inputs
@@ -861,19 +898,42 @@ class KernelDispatcher:
             fetch_pool().submit(self._finish, live, out, batched,
                                 (time.monotonic() - t0) * 1e3, clock)
         else:
-            # fully concurrent submission (real accelerators order their
-            # own queue; non-partitioned host programs don't rendezvous)
-            launch_pool().submit(self._run_and_finish, live, call, batched,
-                                 now)
+            # real accelerators order their own queue and non-partitioned
+            # host programs don't rendezvous, so nothing orders launches
+            # here but the hold (_coalesce). In flight from THIS hand-off,
+            # where the ring decides, not from the pool thread's start
+            self._busy_begin()
+            try:
+                launch_pool().submit(self._run_and_finish, live, call,
+                                     batched, now)
+            except BaseException:
+                self._busy_end()
+                raise
 
-    def _coalesce(self, leader: Launch) -> List[Launch]:
-        """Collect fingerprint-equal launches behind the leader, waiting
-        up to the batching window — but only while the engine observably
-        has more callers than the batch holds (a lone query never waits)."""
-        batch = [leader]
+    def _coalesce(self, leader: Launch,
+                  batch: List[Launch]) -> Optional[float]:
+        """Grow `batch` ([leader]) with fingerprint-equal launches from
+        the ring, in two regimes told apart by what is observed:
+
+          * device free: wait up to the batching window, but only while
+            the engine observably has more callers than the batch holds
+            (a lone query never waits);
+          * _HOLD_DEPTH launches in flight (non-collective path): HOLD
+            the batch, keep collecting up to batch_max, and return the
+            moment `_busy_end` frees a slot (signalled through _cv, not
+            polled). A launch behind a busy device waits either way; held
+            here it joins a batch that reads the columns once. While
+            held, members' cancel checks run every _HOLD_POLL_S and a
+            cancelled member leaves with its own error; close() fails
+            the rest. Launches of other keys wait their turn behind the
+            held batch (FIFO); one that cannot grow is never held.
+
+        Returns the time the hold began (None: not held), for heldMs."""
         if leader.batch_key is None or self.batch_max <= 1:
-            return batch
+            return None
+        holds = not leader.collective
         deadline = time.monotonic() + self.current_window_s()
+        held_at = next_poll = None
         with self._cv:
             while True:
                 i = 0
@@ -883,15 +943,50 @@ class KernelDispatcher:
                         self._cv.notify_all()
                     else:
                         i += 1
+                now = time.monotonic()
+                if holds and self._inflight >= _HOLD_DEPTH:
+                    if self._closed:
+                        self._fail(batch, RuntimeError("dispatcher closed"))
+                        break
+                    if held_at is None:
+                        held_at = now
+                        next_poll = now + _HOLD_POLL_S
+                    elif now >= next_poll:
+                        next_poll = now + _HOLD_POLL_S
+                        self._drop_cancelled(batch)
+                        if not batch:
+                            break
+                    self._cv.wait(next_poll - now)
+                    continue
+                if held_at is not None:
+                    break  # the slot is free: launch what has gathered
                 target = min(self.batch_max, max(1, self._active))
-                if len(batch) >= target:
+                if len(batch) >= target or now >= deadline:
                     break
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                self._cv.wait(left)
+                self._cv.wait(deadline - now)
             self._set_depth_locked()
-        return batch
+        return held_at
+
+    @staticmethod
+    def _fail(batch: List[Launch], error: BaseException) -> None:
+        for it in batch:
+            if not it.future.done():
+                it.future.set_exception(error)
+        batch.clear()
+
+    @staticmethod
+    def _drop_cancelled(batch: List[Launch]) -> None:
+        """Run each member's cancel check; one that raises leaves the
+        batch with the raised error on its future (as `submit` does for
+        a launch waiting for ring space)."""
+        for it in list(batch):
+            if it.cancel_check is None:
+                continue
+            try:
+                it.cancel_check()
+            except BaseException as e:  # noqa: BLE001
+                it.future.set_exception(e)
+                batch.remove(it)
 
     @staticmethod
     def _lead_span(live: List[Launch]):
@@ -903,7 +998,6 @@ class KernelDispatcher:
                         popped: float) -> None:
         span = self._lead_span(live)
         clock = _clock_for(span, popped)
-        self._busy_begin()
         t0 = time.monotonic()
         try:
             with phase_annotation("launch", span):

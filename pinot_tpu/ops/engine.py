@@ -40,7 +40,9 @@ from pinot_tpu.ops import startree_device
 from pinot_tpu.ops import timeseries_device
 from pinot_tpu.ops import vector_device
 from pinot_tpu.ops.dispatch import KernelDispatcher, Launch
-from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan
+from pinot_tpu.ops.plan_ir import (
+    NUM_DOCS, PACK, DeviceLeaf, DevicePlan, pack_params,
+)
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.expressions import (
     Expression, Function, Identifier, Literal)
@@ -458,7 +460,7 @@ class TpuOperatorExecutor:
                                      _p, B, U))
             t_plan = time.perf_counter()
             try:
-                cols, params, num_docs, S_real, D, G = self._stage(
+                cols, params, S, S_real, D, G = self._stage(
                     segments, ctx, plan, batchable=batchable)
             except _NotStageable:
                 self.scan_fallback("staging")
@@ -466,7 +468,7 @@ class TpuOperatorExecutor:
                     dsp.end(outcome="hostFallback")
                 return None
             staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D, G=G)
+                dsp, stage_info, t_plan, S=S, D=D, G=G)
             # collective broker merge (ops/collective.py): fold the
             # per-segment partials on device — one psum/pmin/pmax over
             # the whole mesh — instead of shipping [S, ...] rows to the
@@ -488,8 +490,7 @@ class TpuOperatorExecutor:
                     if not chaos:
                         try:
                             params, minfo = self._merged_prepare(
-                                segments, plan, params, S_real,
-                                int(num_docs.shape[0]), G)
+                                segments, plan, params, S_real, S, G)
                         except _MergeFallback as e:
                             self._merge_fallback(e.reason)
                         except Exception:  # noqa: BLE001 — never fail
@@ -529,15 +530,15 @@ class TpuOperatorExecutor:
                 # and staged-array shapes/dtypes line up (the signature
                 # catches per-table variation: LUT cardinality pads, id
                 # dtype width)
-                S = int(num_docs.shape[0])
                 batch_key = (plan, S, D, G_eff, _shape_sig(cols, params),
                              mesh_sig)
             else:
                 # legacy key: identical staged segment batch only
                 batch_key = (plan, _batch_id(segments), D, G_eff, mesh_sig)
         launch = Launch(
-            call=lambda: kernel(cols, params, num_docs, D=D, G=G_eff),
-            plan=plan, cols=cols, params=params, num_docs=num_docs,
+            # num_docs rides the packed parameters (plan_ir.PACK)
+            call=lambda: kernel(cols, params, None, D=D, G=G_eff),
+            plan=plan, cols=cols, params=params, num_docs=None,
             D=D, G=G_eff, batch_key=batch_key,
             cols_key=self._cols_key(segments, plan),
             factory=factory, dedup_factory=dedup_factory,
@@ -810,9 +811,8 @@ class TpuOperatorExecutor:
         params cache under their own key — the vector fn expression, not
         the residual filter — so two queries sharing a residual but not
         a query vector can never alias."""
-        cols, params, num_docs, S_real, D, _G = self._stage(
+        cols, params, S, S_real, D, _G = self._stage(
             segments, rctx, plan, batchable=batchable)
-        S = int(num_docs.shape[0])
         dim_pad = plan.dim_pad
         row_lens = tuple(_pow2(s.num_docs) * dim_pad for s in segments)
         cols["vec:" + plan.col] = self._vec_block_locked(
@@ -830,21 +830,21 @@ class TpuOperatorExecutor:
         pkey = (_batch_id(segments), plan, fn, "__vec__", S)
         cached = self._params_cache.get(pkey)
         if cached is not None:
-            csegs, cparams, _cnd = cached
+            csegs, cparams = cached
             if all(a is b for a, b in zip(csegs, segments)):
                 self._params_cache.move_to_end(pkey)
                 params.update(cparams)
                 self._params_end(pmark)
-                return cols, params, num_docs, S_real, D
+                return cols, params, S, S_real, D
         qp = vector_device.query_params(segments, plan, qvec, k, S)
         vparams = {key: self._put(arr) for key, arr in qp.items()}
         params.update(vparams)
-        self._params_cache[pkey] = (tuple(segments), vparams, num_docs)
+        self._params_cache[pkey] = (tuple(segments), vparams)
         self._params_cache.move_to_end(pkey)
         while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
             self._params_cache.popitem(last=False)
         self._params_end(pmark)
-        return cols, params, num_docs, S_real, D
+        return cols, params, S, S_real, D
 
     def _vec_block_locked(self, segments, S, W, col, leg, fetch, dtype,
                           row_lens):
@@ -924,7 +924,7 @@ class TpuOperatorExecutor:
             batchable = isinstance(kernel, jax.stages.Wrapped)
             t_plan = time.perf_counter()
             try:
-                cols, params, num_docs, S_real, D = \
+                cols, params, S, S_real, D = \
                     self._stage_vector_locked(segments, rctx, plan, fn,
                                               qvec, k, batchable=batchable)
             except _NotStageable:
@@ -933,7 +933,7 @@ class TpuOperatorExecutor:
                     dsp.end(outcome="hostFallback", reason="staging")
                 return None
             staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D)
+                dsp, stage_info, t_plan, S=S, D=D)
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
@@ -941,15 +941,14 @@ class TpuOperatorExecutor:
         batch_key = None
         if batchable and self._dispatcher.batch_max > 1:
             if self._cross_table and D <= self._doc_bucket_max:
-                S = int(num_docs.shape[0])
                 batch_key = (plan, S, D, 0, _shape_sig(cols, params),
                              ("mesh", self._mesh, self._doc_axis))
             else:
                 batch_key = (plan, _batch_id(segments), D, 0,
                              ("mesh", self._mesh, self._doc_axis))
         launch = Launch(
-            call=lambda: kernel(cols, params, num_docs, D=D),
-            plan=plan, cols=cols, params=params, num_docs=num_docs,
+            call=lambda: kernel(cols, params, None, D=D),
+            plan=plan, cols=cols, params=params, num_docs=None,
             D=D, G=0, batch_key=batch_key,
             cols_key=self._cols_key(segments, plan),
             factory=(lambda B, stacked, _p=plan:
@@ -1468,7 +1467,7 @@ class TpuOperatorExecutor:
             batchable = isinstance(kernel, jax.stages.Wrapped)
             t_plan = time.perf_counter()
             try:
-                cols, params, num_docs, S_real, D, _G = self._stage(
+                cols, params, S, S_real, D, _G = self._stage(
                     segments, ctx, plan, batchable=batchable)
             except _NotStageable:
                 self.scan_fallback("staging")
@@ -1476,7 +1475,7 @@ class TpuOperatorExecutor:
                     dsp.end(outcome="hostFallback")
                 return None
             staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D)
+                dsp, stage_info, t_plan, S=S, D=D)
             if slip is not None:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
@@ -1484,15 +1483,14 @@ class TpuOperatorExecutor:
         batch_key = None
         if batchable and self._dispatcher.batch_max > 1:
             if self._cross_table and D <= self._doc_bucket_max:
-                S = int(num_docs.shape[0])
                 batch_key = (plan, S, D, 0, _shape_sig(cols, params),
                              ("mesh", self._mesh, self._doc_axis))
             else:
                 batch_key = (plan, _batch_id(segments), D, 0,
                              ("mesh", self._mesh, self._doc_axis))
         launch = Launch(
-            call=lambda: kernel(cols, params, num_docs, D=D),
-            plan=plan, cols=cols, params=params, num_docs=num_docs,
+            call=lambda: kernel(cols, params, None, D=D),
+            plan=plan, cols=cols, params=params, num_docs=None,
             D=D, G=0, batch_key=batch_key,
             cols_key=self._cols_key(segments, plan),
             factory=(lambda B, stacked, _p=plan:
@@ -2178,15 +2176,18 @@ class TpuOperatorExecutor:
 
         # per-leaf predicate parameters (cached: filters are frozen
         # expression trees, so they key the resolved literals exactly;
-        # the entry also carries hist slot bounds — they depend only on
-        # (segments, plan), so a repeat query uploads NOTHING)
+        # the entry also carries hist slot bounds and num_docs — they
+        # depend only on (segments, plan), so a repeat query uploads
+        # NOTHING). Every [S] parameter goes into ONE packed int32
+        # [K, S] array (plan_ir.pack_params) and ONE put; the [S, C]
+        # LUT tables and the CLP leaf arrays keep a put each
         pmark = self._params_begin()
         pkey = (_batch_id(segments), plan, ctx.filter,
                 tuple(ctx.agg_filters), S,
                 tuple(ctx.group_by) if plan.tbucket else None)
         cached = self._params_cache.get(pkey)
         if cached is not None:
-            csegs, cparams, cnum_docs = cached
+            csegs, cparams = cached
             if all(a is b for a, b in zip(csegs, segments)):
                 self._params_cache.move_to_end(pkey)  # LRU refresh
                 params.update(cparams)
@@ -2195,7 +2196,10 @@ class TpuOperatorExecutor:
                 if plan.tbucket:
                     self._meter("timeseries_leaf_device")
                 self._params_end(pmark)
-                return cols, params, cnum_docs, S_real, D, G
+                return cols, params, S, S_real, D, G
+        rows: Dict[str, np.ndarray] = {}
+        rows[NUM_DOCS] = np.zeros(S, dtype=np.int32)
+        rows[NUM_DOCS][:S_real] = [s.num_docs for s in segments]
         if plan.tbucket:
             # fused time-bucket cells: start's (hi, lo) planes + step +
             # live bucket count — the ONLY things that change across a
@@ -2205,8 +2209,7 @@ class TpuOperatorExecutor:
                 ctx.group_by[0], ctx.filter, segments)
             if spec is None or spec.count_pad != plan.tbucket[1]:
                 raise _NotStageable()
-            for key, arr in timeseries_device.leaf_params(spec, S).items():
-                params[key] = self._put(arr)
+            rows.update(timeseries_device.leaf_params(spec, S))
         # histogram sketch slots: bucket bounds from segment metadata
         # (missing min/max -> host fallback)
         for j, (op, vidx, _fidx) in enumerate(plan.agg_ops):
@@ -2215,9 +2218,8 @@ class TpuOperatorExecutor:
             col = plan.value_irs[vidx][1]
             lo, span = self._hist_bounds(segments, col)
             B = int(op.split(":")[1])
-            params[f"slot{j}:hlo"] = self._put(np.full(S, lo, dtype=vdt))
-            params[f"slot{j}:hscale"] = self._put(
-                np.full(S, B / span, dtype=vdt))
+            rows[f"slot{j}:hlo"] = np.full(S, lo, dtype=vdt)
+            rows[f"slot{j}:hscale"] = np.full(S, B / span, dtype=vdt)
         # leaf expressions in the exact order _plan appended leaves:
         # main filter first, then each distinct agg FILTER tree
         leaf_exprs: List[Function] = []
@@ -2231,27 +2233,30 @@ class TpuOperatorExecutor:
         for i, (leaf, expr) in enumerate(zip(plan.leaves, leaf_exprs)):
             if leaf.kind == "vrange":
                 lo, hi = _vrange_bounds(expr, vdt)
-                params[f"leaf{i}:lo"] = self._put(np.full(S, lo, dtype=vdt))
-                params[f"leaf{i}:hi"] = self._put(np.full(S, hi, dtype=vdt))
+                rows[f"leaf{i}:lo"] = np.full(S, lo, dtype=vdt)
+                rows[f"leaf{i}:hi"] = np.full(S, hi, dtype=vdt)
                 continue
             if leaf.kind == "vrange64":
                 a, b = _vrange_int_bounds(expr)
-                params[f"leaf{i}:lohi"] = self._put(
-                    np.full(S, a >> 24, dtype=np.int32))
-                params[f"leaf{i}:lolo"] = self._put(
-                    np.full(S, a & 0xFFFFFF, dtype=np.int32))
-                params[f"leaf{i}:hihi"] = self._put(
-                    np.full(S, b >> 24, dtype=np.int32))
-                params[f"leaf{i}:hilo"] = self._put(
-                    np.full(S, b & 0xFFFFFF, dtype=np.int32))
+                for name, cell in (("lohi", a >> 24), ("lolo", a & 0xFFFFFF),
+                                   ("hihi", b >> 24), ("hilo", b & 0xFFFFFF)):
+                    rows[f"leaf{i}:{name}"] = np.full(S, cell, dtype=np.int32)
                 continue
+            if leaf.kind == "clp":
+                try:
+                    arrs = clp_device.leaf_params(
+                        i, leaf, segments, str(expr.args[1].value),
+                        expr.name == "like", S)
+                except ValueError:
+                    raise _NotStageable()
+                for k, arr in arrs.items():
+                    params[k] = self._put(arr)
+                continue
+            resolved = self._resolve_leaf(segments, expr)
             if leaf.kind == "range":
                 lo = np.zeros(S, dtype=np.int32)
                 hi = np.full(S, -1, dtype=np.int32)
-                for s, seg in enumerate(segments):
-                    p = resolve_predicate(seg, expr)
-                    if p is None:
-                        raise _NotStageable()
+                for s, p in enumerate(resolved):
                     if p.kind == "range":
                         lo[s], hi[s] = p.lo, p.hi
                     elif p.kind == "all":
@@ -2262,38 +2267,20 @@ class TpuOperatorExecutor:
                         lo[s] = hi[s] = int(p.ids[0])
                     else:
                         raise _NotStageable()
-                params[f"leaf{i}:lo"] = self._put(lo)
-                params[f"leaf{i}:hi"] = self._put(hi)
+                rows[f"leaf{i}:lo"], rows[f"leaf{i}:hi"] = lo, hi
             elif leaf.kind == "neq":
                 idx = np.full(S, -1, dtype=np.int32)
-                for s, seg in enumerate(segments):
-                    p = resolve_predicate(seg, expr)
-                    if p is None:
-                        raise _NotStageable()
+                for s, p in enumerate(resolved):
                     if p.kind == "notset" and len(p.ids) == 1:
                         idx[s] = int(p.ids[0])
-                    elif p.kind == "all":
-                        idx[s] = -1
-                    else:
+                    elif p.kind != "all":
                         raise _NotStageable()
-                params[f"leaf{i}:idx"] = self._put(idx)
-            elif leaf.kind == "clp":
-                try:
-                    arrs = clp_device.leaf_params(
-                        i, leaf, segments, str(expr.args[1].value),
-                        expr.name == "like", S)
-                except ValueError:
-                    raise _NotStageable()
-                for k, arr in arrs.items():
-                    params[k] = self._put(arr)
+                rows[f"leaf{i}:idx"] = idx
             elif leaf.kind == "lut":
                 C = _pow2(max(s.metadata.columns[leaf.column].cardinality
                               for s in segments), floor=8)
                 table = np.zeros((S, C), dtype=bool)
-                for s, seg in enumerate(segments):
-                    p = resolve_predicate(seg, expr)
-                    if p is None:
-                        raise _NotStageable()
+                for s, (seg, p) in enumerate(zip(segments, resolved)):
                     card = seg.metadata.columns[leaf.column].cardinality
                     if p.kind == "all":
                         table[s, :card] = True
@@ -2310,12 +2297,8 @@ class TpuOperatorExecutor:
                         raise _NotStageable()
                 params[f"leaf{i}:lut"] = self._put(table)
 
-        num_docs = np.zeros(S, dtype=np.int32)
-        num_docs[:S_real] = [s.num_docs for s in segments]
-        num_docs_dev = self._put(num_docs)
-        leaf_params = {k: v for k, v in params.items()
-                       if k.startswith(("leaf", "slot", "tb:"))}
-        self._params_cache[pkey] = (tuple(segments), leaf_params, num_docs_dev)
+        params[PACK] = self._put(pack_params(plan, rows), seg_axis=1)
+        self._params_cache[pkey] = (tuple(segments), dict(params))
         self._params_cache.move_to_end(pkey)
         while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
             self._params_cache.popitem(last=False)  # evict coldest only
@@ -2324,7 +2307,35 @@ class TpuOperatorExecutor:
         if plan.tbucket:
             self._meter("timeseries_leaf_device")
         self._params_end(pmark)
-        return cols, params, num_docs_dev, S_real, D, G
+        return cols, params, S, S_real, D, G
+
+    @staticmethod
+    def _resolve_leaf(segments, expr: Function) -> list:
+        """A dictionary leaf's predicate, resolved a segment — computed
+        once a DISTINCT dictionary: `resolve_predicate` reads a segment
+        only through its column's sorted dictionary, and the segments of
+        one table mostly share theirs (`Dictionary.content_key`), so 32
+        segments cost one binary search a bound, not 32. A leaf any
+        segment cannot resolve is not stageable."""
+        col = expr.args[0].name if expr.args \
+            and isinstance(expr.args[0], Identifier) else None
+        by_dictionary: Dict[bytes, Any] = {}
+        resolved = []
+        for seg in segments:
+            key = None
+            if col is not None and seg.has_column(col):
+                ds = seg.data_source(col)
+                if ds.metadata.has_dictionary:
+                    key = ds.dictionary.content_key
+            p = by_dictionary.get(key) if key is not None else None
+            if p is None:
+                p = resolve_predicate(seg, expr)
+                if p is None:
+                    raise _NotStageable()
+                if key is not None:
+                    by_dictionary[key] = p
+            resolved.append(p)
+        return resolved
 
     # ------------------------------------------------------------------
     # upsert validity masks (device-path upsert, SURVEY §2.3)
@@ -3015,11 +3026,14 @@ class TpuOperatorExecutor:
                     max(abs(int(lo)), abs(int(hi))) > (1 << 24):
                 raise _NotStageable()
 
-    def _put(self, arr: np.ndarray, block: bool = False):
+    def _put(self, arr: np.ndarray, block: bool = False,
+             seg_axis: int = 0):
         """block=True marks [S, D] column blocks, which also shard over the
-        docs axis on a 2-axis mesh; params/bounds shard over segments only.
-        Every byte through here feeds the host->device transfer odometer
-        (residency.transfer_bytes) — steady state must keep it flat."""
+        docs axis on a 2-axis mesh; params/bounds shard over segments only
+        (`seg_axis` 1: the packed [K, S] parameter array, whose rows are
+        replicated). Every byte through here feeds the host->device
+        transfer odometer (residency.transfer_bytes) — steady state must
+        keep it flat."""
         from pinot_tpu.ops import residency as residency_mod
         residency_mod.note_transfer(arr.nbytes, column=block)
         self._meter("hbm_transfer_bytes", arr.nbytes)
@@ -3030,7 +3044,9 @@ class TpuOperatorExecutor:
         if block and self._doc_axis > 1 and arr.ndim == 2:
             spec = P("segments", "docs")
         else:
-            spec = P("segments", *([None] * (arr.ndim - 1)))
+            axes = [None] * arr.ndim
+            axes[seg_axis] = "segments"
+            spec = P(*axes)
         return jax.device_put(arr, NamedSharding(self._mesh, spec))
 
     @staticmethod

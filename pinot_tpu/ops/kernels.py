@@ -28,7 +28,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from pinot_tpu.ops import clp_device, timeseries_device
-from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan
+from pinot_tpu.ops.plan_ir import (
+    NUM_DOCS, PACK, DeviceLeaf, DevicePlan, pack_layout,
+)
 
 # group-by cardinality up to which a grouped sum is ONE one-hot matmul a
 # slot (f32 `one_hot` x einsum over chunks of docs); `group_path` decides
@@ -154,6 +156,61 @@ def compiled_row_assembler(S: int, D: int, row_lens: Tuple[int, ...],
         return out
 
     return jax.jit(_named(assemble, f"assemble_s{S}"))
+
+
+# ---------------------------------------------------------------------------
+# packed parameters (ops/plan_ir.py `pack_layout` / `pack_params`)
+# ---------------------------------------------------------------------------
+
+def unpack_params(plan, params, num_docs=None):
+    """(params, num_docs) as the kernel bodies read them. Params staged
+    with the packed [K, S] int32 array (`PACK`: the engine's scan and
+    top-N legs, the vector leg's residual filter) come back as the named
+    [S] arrays, each a slice at a static row, floats bit-cast back to
+    the value dtype (a float64 from its two word rows), and `num_docs`
+    is row 0 unless the caller brought its own. Per-array params pass
+    through untouched. Rank-agnostic: a stacked [B, K, S] pack gives
+    [B, S] arrays."""
+    pack = params.get(PACK)
+    if pack is None:
+        return params, num_docs
+    out = {k: v for k, v in params.items() if k != PACK}
+    dt = _value_dtype()
+    width = jnp.dtype(dt).itemsize // 4
+    row = 0
+    for name, kind in pack_layout(plan):
+        if kind == "i":
+            out[name] = pack[..., row, :]
+            row += 1
+        elif width == 1:
+            out[name] = jax.lax.bitcast_convert_type(pack[..., row, :], dt)
+            row += 1
+        else:
+            words = jnp.moveaxis(pack[..., row:row + width, :], -2, -1)
+            out[name] = jax.lax.bitcast_convert_type(words, dt)
+            row += width
+    packed_docs = out.pop(NUM_DOCS)
+    return out, packed_docs if num_docs is None else num_docs
+
+
+def unpack_batch(plan, plist, num_docs, stacked: bool):
+    """The mesh kernels' way in, OUTSIDE shard_map (so the rows keep
+    their [.., S] specs): B members' params stacked and unpacked, and
+    the batch's num_docs: the members' own stacked ([B, S]) where their
+    blocks differ, else the one [S] they share (packed: member 0's
+    row, every member having staged the same segments)."""
+    ps, packed_docs = unpack_params(plan, stack_members(plist))
+    if stacked:
+        ns = stack_members(num_docs)
+        return ps, packed_docs if ns is None else ns
+    return ps, packed_docs[0] if num_docs is None else num_docs
+
+
+def stack_members(members):
+    """B members' staged pytrees (params dicts, column dicts, num_docs
+    arrays, or the None a packed launch brings for num_docs) stacked
+    leaf for leaf along a new leading axis."""
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *members)
 
 
 # ---------------------------------------------------------------------------
@@ -778,8 +835,10 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
     """Build the traced kernel fn(cols, params, num_docs, D) -> packed array.
 
     cols:    dict of 'ids:<col>' int32 [S, D] / 'val:<col>' float [S, D]
-    params:  dict of per-leaf predicate arrays ('leaf<i>:lo/hi/idx/lut')
-    num_docs: int32 [S] actual docs per segment (for the padding mask).
+    params:  dict of per-leaf predicate arrays ('leaf<i>:lo/hi/idx/lut'),
+             or their packed form (`unpack_params`)
+    num_docs: int32 [S] actual docs per segment (for the padding mask);
+             None where the pack carries it.
 
     Returns ONE packed array — every separate device->host fetch is a
     sync the query would wait out in turn:
@@ -795,6 +854,7 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
     fp = plan_fingerprint(plan)
 
     def kernel(cols, params, num_docs, D, G=0):
+        params, num_docs = unpack_params(plan, params, num_docs)
         # body runs at trace time: counts compiles
         note_trace(kind, fp, (*extra, int(num_docs.shape[-1]), D, G))
         valid = jnp.arange(D, dtype=jnp.int32)[None, :] < num_docs[:, None]
@@ -838,6 +898,7 @@ def make_topn_kernel(plan: DevicePlan, kind: str = "topn",
     fp = plan_fingerprint(plan)
 
     def kernel(cols, params, num_docs, D):
+        params, num_docs = unpack_params(plan, params, num_docs)
         # body runs at trace time: counts compiles
         note_trace(kind, fp, (*extra, int(num_docs.shape[-1]), D))
         valid = jnp.arange(D, dtype=jnp.int32)[None, :] < num_docs[:, None]
@@ -995,6 +1056,8 @@ def make_sharded_kernel(plan: DevicePlan, mesh):
         return P("segments", *([None] * (arr.ndim - 1)))
 
     def fn(cols, params, num_docs, D, G=0):
+        # outside shard_map: the rows come out [S], sharded as before
+        params, num_docs = unpack_params(plan, params, num_docs)
         in_specs = (
             {k: col_spec(k) for k in cols},
             {k: param_spec(v) for k, v in params.items()},
@@ -1051,14 +1114,12 @@ def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
 
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *clist)
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
-            ns = jnp.stack(ndlist)
+            cs, ps, ns = map(stack_members, (clist, plist, ndlist))
             return jax.vmap(
                 lambda c, p, nd: base(c, p, nd, D=D, G=G))(cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+            ps = stack_members(plist)
             # the index array keeps vmap fed when a filterless plan has
             # EMPTY per-query params (vmap rejects an all-empty pytree)
             idx = jnp.arange(len(plist), dtype=jnp.int32)
@@ -1093,12 +1154,11 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
     base = make_kernel(plan, kind="batched_dedup", extra=(B, U))
 
     def fn(clist, plist, ndlist, idx, D, G=0):
-        cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *clist)
-        ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
-        ns = jnp.stack(ndlist)
+        cs, ps, ns = map(stack_members, (clist, plist, ndlist))
+        pick = jax.tree_util.tree_map
         return jax.vmap(
             lambda p, i: base(
-                jax.tree_util.tree_map(lambda c: c[i], cs), p, ns[i],
+                pick(lambda c: c[i], cs), p, pick(lambda n: n[i], ns),
                 D=D, G=G))(ps, idx)
 
     return jax.jit(_named(fn, f"batched_b{B}_dedup{U}_"
@@ -1127,14 +1187,12 @@ def make_batched_topn_kernel(plan: DevicePlan, B: int,
 
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *clist)
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
-            ns = jnp.stack(ndlist)
+            cs, ps, ns = map(stack_members, (clist, plist, ndlist))
             return jax.vmap(
                 lambda c, p, nd: base(c, p, nd, D=D))(cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+            ps = stack_members(plist)
             idx = jnp.arange(len(plist), dtype=jnp.int32)  # empty-params guard
             return jax.vmap(
                 lambda p, _i: base(cols, p, num_docs, D=D))(ps, idx)
@@ -1187,14 +1245,13 @@ def make_batched_sharded_kernel(plan: DevicePlan, mesh, B: int,
         return _shard_combine_pack(plan, outs, G)
 
     def fn(cols, plist, num_docs, D, G=0):
-        ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+        ps, ns = unpack_batch(plan, plist, num_docs, stacked)
         if stacked:
-            cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *cols)
-            ns = jnp.stack(num_docs)
+            cs = stack_members(cols)
             col_spec = P(None, "segments", "docs")
             nd_spec = P(None, "segments")
         else:
-            cs, ns = cols, num_docs
+            cs = cols
             col_spec = P("segments", "docs")
             nd_spec = P("segments")
         in_specs = (

@@ -31,11 +31,70 @@ Value IR (aggregation inputs / in-kernel transforms):
     ('lit', v)
     ('add'|'sub'|'mul'|'div', a, b)
     ('neg', a)
+
+Packed parameters: every [S]-shaped parameter of a plan (the leaf bounds
+above except 'lut' and 'clp', the 'hist:' slots' bucket bounds, the time
+bucket's four cells, and num_docs) is staged as ONE int32 [K, S] array
+under params[PACK], one host->device put a query. `pack_layout(plan)`
+names its rows; the layout is a function of the plan alone, so it is in
+no cache key and a new literal never retraces. Floats are bit-cast, not
+converted (a float64 bound takes two rows), so a bound reaches the
+kernel exactly as the per-array staging gave it.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
+
+import numpy as np
+
+#: key of the packed [K, S] int32 parameter array in a staged params dict
+PACK = "pack"
+#: row 0 of every packed array: real docs a segment (0 on padded slots)
+NUM_DOCS = "num_docs"
+
+#: a leaf kind's [S] rows: (suffix, "i" int32 | "f" value-dtype float)
+_LEAF_ROWS = {
+    "range": (("lo", "i"), ("hi", "i")),
+    "neq": (("idx", "i"),),
+    "vrange": (("lo", "f"), ("hi", "f")),
+    "vrange64": (("lohi", "i"), ("lolo", "i"), ("hihi", "i"), ("hilo", "i")),
+}
+
+
+@functools.lru_cache(maxsize=1024)
+def pack_layout(plan) -> Tuple[Tuple[str, str], ...]:
+    """(name, "i" | "f") of each packed parameter, in row order. Reads
+    only `tbucket`, `agg_ops` and `leaves`, which VectorPlan mirrors.
+    Memoized a plan: staging asks on every parameter-cache miss."""
+    rows = [(NUM_DOCS, "i")]
+    if plan.tbucket:
+        rows += [(f"tb:{cell}", "i")
+                 for cell in ("shi", "slo", "step", "count")]
+    for j, (op, _vidx, _fidx) in enumerate(plan.agg_ops):
+        if op.startswith("hist:"):
+            rows += [(f"slot{j}:hlo", "f"), (f"slot{j}:hscale", "f")]
+    for i, leaf in enumerate(plan.leaves):
+        rows += [(f"leaf{i}:{suffix}", kind)
+                 for suffix, kind in _LEAF_ROWS.get(leaf.kind, ())]
+    return tuple(rows)
+
+
+def pack_params(plan, arrays: Dict[str, np.ndarray]) -> np.ndarray:
+    """The host side of the pack: `arrays` holds one [S] array a layout
+    row (int32, or the staging value dtype for an "f" row); returns the
+    int32 [K, S] array whose rows are their bits. `view`, never
+    `astype`: a float64 row becomes its (low, high) words, two rows."""
+    rows = []
+    for name, kind in pack_layout(plan):
+        arr = np.ascontiguousarray(arrays[name])
+        if kind == "i":
+            rows.append(arr.astype(np.int32, copy=False))
+        else:
+            words = arr.view(np.int32).reshape(arr.shape[0], -1)
+            rows.extend(words.T)
+    return np.stack(rows)
 
 
 @dataclass(frozen=True)

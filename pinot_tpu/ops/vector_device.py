@@ -114,6 +114,8 @@ def make_vector_kernel(plan: VectorPlan, kind: str = "vector",
     fp = kernels.plan_fingerprint(plan)
 
     def kernel(cols, params, num_docs, D):
+        # the residual filter's parameters come packed from _stage
+        params, num_docs = kernels.unpack_params(plan, params, num_docs)
         kernels.note_trace(kind, fp, (*extra, int(num_docs.shape[-1]), D))
         valid = jnp.arange(D, dtype=jnp.int32)[None, :] < num_docs[:, None]
         V = cols["vec:" + plan.col].reshape(-1, D, plan.dim_pad)
@@ -171,14 +173,13 @@ def make_batched_vector_kernel(plan: VectorPlan, B: int,
     base = make_vector_kernel(plan, kind=kind, extra=(B,))
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *clist)
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
-            ns = jnp.stack(ndlist)
+            cs, ps, ns = map(kernels.stack_members,
+                             (clist, plist, ndlist))
             return jax.vmap(lambda c, p, nd: base(c, p, nd, D=D))(
                 cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+            ps = kernels.stack_members(plist)
             idx = jnp.arange(len(plist), dtype=jnp.int32)
             return jax.vmap(lambda p, _i: base(cols, p, num_docs, D=D))(
                 ps, idx)

@@ -13,6 +13,7 @@ Serialized form:
 """
 from __future__ import annotations
 
+import hashlib
 from typing import Any, Optional, Tuple
 
 import numpy as np
@@ -54,6 +55,27 @@ class Dictionary:
     @property
     def values(self) -> np.ndarray:
         return self._values
+
+    @property
+    def content_key(self) -> bytes:
+        """Digest of (dtype, values), computed once: equal for exactly the
+        dictionaries that resolve every literal to the same dictIds, so a
+        predicate resolved against one stands for all of them (the
+        engine stages a leaf once a DISTINCT dictionary, not once a
+        segment: segments of one table mostly share their low-cardinality
+        dictionaries)."""
+        key = getattr(self, "_content_key", None)
+        if key is None:
+            vals = self._values
+            h = hashlib.sha1(vals.dtype.str.encode())
+            if vals.dtype == np.dtype(object):
+                for v in vals:
+                    b = v.encode("utf-8") if isinstance(v, str) else bytes(v)
+                    h.update(len(b).to_bytes(4, "little") + b)
+            else:
+                h.update(np.ascontiguousarray(vals).tobytes())
+            key = self._content_key = h.digest()
+        return key
 
     @property
     def fst_index(self):

@@ -66,6 +66,9 @@ METRICS: Dict[str, str] = {
     # -- dispatch ring / kernel factory ----------------------------------
     "dispatch_queue_depth": "launches waiting in the dispatch ring",
     "dispatch_batch_size": "coalesced members per launch",
+    "dispatch_held":
+        "launches the ring held for an in-flight slot (the batch grows "
+        "meanwhile)",
     "dispatch_batch_cross_table":
         "batch members coalesced across tables (stacked/dedup variants)",
     "dispatch_batch_dedup":

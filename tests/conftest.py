@@ -10,7 +10,19 @@ and chip_smoke.py covers them on the chip.
 """
 import os
 
-import jax
+# Every process of the suite (the xdist workers, the servers some tests
+# spawn) keeps its compiled programs in ONE git-ignored directory of the
+# suite's own, set before jax reads its flags: the checkout's default
+# cache (ops/device.py) is then written only by a program started without
+# the variable. tests/test_chip_smoke.py compares that directory before
+# and after a run that has to leave it alone, while the other workers
+# compile; any of their compiles of a second or more used to land there
+# in between and fail it (a change to a kernel makes every one a miss).
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_compile_cache_tests"))
+
+import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_num_cpu_devices", 8)

@@ -19,6 +19,7 @@ from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
                               TableConfig, TableType)
 from pinot_tpu.ops import kernels
 from pinot_tpu.ops.engine import TpuOperatorExecutor
+from pinot_tpu.ops.plan_ir import PACK
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.segment.creator import SegmentCreator
 from pinot_tpu.segment.loader import load_segment
@@ -52,7 +53,7 @@ def test_dispatch_overlaps_across_threads(segs, monkeypatch):
         def kernel(cols, params, num_docs, D, G=0):
             calls.append(time.perf_counter())
             time.sleep(KERNEL_S)  # a dispatch in flight
-            S = num_docs.shape[0]
+            S = params[PACK].shape[1]  # num_docs is its row 0
             return np.zeros((S, 1 + len(plan.agg_ops)), np.float32)
         return kernel
 
@@ -97,3 +98,44 @@ def test_results_stay_correct_under_concurrency(segs):
 
     with ThreadPoolExecutor(8) as pool:
         assert all(pool.map(one, range(32)))
+
+
+@pytest.mark.parametrize("clients", [4, 6])
+def test_a_busy_device_batches_the_queries_behind_it(segs, monkeypatch,
+                                                     clients):
+    """Real kernels, a device made slow: while the first launch is in
+    flight the ring holds the queries behind it, they leave as ONE batch,
+    and every caller gets the answer it gets alone (none dropped, none
+    answered with a neighbour's literals)."""
+    import jax
+    from pinot_tpu.ops import dispatch
+    # one device, as on the chip: the launch pool's path (the suite's
+    # 8-device default orders launches under the CPU-collective lock)
+    eng = TpuOperatorExecutor(devices=jax.devices()[:1])
+    ctxs = [QueryContext.from_sql(
+        f"SELECT SUM(m), COUNT(*) FROM t WHERE d < {k}")
+        for k in range(1, clients + 1)]
+
+    def values(results):
+        return [tuple(float(v) for v in r.intermediates) for r in results]
+    alone = [values(eng.execute(segs, c)[0]) for c in ctxs]
+    timer = eng._dispatcher._metrics.timer
+    launches0 = timer("dispatch_batch_size").count
+    held0 = eng._dispatcher._metrics.meter("dispatch_held")
+    # a launch stays in flight this long after its call returned; one in
+    # flight holds the ring (the module's constant may let more through)
+    monkeypatch.setattr(dispatch, "start_copy",
+                        lambda out: time.sleep(KERNEL_S))
+    monkeypatch.setattr(dispatch, "_HOLD_DEPTH", 1)
+    with ThreadPoolExecutor(clients) as pool:
+        got = list(pool.map(lambda c: eng.execute(segs, c), ctxs))
+    assert [values(res) for res, _rem in got] == alone
+    assert all(not rem for _res, rem in got)
+    # the first went alone (inline or off the ring's window), the rest in
+    # one held batch: two ring launches at most, where a ring that
+    # launches behind a busy device makes one a query
+    t = timer("dispatch_batch_size")
+    assert t.count - launches0 <= 2
+    assert t.max_ms >= clients - 2
+    assert eng._dispatcher._metrics.meter("dispatch_held") - held0 >= 1
+    assert eng._dispatcher._inflight == 0

@@ -340,8 +340,9 @@ def _staged(engine, segs, sql):
             plan, _slots = engine._plan(segs, ctx)
         else:
             plan = engine._plan_topn(segs, ctx)
-        cols, params, num_docs, _s, D, G = engine._stage(segs, ctx, plan)
-    return plan, cols, params, num_docs, D, G
+        cols, params, _S, _s, D, G = engine._stage(segs, ctx, plan)
+    # num_docs rides the packed parameters: the kernels take None for it
+    return plan, cols, params, None, D, G
 
 
 def test_jitted_kernels_are_named_by_kind_and_fingerprint(scan_segs):
