@@ -547,202 +547,6 @@ def concurrency_main(smoke: bool = False):
             f"({paired_delta_ms:.2f}ms paired)"
 
 
-def residency_main(smoke: bool = False):
-    """--residency [--smoke]: A/B the HBM residency tier (ISSUE 6).
-
-    Paired cold-vs-resident driver IN THE SAME PROCESS, interleaved like
-    --concurrency so ambient drift hits both arms:
-
-      * resident — one engine kept warm across queries: columns stay in
-        device HBM, blocks assemble from the block cache, params are
-        plan-keyed. The steady state must ship ZERO host->device bytes
-        and compile NOTHING (both odometers asserted).
-      * cold — an engine whose caches (device AND host rows) are dropped
-        before every query: the full re-ship a fresh replica pays —
-        segment decode, pad, stack, link transfer — which is exactly
-        what the residency tier deletes from the steady state.
-      * cold/legacy — the same cold path with residency disabled (host
-        stack + whole-block upload): guards the cold path against
-        regression from the per-row upload + on-device assembly.
-
-    Writes BENCH_residency.json. Kernels compile once up front; cold
-    timing measures the data path, not XLA. --smoke shrinks data and
-    skips the ratio bars.
-
-    Ratio bar: >=5x warm-resident over cold on a real accelerator, where
-    cold pays host decode + the host->device upload per query and
-    resident pays one result fetch (not measured on the chip). On a
-    CPU-ONLY stand-in there is no link to
-    delete — the structural ceiling is (staging + kernel) / kernel with
-    both sides running on the same cores — so the enforced floor drops
-    to 3x (residency still deletes the entire staging phase, which is
-    everything deletable there); the steady-state zero-transfer /
-    zero-retrace bars and the cold-regression bar assert everywhere."""
-    import statistics as stats
-    import tempfile
-
-    import jax
-
-    from pinot_tpu.ops import kernels, residency
-    from pinot_tpu.ops.engine import TpuOperatorExecutor
-    from pinot_tpu.query.executor import QueryExecutor
-    from pinot_tpu.utils.config import PinotConfiguration
-
-    if smoke:
-        from pinot_tpu.models import (DataType, FieldSpec, FieldType,
-                                      Schema, TableConfig, TableType)
-        from pinot_tpu.segment.creator import SegmentCreator
-        from pinot_tpu.segment.loader import load_segment
-        schema = Schema("ssb", [
-            FieldSpec("lo_orderdate", DataType.INT, FieldType.DIMENSION),
-            FieldSpec("lo_discount", DataType.INT, FieldType.DIMENSION),
-            FieldSpec("lo_quantity", DataType.INT, FieldType.DIMENSION),
-            FieldSpec("lo_extendedprice", DataType.INT, FieldType.METRIC),
-        ])
-        tc = TableConfig("ssb", TableType.OFFLINE)
-        tc.indexing.no_dictionary_columns = ["lo_extendedprice"]
-        tc.indexing.compression = "PASS_THROUGH"
-        creator = SegmentCreator(tc, schema)
-        tmp = tempfile.mkdtemp(prefix="bench_residency_")
-        dates = np.array([y * 10000 + m * 100 + d
-                          for y in range(1992, 1999)
-                          for m in range(1, 13) for d in range(1, 29)],
-                         dtype=np.int32)
-        segments = []
-        for i in range(4):
-            rng = np.random.default_rng(5000 + i)
-            n = 50_000
-            out = os.path.join(tmp, f"seg_{i}")
-            creator.build({
-                "lo_orderdate": dates[rng.integers(0, len(dates), n)],
-                "lo_discount": rng.integers(0, 11, n).astype(np.int32),
-                "lo_quantity": rng.integers(1, 51, n).astype(np.int32),
-                "lo_extendedprice": rng.integers(
-                    90_000, 10_000_000, n).astype(np.int32),
-            }, out, f"ssb_{i}")
-            segments.append(load_segment(out))
-    else:
-        os.makedirs(DATA_DIR, exist_ok=True)
-        build_data()
-        segments = load()
-    total_rows = sum(s.num_docs for s in segments)
-
-    def make(resident_enabled: bool):
-        eng = TpuOperatorExecutor(config=PinotConfiguration(overrides={
-            "pinot.server.hbm.resident.enabled": resident_enabled}))
-        return eng, QueryExecutor(segments, use_tpu=True, engine=eng)
-
-    eng_res, ex_res = make(True)        # stays warm: the resident arm
-    eng_cr, ex_cr = make(True)          # flushed per query: cold arm
-    eng_cl, ex_cl = make(False)         # flushed per query: cold legacy
-
-    # compile + first staging for every engine (cold timing must measure
-    # the data path, not XLA)
-    want = ex_res.execute(QUERY).rows
-    for eng, ex in ((eng_cr, ex_cr), (eng_cl, ex_cl)):
-        got = ex.execute(QUERY).rows
-        assert got == want, f"arm disagreement: {got} vs {want}"
-        eng.drop_caches(host=True)
-
-    def one(ex):
-        t0 = time.perf_counter()
-        resp = ex.execute(QUERY)
-        dt = time.perf_counter() - t0
-        assert resp.rows == want
-        return dt * 1e3
-
-    def cold_one(eng, ex):
-        eng.drop_caches(host=True)
-        dt = one(ex)
-        # drop again AFTER timing: a cold arm must not sit on gigabytes
-        # of staged blocks while the resident windows run — that memory
-        # pressure would bleed into the other arm's samples
-        eng.drop_caches(host=True)
-        return dt
-
-    rounds = 2 if smoke else 4
-    res_iters = 8 if smoke else 20
-    cold_iters = 2 if smoke else 4
-    lat_res, lat_cold, lat_cold_legacy = [], [], []
-    res_transfers = res_traces = 0
-    for r in range(rounds):
-        # resident window first; cold flushes touch OTHER engines, so
-        # the resident engine's steady state spans the whole run
-        b0, t0 = residency.transfer_bytes(), kernels.trace_count()
-        for _ in range(res_iters):
-            lat_res.append(one(ex_res))
-        res_transfers += residency.transfer_bytes() - b0
-        res_traces += kernels.trace_count() - t0
-        for i in range(cold_iters):
-            # alternate which cold arm goes first within the pair
-            if (r + i) % 2 == 0:
-                lat_cold.append(cold_one(eng_cr, ex_cr))
-                lat_cold_legacy.append(cold_one(eng_cl, ex_cl))
-            else:
-                lat_cold_legacy.append(cold_one(eng_cl, ex_cl))
-                lat_cold.append(cold_one(eng_cr, ex_cr))
-
-    p50_res = stats.median(lat_res)
-    p50_cold = stats.median(lat_cold)
-    p50_cold_legacy = stats.median(lat_cold_legacy)
-    resident_rate = total_rows / (p50_res / 1e3)
-    cold_rate = total_rows / (p50_cold / 1e3)
-    speedup = resident_rate / max(cold_rate, 1e-9)
-    # paired delta: sample i of both cold arms ran back-to-back
-    cold_paired_delta_ms = stats.median(
-        c - l for c, l in zip(lat_cold, lat_cold_legacy))
-    cold_regress_pct = cold_paired_delta_ms / p50_cold_legacy * 100.0
-    device_like = jax.default_backend() != "cpu"
-    min_speedup = 5.0 if device_like else 3.0
-    out = {
-        "metric": "hbm_residency_warm_vs_cold_speedup",
-        "value": round(speedup, 2),
-        "unit": "x",
-        "num_segments": len(segments),
-        "total_rows": total_rows,
-        "smoke": smoke,
-        "link_rt_ms": round(measure_link_rt_ms(), 2),
-        "resident": {
-            "p50_ms": round(p50_res, 2),
-            "rows_per_sec": round(resident_rate),
-            "transfer_bytes_steady": res_transfers,
-            "retraces_steady": res_traces,
-            "hbm_resident_rows": len(eng_res._residency),
-            "hbm_resident_bytes": eng_res._residency.bytes,
-        },
-        "cold": {"p50_ms": round(p50_cold, 2),
-                 "rows_per_sec": round(cold_rate)},
-        "cold_legacy": {"p50_ms": round(p50_cold_legacy, 2),
-                        "rows_per_sec": round(
-                            total_rows / (p50_cold_legacy / 1e3))},
-        "cold_paired_delta_ms": round(cold_paired_delta_ms, 3),
-        "cold_regress_pct": round(cold_regress_pct, 2),
-        "backend": jax.default_backend(),
-        "asserted": {"min_speedup": min_speedup,
-                     "device_like": device_like,
-                     "max_cold_regress_pct": 10.0,
-                     "max_steady_transfer_bytes": 0,
-                     "max_steady_retraces": 0, "full_mode_only_ratio": smoke},
-    }
-    with open(os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "BENCH_residency.json"), "w") as f:
-        json.dump(out, f, indent=2)
-    print(json.dumps(out))
-    assert res_transfers == 0, \
-        f"resident steady state shipped {res_transfers} bytes"
-    assert res_traces == 0, \
-        f"resident steady state compiled {res_traces} kernels"
-    if not smoke:
-        assert speedup >= min_speedup, \
-            f"warm-resident speedup {speedup:.2f}x < {min_speedup}x " \
-            f"over cold ({jax.default_backend()} backend)"
-        # epsilon absorbs scheduler noise on the paired medians; a real
-        # regression from per-row uploads would show far above this
-        assert cold_regress_pct < 10.0 or cold_paired_delta_ms < 2.0, \
-            f"cold path regressed {cold_regress_pct:.1f}% " \
-            f"({cold_paired_delta_ms:.2f}ms paired)"
-
-
 def _mse_throughput_leg(smoke: bool = False) -> dict:
     """Factory-batched vs serialized leaf dispatch for fingerprint-equal
     MSE traffic (ISSUE 10 acceptance leg). Two measurements:
@@ -2257,7 +2061,7 @@ def ingest_main(smoke: bool = False, out_path: str = None):
         failpoint decision journals must be byte-identical (the PR-3
         chaos bar).
 
-    Writes BENCH_ingest.json (backend-gated like BENCH_residency.json).
+    Writes BENCH_ingest.json (backend-gated).
     """
     import threading
 
@@ -4901,8 +4705,6 @@ if __name__ == "__main__":
         deadline_overhead_main()
     elif "--concurrency" in sys.argv:
         concurrency_main(smoke="--smoke" in sys.argv)
-    elif "--residency" in sys.argv:
-        residency_main(smoke="--smoke" in sys.argv)
     elif "--mse" in sys.argv:
         mse_main(smoke="--smoke" in sys.argv)
     elif "--groups" in sys.argv:

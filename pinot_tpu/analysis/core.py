@@ -87,13 +87,13 @@ class ModuleIndex:
 
     Indexes ``pinot_tpu/`` (production), ``tests/`` (the failpoint
     checker proves every site is armed by a test), and the top-level
-    ``bench*.py`` drivers (they read config knobs too). Files that fail
+    ``bench.py`` driver (it reads config knobs too). Files that fail
     to parse surface as findings from :meth:`parse_errors` rather than
     crashing the run — a syntax error must fail the gate, not the tool.
     """
 
     SUBDIRS = ("pinot_tpu", "tests")
-    TOP_GLOBS = ("bench.py", "bench_cache.py", "bench_extra.py")
+    TOP_GLOBS = ("bench.py",)
 
     def __init__(self, root: Optional[str] = None,
                  files: Optional[Iterable[str]] = None):

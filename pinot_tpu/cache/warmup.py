@@ -226,7 +226,7 @@ class SegmentWarmup:
                     # swap publishes them — the zero-gap pipeline's
                     # residency half applies regardless of cacheability
                     if engine is not None:
-                        with engine.residency_seeding():
+                        with engine.residency.seeding():
                             if engine.prestage([segment], ctx):
                                 self.segments_prestaged += 1
                     continue
@@ -239,7 +239,7 @@ class SegmentWarmup:
                     # columns are what survive both
                     warmed += 1
                     if engine is not None:
-                        with engine.residency_seeding():
+                        with engine.residency.seeding():
                             engine.prestage([segment], ctx)
                     continue
                 ex = QueryExecutor([segment], use_tpu=self.use_tpu,
@@ -251,7 +251,7 @@ class SegmentWarmup:
                     # seeding context admits the columns into HBM
                     # residency with the frequency seed, so the fresh
                     # segment's first routed queries run device-resident
-                    with engine.residency_seeding():
+                    with engine.residency.seeding():
                         ex.execute_context(ctx)
                 else:
                     ex.execute_context(ctx)

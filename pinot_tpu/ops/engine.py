@@ -10,9 +10,8 @@ ONE device program over stacked [num_segments, padded_docs] blocks.
 Responsibilities:
   * supports(ctx): structural check — which query shapes offload
   * plan: QueryContext -> DevicePlan IR (ops/plan_ir.py)
-  * staging: per-(segment, column) device arrays, cached in HBM across
-    queries (the segment-cache SURVEY.md §7.5 calls for), padded to
-    power-of-two doc buckets to bound retraces
+  * staging: WHICH rows a plan needs, padded to power-of-two doc buckets
+    to bound retraces; the tiers that keep them (ops/staging.py)
   * per-segment predicate resolution -> kernel parameter arrays
   * multi-device: inputs sharded over the mesh's `segments` axis
   * result assembly back into AggregationResult/GroupByResult intermediates
@@ -36,6 +35,7 @@ from pinot_tpu.ops import collective
 from pinot_tpu.ops import device as device_mod
 from pinot_tpu.ops import dispatch as dispatch_mod
 from pinot_tpu.ops import kernels
+from pinot_tpu.ops import residency as residency_mod
 from pinot_tpu.ops import startree_device
 from pinot_tpu.ops import timeseries_device
 from pinot_tpu.ops import vector_device
@@ -43,6 +43,7 @@ from pinot_tpu.ops.dispatch import KernelDispatcher, Launch
 from pinot_tpu.ops.plan_ir import (
     NUM_DOCS, PACK, DeviceLeaf, DevicePlan, pack_params,
 )
+from pinot_tpu.ops.staging import BlockStager, batch_id
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.expressions import (
     Expression, Function, Identifier, Literal)
@@ -50,7 +51,8 @@ from pinot_tpu.query.filter import resolve_predicate
 from pinot_tpu.query.results import (
     AggregationResult, ExecutionStats, GroupByResult)
 from pinot_tpu.segment.loader import DataSource, ImmutableSegment
-from pinot_tpu.utils import tracing
+from pinot_tpu.utils import accounting, tracing
+from pinot_tpu.utils.config import PinotConfiguration
 from pinot_tpu.utils.failpoints import fire
 
 MAX_DEVICE_GROUPS = 1 << 20
@@ -109,93 +111,28 @@ class TpuOperatorExecutor:
             if len(self.devices) > 1:
                 from jax.sharding import Mesh
                 self._mesh = Mesh(np.array(self.devices), ("segments",))
-        #: [segments-shard][its devices], in mesh order: segment slot i
-        #: of an [S, ...] block lives on shard i // (S / shards), and a
-        #: resident row is put on that shard's first device
-        self._shards = self._segment_shards(self._mesh) \
-            if self._mesh is not None else []
-        #: bytes of resident rows copied chip to chip at block assembly
-        #: (a row found on another chip than its slab's) since start-up
-        self._cross_chip_bytes = 0
-        #: ASSEMBLED device blocks, LRU-evicted under a byte budget: the
-        #: exact [S, D] arrays kernels consume, keyed by the segment
-        #: batch identity (id+name pairs guard against id() reuse). A
-        #: miss here no longer pays the host link — blocks assemble
-        #: on-device from the per-(segment, column) residency tier below
-        from collections import OrderedDict
-        self._block_cache: "OrderedDict[tuple, Any]" = OrderedDict()
-        self._block_bytes: Dict[tuple, int] = {}
-        self._cache_bytes = 0
-        #: live block count per batch identity — O(1) detection of "this
-        #: batch's LAST block just left", which triggers the params purge
-        self._batch_blocks: Dict[tuple, int] = {}
-        #: host-side padded rows per (segment, column): rebuilding a new
-        #: batch skips segment re-read/decode; LRU-evicted under its own
-        #: byte budget (entries pin their segment, so eviction also
-        #: releases replaced segments)
-        self._host_rows: "OrderedDict[tuple, Any]" = OrderedDict()
-        self._host_bytes = 0
-        import os as _os
-
-        from pinot_tpu.utils.config import PinotConfiguration
-        _cfg = config or PinotConfiguration()
-        # legacy short env names still win for compatibility. The host
-        # row cache is this process's memory, one budget a server; the
-        # two HBM knobs are bytes PER CHIP, and the engine's pools are
-        # the knob times the chips it holds
-        chips = max(len(self.devices), 1)
-        self.host_budget_bytes = int(_os.environ.get(
-            "PINOT_TPU_HOST_ROW_CACHE_BYTES",
-            _cfg.get_int("pinot.server.host.row.cache.bytes")))
-        self.cache_budget_bytes = chips * int(_os.environ.get(
-            "PINOT_TPU_HBM_CACHE_BYTES",
-            _cfg.get_int("pinot.server.hbm.cache.bytes")))
-        #: per-(segment, column) device-resident rows (ops/residency.py):
-        #: the tier that survives batch recomposition — a changed pruned
-        #: subset or a newly sealed segment uploads only rows the device
-        #: has never seen; everything else assembles on-device
-        from pinot_tpu.ops.residency import ResidencyManager
-        resident_bytes = chips * int(_os.environ.get(
-            "PINOT_TPU_HBM_RESIDENT_BYTES",
-            _cfg.get_int("pinot.server.hbm.resident.bytes")))
-        if not _cfg.get_bool("pinot.server.hbm.resident.enabled", True):
-            resident_bytes = 0
-        self._metrics = None  # set after the dispatcher below
-        self._labels = metrics_labels
-        self._residency = ResidencyManager(
-            resident_bytes,
-            admission=_cfg.get_bool("pinot.server.hbm.admission.enabled",
-                                    True),
-            sample_window=_cfg.get_int("pinot.server.hbm.admission.sample"),
-            labels=metrics_labels,
-            devices=self.devices)
-        #: staging lock only: cache mutation (plan/stage/evict) serializes,
-        #: but kernel dispatch + result fetch run OUTSIDE it so concurrent
-        #: queries overlap their device round trips (every result fetch is
-        #: one host<->device sync; overlapped, N queries share its latency).
-        #: Eviction drops cache references WITHOUT .delete(): the staging
-        #: query itself and any concurrently dispatched kernels hold the
-        #: block as an input, and JAX refcounting frees the HBM as soon as
-        #: the last consumer finishes — an eager delete could invalidate a
-        #: buffer mid-flight, and a deferred-until-quiescent delete list
-        #: would pin evicted blocks forever under sustained pipelined load
-        self._engine_lock = threading.RLock()
-        #: resolved predicate parameter arrays per (batch, plan, filter) —
-        #: repeat queries then cost zero host->device param uploads;
-        #: bounded LRU (hot filter parameters survive cache pressure
-        #: instead of a wholesale clear dropping them all at once)
-        self._params_cache: "OrderedDict[tuple, Any]" = OrderedDict()
         #: `_put` calls so far, and the running staging pass's parameter
         #: part (seconds, puts): written under the engine lock, read by
         #: `_staging_attrs` into paramsMs / paramPuts
         self._puts = 0
         self._params_s = 0.0
         self._param_puts = 0
+        _cfg = config or PinotConfiguration()
+        self._labels = metrics_labels
         #: pipelined dispatch stage: ring + micro-batching + fetch
         #: overlap (ops/dispatch.py); owns NO engine state — staging
         #: stays under the engine lock, launches ride the ring
         self._dispatcher = KernelDispatcher(config=_cfg,
                                             labels=metrics_labels)
+        self._metrics = self._dispatcher._metrics
+        #: the cache tiers and the one path a row takes through them
+        #: (ops/staging.py); the legs below say only what to stage
+        self.stager = BlockStager(self.devices, self._mesh, config=_cfg,
+                                  metrics=self._metrics,
+                                  labels=metrics_labels)
+        #: the staging lock is the stager's: one query's plan + stage
+        #: run under it, its launch and result fetch outside it
+        self._engine_lock = self.stager.lock
         #: cross-table shape-bucketed batching (the kernel-factory key):
         #: pad S to pow2 buckets so fingerprint-equal queries over
         #: DIFFERENT tables/partitions share a coalesce key; doc buckets
@@ -212,29 +149,20 @@ class TpuOperatorExecutor:
             "pinot.server.dispatch.doc.bucket.max")
         #: star-tree device leg (ops/startree_device.py): fitted queries
         #: aggregate pre-agg records through the kernel factory instead
-        #: of scanning raw rows; hbm.resident admits the pre-agg
-        #: pseudo-columns into the per-(segment, column) residency tier
+        #: of scanning raw rows
         self._startree_enabled = _cfg.get_bool(
             "pinot.server.startree.enabled", True)
-        self._st_resident = _cfg.get_bool(
-            "pinot.server.startree.hbm.resident", True)
         #: CLP log-column LIKE/regex pushdown (ops/clp_device.py):
         #: patterns compile to logtype LUTs + variable-slot conditions
         #: evaluated as 'clp' filter leaves through the same kernel
-        #: factory; hbm.resident admits the logtype-id / var-slot
-        #: pseudo-columns into the per-(segment, column) residency tier
+        #: factory
         self._clp_enabled = _cfg.get_bool(
             "pinot.server.clp.enabled", True)
-        self._clp_resident = _cfg.get_bool(
-            "pinot.server.clp.hbm.resident", True)
         #: vector-similarity device leg (ops/vector_device.py): ANN
         #: top-K as one batched matmul + lax.top_k over staged vector
-        #: blocks; hbm.resident admits the __vec__ pseudo-columns into
-        #: the per-(segment, column) residency tier
+        #: blocks
         self._vector_enabled = _cfg.get_bool(
             "pinot.server.vector.enabled", True)
-        self._vector_resident = _cfg.get_bool(
-            "pinot.server.vector.hbm.resident", True)
         #: time-series device bucket leg (ops/timeseries_device.py):
         #: floor((t - start) / step) group-bys fuse the bucket id into
         #: the group-by kernel's scatter key instead of falling back to
@@ -250,8 +178,6 @@ class TpuOperatorExecutor:
         #: host-factorized global group-key remap params per
         #: (segment batch, plan) — built once, re-used across queries
         self._gmap_cache: "OrderedDict[tuple, Any]" = OrderedDict()
-        self._metrics = self._dispatcher._metrics
-        self._residency._metrics = self._metrics
 
     # ------------------------------------------------------------------
     # capability check (structural)
@@ -259,15 +185,7 @@ class TpuOperatorExecutor:
     #: cap on selection/order-by top-K offload (limit + offset)
     TOPN_MAX_K = 8192
 
-    #: LRU capacity of the predicate-parameter cache (entries are tiny)
-    PARAMS_CACHE_ENTRIES = 4096
-
-    #: residency miss bursts at/above this many bytes upload in parallel
-    #: on the upload pool (below it, thread handoff costs more than the
-    #: copies themselves)
-    UPLOAD_FANOUT_BYTES = 16 << 20
-
-    #: hard backstop on any single dispatcher/upload future wait
+    #: hard backstop on any single dispatcher future wait
     #: (dispatch_mod.wait_result): queries are bounded by their own
     #: deadline checker well before this — the cap exists for
     #: budget-less internal callers (warmup/prestage) so a wedged
@@ -420,13 +338,7 @@ class TpuOperatorExecutor:
         the engine lock, staging ms split into plan / block look-ups /
         parameter puts, and host->device transfer bytes — exact per
         query because staging holds the engine lock (_staging_lock)."""
-        if parent_span is None:
-            parent_span = tracing.capture()
-        dsp = None
-        if parent_span is not None:
-            dsp = parent_span.child("DeviceDispatch", table=ctx.table,
-                                    mode="agg")
-        from pinot_tpu.ops import residency as residency_mod
+        dsp = self._dispatch_span(ctx, "agg", parent_span)
         with self._staging_lock(dsp) as stage_info:
             # odometer read INSIDE the lock: the diff must cover exactly
             # this query's staging, not a concurrent stager's
@@ -518,28 +430,14 @@ class TpuOperatorExecutor:
             self._meter("group_path", path=path)
             if dsp is not None:
                 dsp.set(groupPath=path)
-        # the mesh shape rides the coalesce key: launches never pair
-        # across differently-sharded engines (or merged with unmerged)
-        mesh_sig = ("mesh", self._mesh, self._doc_axis, minfo is not None)
-        batch_key = None
-        if batchable and self._dispatcher.batch_max > 1:
-            if self._cross_table and D <= self._doc_bucket_max:
-                # the kernel-factory coalesce key: (plan fingerprint,
-                # shape bucket) — fingerprint-equal queries batch across
-                # tables and partitions whenever their padded buckets
-                # and staged-array shapes/dtypes line up (the signature
-                # catches per-table variation: LUT cardinality pads, id
-                # dtype width)
-                batch_key = (plan, S, D, G_eff, _shape_sig(cols, params),
-                             mesh_sig)
-            else:
-                # legacy key: identical staged segment batch only
-                batch_key = (plan, _batch_id(segments), D, G_eff, mesh_sig)
         launch = Launch(
             # num_docs rides the packed parameters (plan_ir.PACK)
             call=lambda: kernel(cols, params, None, D=D, G=G_eff),
             plan=plan, cols=cols, params=params, num_docs=None,
-            D=D, G=G_eff, batch_key=batch_key,
+            D=D, G=G_eff,
+            batch_key=self._coalesce_key(
+                plan, segments, S, D, G_eff, cols, params, batchable,
+                merged=minfo is not None),
             cols_key=self._cols_key(segments, plan),
             factory=factory, dedup_factory=dedup_factory,
             collective=self._needs_cpu_ordering(kernel),
@@ -548,6 +446,25 @@ class TpuOperatorExecutor:
             slip=slip, docs=sum(s.num_docs for s in segments),
             staged_ts=staged_ts)
         return plan, slots_of_fn, S_real, launch, minfo
+
+    def _coalesce_key(self, plan, segments, S, D, G, cols, params,
+                      batchable: bool, merged: bool = False):
+        """The dispatch ring's coalesce key of a staged launch (None:
+        it never batches). The mesh shape rides the key: launches never
+        pair across differently-sharded engines (or merged with
+        unmerged)."""
+        if not batchable or self._dispatcher.batch_max <= 1:
+            return None
+        mesh_sig = ("mesh", self._mesh, self._doc_axis, merged)
+        if self._cross_table and D <= self._doc_bucket_max:
+            # the kernel-factory key: (plan fingerprint, shape bucket) —
+            # fingerprint-equal queries batch across tables and
+            # partitions whenever their padded buckets and staged-array
+            # shapes/dtypes line up (the signature catches per-table
+            # variation: LUT cardinality pads, id dtype width)
+            return (plan, S, D, G, _shape_sig(cols, params), mesh_sig)
+        # legacy key: identical staged segment batch only
+        return (plan, batch_id(segments), D, G, mesh_sig)
 
     # ------------------------------------------------------------------
     # collective broker merge (ops/collective.py)
@@ -595,7 +512,7 @@ class TpuOperatorExecutor:
         stride changes re-upload KBs, never retrace). Cached per
         (segment batch, plan); returns (params, G pad, real group
         count, decode info for _assemble_merged)."""
-        key = (_batch_id(segments), plan, S, G_local)
+        key = (batch_id(segments), plan, S, G_local)
         ent = self._gmap_cache.get(key)
         if ent is not None:
             self._gmap_cache.move_to_end(key)
@@ -814,87 +731,35 @@ class TpuOperatorExecutor:
         cols, params, S, S_real, D, _G = self._stage(
             segments, rctx, plan, batchable=batchable)
         dim_pad = plan.dim_pad
-        row_lens = tuple(_pow2(s.num_docs) * dim_pad for s in segments)
-        cols["vec:" + plan.col] = self._vec_block_locked(
-            segments, S, D * dim_pad, plan.col, "block",
-            (lambda seg: vector_device.vector_row(
-                seg, plan.col, dim_pad, _pow2(seg.num_docs))),
-            np.float32, row_lens)
+        # `(segment, "__vec__/<col>/<leg>")` pseudo-columns: rows pad to
+        # the segment's OWN pow2 doc bucket (times dim_pad for the
+        # flattened vector leg), so every batch composition shares them
+        cols["vec:" + plan.col] = self.stager.stage_block_locked(
+            segments, S, D * dim_pad, "vector", (plan.col, "block"),
+            np.float32, lambda _i, seg: (
+                "vector", f"__vec__/{plan.col}/block",
+                _pow2(seg.num_docs) * dim_pad,
+                lambda sg: vector_device.vector_row(
+                    sg, plan.col, dim_pad, _pow2(sg.num_docs))))
         if plan.ivf:
-            cols["vcell:" + plan.col] = self._vec_block_locked(
-                segments, S, D, plan.col, "cells",
-                (lambda seg: vector_device.cell_row(
-                    seg, plan.col, _pow2(seg.num_docs))),
-                np.int32, tuple(_pow2(s.num_docs) for s in segments))
+            cols["vcell:" + plan.col] = self.stager.stage_block_locked(
+                segments, S, D, "vector", (plan.col, "cells"), np.int32,
+                lambda _i, seg: (
+                    "vector", f"__vec__/{plan.col}/cells",
+                    _pow2(seg.num_docs),
+                    lambda sg: vector_device.cell_row(
+                        sg, plan.col, _pow2(sg.num_docs))))
         pmark = self._params_begin()
-        pkey = (_batch_id(segments), plan, fn, "__vec__", S)
-        cached = self._params_cache.get(pkey)
-        if cached is not None:
-            csegs, cparams = cached
-            if all(a is b for a, b in zip(csegs, segments)):
-                self._params_cache.move_to_end(pkey)
-                params.update(cparams)
-                self._params_end(pmark)
-                return cols, params, S, S_real, D
-        qp = vector_device.query_params(segments, plan, qvec, k, S)
-        vparams = {key: self._put(arr) for key, arr in qp.items()}
-        params.update(vparams)
-        self._params_cache[pkey] = (tuple(segments), vparams)
-        self._params_cache.move_to_end(pkey)
-        while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
-            self._params_cache.popitem(last=False)
+        pkey = (batch_id(segments), plan, fn, "__vec__", S)
+        cached = self.stager.params_get_locked(pkey, segments)
+        if cached is None:
+            qp = vector_device.query_params(segments, plan, qvec, k, S)
+            cached = (tuple(segments),
+                      {key: self._put(arr) for key, arr in qp.items()})
+            self.stager.params_put_locked(pkey, cached)
+        params.update(cached[1])
         self._params_end(pmark)
         return cols, params, S, S_real, D
-
-    def _vec_block_locked(self, segments, S, W, col, leg, fetch, dtype,
-                          row_lens):
-        """One staged [S, W] vector pseudo-column block
-        (`(segment, "__vec__/<col>/<leg>")`), mirroring _st_block_locked:
-        per-segment rows pad to their OWN pow2 doc bucket (times dim_pad
-        for the flattened vector leg) so every batch composition shares
-        the resident rows; the on-device assembler pads the tail to W.
-        Residency admission honors pinot.server.vector.hbm.resident."""
-        dtype_str = np.dtype(dtype).str
-        bkey = (_batch_id(segments), "vector", (col, leg), S, W, dtype_str)
-        entry = self._block_cache.get(bkey)
-        if entry is not None and all(a is b
-                                     for a, b in zip(entry[0], segments)):
-            self._block_cache.move_to_end(bkey)
-            self._meter("hbm_block_hit")
-            return entry[1]
-        self._meter("hbm_block_miss")
-        name = f"__vec__/{col}/{leg}"
-        if self._residency.enabled and self._vector_resident:
-            dev_rows: List[Any] = []
-            missing: List[int] = []
-            for seg in segments:
-                row = self._residency.get(seg, "vector", name, dtype_str)
-                dev_rows.append(row)
-                if row is None:
-                    missing.append(len(dev_rows) - 1)
-            if missing:
-                host_rows = [self._host_row(
-                    segments[i], name, "vector", fetch, dtype,
-                    pad_to=row_lens[i]) for i in missing]
-                uploaded = self._upload_rows(host_rows, missing, S)
-                for i, arr, dev in zip(missing, host_rows, uploaded):
-                    self._residency.admit(segments[i], "vector", name,
-                                          dtype_str, dev, arr.nbytes,
-                                          device=self._dev_label(dev))
-                    dev_rows[i] = dev
-            dev = self._assemble_rows(dev_rows, S, W, dtype_str)
-            nbytes = S * W * np.dtype(dtype).itemsize
-        else:
-            rows = [self._host_row(seg, name, "vector", fetch, dtype,
-                                   pad_to=W)
-                    for seg in segments]
-            block = np.stack(rows) if len(rows) == S else \
-                np.concatenate([np.stack(rows),
-                                np.zeros((S - len(rows), W), dtype=dtype)])
-            dev = self._put(block, block=True)
-            nbytes = block.nbytes
-        self._insert_block(bkey, (tuple(segments), dev), nbytes)
-        return dev
 
     def _prepare_vector(self, segments, ctx: QueryContext, cancel_check):
         """Plan + stage an ANN launch through the kernel factory: the
@@ -903,14 +768,8 @@ class TpuOperatorExecutor:
         fingerprint-equal concurrent ANN queries (different vectors, same
         shape) batch into ONE jit(vmap) launch. Returns
         (plan, S_real, Launch) or None -> host path (reason metered)."""
-        from pinot_tpu.ops import residency as residency_mod
-        from pinot_tpu.utils import accounting
-        dsp = None
-        parent_span = tracing.capture()
+        dsp = self._dispatch_span(ctx, "vector")
         slip = accounting.current_slip()
-        if parent_span is not None:
-            dsp = parent_span.child("DeviceDispatch", table=ctx.table,
-                                    mode="vector")
         with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
             plan, qinfo, rctx = self._plan_vector(segments, ctx)
@@ -938,18 +797,11 @@ class TpuOperatorExecutor:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
         self._meter("vector_served")
-        batch_key = None
-        if batchable and self._dispatcher.batch_max > 1:
-            if self._cross_table and D <= self._doc_bucket_max:
-                batch_key = (plan, S, D, 0, _shape_sig(cols, params),
-                             ("mesh", self._mesh, self._doc_axis))
-            else:
-                batch_key = (plan, _batch_id(segments), D, 0,
-                             ("mesh", self._mesh, self._doc_axis))
         launch = Launch(
             call=lambda: kernel(cols, params, None, D=D),
             plan=plan, cols=cols, params=params, num_docs=None,
-            D=D, G=0, batch_key=batch_key,
+            D=D, G=0, batch_key=self._coalesce_key(
+                plan, segments, S, D, 0, cols, params, batchable),
             cols_key=self._cols_key(segments, plan),
             factory=(lambda B, stacked, _p=plan:
                      vector_device.compiled_batched_vector_kernel(
@@ -975,12 +827,7 @@ class TpuOperatorExecutor:
             return [], segments
         plan, S_real, launch = prep
         with self._dispatcher.active():
-            try:
-                packed = dispatch_mod.wait_result(
-                    self._dispatcher.submit(launch), launch.cancel_check,
-                    max_wait_s=self.LAUNCH_WAIT_CAP_S)
-            finally:
-                launch.end_span()
+            packed = self._await_launch(launch)
         t_asm = time.perf_counter()
         results = vector_device.assemble(segments, ctx, plan,
                                          np.asarray(packed), S_real)
@@ -999,13 +846,8 @@ class TpuOperatorExecutor:
         _prepare_agg's lock/span/odometer discipline exactly; the
         DeviceDispatch span carries starTree=true so traces distinguish
         pre-agg serves from scans."""
-        if parent_span is None:
-            parent_span = tracing.capture()
-        dsp = None
-        if parent_span is not None:
-            dsp = parent_span.child("DeviceDispatch", table=ctx.table,
-                                    mode="startree", starTree=True)
-        from pinot_tpu.ops import residency as residency_mod
+        dsp = self._dispatch_span(ctx, "startree", parent_span,
+                                  starTree=True)
         with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
             plan, needed, fits, reason = startree_device.plan_startree(
@@ -1036,19 +878,12 @@ class TpuOperatorExecutor:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
         self._meter("startree_served")
-        batch_key = None
-        if batchable and self._dispatcher.batch_max > 1:
-            if self._cross_table and D <= self._doc_bucket_max:
-                # the same kernel-factory coalesce key as scans: plan
-                # fingerprint + shape bucket — fingerprint-equal
-                # star-tree queries (same slots/radix, any predicate
-                # constants) share ONE jit(vmap) launch
-                S = int(num_docs.shape[0])
-                batch_key = (plan, S, D, 0, _shape_sig(cols, params),
-                             ("mesh", self._mesh, self._doc_axis))
-            else:
-                batch_key = (plan, _batch_id(segments), D, 0,
-                             ("mesh", self._mesh, self._doc_axis))
+        # the same coalesce key as scans: fingerprint-equal star-tree
+        # queries (same slots/radix, any predicate constants) share ONE
+        # jit(vmap) launch
+        batch_key = self._coalesce_key(
+            plan, segments, int(num_docs.shape[0]), D, 0, cols, params,
+            batchable)
         # the staged-block identity carries the fitted tree indexes:
         # members whose filters fit DIFFERENT trees of one segment must
         # stack, not share a broadcast block
@@ -1057,7 +892,7 @@ class TpuOperatorExecutor:
             call=lambda: kernel(cols, params, num_docs, D=D, G=0),
             plan=plan, cols=cols, params=params, num_docs=num_docs,
             D=D, G=0, batch_key=batch_key,
-            cols_key=(_batch_id(segments), tis),
+            cols_key=(batch_id(segments), tis),
             factory=factory, dedup_factory=None,
             collective=self._needs_cpu_ordering(kernel),
             cancel_check=cancel_check,
@@ -1079,18 +914,28 @@ class TpuOperatorExecutor:
         max_recs = max(int(f.tree.meta.num_records) for f in fits)
         if max_recs > MAX_DOCS_PER_SEGMENT:
             raise _NotStageable()
-        D = _pow2(max_recs)
-        if D % self._doc_axis:
-            a = self._doc_axis
-            D = ((D + a - 1) // a) * a
+        D = _pow2(max_recs)  # docs axis is 1 here (_startree_candidate)
         S = self._padded_S(
             S_real, bucket=batchable and D <= self._doc_bucket_max)
         vdt = np.float64 if jax.config.read("jax_enable_x64") else np.float32
 
+        # per-SEGMENT pseudo-column names (`__startree__<ti>/<col>`):
+        # one segment can hold several trees materializing the same
+        # pair over different record layouts, and host/resident rows
+        # must key on the tree actually fitted — the block key carries
+        # the whole ti tuple for the same reason. Rows pad to the tree's
+        # OWN pow2 record bucket (batch-independent, so every batch
+        # composition shares them)
+        tis = tuple(f.ti for f in fits)
         cols: Dict[str, jnp.ndarray] = {}
         for ckey, form, dtype in startree_device.staged_columns(plan, vdt):
-            cols[ckey] = self._st_block_locked(segments, fits, S, D, ckey, form,
-                                        dtype)
+            cols[ckey] = self.stager.stage_block_locked(
+                segments, S, D, "startree", (ckey, tis), dtype,
+                lambda i, _seg: (
+                    "startree", f"__startree__{fits[i].ti}/{ckey}",
+                    _pow2(int(fits[i].tree.meta.num_records)),
+                    lambda _sg: startree_device.fetch_row(
+                        fits[i].tree, form, dtype)))
 
         # selection mask + record counts: cached like predicate params —
         # a repeat query (same batch, same plan shape, same filter)
@@ -1098,91 +943,31 @@ class TpuOperatorExecutor:
         # indexes are deterministic in (segments, plan, filter), so the
         # scan-path key form is sufficient here too.
         pmark = self._params_begin()
-        pkey = (_batch_id(segments), plan, ctx.filter, "__startree__", S, D)
-        cached = self._params_cache.get(pkey)
-        if cached is not None:
-            csegs, cparams, cnum_docs = cached
-            if all(a is b for a, b in zip(csegs, segments)):
-                self._params_cache.move_to_end(pkey)
-                self._params_end(pmark)
-                return cols, dict(cparams), cnum_docs, S_real, D
-        sel = startree_device.selection_mask(fits, S, D)
-        params = {"sel": self._put(sel, block=True)}
-        num_docs = np.zeros(S, dtype=np.int32)
-        num_docs[:S_real] = [int(f.tree.meta.num_records) for f in fits]
-        num_docs_dev = self._put(num_docs)
-        self._params_cache[pkey] = (tuple(segments), dict(params),
-                                    num_docs_dev)
-        self._params_cache.move_to_end(pkey)
-        while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
-            self._params_cache.popitem(last=False)
+        pkey = (batch_id(segments), plan, ctx.filter, "__startree__", S, D)
+        cached = self.stager.params_get_locked(pkey, segments)
+        if cached is None:
+            sel = startree_device.selection_mask(fits, S, D)
+            num_docs = np.zeros(S, dtype=np.int32)
+            num_docs[:S_real] = [int(f.tree.meta.num_records) for f in fits]
+            cached = (tuple(segments), {"sel": self._put(sel, block=True)},
+                      self._put(num_docs))
+            self.stager.params_put_locked(pkey, cached)
         self._params_end(pmark)
-        return cols, params, num_docs_dev, S_real, D
-
-    def _st_block_locked(self, segments, fits, S, D, ckey, form, dtype):
-        """One staged [S, D] pre-agg block. Mirrors _block /
-        _assemble_resident, with per-SEGMENT pseudo-column names
-        (`__startree__<ti>/<col>`): one segment can hold several trees
-        materializing the same pair over different record layouts, and
-        host/resident rows must key on the tree actually fitted — the
-        batch-level key carries the whole ti tuple for the same reason.
-        Residency admission honors pinot.server.startree.hbm.resident;
-        off, blocks still cache at the assembled tier but rows don't
-        compete for resident-tier bytes."""
-        dtype_str = np.dtype(dtype).str
-        tis = tuple(f.ti for f in fits)
-        bkey = (_batch_id(segments), "startree", (ckey, tis), S, D,
-                dtype_str)
-        entry = self._block_cache.get(bkey)
-        if entry is not None and all(a is b
-                                     for a, b in zip(entry[0], segments)):
-            self._block_cache.move_to_end(bkey)
-            self._meter("hbm_block_hit")
-            return entry[1]
-        self._meter("hbm_block_miss")
-        names = [f"__startree__{f.ti}/{ckey}" for f in fits]
-        fetchers = [
-            (lambda seg, _t=f.tree: startree_device.fetch_row(_t, form,
-                                                              dtype))
-            for f in fits]
-        if self._residency.enabled and self._st_resident:
-            dev_rows: List[Any] = []
-            missing: List[int] = []
-            for seg, name in zip(segments, names):
-                row = self._residency.get(seg, "startree", name, dtype_str)
-                dev_rows.append(row)
-                if row is None:
-                    missing.append(len(dev_rows) - 1)
-            if missing:
-                # rows pad to the tree's OWN pow2 record bucket
-                # (batch-independent, so every batch composition shares
-                # them); the on-device assembler pads the tail to D
-                host_rows = [self._host_row(
-                    segments[i], names[i], "startree", fetchers[i], dtype,
-                    pad_to=_pow2(int(fits[i].tree.meta.num_records)))
-                    for i in missing]
-                uploaded = self._upload_rows(host_rows, missing, S)
-                for i, arr, dev in zip(missing, host_rows, uploaded):
-                    self._residency.admit(segments[i], "startree",
-                                          names[i], dtype_str, dev,
-                                          arr.nbytes,
-                                          device=self._dev_label(dev))
-                    dev_rows[i] = dev
-            dev = self._assemble_rows(dev_rows, S, D, dtype_str)
-            nbytes = S * D * np.dtype(dtype).itemsize
-        else:
-            rows = [self._host_row(seg, name, "startree", fetch, dtype,
-                                   pad_to=D)
-                    for seg, name, fetch in zip(segments, names, fetchers)]
-            block = np.stack(rows) if len(rows) == S else \
-                np.concatenate([np.stack(rows),
-                                np.zeros((S - len(rows), D), dtype=dtype)])
-            dev = self._put(block, block=True)
-            nbytes = block.nbytes
-        self._insert_block(bkey, (tuple(segments), dev), nbytes)
-        return dev
+        return cols, dict(cached[1]), cached[2], S_real, D
 
     # -- staging trace attrs -------------------------------------------
+    @staticmethod
+    def _dispatch_span(ctx: QueryContext, mode: str, parent_span=None,
+                       **attrs):
+        """The query's DeviceDispatch span, a child of `parent_span` (the
+        caller's handle where staging runs off the request thread; else
+        the contextvar's). None untraced."""
+        parent_span = parent_span or tracing.capture()
+        if parent_span is None:
+            return None
+        return parent_span.child("DeviceDispatch", table=ctx.table,
+                                 mode=mode, **attrs)
+
     @contextlib.contextmanager
     def _staging_lock(self, dsp):
         """The engine lock round one query's plan + stage, the wait for
@@ -1197,7 +982,6 @@ class TpuOperatorExecutor:
             with self._engine_lock:
                 yield None
             return
-        from pinot_tpu.ops import residency as residency_mod
         with dispatch_mod.phase_annotation("lock_wait", dsp):
             self._engine_lock.acquire()
         try:
@@ -1222,7 +1006,7 @@ class TpuOperatorExecutor:
         dsp.set(meshDevices=len(self.devices),
                 chipBytesInUse=[m.get("bytes_in_use") for m in stats],
                 chipPeakBytes=[m.get("peak_bytes_in_use") for m in stats],
-                crossChipBytes=self._cross_chip_bytes)
+                crossChipBytes=self.stager.cross_chip_bytes)
 
     def _params_begin(self):
         """Mark the start of a staging pass's parameter part (resolve
@@ -1244,7 +1028,6 @@ class TpuOperatorExecutor:
         block-cache look-ups and, on a miss, row fetch + upload)."""
         if dsp is None or snap is None:
             return 0.0
-        from pinot_tpu.ops import residency as residency_mod
         t0, xfer0 = snap
         now = time.perf_counter()
         dsp.set(
@@ -1275,43 +1058,53 @@ class TpuOperatorExecutor:
             return self._execute_distinct(segments, ctx, cancel_check)
         if not ctx.aggregations:
             return self._execute_topn(segments, ctx, cancel_check)
-        from pinot_tpu.utils import accounting
-        slip = accounting.current_slip()
         with self._dispatcher.active():
-            # star-tree leg first: a fitted tree answers from pre-agg
-            # records; any fallback reason drops through to the scan
-            # prepare below (and transitively to the host path)
-            st = self._prepare_startree(segments, ctx, cancel_check,
-                                        slip=slip) \
-                if self._startree_candidate(segments) else None
-            if st is not None:
-                st_plan, needed, fits, S_real, launch = st
-            else:
-                prep = self._prepare_agg(segments, ctx, cancel_check,
-                                         slip=slip)
-                if prep is None:
-                    return [], segments
-                plan, slots_of_fn, S_real, launch, minfo = prep
-            try:
-                # deadline-bounded: the checker carries the query's
-                # remaining budget; the cap backstops budget-less callers
-                packed = dispatch_mod.wait_result(
-                    self._dispatcher.submit(launch), launch.cancel_check,
-                    max_wait_s=self.LAUNCH_WAIT_CAP_S)
-            finally:
-                launch.end_span()
+            prep = self._prepare(segments, ctx, cancel_check,
+                                 slip=accounting.current_slip())
+            if prep is None:
+                return [], segments
+            launch, assemble = prep
+            packed = self._await_launch(launch)
         t_asm = time.perf_counter()
-        if st is not None:
-            results = startree_device.assemble(segments, ctx, st_plan,
-                                               needed, fits, packed)
-        elif minfo is not None:
-            results = self._assemble_merged(segments, ctx, plan, packed,
-                                            S_real, slots_of_fn, minfo)
-        else:
-            results = self._assemble(segments, ctx, plan, packed, S_real,
-                                     slots_of_fn)
+        results = assemble(packed)
         self._note_assemble(launch, t_asm)
         return results, []
+
+    def _await_launch(self, launch: Launch):
+        """Submit to the ring and wait for the packed result, deadline-
+        bounded: the checker carries the query's remaining budget; the
+        cap backstops budget-less callers. Ends the launch's span."""
+        try:
+            return dispatch_mod.wait_result(
+                self._dispatcher.submit(launch), launch.cancel_check,
+                max_wait_s=self.LAUNCH_WAIT_CAP_S)
+        finally:
+            launch.end_span()
+
+    def _prepare(self, segments, ctx: QueryContext, cancel_check,
+                 parent_span=None, slip=None):
+        """Plan + stage an aggregation: (Launch, packed result ->
+        per-segment results), or None -> host path. Star-tree leg
+        first: a fitted tree answers from pre-agg records; any fallback
+        reason drops through to the scan prepare (and transitively to
+        the host path)."""
+        st = self._prepare_startree(segments, ctx, cancel_check,
+                                    parent_span=parent_span, slip=slip) \
+            if self._startree_candidate(segments) else None
+        if st is not None:
+            st_plan, needed, fits, _S_real, launch = st
+            return launch, lambda packed: startree_device.assemble(
+                segments, ctx, st_plan, needed, fits, packed)
+        prep = self._prepare_agg(segments, ctx, cancel_check,
+                                 parent_span=parent_span, slip=slip)
+        if prep is None:
+            return None
+        plan, slots_of_fn, S_real, launch, minfo = prep
+        if minfo is not None:
+            return launch, lambda packed: self._assemble_merged(
+                segments, ctx, plan, packed, S_real, slots_of_fn, minfo)
+        return launch, lambda packed: self._assemble(
+            segments, ctx, plan, packed, S_real, slots_of_fn)
 
     @staticmethod
     def _note_assemble(launch: Launch, t0: float, parent=None) -> None:
@@ -1351,65 +1144,30 @@ class TpuOperatorExecutor:
         # capture on the CALLER thread: staging runs on the staging pool
         # where neither the trace contextvar nor the accounting
         # thread-local flows
-        from pinot_tpu.utils import accounting
         parent_span = tracing.capture()
         slip = accounting.current_slip()
 
         def stage_and_enqueue():
             try:
-                st = self._prepare_startree(segments, ctx, cancel_check,
-                                            parent_span=parent_span,
-                                            slip=slip) \
-                    if self._startree_candidate(segments) else None
-                if st is not None:
-                    st_plan, needed, fits, _S_real, launch = st
-                    lfut = self._dispatcher.submit(launch)
-
-                    def finish_st(f):
-                        launch.end_span()
-                        t_asm = time.perf_counter()
-                        try:
-                            # lint: hang(done-callback: f is already resolved)
-                            packed = f.result()
-                            results = startree_device.assemble(
-                                segments, ctx, st_plan, needed, fits,
-                                packed)
-                            self._note_assemble(launch, t_asm, parent_span)
-                            out.set_result((results, []))
-                        except BaseException as e:  # noqa: BLE001
-                            out.set_exception(e)
-
-                    lfut.add_done_callback(finish_st)
-                    return
-                prep = self._prepare_agg(segments, ctx, cancel_check,
-                                         parent_span=parent_span,
-                                         slip=slip)
+                prep = self._prepare(segments, ctx, cancel_check,
+                                     parent_span=parent_span, slip=slip)
                 if prep is None:
                     out.set_result(([], segments))
                     return
-                plan, slots_of_fn, S_real, launch, minfo = prep
-                lfut = self._dispatcher.submit(launch)
+                launch, assemble = prep
 
                 def finish(f):
                     launch.end_span()
                     t_asm = time.perf_counter()
                     try:
                         # lint: hang(done-callback: f is already resolved)
-                        packed = f.result()
-                        if minfo is not None:
-                            results = self._assemble_merged(
-                                segments, ctx, plan, packed, S_real,
-                                slots_of_fn, minfo)
-                        else:
-                            results = self._assemble(
-                                segments, ctx, plan, packed, S_real,
-                                slots_of_fn)
+                        results = assemble(f.result())
                         self._note_assemble(launch, t_asm, parent_span)
                         out.set_result((results, []))
                     except BaseException as e:  # noqa: BLE001
                         out.set_exception(e)
 
-                lfut.add_done_callback(finish)
+                self._dispatcher.submit(launch).add_done_callback(finish)
             except BaseException as e:  # noqa: BLE001
                 out.set_exception(e)
 
@@ -1447,14 +1205,8 @@ class TpuOperatorExecutor:
         paying one XLA launch per stage per query. Caller must hold no
         engine state; returns (S_real, Launch) or None -> host path.
         Must be called with doc_axis == 1 (sharded top-K stays host)."""
-        from pinot_tpu.ops import residency as residency_mod
-        from pinot_tpu.utils import accounting
-        dsp = None
-        parent_span = tracing.capture()
+        dsp = self._dispatch_span(ctx, mode)
         slip = accounting.current_slip()
-        if parent_span is not None:
-            dsp = parent_span.child("DeviceDispatch", table=ctx.table,
-                                    mode=mode)
         with self._staging_lock(dsp) as stage_info:
             xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
             plan = self._plan_topn(segments, ctx)
@@ -1480,18 +1232,11 @@ class TpuOperatorExecutor:
                 slip.add(transfer_bytes=int(
                     residency_mod.transfer_bytes() - xfer0))
         self._meter("scan_served")
-        batch_key = None
-        if batchable and self._dispatcher.batch_max > 1:
-            if self._cross_table and D <= self._doc_bucket_max:
-                batch_key = (plan, S, D, 0, _shape_sig(cols, params),
-                             ("mesh", self._mesh, self._doc_axis))
-            else:
-                batch_key = (plan, _batch_id(segments), D, 0,
-                             ("mesh", self._mesh, self._doc_axis))
         launch = Launch(
             call=lambda: kernel(cols, params, None, D=D),
             plan=plan, cols=cols, params=params, num_docs=None,
-            D=D, G=0, batch_key=batch_key,
+            D=D, G=0, batch_key=self._coalesce_key(
+                plan, segments, S, D, 0, cols, params, batchable),
             cols_key=self._cols_key(segments, plan),
             factory=(lambda B, stacked, _p=plan:
                      kernels.compiled_batched_topn_kernel(_p, B, stacked)),
@@ -1514,12 +1259,7 @@ class TpuOperatorExecutor:
             return [], segments
         S_real, launch = prep
         with self._dispatcher.active():
-            try:
-                packed = dispatch_mod.wait_result(
-                    self._dispatcher.submit(launch), launch.cancel_check,
-                    max_wait_s=self.LAUNCH_WAIT_CAP_S)
-            finally:
-                launch.end_span()
+            packed = self._await_launch(launch)
         t_asm = time.perf_counter()
         results = self._assemble_topn(segments, ctx, packed, S_real)
         self._note_assemble(launch, t_asm)
@@ -1848,12 +1588,7 @@ class TpuOperatorExecutor:
         S_real, launch = prep
         plan = launch.plan
         with self._dispatcher.active():
-            try:
-                packed = dispatch_mod.wait_result(
-                    self._dispatcher.submit(launch), launch.cancel_check,
-                    max_wait_s=self.LAUNCH_WAIT_CAP_S)
-            finally:
-                launch.end_span()
+            packed = self._await_launch(launch)
         out = []
         for s, seg in enumerate(segments[:S_real]):
             matched = int(packed[s, 0])
@@ -2128,24 +1863,17 @@ class TpuOperatorExecutor:
                         raise _NotStageable()
                     return fn(r)
                 return fetch_row
-            cols["clpid:" + col] = self._block(
-                segments, S, D, col, "clpid",
-                clp_fetch(clp_device.row_ids), np.int32,
-                resident=self._clp_resident)
-            for j in range(kd):
-                cols[f"clpdv{j}:{col}"] = self._block(
-                    segments, S, D, col, f"clpdv{j}",
-                    clp_fetch(lambda r, _j=j: clp_device.row_dict_slot(
-                        r, _j)), np.int32, resident=self._clp_resident)
+            family = [("clpid", clp_device.row_ids)] + [
+                (f"clpdv{j}", lambda r, _j=j: clp_device.row_dict_slot(r, _j))
+                for j in range(kd)]
             for j in range(ke):
-                cols[f"clpehi{j}:{col}"] = self._block(
-                    segments, S, D, col, f"clpehi{j}",
-                    clp_fetch(lambda r, _j=j: clp_device.row_enc_hi(
-                        r, _j)), np.int32, resident=self._clp_resident)
-                cols[f"clpelo{j}:{col}"] = self._block(
-                    segments, S, D, col, f"clpelo{j}",
-                    clp_fetch(lambda r, _j=j: clp_device.row_enc_lo(
-                        r, _j)), np.int32, resident=self._clp_resident)
+                family += [(f"clpehi{j}",
+                            lambda r, _j=j: clp_device.row_enc_hi(r, _j)),
+                           (f"clpelo{j}",
+                            lambda r, _j=j: clp_device.row_enc_lo(r, _j))]
+            for kind, fn in family:
+                cols[f"{kind}:{col}"] = self._block(
+                    segments, S, D, col, kind, clp_fetch(fn), np.int32)
 
         # value columns: stage MATERIALIZED values (dictionary take done
         # host-side at staging, cached in HBM) rather than in-kernel
@@ -2182,21 +1910,18 @@ class TpuOperatorExecutor:
         # [K, S] array (plan_ir.pack_params) and ONE put; the [S, C]
         # LUT tables and the CLP leaf arrays keep a put each
         pmark = self._params_begin()
-        pkey = (_batch_id(segments), plan, ctx.filter,
+        pkey = (batch_id(segments), plan, ctx.filter,
                 tuple(ctx.agg_filters), S,
                 tuple(ctx.group_by) if plan.tbucket else None)
-        cached = self._params_cache.get(pkey)
+        cached = self.stager.params_get_locked(pkey, segments)
         if cached is not None:
-            csegs, cparams = cached
-            if all(a is b for a, b in zip(csegs, segments)):
-                self._params_cache.move_to_end(pkey)  # LRU refresh
-                params.update(cparams)
-                if plan.clp_cols:
-                    self._meter("clp_served")
-                if plan.tbucket:
-                    self._meter("timeseries_leaf_device")
-                self._params_end(pmark)
-                return cols, params, S, S_real, D, G
+            params.update(cached[1])
+            if plan.clp_cols:
+                self._meter("clp_served")
+            if plan.tbucket:
+                self._meter("timeseries_leaf_device")
+            self._params_end(pmark)
+            return cols, params, S, S_real, D, G
         rows: Dict[str, np.ndarray] = {}
         rows[NUM_DOCS] = np.zeros(S, dtype=np.int32)
         rows[NUM_DOCS][:S_real] = [s.num_docs for s in segments]
@@ -2298,10 +2023,7 @@ class TpuOperatorExecutor:
                 params[f"leaf{i}:lut"] = self._put(table)
 
         params[PACK] = self._put(pack_params(plan, rows), seg_axis=1)
-        self._params_cache[pkey] = (tuple(segments), dict(params))
-        self._params_cache.move_to_end(pkey)
-        while len(self._params_cache) > self.PARAMS_CACHE_ENTRIES:
-            self._params_cache.popitem(last=False)  # evict coldest only
+        self.stager.params_put_locked(pkey, (tuple(segments), dict(params)))
         if plan.clp_cols:
             self._meter("clp_served")
         if plan.tbucket:
@@ -2357,7 +2079,7 @@ class TpuOperatorExecutor:
         two coalesced members whose upsert bitmaps moved between their
         stagings stack separately instead of silently sharing one
         member's snapshot through the broadcast variant."""
-        base = _batch_id(segments)
+        base = batch_id(segments)
         if plan.valid_mask:
             return (base, tuple(self._mask_stamp(s) for s in segments))
         return base
@@ -2376,23 +2098,6 @@ class TpuOperatorExecutor:
         upsert lands in the NEXT staging, the same discipline as the
         host executor's per-query to_mask()."""
         stamps = tuple(self._mask_stamp(s) for s in segments)
-        batch = _batch_id(segments)
-        bkey = (batch, "vmask", "__valid__", S, D, stamps)
-        entry = self._block_cache.get(bkey)
-        if entry is not None and all(a is b
-                                     for a, b in zip(entry[0], segments)):
-            self._block_cache.move_to_end(bkey)
-            self._meter("hbm_block_hit")
-            return entry[1]
-        self._meter("hbm_block_miss")
-        # purge blocks staged under superseded mask versions of THIS
-        # batch: every future lookup carries the new stamps, so the old
-        # block is unreachable and would squat in the HBM budget
-        for k in [k for k in self._block_cache
-                  if k[0] == batch and k[1] == "vmask" and k != bkey]:
-            del self._block_cache[k]
-            self._cache_bytes -= self._block_bytes.pop(k)
-            self._drop_batch_block(k[0])
 
         def fetch_row(seg):
             valid = getattr(seg, "valid_doc_ids", None)
@@ -2406,51 +2111,11 @@ class TpuOperatorExecutor:
                     [m, np.zeros(seg.num_docs - len(m), dtype=bool)])
             return m[:seg.num_docs]
 
-        dtype_str = np.dtype(bool).str
-        if self._residency.enabled:
-            dev_rows: List[Any] = []
-            missing: List[int] = []
-            for seg, stamp in zip(segments, stamps):
-                row = self._residency.get(seg, f"vmask:{stamp}",
-                                          "__valid__", dtype_str)
-                dev_rows.append(row)
-                if row is None:
-                    missing.append(len(dev_rows) - 1)
-            for i in missing:
-                seg = segments[i]
-                # a miss means this stamp was never staged: purge the
-                # superseded stamps' rows (host + resident) — they are
-                # unreachable and would squat in both budgets
-                self._residency.invalidate_superseded_kind(
-                    seg, "vmask:", f"vmask:{stamps[i]}", "__valid__")
-                for hk in [k for k, v in self._host_rows.items()
-                           if k[0] == id(seg) and v[0] is seg
-                           and isinstance(k[1], str)
-                           and k[1].startswith("vmask:")
-                           and k[1] != f"vmask:{stamps[i]}"]:
-                    _s, payload = self._host_rows.pop(hk)
-                    self._host_bytes -= _entry_nbytes(payload)
-                arr = self._host_row(seg, "__valid__",
-                                     f"vmask:{stamps[i]}", fetch_row, bool)
-                dev = self._put_row(arr, self._slot_device(i, S))
-                self._residency.admit(seg, f"vmask:{stamps[i]}",
-                                      "__valid__", dtype_str, dev,
-                                      arr.nbytes,
-                                      device=self._dev_label(dev))
-                dev_rows[i] = dev
-            dev = self._assemble_rows(dev_rows, S, D, dtype_str)
-            nbytes = S * D
-        else:
-            rows = [self._host_row(seg, "__valid__", f"vmask:{st}",
-                                   fetch_row, bool, pad_to=D)
-                    for seg, st in zip(segments, stamps)]
-            block = np.stack(rows) if len(rows) == S else \
-                np.concatenate([np.stack(rows),
-                                np.zeros((S - len(rows), D), dtype=bool)])
-            dev = self._put(block, block=True)
-            nbytes = block.nbytes
-        self._insert_block(bkey, (tuple(segments), dev), nbytes)
-        return dev
+        return self.stager.stage_block_locked(
+            segments, S, D, "vmask", "__valid__", bool,
+            lambda i, seg: (f"vmask:{stamps[i]}", "__valid__",
+                            _pow2(seg.num_docs), fetch_row),
+            stamps=stamps)
 
     def _stage_gkey(self, segments, S, D, plan: DevicePlan):
         """Compacted combined group keys: one int32 [S, D] code block,
@@ -2486,12 +2151,10 @@ class TpuOperatorExecutor:
             return self._segment_gkey_locked(seg, plan)
 
     def _segment_gkey_locked(self, seg, plan: DevicePlan):
-        sig = ",".join(plan.group_cols)
-        rkey = (id(seg), "gkey", sig)
-        rentry = self._host_rows.get(rkey)
-        if rentry is not None and rentry[0] is seg:
-            self._host_rows.move_to_end(rkey)
-            return rentry[1]
+        rkey = (id(seg), "gkey", ",".join(plan.group_cols))
+        cached = self.stager.host_get_locked(rkey, seg)
+        if cached is not None:
+            return cached
         cards = []
         prod = 1
         for col in plan.group_cols:
@@ -2525,35 +2188,12 @@ class TpuOperatorExecutor:
             table[:, j] = rem % cards[j]
             rem //= cards[j]
         codes = inv.astype(np.int32)
-        self._host_rows[rkey] = (seg, (codes, table))
-        self._host_bytes += codes.nbytes + table.nbytes
-        while self._host_bytes > self.host_budget_bytes \
-                and len(self._host_rows) > 1:
-            _k, (_s, _a) = self._host_rows.popitem(last=False)
-            self._host_bytes -= _entry_nbytes(_a)
+        self.stager.host_put_locked(rkey, seg, (codes, table))
         return codes, table
 
     def _stacked(self, segments, S, D, col, kind, fetch, dtype):
-        """Stacked per-segment column block, three-level cached:
-
-        * HOST level, per (segment, column): the padded numpy row (its
-          own pow2 doc bucket) — rebuilding any batch skips segment
-          re-read/re-decode.
-        * RESIDENT level, per (segment, column): the same row in device
-          HBM (ops/residency.py) — a changed batch (pruning picked a
-          different subset, a new segment sealed) uploads ONLY rows the
-          device has never seen, instead of re-shipping every column
-          from the host.
-        * ASSEMBLED level, per (batch, column): the [S, D] block the
-          kernel consumes, built ON-DEVICE from resident rows
-          (kernels.compiled_row_assembler) — steady state is zero
-          transfers and zero assembly.
-
-        Entries at every level hold strong segment references and verify
-        identity on hit, so a refreshed segment (same name, new object)
-        can never serve stale data — id() is not recycled while an entry
-        pins the old object, and a new object misses.
-        """
+        """[S, D] block of a real column: `fetch(data source)` gives a
+        segment's row, through the stager's three tiers."""
 
         def fetch_row(seg):
             if not seg.has_column(col):
@@ -2563,197 +2203,13 @@ class TpuOperatorExecutor:
         return self._block(segments, S, D, col, kind, fetch_row, dtype)
 
     def _block(self, segments, S, D, col, kind, fetch_row, dtype,
-               host_cache: bool = True, resident: bool = True):
-        """resident=False (clp.hbm.resident off): skip the per-row
-        residency tier for this block family — host stack + whole-block
-        upload, so opted-out pseudo-columns never evict scan columns."""
-        dtype_str = np.dtype(dtype).str
-        bkey = (_batch_id(segments), kind, col, S, D, dtype_str)
-        entry = self._block_cache.get(bkey)
-        if entry is not None and all(a is b
-                                     for a, b in zip(entry[0], segments)):
-            self._block_cache.move_to_end(bkey)  # LRU touch
-            self._meter("hbm_block_hit")
-            return entry[1]
-        self._meter("hbm_block_miss")
-        if self._residency.enabled and resident:
-            dev = self._assemble_resident(segments, S, D, col, kind,
-                                          fetch_row, dtype, host_cache)
-            nbytes = S * D * np.dtype(dtype).itemsize
-        else:
-            # legacy path: host-side stack + one whole-block upload
-            rows = [self._host_row(seg, col, kind, fetch_row, dtype,
-                                   host_cache, pad_to=D)
-                    for seg in segments]
-            block = np.stack(rows) if len(rows) == S else \
-                np.concatenate([np.stack(rows),
-                                np.zeros((S - len(rows), D), dtype=dtype)])
-            dev = self._put(block, block=True)
-            nbytes = block.nbytes
-        self._insert_block(bkey, (tuple(segments), dev), nbytes)
-        return dev
-
-    def _assemble_resident(self, segments, S, D, col, kind, fetch_row,
-                           dtype, host_cache: bool):
-        """[S, D] block from per-segment resident rows: misses build on
-        the host and upload individually (in parallel for multi-row
-        bursts — ops/dispatch.upload_pool), hits cost nothing, and the
-        stack itself runs on-device."""
-        dtype_str = np.dtype(dtype).str
-        dev_rows: List[Any] = []
-        missing: List[int] = []
-        for seg in segments:
-            row = self._residency.get(seg, kind, col, dtype_str)
-            dev_rows.append(row)
-            if row is None:
-                missing.append(len(dev_rows) - 1)
-        if missing:
-            # host rows first: _NotStageable must surface BEFORE any
-            # upload (a doomed plan should not churn the resident tier)
-            host_rows = [self._host_row(segments[i], col, kind, fetch_row,
-                                        dtype, host_cache)
-                         for i in missing]
-            uploaded = self._upload_rows(host_rows, missing, S)
-            for i, arr, dev in zip(missing, host_rows, uploaded):
-                self._residency.admit(segments[i], kind, col, dtype_str,
-                                      dev, arr.nbytes,
-                                      device=self._dev_label(dev))
-                dev_rows[i] = dev
-        return self._assemble_rows(dev_rows, S, D, dtype_str)
-
-    def _upload_rows(self, host_rows, slots, S: int) -> list:
-        """One `_put_row` a host row, each to the device that owns its
-        segment slot of an [S, ...] block."""
-        targets = [self._slot_device(i, S) for i in slots]
-        if len(host_rows) > 1 and sum(
-                a.nbytes for a in host_rows) >= self.UPLOAD_FANOUT_BYTES:
-            # double-buffer big bursts: row N+1's transfer overlaps
-            # row N's (and, under execute_async, the previous
-            # query's kernel). Small rows stay inline — thread
-            # handoff costs more than the copy
-            futs = [dispatch_mod.upload_pool().submit(self._put_row, a, d)
-                    for a, d in zip(host_rows, targets)]
-            # pool-executed device_puts always complete; the cap
-            # bounds a wedged-device-link hang (no query deadline
-            # here — staging also runs under warmup/prestage)
-            return [dispatch_mod.wait_result(
-                f, max_wait_s=self.LAUNCH_WAIT_CAP_S) for f in futs]
-        return [self._put_row(a, d) for a, d in zip(host_rows, targets)]
-
-    @staticmethod
-    def _segment_shards(mesh) -> List[list]:
-        """[segments-shard][its devices] of a mesh, in mesh order (one
-        shard of every device where the mesh has no segments axis)."""
-        devs = mesh.devices
-        if "segments" not in mesh.axis_names:
-            return [list(devs.flat)]
-        devs = np.moveaxis(devs, mesh.axis_names.index("segments"), 0)
-        return [list(d) for d in devs.reshape(devs.shape[0], -1)]
-
-    def _slot_device(self, slot: int, S: int):
-        """The device that owns segment slot `slot` of an [S, ...] block
-        (S a multiple of the segments axis, `_padded_S`): the first
-        device of the slot's segments-shard. None without a mesh."""
-        if self._mesh is None:
-            return None
-        return self._shards[slot // (S // len(self._shards))][0]
-
-    def _assemble_rows(self, dev_rows, S: int, D: int, dtype_str: str):
-        """The kernel-ready [S, D] block from one resident row a segment
-        slot (fewer rows than S leave zero slots at the end), stacked
-        on-device (kernels.compiled_row_assembler). On a mesh every
-        segments-shard's [S / shards, D] slab is stacked on the device
-        that owns it (`_slot_device`), from the rows `_put_row` already
-        placed there, and the global array is made from the slabs: no
-        device holds more than its shard of the block beside its own
-        resident rows. A row found on another chip (a batch recomposed
-        after pruning) is copied chip to chip to its slab's device
-        only, never over the host link, and metered as
-        `hbm_cross_chip_bytes`. On a (segments, docs) mesh the slab is
-        then split over `docs` among its own shard's devices."""
-        if self._mesh is None:
-            assembler = kernels.compiled_row_assembler(
-                S, D, tuple(int(r.shape[0]) for r in dev_rows), dtype_str)
-            return assembler(tuple(dev_rows))
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        sharding = NamedSharding(
-            self._mesh, P("segments", "docs") if self._doc_axis > 1
-            else P("segments", None))
-        # which docs of its slab each device of a shard holds
-        index = sharding.devices_indices_map((S, D)) \
-            if len(self._shards[0]) > 1 else None
-        per = S // len(self._shards)
-        pieces = []
-        for j, shard in enumerate(self._shards):
-            home = shard[0]
-            rows = []
-            for r in dev_rows[j * per:(j + 1) * per]:
-                if home not in r.devices():
-                    self._cross_chip_bytes += r.nbytes
-                    self._meter("hbm_cross_chip_bytes", r.nbytes)
-                    r = jax.device_put(r, home)
-                rows.append(r)
-            assembler = kernels.compiled_row_assembler(
-                per, D, tuple(int(r.shape[0]) for r in rows), dtype_str)
-            if rows:
-                slab = assembler(tuple(rows))
-            else:  # a slab of padding only: no input says where it lives
-                with jax.default_device(home):
-                    slab = assembler(())
-            pieces += [slab if len(shard) == 1
-                       else jax.device_put(slab[:, index[d][1]], d)
-                       for d in shard]
-        return jax.make_array_from_single_device_arrays(
-            (S, D), sharding, pieces)
-
-    def _host_row(self, seg, col, kind, fetch_row, dtype,
-                  cache: bool = True, pad_to: Optional[int] = None):
-        """Padded numpy row for one (segment, column): the segment's own
-        pow2 doc bucket (batch-independent, so every batch composition
-        shares it), via the host row cache."""
-        Dr = pad_to if pad_to is not None else _pow2(seg.num_docs)
-        rkey = (id(seg), kind, col, Dr, np.dtype(dtype).str)
-        rentry = self._host_rows.get(rkey)
-        if rentry is not None and rentry[0] is seg:
-            self._host_rows.move_to_end(rkey)
-            self._meter("host_row_hit")
-            return rentry[1]
-        self._meter("host_row_miss")
-        raw = fetch_row(seg)
-        arr = np.zeros(Dr, dtype=dtype)
-        arr[:len(raw)] = raw
-        if cache:
-            self._host_rows[rkey] = (seg, arr)
-            self._host_bytes += arr.nbytes
-            while self._host_bytes > self.host_budget_bytes \
-                    and len(self._host_rows) > 1:
-                _k, (_s, _a) = self._host_rows.popitem(last=False)
-                self._host_bytes -= _entry_nbytes(_a)
-                self._meter("host_row_evicted")
-            self._refresh_tier_gauges()
-        return arr
-
-    def _put_row(self, arr: np.ndarray, device=None):
-        """Upload ONE residency row to `device`, the chip that owns its
-        segment slot (`_slot_device`): a segment's rows live where its
-        shard of every block lives, so blocks assemble per shard with
-        no chip-to-chip copy and the per-chip budgets
-        (ops/residency.py) fill evenly. None (no mesh): the default
-        device. Runs on upload-pool threads for multi-row bursts and
-        touches no engine state."""
-        from pinot_tpu.ops import residency as residency_mod
-        residency_mod.note_transfer(arr.nbytes, column=True)
-        self._meter("hbm_transfer_bytes", arr.nbytes)
-        if device is None:
-            return jnp.asarray(arr)
-        return jax.device_put(arr, device)
-
-    @staticmethod
-    def _dev_label(arr) -> str:
-        """`platform:id` label of the device holding a committed row —
-        the key the per-chip residency ledger and `device=` gauges use."""
-        d = next(iter(arr.devices()))
-        return f"{d.platform}:{d.id}"
+               host_cache: bool = True):
+        """[S, D] block whose rows are `(segment, kind, col)`, each
+        padded to its segment's own pow2 doc bucket."""
+        return self.stager.stage_block_locked(
+            segments, S, D, kind, col, dtype,
+            lambda _i, seg: (kind, col, _pow2(seg.num_docs), fetch_row),
+            host_cache=host_cache)
 
     def _meter(self, name: str, value: float = 1, **labels: str) -> None:
         """labels: the `reason=` of a `*_fallback` meter, the `path=` of
@@ -2764,119 +2220,17 @@ class TpuOperatorExecutor:
             labels = dict(self._labels or {}, **labels)
         self._metrics.add_meter(name, value, labels=labels or self._labels)
 
-    def _refresh_tier_gauges(self) -> None:
-        if self._metrics is None:
-            return
-        self._metrics.set_gauge(
-            "hbm_cache_bytes", self._cache_bytes + self._residency.bytes,
-            labels=self._labels)
-        self._metrics.set_gauge("host_row_cache_bytes", self._host_bytes,
-                                labels=self._labels)
-        if len(self.devices) > 1:
-            # per-chip split: assembled blocks are sharded evenly over
-            # the mesh (equal per-chip share of _cache_bytes); resident
-            # rows are committed whole to one chip each, so their bytes
-            # attribute exactly (the skew admission control watches)
-            by_dev = self._residency.bytes_by_device()
-            share = self._cache_bytes // len(self.devices)
-            for d in self.devices:
-                lab = f"{d.platform}:{d.id}"
-                labels = dict(self._labels or {})
-                labels["device"] = lab
-                self._metrics.set_gauge(
-                    "hbm_cache_bytes", share + by_dev.get(lab, 0),
-                    labels=labels)
-                self._metrics.set_gauge(
-                    "hbm_resident_bytes", by_dev.get(lab, 0),
-                    labels=labels)
-
-    def _insert_block(self, key, entry, nbytes: int) -> None:
-        if key not in self._block_cache:
-            self._batch_blocks[key[0]] = \
-                self._batch_blocks.get(key[0], 0) + 1
-        else:
-            self._cache_bytes -= self._block_bytes[key]
-        self._block_cache[key] = entry
-        self._block_bytes[key] = nbytes
-        self._cache_bytes += nbytes
-        while self._cache_bytes > self.cache_budget_bytes and len(self._block_cache) > 1:
-            # drop the reference only — the current query and concurrent
-            # dispatches hold evicted blocks as kernel inputs; refcounting
-            # frees the HBM when the last consumer finishes
-            old_key, _entry = self._block_cache.popitem(last=False)
-            self._cache_bytes -= self._block_bytes.pop(old_key)
-            self._meter("hbm_evicted")
-            self._drop_batch_block(old_key[0])
-        self._refresh_tier_gauges()
-
-    def _drop_batch_block(self, batch: tuple) -> None:
-        """One block of `batch` left the cache; when it was the LAST,
-        the batch's predicate params can never pair with a live block
-        again — drop them now instead of stranding them until global
-        LRU pressure (params key on (batch, plan, filter)). The
-        refcount keeps the common case O(1); the bounded params scan
-        runs once per batch death, not per eviction."""
-        n = self._batch_blocks.get(batch, 1) - 1
-        if n > 0:
-            self._batch_blocks[batch] = n
-            return
-        self._batch_blocks.pop(batch, None)
-        for pk in [k for k in self._params_cache if k[0] == batch]:
-            del self._params_cache[pk]
-
     # ------------------------------------------------------------------
     # residency lifecycle (invalidation, warmup seeding, proactive load)
     # ------------------------------------------------------------------
     @property
     def residency(self):
-        return self._residency
-
-    def residency_seeding(self):
-        """Context manager marking staging as warmup-driven: resident-row
-        admissions bypass the frequency duel and carry the seed boost
-        (cache/warmup.py replay calls this around each plan)."""
-        return self._residency.seeding()
+        return self.stager.residency
 
     def invalidate_segment(self, name: str, keep=None) -> None:
-        """Drop every cached artifact for a replaced/removed segment
-        NAME — resident rows, assembled blocks, host rows, predicate
-        params — sparing entries pinned to `keep` (the just-warmed live
-        object). Identity keying already makes stale entries
-        unreachable; this reclaims their HBM/host bytes promptly, on the
-        same epoch-moving events the result caches invalidate on."""
-        with self._engine_lock:
-            def stale(seg) -> bool:
-                return seg.name == name and (keep is None or seg is not keep)
-
-            for k in [k for k, (segs, _d) in self._block_cache.items()
-                      if any(stale(s) for s in segs)]:
-                del self._block_cache[k]
-                self._cache_bytes -= self._block_bytes.pop(k)
-                self._drop_batch_block(k[0])
-            for k in [k for k, v in self._host_rows.items() if stale(v[0])]:
-                _s, payload = self._host_rows.pop(k)
-                self._host_bytes -= _entry_nbytes(payload)
-            for k in [k for k, v in self._params_cache.items()
-                      if any(stale(s) for s in v[0])]:
-                del self._params_cache[k]
-            self._residency.invalidate_segment(name, keep=keep)
-            self._refresh_tier_gauges()
-
-    def drop_caches(self, host: bool = True) -> None:
-        """Bench/test hook: release the device tier (assembled blocks +
-        resident rows + params); host=True also drops host rows — the
-        fully cold replica state."""
-        with self._engine_lock:
-            self._block_cache.clear()
-            self._block_bytes.clear()
-            self._batch_blocks.clear()
-            self._cache_bytes = 0
-            self._params_cache.clear()
-            self._residency.drop_all()
-            if host:
-                self._host_rows.clear()
-                self._host_bytes = 0
-            self._refresh_tier_gauges()
+        """BlockStager.invalidate_segment: a replaced/removed segment
+        NAME leaves every tier, sparing entries pinned to `keep`."""
+        self.stager.invalidate_segment(name, keep=keep)
 
     def prestage(self, segments, ctx: QueryContext) -> bool:
         """Proactively stage a plan's columns into the device tier
@@ -3034,7 +2388,6 @@ class TpuOperatorExecutor:
         replicated). Every byte through here feeds the host->device
         transfer odometer (residency.transfer_bytes) — steady state must
         keep it flat."""
-        from pinot_tpu.ops import residency as residency_mod
         residency_mod.note_transfer(arr.nbytes, column=block)
         self._meter("hbm_transfer_bytes", arr.nbytes)
         self._puts += 1
@@ -3304,19 +2657,6 @@ def _isum_u_value(planes: np.ndarray) -> float:
         s = int(planes[2 * k]) * 4096 + int(planes[2 * k + 1])
         total += s << (kernels.ISUM_U_BITS * k)
     return float(total)
-
-
-def _entry_nbytes(a) -> int:
-    """Bytes of a host-row cache payload (array, or (codes, table))."""
-    if isinstance(a, tuple):
-        return sum(x.nbytes for x in a)
-    return a.nbytes
-
-
-def _batch_id(segments) -> tuple:
-    """Identity of a segment batch: id() alone can be reused after GC, so
-    pair it with the segment name."""
-    return tuple((id(s), s.name) for s in segments)
 
 
 def _shape_sig(cols: Dict[str, Any], params: Dict[str, Any]) -> tuple:

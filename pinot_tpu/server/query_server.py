@@ -252,11 +252,9 @@ class ServerQueryExecutor:
         device memory already holds the table's columns. Empty when no
         device engine/resident tier exists — the hint is best-effort."""
         engine = self._engine
-        res = getattr(engine, "_residency", None) \
-            if engine is not None else None
-        if res is None or not getattr(res, "enabled", False):
+        if engine is None or not engine.residency.enabled:
             return {}
-        by_seg = res.resident_bytes_by_segment()
+        by_seg = engine.residency.resident_bytes_by_segment()
         if not by_seg:
             return {}
         out: dict = {}
@@ -285,10 +283,8 @@ class ServerQueryExecutor:
         worst = 0.0
         # lint: unlocked(reference snapshot; _shared_engine publishes the engine once under its lock and never unsets it)
         engine = self._engine
-        res = getattr(engine, "_residency", None) \
-            if engine is not None else None
-        if res is not None and getattr(res, "enabled", False):
-            worst = max(worst, res.pressure())
+        if engine is not None and engine.residency.enabled:
+            worst = max(worst, engine.residency.pressure())
         for fn in list(self._pressure_sources):
             try:
                 worst = max(worst, float(fn()))

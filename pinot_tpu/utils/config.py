@@ -50,16 +50,16 @@ KEYS: Dict[str, Any] = {
     # the same-batch (broadcast-only) key.
     "pinot.server.dispatch.batch.cross.table": True,
     "pinot.server.dispatch.doc.bucket.max": 1 << 20,
-    # HBM memory tiers (ops/engine.py + ops/residency.py):
+    # HBM memory tiers (ops/staging.py + ops/residency.py):
     # .hbm.cache.bytes bounds the ASSEMBLED [S, D] block cache;
-    # .hbm.resident.* bounds the per-(segment, column) resident-row tier
-    # that survives batch recomposition (misses assemble on-device).
+    # .hbm.resident.bytes bounds the per-(segment, column) resident-row
+    # tier that survives batch recomposition (misses assemble on-device;
+    # 0 retains nothing, and every miss uploads its rows one by one).
     # Admission is TinyLFU-style: when full, a candidate row must be
     # more frequent than the LRU victim to be retained (warmup-seeded
     # rows bypass the duel); .admission.sample is the frequency aging
     # window (counters halve when it fills).
     "pinot.server.hbm.cache.bytes": 8 << 30,
-    "pinot.server.hbm.resident.enabled": True,
     "pinot.server.hbm.resident.bytes": 6 << 30,
     "pinot.server.hbm.admission.enabled": True,
     "pinot.server.hbm.admission.sample": 4096,
@@ -69,21 +69,15 @@ KEYS: Dict[str, Any] = {
     # False is the escape hatch back to the host IndexedTable fold
     "pinot.server.mesh.collective.merge": True,
     # star-tree device leg (ops/startree_device.py): fitted aggregations
-    # answer from pre-agg records through the kernel factory; .hbm.resident
-    # admits the pre-agg pseudo-columns into the resident-row tier
+    # answer from pre-agg records through the kernel factory
     "pinot.server.startree.enabled": True,
-    "pinot.server.startree.hbm.resident": True,
     # CLP log-column LIKE/regex pushdown (ops/clp_device.py): patterns
     # compile to logtype LUTs + variable-slot conditions evaluated as
-    # device filter leaves; .hbm.resident admits the logtype-id/var-slot
-    # pseudo-columns into the resident-row tier
+    # device filter leaves
     "pinot.server.clp.enabled": True,
-    "pinot.server.clp.hbm.resident": True,
     # vector-similarity device leg (ops/vector_device.py): ANN top-K as
-    # a batched matmul over staged vector blocks; .hbm.resident admits
-    # the __vec__ pseudo-columns into the resident-row tier
+    # a batched matmul over staged vector blocks
     "pinot.server.vector.enabled": True,
-    "pinot.server.vector.hbm.resident": True,
     # time-series device leg (ops/timeseries_device.py): fuse
     # floor((t-start)/step) into the group-by kernel's key instead of
     # falling back to the host expression path

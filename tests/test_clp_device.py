@@ -275,10 +275,11 @@ class TestDeviceParity:
         assert _meter(eng, "clp_fallback", reason="disabled") >= 1
 
     def test_non_resident_tier_still_serves(self, segs):
-        """pinot.server.clp.hbm.resident=false: pseudo-columns take the
-        legacy whole-block upload path, answers unchanged."""
+        """pinot.server.hbm.resident.bytes=0: the CLP pseudo-columns go
+        through the one staging path (row-by-row upload, on-device
+        assembly), nothing is retained, answers unchanged."""
         loaded, all_msgs = segs
-        eng = _engine("nonres", **{"pinot.server.clp.hbm.resident": False})
+        eng = _engine("nonres", **{"pinot.server.hbm.resident.bytes": 0})
         dev = QueryExecutor(loaded, use_tpu=True, engine=eng)
         r = dev.execute(
             "SELECT COUNT(*) FROM logs WHERE message LIKE '%web-01%'")
@@ -286,6 +287,8 @@ class TestDeviceParity:
         assert r.result_table.rows[0][0] == \
             sum(1 for m in all_msgs if "web-01" in m)
         assert _meter(eng, "clp_served") == 1
+        assert {k[1] for k in eng.stager._block_cache} >= {"clpid"}
+        assert len(eng.residency) == 0 and eng.residency.bytes == 0
 
 
 class TestZeroRetrace:

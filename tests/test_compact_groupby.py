@@ -72,7 +72,7 @@ class TestCompactGroupBy:
         assert_responses_equal(a, b, self.SQL)
         assert len(a.result_table.rows) > 10000  # genuinely sparse+wide
         assert any(k[1] == "gkey" for k in
-                   tpu.tpu_engine._block_cache), "compact path not used"
+                   tpu.tpu_engine.stager._block_cache), "compact path not used"
 
     def test_with_filter_and_min_max(self, segs):
         sql = ("SELECT a, b, c, MIN(m), MAX(m), AVG(m) FROM t "
@@ -99,6 +99,6 @@ class TestCompactGroupBy:
         eng = TpuOperatorExecutor()
         tpu = QueryExecutor(segs, use_tpu=True, engine=eng)
         tpu.execute(self.SQL)
-        hosts_before = len(eng._host_rows)
+        hosts_before = len(eng.stager._host_rows)
         tpu.execute(self.SQL)
-        assert len(eng._host_rows) == hosts_before  # no re-factorize
+        assert len(eng.stager._host_rows) == hosts_before  # no re-factorize

@@ -56,7 +56,7 @@ def _check(segs, sql, expect_device=True):
     assert not a.exceptions and not b.exceptions, (a.exceptions, b.exceptions)
     assert_responses_equal(a, b, sql)
     if expect_device:
-        assert len(tpu.tpu_engine._block_cache) > 0, \
+        assert len(tpu.tpu_engine.stager._block_cache) > 0, \
             f"device path never engaged for {sql!r}"
     return b
 
@@ -103,7 +103,7 @@ class TestSelectionOffload:
         a, b = cpu.execute(sql), tpu.execute(sql)
         # unordered selection: compare as multisets
         assert sorted(a.result_table.rows) == sorted(b.result_table.rows)
-        assert len(tpu.tpu_engine._block_cache) > 0
+        assert len(tpu.tpu_engine.stager._block_cache) > 0
 
     def test_offset(self, segs):
         _check(segs, "SELECT m FROM t ORDER BY m LIMIT 5 OFFSET 3")
@@ -140,7 +140,7 @@ class TestTopnSentinel:
         sql = "SELECT d FROM t WHERE d = 1 ORDER BY x LIMIT 20"
         a, b = cpu.execute(sql), tpu.execute(sql)
         assert len(b.result_table.rows) == len(a.result_table.rows) == 10
-        assert len(tpu.tpu_engine._block_cache) > 0
+        assert len(tpu.tpu_engine.stager._block_cache) > 0
 
 
 class TestDistinctOffload:

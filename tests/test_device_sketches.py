@@ -54,7 +54,7 @@ class TestDeviceHll:
         rh = host.execute(sql)
         rd = dev.execute(sql)
         assert rh.rows == rd.rows  # same registers -> same estimate
-        assert len(dev._tpu_engine._block_cache) > 0, "device not engaged"
+        assert len(dev._tpu_engine.stager._block_cache) > 0, "device not engaged"
 
     def test_estimate_accuracy(self, executors, segments):
         _host, dev = executors
@@ -171,7 +171,7 @@ class TestExactIntSums:
         rh = host.execute("SELECT SUM(v) FROM it").rows[0][0]
         rd = dev.execute("SELECT SUM(v) FROM it").rows[0][0]
         assert float(rd) == float(rh) == float(exact)
-        assert len(dev.tpu_engine._block_cache) > 0
+        assert len(dev.tpu_engine.stager._block_cache) > 0
 
     def test_negative_and_filtered(self, int_segments):
         segs, arrays = int_segments
@@ -205,7 +205,7 @@ class TestHllFilterOnSameColumn:
         dev = QueryExecutor([seg], use_tpu=True)
         sql = "SELECT DISTINCTCOUNTHLL(x) FROM h WHERE x > 5000"
         assert host.execute(sql).rows == dev.execute(sql).rows
-        assert len(dev.tpu_engine._block_cache) > 0
+        assert len(dev.tpu_engine.stager._block_cache) > 0
 
     def test_huge_longs_fall_back_and_stay_distinct(self, tmp_path):
         # |v| >= 2^55: device path must decline, and the HOST fold must

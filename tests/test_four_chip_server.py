@@ -169,27 +169,27 @@ KNOBS = {"pinot.server.hbm.cache.bytes": 1_000_000,
 @pytest.mark.parametrize("n", [1, 2, 4, 8])
 def test_hbm_pools_are_the_knob_times_the_devices(n):
     eng = implicit_engine(n, **KNOBS)
-    assert eng.cache_budget_bytes == n * 1_000_000
-    assert eng._residency.budget_bytes == n * 600_000
-    assert eng._residency.device_budget_bytes == 600_000
+    assert eng.stager.cache_budget_bytes == n * 1_000_000
+    assert eng.residency.budget_bytes == n * 600_000
+    assert eng.residency.device_budget_bytes == 600_000
     # host memory is the process's, whatever chips it holds
-    assert eng.host_budget_bytes == 5_000_000
+    assert eng.stager.host_budget_bytes == 5_000_000
 
 
 def test_hbm_environment_names_are_per_chip_too(monkeypatch):
     monkeypatch.setenv("PINOT_TPU_HBM_CACHE_BYTES", "4096")
     monkeypatch.setenv("PINOT_TPU_HBM_RESIDENT_BYTES", "2048")
-    assert implicit_engine(1).cache_budget_bytes == 4096
+    assert implicit_engine(1).stager.cache_budget_bytes == 4096
     eng = implicit_engine(4)
-    assert (eng.cache_budget_bytes, eng._residency.budget_bytes,
-            eng._residency.device_budget_bytes) == (4 * 4096, 4 * 2048, 2048)
+    assert (eng.stager.cache_budget_bytes, eng.residency.budget_bytes,
+            eng.residency.device_budget_bytes) == (4 * 4096, 4 * 2048, 2048)
 
 
 def test_an_explicit_mesh_counts_every_device_it_holds():
     eng = TpuOperatorExecutor(mesh=make_mesh(jax.devices()[:8], doc_axis=2),
                               config=PinotConfiguration(overrides=KNOBS))
-    assert eng.cache_budget_bytes == 8 * 1_000_000
-    assert eng._residency.device_budget_bytes == 600_000
+    assert eng.stager.cache_budget_bytes == 8 * 1_000_000
+    assert eng.residency.device_budget_bytes == 600_000
 
 
 def test_a_chip_over_its_own_share_evicts_though_the_pool_has_room(ssb):
@@ -202,14 +202,14 @@ def test_a_chip_over_its_own_share_evicts_though_the_pool_has_room(ssb):
                                 "pinot.server.hbm.admission.enabled": False})
     device = QueryExecutor(segs, use_tpu=True, engine=eng)
     assert not device.execute(q1_sql("q1_1")).exceptions
-    first = eng._residency.evicted
+    first = eng.residency.evicted
     resp = device.execute(
         "SELECT SUM(lo_quantity * lo_extendedprice), MAX(lo_orderdate) "
         "FROM ssb WHERE lo_discount > 2 OPTION(skipCache=true)")
     assert not resp.exceptions
-    assert eng._residency.evicted > first
-    assert eng._residency.bytes < eng._residency.budget_bytes
-    assert max(eng._residency.bytes_by_device().values()) <= 40_000
+    assert eng.residency.evicted > first
+    assert eng.residency.bytes < eng.residency.budget_bytes
+    assert max(eng.residency.bytes_by_device().values()) <= 40_000
 
 
 # -- (c) per-shard assembly --------------------------------------------------
@@ -218,7 +218,7 @@ def anchor_assembled(engine, bkey, entry):
     copied to device 0, stacked there, resharded over the mesh."""
     _batch, kind, col, S, D, dtype_str = bkey
     segments = entry[0]
-    rows = [engine._residency.get(seg, kind, col, dtype_str)
+    rows = [engine.residency.get(seg, kind, col, dtype_str)
             for seg in segments]
     assert all(r is not None for r in rows)
     rows = [jax.device_put(r, engine.devices[0]) for r in rows]
@@ -240,7 +240,7 @@ def check_blocks(engine) -> int:
     the same sharding, each shard where the anchor's reshard put it;
     returns how many were checked."""
     checked = 0
-    for bkey, entry in list(engine._block_cache.items()):
+    for bkey, entry in list(engine.stager._block_cache.items()):
         if bkey[1] in ("vmask", "vector", "startree"):
             continue  # pseudo-columns: their rows go by other names
         want = anchor_assembled(engine, bkey, entry)
@@ -276,18 +276,18 @@ def test_block_is_bit_equal_to_the_anchor_assembled_one(ssb, n, doc_axis):
     # every resident row lives on its slab's device: slot i of S belongs
     # to segments-shard i // (S / shards)
     shards = eng._seg_axis
-    for bkey, entry in eng._block_cache.items():
+    for bkey, entry in eng.stager._block_cache.items():
         S = bkey[3]
         assert S % shards == 0
         for slot, seg in enumerate(entry[0]):
-            home = eng._slot_device(slot, S)
-            assert home is eng._shards[slot // (S // shards)][0]
-            key = eng._residency._key(seg, bkey[1], bkey[2], bkey[5])
-            held = eng._residency._entries.get(key)
+            home = eng.stager._slot_device(slot, S)
+            assert home is eng.stager._shards[slot // (S // shards)][0]
+            key = eng.residency._key(seg, bkey[1], bkey[2], bkey[5])
+            held = eng.residency._entries.get(key)
             if held is not None:
                 assert held[3] == label(home) \
                     and held[1].devices() == {home}
-    assert eng._cross_chip_bytes == 0
+    assert eng.stager.cross_chip_bytes == 0
     assert not eng._metrics.meter("hbm_cross_chip_bytes")
 
 
@@ -298,7 +298,7 @@ def test_a_recomposed_batch_moves_only_the_rows_that_changed_chip(ssb):
     segs, cols = ssb
     eng = implicit_engine(4)
     QueryExecutor(segs, use_tpu=True, engine=eng).execute(q1_sql("q1_1"))
-    assert eng._cross_chip_bytes == 0
+    assert eng.stager.cross_chip_bytes == 0
     uploaded = eng._metrics.meter("hbm_transfer_bytes")
     tail = segs[4:]
     resp = QueryExecutor(tail, use_tpu=True, engine=eng).execute(
@@ -306,17 +306,17 @@ def test_a_recomposed_batch_moves_only_the_rows_that_changed_chip(ssb):
     assert tuple(int(v) for v in resp.rows[0]) == reference(cols[4:], "q1_1")
     moved = 0
     tail_ids = tuple(id(s) for s in tail)
-    blocks = [(k, e) for k, e in eng._block_cache.items()
+    blocks = [(k, e) for k, e in eng.stager._block_cache.items()
               if tuple(id(s) for s in e[0]) == tail_ids]
     assert len(blocks) >= 4
     for bkey, entry in blocks:
         for slot, seg in enumerate(entry[0]):
-            held = eng._residency._entries[
-                eng._residency._key(seg, bkey[1], bkey[2], bkey[5])]
-            if held[3] != label(eng._slot_device(slot, bkey[3])):
+            held = eng.residency._entries[
+                eng.residency._key(seg, bkey[1], bkey[2], bkey[5])]
+            if held[3] != label(eng.stager._slot_device(slot, bkey[3])):
                 assert slot < 3
                 moved += held[1].nbytes
-    assert moved > 0 and eng._cross_chip_bytes == moved
+    assert moved > 0 and eng.stager.cross_chip_bytes == moved
     assert eng._metrics.meter("hbm_cross_chip_bytes") == moved
     # chip to chip, never the host link: only parameters were uploaded
     assert eng._metrics.meter("hbm_transfer_bytes") - uploaded < 4096
@@ -329,7 +329,7 @@ def test_no_chip_holds_more_than_its_slab(ssb):
     segs, _cols = ssb
     eng = implicit_engine(4)
     QueryExecutor(segs, use_tpu=True, engine=eng).execute(q1_sql("q1_2"))
-    for bkey, entry in eng._block_cache.items():
+    for bkey, entry in eng.stager._block_cache.items():
         S, D = bkey[3], bkey[4]
         shards = entry[1].addressable_shards
         assert sorted(label(s.device) for s in shards) == \

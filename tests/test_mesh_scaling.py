@@ -222,10 +222,10 @@ class TestPerChipObservability:
                              labels={"device": lab}) is not None, lab
         # resident rows were committed to specific chips — the split is
         # real attribution, not an even smear
-        by_dev = engine._residency.bytes_by_device()
-        assert sum(by_dev.values()) == engine._residency.bytes
+        by_dev = engine.residency.bytes_by_device()
+        assert sum(by_dev.values()) == engine.residency.bytes
         assert sum(reg.gauge("hbm_resident_bytes", labels={"device": lab})
-                   for lab in labels) == engine._residency.bytes
+                   for lab in labels) == engine.residency.bytes
 
     def test_health_rollup_reports_max_device(self, segs):
         from pinot_tpu.health.rollup import role_health_summary

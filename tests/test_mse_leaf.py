@@ -218,7 +218,7 @@ class TestLeafOnDevice:
         for s in c.servers:
             eng = s.executor._engine
             if eng is not None:
-                staged += len(eng._block_cache)
+                staged += len(eng.stager._block_cache)
         assert staged > 0, "leaf stage never staged blocks on the engine"
 
     def test_global_agg_on_device(self, tpu_cluster):
@@ -260,16 +260,16 @@ class TestLeafScanOnDevice:
         c, cols = tpu_cluster
         for s in c.servers:
             eng = s.executor._shared_engine()
-            eng._block_cache.clear()
-            eng._block_bytes.clear()
-            eng._cache_bytes = 0
+            eng.stager._block_cache.clear()
+            eng.stager._block_bytes.clear()
+            eng.stager._cache_bytes = 0
         resp = c.query(
             "SELECT a.d, COUNT(*) AS n FROM sales a "
             "JOIN sales b ON a.d = b.d "
             "WHERE a.q BETWEEN 10 AND 12 AND b.q BETWEEN 10 AND 12 "
             "GROUP BY a.d ORDER BY a.d LIMIT 100")
         assert not resp.exceptions, resp.exceptions
-        staged = sum(len(s.executor._shared_engine()._block_cache)
+        staged = sum(len(s.executor._shared_engine().stager._block_cache)
                      for s in c.servers)
         assert staged > 0, "leaf scan did not stage device blocks"
         # correctness vs numpy

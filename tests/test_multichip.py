@@ -89,9 +89,9 @@ class TestMultichipSql:
         fallback): the engine's block cache fills with sharded arrays."""
         device, host, engine = mesh_harness
         device.execute("SELECT SUM(doubleCol) FROM testTable")
-        assert engine._block_cache, "device path never staged a block"
+        assert engine.stager._block_cache, "device path never staged a block"
         from jax.sharding import NamedSharding
-        any_block = next(iter(engine._block_cache.values()))[1]
+        any_block = next(iter(engine.stager._block_cache.values()))[1]
         sh = any_block.sharding
         assert isinstance(sh, NamedSharding)
         assert dict(zip(sh.mesh.axis_names, sh.mesh.devices.shape)) == \

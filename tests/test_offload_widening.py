@@ -59,7 +59,7 @@ def _parity(segs, sql, engine=None, expect_offload=True):
             else:
                 assert x == y, (sql, a.rows, b.rows)
     if expect_offload:
-        assert eng._block_cache, f"query fell back to host: {sql}"
+        assert eng.stager._block_cache, f"query fell back to host: {sql}"
     return b
 
 
@@ -96,7 +96,7 @@ class TestBigIntFilters:
             _parity(time_segs,
                     f"SELECT COUNT(*) FROM testTable WHERE tsMillis > {MS0}",
                     engine=eng)
-            kinds = {k[1] for k in eng._block_cache}
+            kinds = {k[1] for k in eng.stager._block_cache}
             assert "valhi" in kinds and "vallo" in kinds
 
 
@@ -155,7 +155,7 @@ class TestBigIntReviewRegressions:
             _parity(segs,
                     f"SELECT COUNT(*), SUM(v) FROM t WHERE tsNanos > {base}",
                     engine=eng, expect_offload=False)
-            kinds = {k[1] for k in eng._block_cache}
+            kinds = {k[1] for k in eng.stager._block_cache}
             assert "valhi" not in kinds
 
     def test_infinite_literal_falls_back(self, time_segs):

@@ -273,7 +273,7 @@ def test_a_miss_is_one_put_and_a_hit_none(segs, template, devices):
         assert _puts(eng, segs, sql) == PUT_LEGS[template], sql  # miss
         assert _puts(eng, segs, sql) == 0, sql                   # hit
     if devices > 1:
-        pack = next(iter(eng._params_cache.values()))[1][PACK]
+        pack = next(iter(eng.stager._params_cache.values()))[1][PACK]
         assert len(pack.sharding.device_set) == devices
         assert pack.sharding.spec == jax.sharding.PartitionSpec(
             None, "segments")

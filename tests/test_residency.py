@@ -30,7 +30,8 @@ import pytest
 from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
                               TableConfig, TableType)
 from pinot_tpu.ops import residency as residency_mod
-from pinot_tpu.ops.engine import TpuOperatorExecutor, _batch_id
+from pinot_tpu.ops.engine import TpuOperatorExecutor
+from pinot_tpu.ops.staging import batch_id
 from pinot_tpu.ops.residency import ResidencyManager
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.segment.creator import SegmentCreator
@@ -186,13 +187,13 @@ class TestCrossBatchResidency:
         two_segments = residency_mod.column_transfer_bytes() - start
         assert two_segments > 0
         c0 = residency_mod.column_transfer_bytes()
-        m0 = eng._residency.misses
+        m0 = eng.residency.misses
         res, rem = eng.execute(segs, ctx)  # one NEW segment joins
         assert not rem
         delta = residency_mod.column_transfer_bytes() - c0
         assert 0 < delta < two_segments  # only the newcomer's rows
         # exactly the new segment's two rows (ids:d + val:m) missed
-        assert eng._residency.misses - m0 == 2
+        assert eng.residency.misses - m0 == 2
         assert agg_values(res) == agg_values(make_engine().execute(
             segs, ctx)[0])
 
@@ -205,7 +206,7 @@ class TestCrossBatchResidency:
         ex = QueryExecutor(segs, use_tpu=True, engine=eng)
         sql = "SELECT PERCENTILETDIGEST95(m), COUNT(*) FROM t"
         r1 = ex.execute(sql)
-        assert eng._block_cache, "sketch query fell back to host"
+        assert eng.stager._block_cache, "sketch query fell back to host"
         b0 = residency_mod.transfer_bytes()
         r2 = ex.execute(sql)
         assert residency_mod.transfer_bytes() == b0, \
@@ -251,14 +252,14 @@ class TestInvalidation:
         ctx = QueryContext.from_sql(SQL)
         eng.execute(segs, ctx)
         name = segs[0].name
-        assert eng._residency.resident_for(name) > 0
+        assert eng.residency.resident_for(name) > 0
         eng.invalidate_segment(name)
-        assert eng._residency.resident_for(name) == 0
+        assert eng.residency.resident_for(name) == 0
         assert not any(any(s.name == name for s in e[0])
-                       for e in eng._block_cache.values())
+                       for e in eng.stager._block_cache.values())
         assert not any(any(s.name == name for s in v[0])
-                       for v in eng._params_cache.values())
-        assert not any(v[0].name == name for v in eng._host_rows.values())
+                       for v in eng.stager._params_cache.values())
+        assert not any(v[0].name == name for v in eng.stager._host_rows.values())
         res, rem = eng.execute(segs, ctx)  # re-stages cleanly
         assert not rem and res
 
@@ -282,11 +283,11 @@ class TestInvalidation:
                 ex.execute("t_OFFLINE", sql))
             assert float(results[0].intermediates[0]) == 500.0
             eng = ex._shared_engine()
-            assert eng._residency.resident_for("t_0") > 0
+            assert eng.residency.resident_for("t_0") > 0
             dm.table("t_OFFLINE").add_segment(v2)  # replace
             with eng._engine_lock:
                 pinned = [e[0] for k, e in
-                          eng._residency._entries.items() if k[1] == "t_0"]
+                          eng.residency._entries.items() if k[1] == "t_0"]
             # warmup re-staged the NEW object; the old one is gone
             assert pinned and all(p is v2 for p in pinned)
             results, _exc, _st = deserialize_results(
@@ -314,15 +315,15 @@ class TestWarmupSeeding:
         w = SegmentWarmup(log, cache, use_tpu=True, engine_fn=lambda: eng)
         assert w.warm("t", segs[0]) >= 1
         name = segs[0].name
-        assert eng._residency.resident_for(name) > 0
+        assert eng.residency.resident_for(name) > 0
         # seeded: one replay left MORE than one access worth of credit
-        assert eng._residency.frequency(name, "val", "m") > 1
+        assert eng.residency.frequency(name, "val", "m") > 1
         # L2-hit path still prestages: drop the device tier, warm again —
         # the result cache hits, but columns come back resident anyway
-        eng.drop_caches()
-        assert eng._residency.resident_for(name) == 0
+        eng.stager.drop_caches()
+        assert eng.residency.resident_for(name) == 0
         assert w.warm("t", segs[0]) >= 1
-        assert eng._residency.resident_for(name) > 0
+        assert eng.residency.resident_for(name) > 0
 
 
 # ---------------------------------------------------------------------------
@@ -336,25 +337,25 @@ class TestParamsCacheBounded:
         # The knob is bytes PER CHIP: the engine's pool is knob x devices
         # (the conftest forces 8), so 62,500 a chip is the 500,000 pool
         eng = make_engine(**{"pinot.server.hbm.cache.bytes": 62_500})
-        assert eng.cache_budget_bytes == 62_500 * len(eng.devices) == 500_000
+        assert eng.stager.cache_budget_bytes == 62_500 * len(eng.devices) == 500_000
         ctx = QueryContext.from_sql(SQL)
         eng.execute(segs[:2], ctx)
-        key_a = _batch_id(segs[:2])
-        assert any(k[0] == key_a for k in eng._params_cache)
+        key_a = batch_id(segs[:2])
+        assert any(k[0] == key_a for k in eng.stager._params_cache)
         eng.execute(segs, ctx)
-        assert not any(k[0] == key_a for k in eng._block_cache), \
+        assert not any(k[0] == key_a for k in eng.stager._block_cache), \
             "test premise: batch A's blocks should have evicted"
-        assert not any(k[0] == key_a for k in eng._params_cache), \
+        assert not any(k[0] == key_a for k in eng.stager._params_cache), \
             "params for a fully evicted batch were stranded"
 
     def test_invalidate_drops_params_for_segment(self, segs):
         eng = make_engine()
         ctx = QueryContext.from_sql(SQL)
         eng.execute(segs, ctx)
-        assert eng._params_cache
+        assert eng.stager._params_cache
         eng.invalidate_segment(segs[1].name)
         assert not any(any(s.name == segs[1].name for s in v[0])
-                       for v in eng._params_cache.values())
+                       for v in eng.stager._params_cache.values())
 
 
 # ---------------------------------------------------------------------------
@@ -402,7 +403,7 @@ class TestResidencyChaos:
             if eng is not None:
                 with eng._engine_lock:
                     pinned = [e[0] for k, e in
-                              eng._residency._entries.items()
+                              eng.residency._entries.items()
                               if k[1] == "rt_0"]
             return {"seen": set(seen), "errors": errors, "final": final,
                     "stale_pins": [p for p in pinned if p is not v2]}

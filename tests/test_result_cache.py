@@ -515,13 +515,19 @@ class TestMetricsSatellites:
 
 
 class TestEngineParamsCacheLru:
-    def test_bounded_lru_shape(self):
-        # structural check (no device work): the params cache is an
-        # OrderedDict with a capacity constant, not an unbounded dict
-        from collections import OrderedDict
-
-        from pinot_tpu.ops.engine import TpuOperatorExecutor
-        assert TpuOperatorExecutor.PARAMS_CACHE_ENTRIES == 4096
-        ex = TpuOperatorExecutor.__new__(TpuOperatorExecutor)
-        ex._params_cache = OrderedDict()
-        assert isinstance(ex._params_cache, OrderedDict)
+    def test_bounded_lru_shape(self, monkeypatch):
+        # no device work: the params cache trims to its capacity
+        # constant, coldest first, and a hit refreshes its entry
+        from pinot_tpu.ops.staging import BlockStager
+        assert BlockStager.PARAMS_CACHE_ENTRIES == 4096
+        monkeypatch.setattr(BlockStager, "PARAMS_CACHE_ENTRIES", 3)
+        st = BlockStager(devices=[])
+        segs = (object(),)
+        with st.lock:
+            for i in range(3):
+                st.params_put_locked((i,), (segs, {"p": i}))
+            assert st.params_get_locked((0,), segs)[1] == {"p": 0}
+            st.params_put_locked((3,), (segs, {"p": 3}))
+            assert list(st._params_cache) == [(2,), (0,), (3,)]
+            # same key, other segment objects: a miss
+            assert st.params_get_locked((0,), (object(),)) is None
