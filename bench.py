@@ -37,6 +37,8 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+from pinot_tpu.ops.plan_ir import batch_params  # noqa: E402
+
 NUM_SEGMENTS = 16
 DOCS_PER_SEGMENT = 8_000_000
 PIPELINE_DEPTH = 16
@@ -403,7 +405,7 @@ def concurrency_main(smoke: bool = False):
         b = 2
         while b <= max(2, dispatch_mod._pow2(clients)):
             kern = dispatch_mod.compiled_batched_kernel(launch.plan, b)
-            plist = (launch.params,) * b
+            plist = batch_params([launch.params] * b)
             if guard is not None:
                 with guard:
                     jax.block_until_ready(kern(
@@ -1445,13 +1447,14 @@ def batching_main(smoke: bool = False, out_path: str = None):
                     with guard:
                         jax.block_until_ready(kern(
                             tuple(m.cols for m in members),
-                            tuple(m.params for m in members),
+                            batch_params([m.params for m in members]),
                             tuple(m.num_docs for m in members),
                             D=lead.D, G=lead.G))
                 else:
                     with guard:
                         jax.block_until_ready(kern(
-                            lead.cols, (lead.params,) * b, lead.num_docs,
+                            lead.cols, batch_params([lead.params] * b),
+                            lead.num_docs,
                             D=lead.D, G=lead.G))
             # same-cols member-grouped (dedup) variants: a stacked batch
             # with duplicate tables dedups its stack, keyed (plan, B, U)
@@ -1467,7 +1470,7 @@ def batching_main(smoke: bool = False, out_path: str = None):
                     with guard:
                         jax.block_until_ready(kern(
                             tuple(m.cols for m in uniqs),
-                            (lead.params,) * b,
+                            batch_params([lead.params] * b),
                             tuple(m.num_docs for m in uniqs),
                             idx, D=lead.D, G=lead.G))
                     u *= 2
@@ -1566,12 +1569,12 @@ def batching_main(smoke: bool = False, out_path: str = None):
         if warm_stacked:
             members = [launches[i % len(launches)] for i in range(B)]
             clist = tuple(m.cols for m in members)
-            plist8 = tuple(m.params for m in members)
+            plist8 = batch_params([m.params for m in members])
             ndlist = tuple(m.num_docs for m in members)
             batch8_ms = timed(lambda: kern(clist, plist8, ndlist,
                                            D=lead.D, G=lead.G))
         else:
-            plist8 = (lead.params,) * B
+            plist8 = batch_params([lead.params] * B)
             batch8_ms = timed(lambda: kern(lead.cols, plist8,
                                            lead.num_docs,
                                            D=lead.D, G=lead.G))
@@ -1945,7 +1948,7 @@ def startree_main(smoke: bool = False, out_path: str = None):
         kern = launch.factory(b, False)
         with guard:
             jax.block_until_ready(kern(
-                launch.cols, (launch.params,) * b, launch.num_docs,
+                launch.cols, batch_params([launch.params] * b), launch.num_docs,
                 D=launch.D, G=launch.G))
         b *= 2
     traces0 = kernels.trace_count()
@@ -3173,7 +3176,7 @@ def logs_main(smoke: bool = False, out_path: "str | None" = None):
         kern = launch.factory(b, False)
         with guard:
             jax.block_until_ready(kern(
-                launch.cols, (launch.params,) * b, launch.num_docs,
+                launch.cols, batch_params([launch.params] * b), launch.num_docs,
                 D=launch.D, G=launch.G))
         b *= 2
     traces0 = kernels.trace_count()
@@ -4443,7 +4446,7 @@ def vector_main(smoke: bool = False, out_path: str = None):
         kern = launch.factory(b, False)
         with guard:
             jax.block_until_ready(kern(
-                launch.cols, (launch.params,) * b, launch.num_docs,
+                launch.cols, batch_params([launch.params] * b), launch.num_docs,
                 D=launch.D, G=launch.G))
         b *= 2
     traces0 = kernels.trace_count()

@@ -699,11 +699,17 @@ def drive(args, cluster, work, native, t_start) -> dict:
         rows, resp, cold_ms = cluster.query(sql)
         accuracy = check_main(name, rows, want)
         mid = cluster.counters()
-        warm = []
+        warm, warm_arg_bytes = [], 0
         for _ in range(args.warm):
             rows, resp, ms = cluster.query(sql)
             check_main(name, rows, want)
             warm.append(ms)
+            # the packed parameters ride every launch as its own jit
+            # argument, a hit's too: counted by `hbm_transfer_bytes`,
+            # named on the span, and no upload of anything cached
+            warm_arg_bytes += sum(
+                d.get("paramsXferBytes", 0)
+                for d in spans(resp["traceInfo"], "DeviceDispatch"))
         after = cluster.counters()
         entry = {
             "name": name, "family": family, "device_served": True,
@@ -712,8 +718,9 @@ def drive(args, cluster, work, native, t_start) -> dict:
             "cold_upload_bytes": series_delta(before, mid,
                                               "hbm_transfer_bytes"),
             "cold_compiles": series_delta(before, mid, "kernel_retrace"),
-            "warm_upload_bytes": series_delta(mid, after,
-                                              "hbm_transfer_bytes"),
+            "warm_upload_bytes": series_delta(
+                mid, after, "hbm_transfer_bytes") - warm_arg_bytes,
+            "warm_launch_arg_bytes": warm_arg_bytes,
             "warm_compiles": series_delta(mid, after, "kernel_retrace"),
             "device_kernel_fetch_ms": spans(
                 resp["traceInfo"], "DeviceDispatch")[0].get("kernelMs"),

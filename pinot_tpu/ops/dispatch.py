@@ -98,6 +98,7 @@ import numpy as np
 import jax
 
 from pinot_tpu.ops import kernels
+from pinot_tpu.ops.plan_ir import batch_params
 from pinot_tpu.utils.failpoints import fire
 
 #: XLA's intra-process CPU collectives rendezvous by (devices, op) — two
@@ -262,6 +263,15 @@ def start_copy(out) -> None:
         start()
 
 
+def host_arg_bytes(params) -> int:
+    """Bytes of a launch's params that are still host (numpy) arrays:
+    the packed [K, S] parameters of one query, or a batch's [B, K, S]
+    (plan_ir.batch_params). They reach the device as arguments of the
+    jit call, one transfer a launch."""
+    return sum(v.nbytes for v in (params or {}).values()
+               if isinstance(v, np.ndarray))
+
+
 def split_packed(arr: np.ndarray, n: int) -> List[np.ndarray]:
     """Zero-copy per-member split of a batched result fetch (ROADMAP
     item): the N coalesced callers receive VIEWS into the ONE packed
@@ -386,8 +396,11 @@ def _clock_for(span, popped: Optional[float] = None):
 class Launch:
     """One staged device launch waiting in the ring.
 
-    `call` runs the already-compiled single-query kernel; the batching
-    fields (plan/cols/params/num_docs/D/G) are only read when
+    `call` runs the already-compiled single-query kernel; `params` is
+    the staged dict (the packed [K, S] parameters a HOST array in it,
+    which rides the launch as a jit argument: `_charge_args` counts
+    its bytes; what else it holds is on the device); the other batching
+    fields (plan/cols/num_docs/D/G) are only read when
     `batch_key` is set and the ring coalesces this launch with
     fingerprint-equal peers. `batch_key` is the SHAPE-BUCKET key (plan,
     S, D, G, array-shape signature) — members of one batch may stage
@@ -446,7 +459,7 @@ class Launch:
         #: real docs staged for this member (the cost-split weight)
         self.docs = int(docs)
         #: time.monotonic() of a TRACED launch's two hand-overs (0.0 =
-        #: not taken): staging done (the engine's, under its lock) and
+        #: not taken): staging done (the engine's `_staging_attrs`) and
         #: the result's copy landed (the dispatcher's); with enq_ts they
         #: give submitMs and handoffMs
         self.staged_ts = staged_ts
@@ -565,6 +578,23 @@ class KernelDispatcher:
     def observe(self, name: str, value: float) -> None:
         self._metrics.add_timing(name, value, labels=self._labels)
 
+    def _charge_args(self, live: List["Launch"], params) -> None:
+        """The host->device bytes a launch's own arguments cost (the
+        packed parameters: no `_put` counts them, the jit call makes the
+        transfer): `hbm_transfer_bytes` once a launch, `paramsXferBytes`
+        (the launch's total) on every member's span, and each member's
+        own pack on its charge slip."""
+        nbytes = host_arg_bytes(params)
+        if not nbytes:
+            return
+        self._metrics.add_meter("hbm_transfer_bytes", nbytes,
+                                labels=self._labels)
+        for it in live:
+            if it.span is not None:
+                it.span.set(paramsXferBytes=nbytes)
+            if it.slip is not None:
+                it.slip.add(transfer_bytes=host_arg_bytes(it.params))
+
     def _set_depth_locked(self) -> None:
         self._metrics.set_gauge("dispatch_queue_depth", len(self._pending),
                                 labels=self._labels)
@@ -681,6 +711,7 @@ class KernelDispatcher:
             guard = _CPU_COLLECTIVE_LOCK if launch.collective \
                 else contextlib.nullcontext()
             span = launch.span
+            self._charge_args([launch], launch.params)
             self._busy_begin()
             clock = _clock_for(span)
             t0 = time.monotonic()
@@ -807,7 +838,11 @@ class KernelDispatcher:
             bucket = _pow2(len(live))
             lead = live[0]
             pad = bucket - len(live)
-            plist = tuple(it.params for it in live) + (lead.params,) * pad
+            # ONE [B, K, S] host array of the members' packed parameters
+            # (what else a member's params hold is on the device already)
+            plist = batch_params(
+                [it.params for it in live] + [lead.params] * pad)
+            self._charge_args(live, plist)
             # broadcast when every member staged the SAME column blocks
             # (one shared pass over one copy of the data); stacked when
             # members come from different tables/partitions in the same
@@ -870,6 +905,7 @@ class KernelDispatcher:
                     it.span.set(variant=variant)
         else:
             call = live[0].call
+            self._charge_args(live, live[0].params)
             if live[0].span is not None:
                 live[0].span.set(variant="single")
         if live[0].collective:

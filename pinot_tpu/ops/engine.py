@@ -111,17 +111,11 @@ class TpuOperatorExecutor:
             if len(self.devices) > 1:
                 from jax.sharding import Mesh
                 self._mesh = Mesh(np.array(self.devices), ("segments",))
-        #: `_put` calls so far, and the running staging pass's parameter
-        #: part (seconds, puts): written under the engine lock, read by
-        #: `_staging_attrs` into paramsMs / paramPuts
-        self._puts = 0
-        self._params_s = 0.0
-        self._param_puts = 0
         _cfg = config or PinotConfiguration()
         self._labels = metrics_labels
         #: pipelined dispatch stage: ring + micro-batching + fetch
-        #: overlap (ops/dispatch.py); owns NO engine state — staging
-        #: stays under the engine lock, launches ride the ring
+        #: overlap (ops/dispatch.py); owns NO engine state — the block
+        #: look-ups stay under the staging lock, launches ride the ring
         self._dispatcher = KernelDispatcher(config=_cfg,
                                             labels=metrics_labels)
         self._metrics = self._dispatcher._metrics
@@ -130,8 +124,10 @@ class TpuOperatorExecutor:
         self.stager = BlockStager(self.devices, self._mesh, config=_cfg,
                                   metrics=self._metrics,
                                   labels=metrics_labels)
-        #: the staging lock is the stager's: one query's plan + stage
-        #: run under it, its launch and result fetch outside it
+        #: the staging lock is the stager's, and guards the stager and
+        #: nothing else: a query holds it for its block look-ups and the
+        #: parameter-cache probe (`_staging_lock`); plan, literal resolve,
+        #: the packed parameters, launch and fetch all run outside it
         self._engine_lock = self.stager.lock
         #: cross-table shape-bucketed batching (the kernel-factory key):
         #: pad S to pow2 buckets so fingerprint-equal queries over
@@ -327,89 +323,86 @@ class TpuOperatorExecutor:
     def _prepare_agg(self, segments: List[ImmutableSegment],
                      ctx: QueryContext, cancel_check=None,
                      parent_span=None, slip=None):
-        """Plan + stage under the engine lock (they mutate the block
-        caches), then wrap the launch for the dispatch ring. Returns
-        (plan, slots_of_fn, S_real, Launch), or None -> host fallback.
+        """Plan, then stage (`_stage`: only its block look-ups hold the
+        staging lock), then wrap the launch for the dispatch ring.
+        Returns (plan, slots_of_fn, S_real, Launch, minfo), or None ->
+        host fallback.
 
         parent_span: explicit tracing.SpanHandle for callers off the
         request thread (execute_async stages on the staging pool, where
         the trace contextvar doesn't flow); sync callers inherit the
-        contextvar. The DeviceDispatch child span carries the wait for
-        the engine lock, staging ms split into plan / block look-ups /
-        parameter puts, and host->device transfer bytes — exact per
-        query because staging holds the engine lock (_staging_lock)."""
+        contextvar. The DeviceDispatch child span carries staging ms
+        split into plan / wait for the lock / block look-ups under it /
+        parameters, and the host->device bytes of THIS pass (`_StagePass`
+        counts them itself: passes overlap, so no odometer diff would be
+        one query's)."""
         dsp = self._dispatch_span(ctx, "agg", parent_span)
-        with self._staging_lock(dsp) as stage_info:
-            # odometer read INSIDE the lock: the diff must cover exactly
-            # this query's staging, not a concurrent stager's
-            xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            plan_info = self._plan(segments, ctx)
-            if plan_info is None:
-                self.scan_fallback("plan")
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback")
-                return None
-            plan, slots_of_fn = plan_info
-            # resolve the kernel BEFORE staging: non-batchable launches
-            # (non-jit kernel stand-ins) must not pay pow2 S padding for
-            # a coalesce they can never join
-            if self._doc_axis > 1:
-                # doc-sharded engines batch too: the factory vmaps
-                # INSIDE shard_map (kernels.make_batched_sharded_kernel)
-                kernel = kernels.compiled_sharded_kernel(plan, self._mesh)
-                batchable = isinstance(kernel, jax.stages.Wrapped)
-                factory = (lambda B, stacked, _p=plan, _m=self._mesh:
-                           kernels.compiled_batched_sharded_kernel(
-                               _p, _m, B, stacked))
-                dedup_factory = None  # sharded in_specs are per-member
+        info = _StagePass(dsp)
+        plan_info = self._plan(segments, ctx)
+        if plan_info is None:
+            self.scan_fallback("plan")
+            if dsp is not None:
+                dsp.end(outcome="hostFallback")
+            return None
+        plan, slots_of_fn = plan_info
+        # resolve the kernel BEFORE staging: non-batchable launches
+        # (non-jit kernel stand-ins) must not pay pow2 S padding for
+        # a coalesce they can never join
+        if self._doc_axis > 1:
+            # doc-sharded engines batch too: the factory vmaps
+            # INSIDE shard_map (kernels.make_batched_sharded_kernel)
+            kernel = kernels.compiled_sharded_kernel(plan, self._mesh)
+            batchable = isinstance(kernel, jax.stages.Wrapped)
+            factory = (lambda B, stacked, _p=plan, _m=self._mesh:
+                       kernels.compiled_batched_sharded_kernel(
+                           _p, _m, B, stacked))
+            dedup_factory = None  # sharded in_specs are per-member
+        else:
+            kernel = kernels.compiled_kernel(plan)
+            batchable = isinstance(kernel, jax.stages.Wrapped)
+            factory = (lambda B, stacked, _p=plan:
+                       kernels.compiled_batched_kernel(_p, B, stacked))
+            dedup_factory = (lambda B, U, _p=plan:
+                             kernels.compiled_batched_dedup_kernel(
+                                 _p, B, U))
+        info.planned()
+        try:
+            cols, params, S, S_real, D, G = self._stage(
+                segments, ctx, plan, batchable=batchable, info=info)
+        except _NotStageable:
+            self.scan_fallback("staging")
+            if dsp is not None:
+                dsp.end(outcome="hostFallback")
+            return None
+        staged_ts = self._staging_attrs(info, S=S, D=D, G=G)
+        # collective broker merge (ops/collective.py): fold the
+        # per-segment partials on device — one psum/pmin/pmax over
+        # the whole mesh — instead of shipping [S, ...] rows to the
+        # host IndexedTable fold. Any gate trips back to the
+        # per-segment launch below, metered by reason
+        minfo = None
+        if self._explicit_mesh and len(self.devices) > 1 \
+                and batchable:
+            if not self._collective_merge:
+                self._merge_fallback("disabled")
             else:
-                kernel = kernels.compiled_kernel(plan)
-                batchable = isinstance(kernel, jax.stages.Wrapped)
-                factory = (lambda B, stacked, _p=plan:
-                           kernels.compiled_batched_kernel(_p, B, stacked))
-                dedup_factory = (lambda B, U, _p=plan:
-                                 kernels.compiled_batched_dedup_kernel(
-                                     _p, B, U))
-            t_plan = time.perf_counter()
-            try:
-                cols, params, S, S_real, D, G = self._stage(
-                    segments, ctx, plan, batchable=batchable)
-            except _NotStageable:
-                self.scan_fallback("staging")
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback")
-                return None
-            staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=S, D=D, G=G)
-            # collective broker merge (ops/collective.py): fold the
-            # per-segment partials on device — one psum/pmin/pmax over
-            # the whole mesh — instead of shipping [S, ...] rows to the
-            # host IndexedTable fold. Any gate trips back to the
-            # per-segment launch below, metered by reason
-            minfo = None
-            if self._explicit_mesh and len(self.devices) > 1 \
-                    and batchable:
-                if not self._collective_merge:
-                    self._merge_fallback("disabled")
-                else:
-                    chaos = False
+                chaos = False
+                try:
+                    fire("server.mesh.collective", table=ctx.table,
+                         mode="agg")
+                except BaseException:  # noqa: BLE001 — armed chaos
+                    self._merge_fallback("chaos")  # -> host fold
+                    chaos = True
+                if not chaos:
                     try:
-                        fire("server.mesh.collective", table=ctx.table,
-                             mode="agg")
-                    except BaseException:  # noqa: BLE001 — armed chaos
-                        self._merge_fallback("chaos")  # -> host fold
-                        chaos = True
-                    if not chaos:
-                        try:
-                            params, minfo = self._merged_prepare(
-                                segments, plan, params, S_real, S, G)
-                        except _MergeFallback as e:
-                            self._merge_fallback(e.reason)
-                        except Exception:  # noqa: BLE001 — never fail
-                            self._merge_fallback("staging")  # the query
-            if slip is not None:
-                slip.add(transfer_bytes=int(
-                    residency_mod.transfer_bytes() - xfer0))
+                        params, minfo = self._merged_prepare(
+                            segments, plan, params, S_real, S, G, info)
+                    except _MergeFallback as e:
+                        self._merge_fallback(e.reason)
+                    except Exception:  # noqa: BLE001 — never fail
+                        self._merge_fallback("staging")  # the query
+        if slip is not None:
+            slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("scan_served")
         G_eff = G
         num_groups = plan.num_groups or G
@@ -431,7 +424,8 @@ class TpuOperatorExecutor:
             if dsp is not None:
                 dsp.set(groupPath=path)
         launch = Launch(
-            # num_docs rides the packed parameters (plan_ir.PACK)
+            # num_docs rides the packed parameters (plan_ir.PACK), a
+            # host array here: the call's own argument, one transfer
             call=lambda: kernel(cols, params, None, D=D, G=G_eff),
             plan=plan, cols=cols, params=params, num_docs=None,
             D=D, G=G_eff,
@@ -481,10 +475,11 @@ class TpuOperatorExecutor:
         self._meter("mesh_merge_fallback", reason=reason)
 
     def _merged_prepare(self, segments, plan: DevicePlan, params,
-                        S_real: int, S: int, G_local: int):
+                        S_real: int, S: int, G_local: int, info=None):
         """Gate + group-key factorization for the collective merge.
-        Returns (params with the remap entries merged in, minfo) or
-        raises _MergeFallback(reason). Caller holds the engine lock."""
+        Returns (params with the remap entries merged in beside the
+        host-side pack, minfo) or raises _MergeFallback(reason). Runs
+        after staging, outside the staging lock."""
         if kernels._value_dtype() == jnp.float32:
             # merged counts/isum halves sum ACROSS segments: exactness
             # needs total docs < 2^24 and < 4096 real segments (the
@@ -495,14 +490,14 @@ class TpuOperatorExecutor:
         if not plan.group_cols:
             return params, {"S": S, "G": 0}
         gparams, G_m, n_real, decode = self._merged_group_params(
-            segments, plan, S, G_local)
+            segments, plan, S, G_local, info)
         params = dict(params)
         params.update(gparams)
         return params, {"S": S, "G": G_m, "n_real": n_real,
                         "decode": decode}
 
     def _merged_group_params(self, segments, plan: DevicePlan, S: int,
-                             G_local: int):
+                             G_local: int, info=None):
         """Factorize a GLOBAL group-key space once host-side: dictIds
         and compact codes are segment-local, so the device can only
         merge groups through a remap to shared indices. Compact plans
@@ -511,18 +506,20 @@ class TpuOperatorExecutor:
         [S, k] global strides (mixed radix over UNION cardinalities —
         stride changes re-upload KBs, never retrace). Cached per
         (segment batch, plan); returns (params, G pad, real group
-        count, decode info for _assemble_merged)."""
+        count, decode info for _assemble_merged). The staging lock is
+        held for the cache probe and the insert only: the factorization
+        and its puts run outside it."""
         key = (batch_id(segments), plan, S, G_local)
-        ent = self._gmap_cache.get(key)
-        if ent is not None:
-            self._gmap_cache.move_to_end(key)
-            return ent
+        with self._engine_lock:
+            ent = self._gmap_cache.get(key)
+            if ent is not None:
+                self._gmap_cache.move_to_end(key)
+                return ent
         n_slots = max(len(plan.agg_ops), 1)
         if plan.group_compact:
             per_seg = []
             for seg in segments:
-                # lint: unlocked(called from _prepare_agg's merged branch, which runs under the engine RLock)
-                _codes, table = self._segment_gkey_locked(seg, plan)
+                _codes, table = self._segment_gkey(seg, plan)
                 dicts = [seg.data_source(c).dictionary
                          for c in plan.group_cols]
                 cols_vals = [d.get_values(table[:, j])
@@ -542,7 +539,7 @@ class TpuOperatorExecutor:
             for s, tuples in enumerate(per_seg):
                 for code, t in enumerate(tuples):
                     gmap[s, code] = index[t]
-            gparams = {"gmap": self._put(gmap)}
+            gparams = {"gmap": self._put(gmap, info)}
             decode = union  # global index -> key value tuple
         else:
             unions = []
@@ -583,15 +580,16 @@ class TpuOperatorExecutor:
                 gm = np.zeros((S, Cpad), np.int32)
                 for s, v in enumerate(vals):
                     gm[s, :len(v)] = np.searchsorted(union, v)
-                gparams[f"gmap{ci}"] = self._put(gm)
+                gparams[f"gmap{ci}"] = self._put(gm, info)
             gstride = np.ascontiguousarray(np.broadcast_to(
                 np.asarray(strides, np.int32), (S, len(strides))))
-            gparams["gstride"] = self._put(gstride)
+            gparams["gstride"] = self._put(gstride, info)
             decode = (tuple(strides), tuple(cards), tuple(unions))
         ent = (gparams, G_m, n_real, decode)
-        self._gmap_cache[key] = ent
-        while len(self._gmap_cache) > self.GMAP_CACHE_ENTRIES:
-            self._gmap_cache.popitem(last=False)
+        with self._engine_lock:
+            self._gmap_cache[key] = ent
+            while len(self._gmap_cache) > self.GMAP_CACHE_ENTRIES:
+                self._gmap_cache.popitem(last=False)
         return ent
 
     # ------------------------------------------------------------------
@@ -720,45 +718,48 @@ class TpuOperatorExecutor:
             options=ctx.options)
         return plan, (fn, qvec, k), rctx
 
-    def _stage_vector_locked(self, segments, rctx: QueryContext, plan,
-                             fn, qvec, k, batchable: bool = True):
-        """Residual-filter staging via the generic _stage (the VectorPlan
-        duck-types DevicePlan for every field it reads), plus the vector
-        block / IVF cell pseudo-columns and the per-QUERY params. Query
-        params cache under their own key — the vector fn expression, not
-        the residual filter — so two queries sharing a residual but not
-        a query vector can never alias."""
-        cols, params, S, S_real, D, _G = self._stage(
-            segments, rctx, plan, batchable=batchable)
-        dim_pad = plan.dim_pad
-        # `(segment, "__vec__/<col>/<leg>")` pseudo-columns: rows pad to
-        # the segment's OWN pow2 doc bucket (times dim_pad for the
-        # flattened vector leg), so every batch composition shares them
-        cols["vec:" + plan.col] = self.stager.stage_block_locked(
-            segments, S, D * dim_pad, "vector", (plan.col, "block"),
-            np.float32, lambda _i, seg: (
-                "vector", f"__vec__/{plan.col}/block",
-                _pow2(seg.num_docs) * dim_pad,
-                lambda sg: vector_device.vector_row(
-                    sg, plan.col, dim_pad, _pow2(sg.num_docs))))
-        if plan.ivf:
-            cols["vcell:" + plan.col] = self.stager.stage_block_locked(
-                segments, S, D, "vector", (plan.col, "cells"), np.int32,
-                lambda _i, seg: (
-                    "vector", f"__vec__/{plan.col}/cells",
-                    _pow2(seg.num_docs),
-                    lambda sg: vector_device.cell_row(
-                        sg, plan.col, _pow2(sg.num_docs))))
-        pmark = self._params_begin()
-        pkey = (batch_id(segments), plan, fn, "__vec__", S)
-        cached = self.stager.params_get_locked(pkey, segments)
-        if cached is None:
+    def _stage_vector(self, segments, rctx: QueryContext, plan,
+                      fn, qvec, k, info, batchable: bool = True):
+        """Residual-filter staging as `_stage` does it (the VectorPlan
+        duck-types DevicePlan for every field it reads), with the vector
+        block / IVF cell pseudo-columns looked up under the same hold of
+        the staging lock, plus the per-QUERY params, built and put
+        outside it. Query params cache under their own key — the vector
+        fn expression, not the residual filter — so two queries sharing
+        a residual but not a query vector can never alias."""
+        with self._staging_lock(info):
+            cols, S, S_real, D, _G, pkey, cached = \
+                self._stage_blocks_locked(segments, rctx, plan, batchable)
+            dim_pad = plan.dim_pad
+            # `(segment, "__vec__/<col>/<leg>")` pseudo-columns: rows pad
+            # to the segment's OWN pow2 doc bucket (times dim_pad for the
+            # flattened vector leg), so every batch composition shares
+            # them
+            cols["vec:" + plan.col] = self.stager.stage_block_locked(
+                segments, S, D * dim_pad, "vector", (plan.col, "block"),
+                np.float32, lambda _i, seg: (
+                    "vector", f"__vec__/{plan.col}/block",
+                    _pow2(seg.num_docs) * dim_pad,
+                    lambda sg: vector_device.vector_row(
+                        sg, plan.col, dim_pad, _pow2(sg.num_docs))))
+            if plan.ivf:
+                cols["vcell:" + plan.col] = self.stager.stage_block_locked(
+                    segments, S, D, "vector", (plan.col, "cells"),
+                    np.int32, lambda _i, seg: (
+                        "vector", f"__vec__/{plan.col}/cells",
+                        _pow2(seg.num_docs),
+                        lambda sg: vector_device.cell_row(
+                            sg, plan.col, _pow2(sg.num_docs))))
+            vkey = (batch_id(segments), plan, fn, "__vec__", S)
+            vcached = self.stager.params_get_locked(vkey, segments)
+        params = self._stage_params(segments, rctx, plan, S, S_real,
+                                    pkey, cached, info)
+        if vcached is None:
             qp = vector_device.query_params(segments, plan, qvec, k, S)
-            cached = (tuple(segments),
-                      {key: self._put(arr) for key, arr in qp.items()})
-            self.stager.params_put_locked(pkey, cached)
-        params.update(cached[1])
-        self._params_end(pmark)
+            vcached = (tuple(segments),
+                       {key: self._put(arr, info) for key, arr in qp.items()})
+            self._params_insert(vkey, vcached, info)
+        params.update(vcached[1])
         return cols, params, S, S_real, D
 
     def _prepare_vector(self, segments, ctx: QueryContext, cancel_check):
@@ -770,32 +771,29 @@ class TpuOperatorExecutor:
         (plan, S_real, Launch) or None -> host path (reason metered)."""
         dsp = self._dispatch_span(ctx, "vector")
         slip = accounting.current_slip()
-        with self._staging_lock(dsp) as stage_info:
-            xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            plan, qinfo, rctx = self._plan_vector(segments, ctx)
-            if plan is None:
-                self._vector_fallback(qinfo)
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback", reason=qinfo)
-                return None
-            fn, qvec, k = qinfo
-            kernel = vector_device.compiled_vector_kernel(plan)
-            batchable = isinstance(kernel, jax.stages.Wrapped)
-            t_plan = time.perf_counter()
-            try:
-                cols, params, S, S_real, D = \
-                    self._stage_vector_locked(segments, rctx, plan, fn,
-                                              qvec, k, batchable=batchable)
-            except _NotStageable:
-                self._vector_fallback("staging")
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback", reason="staging")
-                return None
-            staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=S, D=D)
-            if slip is not None:
-                slip.add(transfer_bytes=int(
-                    residency_mod.transfer_bytes() - xfer0))
+        info = _StagePass(dsp)
+        plan, qinfo, rctx = self._plan_vector(segments, ctx)
+        if plan is None:
+            self._vector_fallback(qinfo)
+            if dsp is not None:
+                dsp.end(outcome="hostFallback", reason=qinfo)
+            return None
+        fn, qvec, k = qinfo
+        kernel = vector_device.compiled_vector_kernel(plan)
+        batchable = isinstance(kernel, jax.stages.Wrapped)
+        info.planned()
+        try:
+            cols, params, S, S_real, D = self._stage_vector(
+                segments, rctx, plan, fn, qvec, k, info,
+                batchable=batchable)
+        except _NotStageable:
+            self._vector_fallback("staging")
+            if dsp is not None:
+                dsp.end(outcome="hostFallback", reason="staging")
+            return None
+        staged_ts = self._staging_attrs(info, S=S, D=D)
+        if slip is not None:
+            slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("vector_served")
         launch = Launch(
             call=lambda: kernel(cols, params, None, D=D),
@@ -848,35 +846,32 @@ class TpuOperatorExecutor:
         pre-agg serves from scans."""
         dsp = self._dispatch_span(ctx, "startree", parent_span,
                                   starTree=True)
-        with self._staging_lock(dsp) as stage_info:
-            xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            plan, needed, fits, reason = startree_device.plan_startree(
-                segments, ctx)
-            if plan is None:
-                self._st_fallback(reason)
-                if dsp is not None:
-                    dsp.end(outcome="scanFallback", reason=reason)
-                return None
-            kernel = startree_device.compiled_startree_kernel(plan)
-            batchable = isinstance(kernel, jax.stages.Wrapped)
-            factory = (lambda B, stacked, _p=plan:
-                       startree_device.compiled_batched_startree_kernel(
-                           _p, B, stacked))
-            t_plan = time.perf_counter()
-            try:
-                cols, params, num_docs, S_real, D = self._stage_startree_locked(
-                    segments, ctx, plan, fits, batchable=batchable)
-            except _NotStageable:
-                self._st_fallback("staging")
-                if dsp is not None:
-                    dsp.end(outcome="scanFallback", reason="staging")
-                return None
-            staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=int(num_docs.shape[0]), D=D,
-                G=plan.num_groups)
-            if slip is not None:
-                slip.add(transfer_bytes=int(
-                    residency_mod.transfer_bytes() - xfer0))
+        info = _StagePass(dsp)
+        plan, needed, fits, reason = startree_device.plan_startree(
+            segments, ctx)
+        if plan is None:
+            self._st_fallback(reason)
+            if dsp is not None:
+                dsp.end(outcome="scanFallback", reason=reason)
+            return None
+        kernel = startree_device.compiled_startree_kernel(plan)
+        batchable = isinstance(kernel, jax.stages.Wrapped)
+        factory = (lambda B, stacked, _p=plan:
+                   startree_device.compiled_batched_startree_kernel(
+                       _p, B, stacked))
+        info.planned()
+        try:
+            cols, params, num_docs, S_real, D = self._stage_startree(
+                segments, ctx, plan, fits, info, batchable=batchable)
+        except _NotStageable:
+            self._st_fallback("staging")
+            if dsp is not None:
+                dsp.end(outcome="scanFallback", reason="staging")
+            return None
+        staged_ts = self._staging_attrs(
+            info, S=int(num_docs.shape[0]), D=D, G=plan.num_groups)
+        if slip is not None:
+            slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("startree_served")
         # the same coalesce key as scans: fingerprint-equal star-tree
         # queries (same slots/radix, any predicate constants) share ONE
@@ -901,14 +896,15 @@ class TpuOperatorExecutor:
             staged_ts=staged_ts)
         return plan, needed, fits, S_real, launch
 
-    def _stage_startree_locked(self, segments, ctx: QueryContext, plan, fits,
-                        batchable: bool = True):
+    def _stage_startree(self, segments, ctx: QueryContext, plan, fits,
+                        info, batchable: bool = True):
         """Stage the fitted trees' pre-agg metric/dim-code rows as
         `(segment, "__startree__<ti>/<col>")` pseudo-columns through the
         same host-row / residency / assembled-block tiers as real
-        columns, plus the per-query [S, D] selection mask (the traversal
-        result) as kernel params. D is the pow2 bucket of the LARGEST
-        fitted tree's record count: star records make num_records exceed
+        columns (under the staging lock), plus the per-query [S, D]
+        selection mask (the traversal result) as kernel params, built
+        and put outside it. D is the pow2 bucket of the LARGEST fitted
+        tree's record count: star records make num_records exceed
         num_docs, so the scan path's bucket cannot be reused."""
         S_real = len(segments)
         max_recs = max(int(f.tree.meta.num_records) for f in fits)
@@ -928,31 +924,31 @@ class TpuOperatorExecutor:
         # composition shares them)
         tis = tuple(f.ti for f in fits)
         cols: Dict[str, jnp.ndarray] = {}
-        for ckey, form, dtype in startree_device.staged_columns(plan, vdt):
-            cols[ckey] = self.stager.stage_block_locked(
-                segments, S, D, "startree", (ckey, tis), dtype,
-                lambda i, _seg: (
-                    "startree", f"__startree__{fits[i].ti}/{ckey}",
-                    _pow2(int(fits[i].tree.meta.num_records)),
-                    lambda _sg: startree_device.fetch_row(
-                        fits[i].tree, form, dtype)))
-
         # selection mask + record counts: cached like predicate params —
         # a repeat query (same batch, same plan shape, same filter)
         # re-traverses nothing and uploads nothing. The fitted tree
         # indexes are deterministic in (segments, plan, filter), so the
         # scan-path key form is sufficient here too.
-        pmark = self._params_begin()
         pkey = (batch_id(segments), plan, ctx.filter, "__startree__", S, D)
-        cached = self.stager.params_get_locked(pkey, segments)
+        with self._staging_lock(info):
+            for ckey, form, dtype in startree_device.staged_columns(
+                    plan, vdt):
+                cols[ckey] = self.stager.stage_block_locked(
+                    segments, S, D, "startree", (ckey, tis), dtype,
+                    lambda i, _seg: (
+                        "startree", f"__startree__{fits[i].ti}/{ckey}",
+                        _pow2(int(fits[i].tree.meta.num_records)),
+                        lambda _sg: startree_device.fetch_row(
+                            fits[i].tree, form, dtype)))
+            cached = self.stager.params_get_locked(pkey, segments)
         if cached is None:
             sel = startree_device.selection_mask(fits, S, D)
             num_docs = np.zeros(S, dtype=np.int32)
             num_docs[:S_real] = [int(f.tree.meta.num_records) for f in fits]
-            cached = (tuple(segments), {"sel": self._put(sel, block=True)},
-                      self._put(num_docs))
-            self.stager.params_put_locked(pkey, cached)
-        self._params_end(pmark)
+            cached = (tuple(segments),
+                      {"sel": self._put(sel, info, block=True)},
+                      self._put(num_docs, info))
+            self._params_insert(pkey, cached, info)
         return cols, dict(cached[1]), cached[2], S_real, D
 
     # -- staging trace attrs -------------------------------------------
@@ -969,35 +965,50 @@ class TpuOperatorExecutor:
                                  mode=mode, **attrs)
 
     @contextlib.contextmanager
-    def _staging_lock(self, dsp):
-        """The engine lock round one query's plan + stage, the wait for
-        it measured where it happens: `lockWaitMs` runs from the
-        DeviceDispatch span's opening to the lock acquired, so under N
-        clients the queue for staging has its own number and is no part
-        of `stagingMs`. Yields the snapshot `_staging_attrs` diffs
-        (None span -> no snapshot, no clock read, no annotation). The
-        same two phases go to the profiler's host plane as
-        `pinot:lock_wait` / `pinot:staging`."""
-        if dsp is None:
-            with self._engine_lock:
-                yield None
-            return
+    def _staging_lock(self, info):
+        """The staging lock (the stager's) round what it guards and
+        nothing else: one pass's `*_locked` look-ups. The wait for it
+        and the hold are measured where they happen and added to the
+        pass (`lockWaitMs`; `blocksMs` and its share of `lockHeldMs`),
+        and what the stager uploaded under the hold (rows of a block
+        miss) joins the pass's bytes: exact, only a holder uploads. An
+        untraced pass reads no clock and makes no annotation. Wait and
+        hold go to the profiler's host plane as `pinot:lock_wait` /
+        `pinot:staging`."""
+        dsp = info.span
+        t0 = time.perf_counter() if dsp is not None else 0.0
         with dispatch_mod.phase_annotation("lock_wait", dsp):
             self._engine_lock.acquire()
+        t1 = time.perf_counter() if dsp is not None else 0.0
+        up0 = self.stager.uploaded_bytes
         try:
             with dispatch_mod.phase_annotation("staging", dsp):
-                self._params_s = 0.0
-                self._param_puts = 0
-                yield (time.perf_counter(), residency_mod.transfer_bytes())
+                yield
         finally:
+            info.xfer_bytes += self.stager.uploaded_bytes - up0
             self._engine_lock.release()
-            self._chip_attrs(dsp)
+            if dsp is not None:
+                info.wait_s += t1 - t0
+                info.blocks_s += time.perf_counter() - t1
+
+    def _params_insert(self, pkey: tuple, entry: tuple, info) -> None:
+        """A parameter-cache miss's entry, built outside the staging
+        lock, inserted under a short hold of it (its wait joins the
+        pass's `lockWaitMs`, its hold `lockHeldMs`)."""
+        traced = info.span is not None
+        t0 = time.perf_counter() if traced else 0.0
+        with self._engine_lock:
+            t1 = time.perf_counter() if traced else 0.0
+            self.stager.params_put_locked(pkey, entry)
+        if traced:
+            info.wait_s += t1 - t0
+            info.insert_s += time.perf_counter() - t1
 
     def _chip_attrs(self, dsp) -> None:
         """A mesh engine's traced DeviceDispatch: how many devices the
         launch spans and what each chip's allocator holds and has held
         at most (`memory_stats()`, None where the backend keeps none),
-        read once the engine lock is released, so no other query waits
+        read once the staging lock is released, so no other query waits
         for it; plus the bytes moved chip to chip at block assembly
         since start-up. One device: nothing is set."""
         if len(self.devices) < 2:
@@ -1008,46 +1019,46 @@ class TpuOperatorExecutor:
                 chipPeakBytes=[m.get("peak_bytes_in_use") for m in stats],
                 crossChipBytes=self.stager.cross_chip_bytes)
 
-    def _params_begin(self):
-        """Mark the start of a staging pass's parameter part (resolve
-        literals, build and `_put` the tiny arrays); `_params_end` adds
-        it to the pass's paramsMs / paramPuts. Caller holds the engine
-        lock."""
-        return time.perf_counter(), self._puts
-
-    def _params_end(self, mark) -> None:
-        self._params_s += time.perf_counter() - mark[0]
-        self._param_puts += self._puts - mark[1]
-
-    def _staging_attrs(self, dsp, snap, t_plan: float, **dims) -> float:
+    def _staging_attrs(self, info, **dims) -> float:
         """Returns the Launch's `staged_ts` (0.0 untraced), having set
-        stagingMs (lock acquired -> staged) and its three parts:
-        planMs (plan + kernel look-up, to `t_plan`), paramsMs (the
-        `_params_begin/_end` sections, with their `_put` count
-        paramPuts) and blocksMs (the rest of the stage call: the
-        block-cache look-ups and, on a miss, row fetch + upload)."""
-        if dsp is None or snap is None:
+        the pass's stretch of the span, opening -> staged, as four parts
+        that tile it: planMs (plan + kernel look-up, no lock),
+        lockWaitMs (the waits for the staging lock), blocksMs (the hold:
+        block-cache look-ups, the parameter-cache probe and, on a miss,
+        row fetch + upload) and paramsMs (the rest: literals resolved,
+        the [K, S] pack built, a LUT table put, the entry inserted);
+        stagingMs is the three that are work. lockHeldMs is all the
+        pass held the lock (the hold, plus the insert's), paramPuts its
+        `_put` calls (none for the pack: it rides the launch) and
+        transferBytes what those and a block miss's rows moved."""
+        dsp = info.span
+        if dsp is None:
             return 0.0
-        t0, xfer0 = snap
         now = time.perf_counter()
+        plan_s = info.t_plan - info.t_open
+        params_s = now - info.t_plan - info.wait_s - info.blocks_s
         dsp.set(
-            lockWaitMs=round(t0 * 1e3 - dsp.node.start_ms, 3),
-            stagingMs=round((now - t0) * 1e3, 3),
-            planMs=round((t_plan - t0) * 1e3, 3),
-            blocksMs=round((now - t_plan - self._params_s) * 1e3, 3),
-            paramsMs=round(self._params_s * 1e3, 3),
-            paramPuts=self._param_puts,
-            transferBytes=int(residency_mod.transfer_bytes() - xfer0),
+            lockWaitMs=round(info.wait_s * 1e3, 3),
+            lockHeldMs=round((info.blocks_s + info.insert_s) * 1e3, 3),
+            stagingMs=round((plan_s + info.blocks_s + params_s) * 1e3, 3),
+            planMs=round(plan_s * 1e3, 3),
+            blocksMs=round(info.blocks_s * 1e3, 3),
+            paramsMs=round(params_s * 1e3, 3),
+            paramPuts=info.puts,
+            transferBytes=info.xfer_bytes,
             **dims)
-        return time.monotonic()
+        staged_ts = time.monotonic()
+        self._chip_attrs(dsp)  # after the stamp: no staging phase's time
+        return staged_ts
 
     def execute(self, segments: List[ImmutableSegment], ctx: QueryContext,
                 cancel_check=None
                 ) -> Tuple[List[Any], List[ImmutableSegment]]:
         """Returns (device results, segments to fall back to host).
 
-        Plan + staging run under the engine lock (they mutate the block
-        caches); the launch rides the dispatch ring, which coalesces
+        Staging's block look-ups run under the staging lock (they
+        mutate the block caches), plan and parameters outside it; the
+        launch rides the dispatch ring, which coalesces
         fingerprint-equal concurrent queries into one batched kernel and
         fetches results off-ring — N server threads overlap their device
         round trips instead of serializing behind one sync each.
@@ -1207,30 +1218,27 @@ class TpuOperatorExecutor:
         Must be called with doc_axis == 1 (sharded top-K stays host)."""
         dsp = self._dispatch_span(ctx, mode)
         slip = accounting.current_slip()
-        with self._staging_lock(dsp) as stage_info:
-            xfer0 = residency_mod.transfer_bytes() if slip is not None else 0
-            plan = self._plan_topn(segments, ctx)
-            if plan is None:
-                self.scan_fallback("plan")
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback")
-                return None
-            kernel = kernels.compiled_topn_kernel(plan)
-            batchable = isinstance(kernel, jax.stages.Wrapped)
-            t_plan = time.perf_counter()
-            try:
-                cols, params, S, S_real, D, _G = self._stage(
-                    segments, ctx, plan, batchable=batchable)
-            except _NotStageable:
-                self.scan_fallback("staging")
-                if dsp is not None:
-                    dsp.end(outcome="hostFallback")
-                return None
-            staged_ts = self._staging_attrs(
-                dsp, stage_info, t_plan, S=S, D=D)
-            if slip is not None:
-                slip.add(transfer_bytes=int(
-                    residency_mod.transfer_bytes() - xfer0))
+        info = _StagePass(dsp)
+        plan = self._plan_topn(segments, ctx)
+        if plan is None:
+            self.scan_fallback("plan")
+            if dsp is not None:
+                dsp.end(outcome="hostFallback")
+            return None
+        kernel = kernels.compiled_topn_kernel(plan)
+        batchable = isinstance(kernel, jax.stages.Wrapped)
+        info.planned()
+        try:
+            cols, params, S, S_real, D, _G = self._stage(
+                segments, ctx, plan, batchable=batchable, info=info)
+        except _NotStageable:
+            self.scan_fallback("staging")
+            if dsp is not None:
+                dsp.end(outcome="hostFallback")
+            return None
+        staged_ts = self._staging_attrs(info, S=S, D=D)
+        if slip is not None:
+            slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("scan_served")
         launch = Launch(
             call=lambda: kernel(cols, params, None, D=D),
@@ -1801,10 +1809,30 @@ class TpuOperatorExecutor:
         return D
 
     def _stage(self, segments, ctx: QueryContext, plan: DevicePlan,
-               batchable: bool = True):
-        """batchable=False (top-N / doc-id scans — launches that never
-        carry a batch_key) skips the pow2 S bucket: shape-bucket padding
-        only buys cross-table coalescing, which those paths can't use."""
+               batchable: bool = True, info=None):
+        """One query's staged inputs: (cols, params, S, S_real, D, G).
+        The block look-ups and the parameter-cache probe run under the
+        staging lock (`_stage_blocks_locked`); what a probe's miss costs
+        (`_stage_params`: literals resolved, the pack built) runs after
+        its release. batchable=False (top-N / doc-id scans — launches
+        that never carry a batch_key) skips the pow2 S bucket:
+        shape-bucket padding only buys cross-table coalescing, which
+        those paths can't use. info: the caller's `_StagePass` (None:
+        an untraced pass of its own, for callers that want no counts)."""
+        info = info or _StagePass(None)
+        with self._staging_lock(info):
+            cols, S, S_real, D, G, pkey, cached = \
+                self._stage_blocks_locked(segments, ctx, plan, batchable)
+        params = self._stage_params(segments, ctx, plan, S, S_real, pkey,
+                                    cached, info)
+        return cols, params, S, S_real, D, G
+
+    def _stage_blocks_locked(self, segments, ctx: QueryContext,
+                             plan: DevicePlan, batchable: bool):
+        """The part of staging that touches what the lock guards: every
+        [S, D] block of the plan through the stager's tiers, and the
+        probe of the parameter cache. Returns (cols, S, S_real, D, G,
+        pkey, the cached entry or None)."""
         S_real = len(segments)
         if max(s.num_docs for s in segments) > MAX_DOCS_PER_SEGMENT:
             raise _NotStageable()
@@ -1813,7 +1841,6 @@ class TpuOperatorExecutor:
             S_real, bucket=batchable and D <= self._doc_bucket_max)
 
         cols: Dict[str, jnp.ndarray] = {}
-        params: Dict[str, jnp.ndarray] = {}
         vdt = np.float64 if jax.config.read("jax_enable_x64") else np.float32
 
         for col in plan.dict_cols:
@@ -1905,23 +1932,40 @@ class TpuOperatorExecutor:
         # per-leaf predicate parameters (cached: filters are frozen
         # expression trees, so they key the resolved literals exactly;
         # the entry also carries hist slot bounds and num_docs — they
-        # depend only on (segments, plan), so a repeat query uploads
-        # NOTHING). Every [S] parameter goes into ONE packed int32
-        # [K, S] array (plan_ir.pack_params) and ONE put; the [S, C]
-        # LUT tables and the CLP leaf arrays keep a put each
-        pmark = self._params_begin()
+        # depend only on (segments, plan), so a repeat query resolves
+        # NOTHING)
         pkey = (batch_id(segments), plan, ctx.filter,
                 tuple(ctx.agg_filters), S,
                 tuple(ctx.group_by) if plan.tbucket else None)
         cached = self.stager.params_get_locked(pkey, segments)
-        if cached is not None:
-            params.update(cached[1])
-            if plan.clp_cols:
-                self._meter("clp_served")
-            if plan.tbucket:
-                self._meter("timeseries_leaf_device")
-            self._params_end(pmark)
-            return cols, params, S, S_real, D, G
+        return cols, S, S_real, D, G, pkey, cached
+
+    def _stage_params(self, segments, ctx: QueryContext, plan: DevicePlan,
+                      S: int, S_real: int, pkey: tuple, cached, info):
+        """A staged query's params dict, with NO lock held: the cached
+        entry's, or on a miss every [S] parameter resolved into ONE
+        packed int32 [K, S] HOST array (plan_ir.pack_params) — it stays
+        numpy, in the cache too, and reaches the device as an argument
+        of the launch (one transfer a launch, a batch's packs stacked
+        on the host first: plan_ir.batch_params); the [S, C] LUT tables
+        and the CLP leaf arrays keep a `_put` each. The entry is
+        inserted under a short hold of the lock (`_params_insert`); the
+        caller gets a dict of its own (the merge leg adds to it)."""
+        if cached is None:
+            cached = (tuple(segments), self._resolve_params(
+                segments, ctx, plan, S, S_real, info))
+            self._params_insert(pkey, cached, info)
+        if plan.clp_cols:
+            self._meter("clp_served")
+        if plan.tbucket:
+            self._meter("timeseries_leaf_device")
+        return dict(cached[1])
+
+    def _resolve_params(self, segments, ctx: QueryContext,
+                        plan: DevicePlan, S: int, S_real: int, info):
+        """What a parameter-cache miss builds (`_stage_params`)."""
+        params: Dict[str, Any] = {}
+        vdt = np.float64 if jax.config.read("jax_enable_x64") else np.float32
         rows: Dict[str, np.ndarray] = {}
         rows[NUM_DOCS] = np.zeros(S, dtype=np.int32)
         rows[NUM_DOCS][:S_real] = [s.num_docs for s in segments]
@@ -1975,7 +2019,7 @@ class TpuOperatorExecutor:
                 except ValueError:
                     raise _NotStageable()
                 for k, arr in arrs.items():
-                    params[k] = self._put(arr)
+                    params[k] = self._put(arr, info)
                 continue
             resolved = self._resolve_leaf(segments, expr)
             if leaf.kind == "range":
@@ -2020,16 +2064,10 @@ class TpuOperatorExecutor:
                         table[s, p.ids] = False
                     else:
                         raise _NotStageable()
-                params[f"leaf{i}:lut"] = self._put(table)
+                params[f"leaf{i}:lut"] = self._put(table, info)
 
-        params[PACK] = self._put(pack_params(plan, rows), seg_axis=1)
-        self.stager.params_put_locked(pkey, (tuple(segments), dict(params)))
-        if plan.clp_cols:
-            self._meter("clp_served")
-        if plan.tbucket:
-            self._meter("timeseries_leaf_device")
-        self._params_end(pmark)
-        return cols, params, S, S_real, D, G
+        params[PACK] = pack_params(plan, rows)
+        return params
 
     @staticmethod
     def _resolve_leaf(segments, expr: Function) -> list:
@@ -2239,45 +2277,42 @@ class TpuOperatorExecutor:
         serves, so its first routed query pays compute, not the link."""
         if not segments or ctx.distinct or not self.supports(ctx):
             return False
-        with self._engine_lock:
-            if ctx.aggregations and self._startree_candidate(segments):
-                # star-tree leg first, mirroring execute's routing: a
-                # plan that will serve from pre-agg records must warm
-                # THOSE blocks, not the raw scan columns
-                st_plan, _needed, fits, _reason = \
-                    startree_device.plan_startree(segments, ctx)
-                if st_plan is not None:
-                    kern = startree_device.compiled_startree_kernel(
-                        st_plan)
-                    try:
-                        self._stage_startree_locked(
-                            segments, ctx, st_plan, fits,
-                            batchable=isinstance(kern,
-                                                 jax.stages.Wrapped))
-                        return True
-                    except _NotStageable:
-                        pass
-            if ctx.aggregations:
-                plan_info = self._plan(segments, ctx)
-                plan = plan_info[0] if plan_info is not None else None
-                kern = None if plan is None \
-                    else (kernels.compiled_sharded_kernel(plan, self._mesh)
-                          if self._doc_axis > 1
-                          else kernels.compiled_kernel(plan))
-            else:
-                plan = self._plan_topn(segments, ctx)
-                kern = None if plan is None \
-                    else kernels.compiled_topn_kernel(plan)
-            if plan is None:
-                return False
-            try:
-                # mirror the serving path's S bucket (agg AND top-N
-                # launches ride the factory now) so warmed blocks are
-                # the EXACT blocks the first routed query will consume
-                self._stage(segments, ctx, plan,
-                            batchable=isinstance(kern, jax.stages.Wrapped))
-            except _NotStageable:
-                return False
+        if ctx.aggregations and self._startree_candidate(segments):
+            # star-tree leg first, mirroring execute's routing: a
+            # plan that will serve from pre-agg records must warm
+            # THOSE blocks, not the raw scan columns
+            st_plan, _needed, fits, _reason = \
+                startree_device.plan_startree(segments, ctx)
+            if st_plan is not None:
+                kern = startree_device.compiled_startree_kernel(st_plan)
+                try:
+                    self._stage_startree(
+                        segments, ctx, st_plan, fits, _StagePass(None),
+                        batchable=isinstance(kern, jax.stages.Wrapped))
+                    return True
+                except _NotStageable:
+                    pass
+        if ctx.aggregations:
+            plan_info = self._plan(segments, ctx)
+            plan = plan_info[0] if plan_info is not None else None
+            kern = None if plan is None \
+                else (kernels.compiled_sharded_kernel(plan, self._mesh)
+                      if self._doc_axis > 1
+                      else kernels.compiled_kernel(plan))
+        else:
+            plan = self._plan_topn(segments, ctx)
+            kern = None if plan is None \
+                else kernels.compiled_topn_kernel(plan)
+        if plan is None:
+            return False
+        try:
+            # mirror the serving path's S bucket (agg AND top-N
+            # launches ride the factory now) so warmed blocks are
+            # the EXACT blocks the first routed query will consume
+            self._stage(segments, ctx, plan,
+                        batchable=isinstance(kern, jax.stages.Wrapped))
+        except _NotStageable:
+            return False
         return True
 
     @staticmethod
@@ -2380,26 +2415,27 @@ class TpuOperatorExecutor:
                     max(abs(int(lo)), abs(int(hi))) > (1 << 24):
                 raise _NotStageable()
 
-    def _put(self, arr: np.ndarray, block: bool = False,
-             seg_axis: int = 0):
-        """block=True marks [S, D] column blocks, which also shard over the
-        docs axis on a 2-axis mesh; params/bounds shard over segments only
-        (`seg_axis` 1: the packed [K, S] parameter array, whose rows are
-        replicated). Every byte through here feeds the host->device
-        transfer odometer (residency.transfer_bytes) — steady state must
-        keep it flat."""
+    def _put(self, arr: np.ndarray, info=None, block: bool = False):
+        """One host->device put of what a launch needs beside its packed
+        parameters (a LUT table, CLP leaf arrays, a selection mask, the
+        vector leg's query arrays, the merge's remaps), made OUTSIDE the
+        staging lock and counted on the pass (`info`). block=True marks
+        [S, D] blocks, which also shard over the docs axis on a 2-axis
+        mesh; everything else shards over segments only. Every byte
+        through here feeds the host->device transfer odometer
+        (residency.transfer_bytes) — steady state must keep it flat."""
         residency_mod.note_transfer(arr.nbytes, column=block)
         self._meter("hbm_transfer_bytes", arr.nbytes)
-        self._puts += 1
+        if info is not None:
+            info.puts += 1
+            info.xfer_bytes += arr.nbytes
         if self._mesh is None:
             return jnp.asarray(arr)
         from jax.sharding import NamedSharding, PartitionSpec as P
         if block and self._doc_axis > 1 and arr.ndim == 2:
             spec = P("segments", "docs")
         else:
-            axes = [None] * arr.ndim
-            axes[seg_axis] = "segments"
-            spec = P(*axes)
+            spec = P("segments", *([None] * (arr.ndim - 1)))
         return jax.device_put(arr, NamedSharding(self._mesh, spec))
 
     @staticmethod
@@ -2657,6 +2693,35 @@ def _isum_u_value(planes: np.ndarray) -> float:
         s = int(planes[2 * k]) * 4096 + int(planes[2 * k + 1])
         total += s << (kernels.ISUM_U_BITS * k)
     return float(total)
+
+
+class _StagePass:
+    """One query's staging pass: its clock reads and its own counts,
+    for the DeviceDispatch span (`_staging_attrs`) and the charge slip.
+    Passes overlap — only the block look-ups hold the staging lock — so
+    none of this can be an engine attribute or an odometer's diff. An
+    untraced pass (span None) reads no clock; it still counts its puts
+    and bytes."""
+
+    __slots__ = ("span", "t_open", "t_plan", "wait_s", "blocks_s",
+                 "insert_s", "puts", "xfer_bytes")
+
+    def __init__(self, span):
+        self.span = span
+        #: perf_counter seconds: the span's opening, and plan + kernel
+        #: look-up done (`planned`)
+        self.t_open = self.t_plan = \
+            span.node.start_ms / 1e3 if span is not None else 0.0
+        #: seconds waited for the staging lock, and holding it: for the
+        #: block look-ups, and to insert what a parameter miss built
+        self.wait_s = self.blocks_s = self.insert_s = 0.0
+        #: `_put` calls, and host->device bytes of puts and row uploads
+        self.puts = 0
+        self.xfer_bytes = 0
+
+    def planned(self) -> None:
+        if self.span is not None:
+            self.t_plan = time.perf_counter()
 
 
 def _shape_sig(cols: Dict[str, Any], params: Dict[str, Any]) -> tuple:

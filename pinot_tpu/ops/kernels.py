@@ -195,21 +195,30 @@ def unpack_params(plan, params, num_docs=None):
 
 def unpack_batch(plan, plist, num_docs, stacked: bool):
     """The mesh kernels' way in, OUTSIDE shard_map (so the rows keep
-    their [.., S] specs): B members' params stacked and unpacked, and
-    the batch's num_docs: the members' own stacked ([B, S]) where their
-    blocks differ, else the one [S] they share (packed: member 0's
-    row, every member having staged the same segments)."""
-    ps, packed_docs = unpack_params(plan, stack_members(plist))
+    their [.., S] specs): a batch's params (`plan_ir.batch_params`)
+    stacked and unpacked, and the batch's num_docs: the members' own
+    stacked ([B, S]) where their blocks differ, else the one [S] they
+    share (packed: member 0's row, every member having staged the same
+    segments)."""
+    ps, packed_docs = unpack_params(plan, stack_params(plist))
     if stacked:
         ns = stack_members(num_docs)
         return ps, packed_docs if ns is None else ns
     return ps, packed_docs[0] if num_docs is None else num_docs
 
 
+def stack_params(plist):
+    """Inside the jit: a batch's params (`plan_ir.batch_params`: the
+    packs one host-stacked [B, K, S] array, what is on the device a
+    tuple of the B members' arrays) with every leaf [B, ...]."""
+    return {k: jnp.stack(v) if isinstance(v, tuple) else v
+            for k, v in plist.items()}
+
+
 def stack_members(members):
-    """B members' staged pytrees (params dicts, column dicts, num_docs
-    arrays, or the None a packed launch brings for num_docs) stacked
-    leaf for leaf along a new leading axis."""
+    """B members' staged pytrees (column dicts, num_docs arrays, or the
+    None a packed launch brings for num_docs) stacked leaf for leaf
+    along a new leading axis."""
     return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *members)
 
 
@@ -836,7 +845,11 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
 
     cols:    dict of 'ids:<col>' int32 [S, D] / 'val:<col>' float [S, D]
     params:  dict of per-leaf predicate arrays ('leaf<i>:lo/hi/idx/lut'),
-             or their packed form (`unpack_params`)
+             or their packed form (`unpack_params`): the engine stages
+             the pack as a HOST int32 [K, S] array and passes it here as
+             it is, so the jit call's own argument path makes the one
+             host->device transfer of the launch; LUT tables and CLP
+             leaf arrays beside it are device arrays already
     num_docs: int32 [S] actual docs per segment (for the padding mask);
              None where the pack carries it.
 
@@ -1114,15 +1127,16 @@ def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
 
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs, ps, ns = map(stack_members, (clist, plist, ndlist))
+            cs, ns = map(stack_members, (clist, ndlist))
+            ps = stack_params(plist)
             return jax.vmap(
                 lambda c, p, nd: base(c, p, nd, D=D, G=G))(cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = stack_members(plist)
+            ps = stack_params(plist)
             # the index array keeps vmap fed when a filterless plan has
             # EMPTY per-query params (vmap rejects an all-empty pytree)
-            idx = jnp.arange(len(plist), dtype=jnp.int32)
+            idx = jnp.arange(B, dtype=jnp.int32)
             return jax.vmap(
                 lambda p, _i: base(cols, p, num_docs, D=D, G=G))(ps, idx)
 
@@ -1154,7 +1168,8 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
     base = make_kernel(plan, kind="batched_dedup", extra=(B, U))
 
     def fn(clist, plist, ndlist, idx, D, G=0):
-        cs, ps, ns = map(stack_members, (clist, plist, ndlist))
+        cs, ns = map(stack_members, (clist, ndlist))
+        ps = stack_params(plist)
         pick = jax.tree_util.tree_map
         return jax.vmap(
             lambda p, i: base(
@@ -1187,13 +1202,14 @@ def make_batched_topn_kernel(plan: DevicePlan, B: int,
 
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs, ps, ns = map(stack_members, (clist, plist, ndlist))
+            cs, ns = map(stack_members, (clist, ndlist))
+            ps = stack_params(plist)
             return jax.vmap(
                 lambda c, p, nd: base(c, p, nd, D=D))(cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = stack_members(plist)
-            idx = jnp.arange(len(plist), dtype=jnp.int32)  # empty-params guard
+            ps = stack_params(plist)
+            idx = jnp.arange(B, dtype=jnp.int32)  # empty-params guard
             return jax.vmap(
                 lambda p, _i: base(cols, p, num_docs, D=D))(ps, idx)
 

@@ -35,8 +35,12 @@ Value IR (aggregation inputs / in-kernel transforms):
 Packed parameters: every [S]-shaped parameter of a plan (the leaf bounds
 above except 'lut' and 'clp', the 'hist:' slots' bucket bounds, the time
 bucket's four cells, and num_docs) is staged as ONE int32 [K, S] array
-under params[PACK], one host->device put a query. `pack_layout(plan)`
-names its rows; the layout is a function of the plan alone, so it is in
+under params[PACK]. It stays a HOST (numpy) array until the launch: a
+lone launch hands it to the jit'd kernel as an argument, a batch's B
+packs are stacked on the host into one [B, K, S] array
+(`batch_params`), and jit's own argument path makes the one transfer a
+launch. `pack_layout(plan)` names its rows; the layout is a function of
+the plan alone, so it is in
 no cache key and a new literal never retraces. Floats are bit-cast, not
 converted (a float64 bound takes two rows), so a bound reaches the
 kernel exactly as the per-array staging gave it.
@@ -95,6 +99,22 @@ def pack_params(plan, arrays: Dict[str, np.ndarray]) -> np.ndarray:
             words = arr.view(np.int32).reshape(arr.shape[0], -1)
             rows.extend(words.T)
     return np.stack(rows)
+
+
+def batch_params(members):
+    """B members' staged params dicts as ONE dict, the batched kernels'
+    `plist`, made on the HOST by whoever launches the batch (the
+    dispatch ring): what a member still holds on the host (the packed
+    [K, S] parameters, a numpy array until the launch) is stacked here
+    into one [B, K, S] array, which jit's own argument path transfers
+    once a launch; what is on the device already (LUT tables, CLP leaf
+    arrays, the vector leg's query arrays) stays a tuple of the B
+    members' arrays and is stacked inside the jit
+    (`kernels.stack_params`)."""
+    return {k: (np.stack([m[k] for m in members])
+                if isinstance(v, np.ndarray)
+                else tuple(m[k] for m in members))
+            for k, v in members[0].items()}
 
 
 @dataclass(frozen=True)

@@ -23,11 +23,15 @@ never serve stale data — id() is not recycled while an entry pins the
 old object, and a new object misses.
 
 Beside the tiers sits the predicate-parameter cache: its entries key on
-the batch, and die with the batch's last assembled block.
+the batch, and die with the batch's last assembled block. An entry's
+packed [K, S] parameters are a host (numpy) array: they reach the
+device as an argument of the launch, never through a put.
 
 This module knows no query shape and imports nothing from ops/engine.py.
-Every `*_locked` method runs under `BlockStager.lock` (the engine takes
-it as its staging lock round one query's plan + stage).
+Every `*_locked` method runs under `BlockStager.lock`, and the lock
+guards nothing but these methods' state: the engine takes it round one
+query's block look-ups and parameter-cache probe, and again, briefly,
+to insert what a probe's miss built outside it.
 """
 from __future__ import annotations
 
@@ -114,6 +118,10 @@ class BlockStager:
         #: bytes of resident rows copied chip to chip at block assembly
         #: (a row found on another chip than its slab's) since start-up
         self.cross_chip_bytes = 0
+        #: bytes of rows uploaded host->device by block misses since
+        #: start-up; it moves under the lock only, so a holder's diff of
+        #: it is that holder's own
+        self.uploaded_bytes = 0
         #: ASSEMBLED device blocks, LRU-evicted under a byte budget: the
         #: exact [S, W] arrays kernels consume, keyed by the segment
         #: batch identity (id+name pairs guard against id() reuse)
@@ -201,6 +209,7 @@ class BlockStager:
         host_rows = [self._host_row_locked(segments[i], *specs[i], dtype,
                                            host_cache) for i in missing]
         uploaded = self._upload_rows(host_rows, missing, S)
+        self.uploaded_bytes += sum(a.nbytes for a in host_rows)
         for i, arr, dev in zip(missing, host_rows, uploaded):
             self.residency.admit(segments[i], specs[i][0], specs[i][1],
                                  dtype_str, dev, arr.nbytes,

@@ -159,14 +159,14 @@ def make_batched_startree_kernel(plan: StarTreePlan, B: int,
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
             cs = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *clist)
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
+            ps = kernels.stack_params(plist)
             ns = jnp.stack(ndlist)
             return jax.vmap(lambda c, p, nd: base(c, p, nd, D=D, G=G))(
                 cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *plist)
-            idx = jnp.arange(len(plist), dtype=jnp.int32)
+            ps = kernels.stack_params(plist)
+            idx = jnp.arange(B, dtype=jnp.int32)
             return jax.vmap(lambda p, _i: base(cols, p, num_docs, D=D, G=G))(
                 ps, idx)
     return jax.jit(fn, static_argnames=("D", "G"))

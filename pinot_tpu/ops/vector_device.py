@@ -173,14 +173,14 @@ def make_batched_vector_kernel(plan: VectorPlan, B: int,
     base = make_vector_kernel(plan, kind=kind, extra=(B,))
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
-            cs, ps, ns = map(kernels.stack_members,
-                             (clist, plist, ndlist))
+            cs, ns = map(kernels.stack_members, (clist, ndlist))
+            ps = kernels.stack_params(plist)
             return jax.vmap(lambda c, p, nd: base(c, p, nd, D=D))(
                 cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
-            ps = kernels.stack_members(plist)
-            idx = jnp.arange(len(plist), dtype=jnp.int32)
+            ps = kernels.stack_params(plist)
+            idx = jnp.arange(B, dtype=jnp.int32)
             return jax.vmap(lambda p, _i: base(cols, p, num_docs, D=D))(
                 ps, idx)
     return jax.jit(fn, static_argnames=("D", "G"))

@@ -127,7 +127,9 @@ METRICS: Dict[str, str] = {
     "hbm_resident_miss": "resident-row tier misses",
     "hbm_admission_rejected": "rows the TinyLFU admission duel rejected",
     "hbm_evicted": "rows evicted from the resident tier",
-    "hbm_transfer_bytes": "host->device bytes shipped by residency",
+    "hbm_transfer_bytes": "host->device bytes shipped by residency and the "
+                          "engine's puts, plus the packed-parameter argument "
+                          "of every launch",
     "hbm_cross_chip_bytes":
         "bytes of resident rows copied chip to chip at block assembly (a "
         "row found on another chip than its slab's: a batch recomposed "

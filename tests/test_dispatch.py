@@ -473,12 +473,14 @@ class _Device:
 
     def launch(self, key, cancel_check=None):
         def factory(bucket, stacked):
+            # plist: the batch's params, one dict (plan_ir.batch_params);
+            # what is no host array stays a tuple of the members' own
             return lambda cols, plist, num_docs, D, G: self._run(
-                key, len({id(p) for p in plist}),  # padding repeats one
-                np.zeros((bucket, 1)))
+                key, len({id(p) for p in plist["member"]}),  # padding
+                np.zeros((bucket, 1)))                 # repeats one
         la = dispatch.Launch(
             call=lambda: self._run(key, 1, np.zeros((1, 1))),
-            params=object(), batch_key=key, cols_key="cols",
+            params={"member": object()}, batch_key=key, cols_key="cols",
             factory=factory, cancel_check=cancel_check, span=_Span())
         return la
 

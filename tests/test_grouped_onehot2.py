@@ -28,7 +28,7 @@ from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
                               TableConfig, TableType)
 from pinot_tpu.ops import kernels
 from pinot_tpu.ops.engine import TpuOperatorExecutor
-from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan
+from pinot_tpu.ops.plan_ir import DeviceLeaf, DevicePlan, batch_params
 from pinot_tpu.parallel import make_mesh
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.executor import QueryExecutor
@@ -151,8 +151,9 @@ def test_onehot2_under_vmap():
         kernel = kernels.make_batched_kernel(_plan(G, ops, filtered=True), 2)
         cols = {"ids:g": jnp.asarray(keys), "val:v": jnp.asarray(vals),
                 "val:f": jnp.asarray(filt)}
-        plist = [{"leaf0:lo": jnp.zeros(S, jnp.float32),
-                  "leaf0:hi": jnp.full(S, hi, jnp.float32)} for hi in his]
+        plist = batch_params(
+            [{"leaf0:lo": jnp.zeros(S, jnp.float32),
+              "leaf0:hi": jnp.full(S, hi, jnp.float32)} for hi in his])
         got = np.asarray(kernel(cols, plist, jnp.asarray(num_docs), D=D))
     assert got.shape == (2, S, G, len(ops))
     valid = np.arange(D)[None, :] < num_docs[:, None]

@@ -567,7 +567,7 @@ class TestWorkload:
         for i, d in enumerate(docs):
             acct.begin_query(f"c{i}", None)
             launches.append(Launch(
-                call=lambda: np.zeros(4), plan="fp", cols=(), params=(i,),
+                call=lambda: np.zeros(4), plan="fp", cols=(), params={"i": i},
                 num_docs=None, D=8, G=0, batch_key=("fp", 8, 8, 0),
                 cols_key=("same",), factory=factory,
                 slip=acct.slip(f"c{i}"), docs=d))

@@ -1,5 +1,6 @@
-"""One packed parameter put a query (ops/plan_ir.py `pack_params`,
-ops/kernels.py `unpack_params`, engine `_stage`).
+"""One packed parameter array a query (ops/plan_ir.py `pack_params`,
+ops/kernels.py `unpack_params`, engine `_stage`); since PR 33 a host
+array that rides the launch as a jit argument, never put.
 
   * every leaf kind whose parameters are [S]-shaped ('range', 'neq',
     'vrange' under f32 and f64 staging, 'vrange64', the 'hist:' slots'
@@ -7,8 +8,9 @@ ops/kernels.py `unpack_params`, engine `_stage`).
     the kernel run on the pack answers bit for bit what it answers on
     the per-array parameters, and a float bound reaches it with the bits
     the host computed
-  * a parameter-cache miss is one put (+ 1 for a LUT leaf's [S, C]
-    table), a hit none, on a 4-device mesh engine too
+  * a parameter-cache miss puts nothing (1 for a LUT leaf's [S, C]
+    table), a hit nothing, on a 4-device mesh engine too; the cache
+    keeps the pack as the numpy array the launch takes
   * the row layout is a function of the plan: new literals and the batch
     buckets 2 / 4 / 8 of one plan trace nothing new
 """
@@ -245,26 +247,38 @@ def test_a_leaf_resolves_once_a_distinct_dictionary(segs, tmp_path,
 
 
 def _puts(eng, segs, sql):
-    before = eng._puts
-    results, remaining = eng.execute(segs, QueryContext.from_sql(sql))
+    """`_put` calls of one query (the engine keeps no count of its own:
+    a staging pass counts its puts on its `_StagePass`)."""
+    calls = []
+    put = eng._put
+
+    def counted(arr, *args, **kwargs):
+        calls.append(arr.shape)
+        return put(arr, *args, **kwargs)
+    eng._put = counted
+    try:
+        results, remaining = eng.execute(segs, QueryContext.from_sql(sql))
+    finally:
+        del eng._put
     assert not remaining, sql
-    return eng._puts - before
+    return len(calls)
 
 
-#: SQL template -> puts a parameter-cache miss costs
+#: SQL template -> puts a parameter-cache miss costs: the pack none, it
+#: is the launch's own argument; a LUT table one
 PUT_LEGS = {
     "SELECT SUM(m), COUNT(*) FROM t WHERE d BETWEEN {a} AND 8 AND f < 1500":
-        1,
-    "SELECT SUM(m) FROM t WHERE d <> {a} AND x > 5000": 1,
-    "SELECT PERCENTILETDIGEST95(f) FROM t WHERE d > {a}": 1,
-    "SELECT d, COUNT(*) FROM t WHERE d IN ({a}, 7, 9) GROUP BY d": 2,
-    "SELECT m FROM t WHERE d > {a} ORDER BY m LIMIT 5": 1,
+        0,
+    "SELECT SUM(m) FROM t WHERE d <> {a} AND x > 5000": 0,
+    "SELECT PERCENTILETDIGEST95(f) FROM t WHERE d > {a}": 0,
+    "SELECT d, COUNT(*) FROM t WHERE d IN ({a}, 7, 9) GROUP BY d": 1,
+    "SELECT m FROM t WHERE d > {a} ORDER BY m LIMIT 5": 0,
 }
 
 
 @pytest.mark.parametrize("devices", [1, 4])
 @pytest.mark.parametrize("template", sorted(PUT_LEGS))
-def test_a_miss_is_one_put_and_a_hit_none(segs, template, devices):
+def test_a_miss_puts_no_pack_and_a_hit_nothing(segs, template, devices):
     eng = TpuOperatorExecutor(devices=jax.devices()[:devices])
     assert (eng._mesh is not None) == (devices > 1)
     _puts(eng, segs, template.format(a=1))  # the column blocks go up
@@ -272,14 +286,13 @@ def test_a_miss_is_one_put_and_a_hit_none(segs, template, devices):
         sql = template.format(a=a)
         assert _puts(eng, segs, sql) == PUT_LEGS[template], sql  # miss
         assert _puts(eng, segs, sql) == 0, sql                   # hit
-    if devices > 1:
-        pack = next(iter(eng.stager._params_cache.values()))[1][PACK]
-        assert len(pack.sharding.device_set) == devices
-        assert pack.sharding.spec == jax.sharding.PartitionSpec(
-            None, "segments")
+    # the cache keeps what the launch takes: a host array, on a mesh too
+    for _segs, params in eng.stager._params_cache.values():
+        assert type(params[PACK]) is np.ndarray
+        assert params[PACK].dtype == np.int32
 
 
-def test_the_span_counts_the_one_put(segs):
+def test_the_span_counts_no_put_for_the_pack(segs):
     eng = TpuOperatorExecutor()
     qe = QueryExecutor(segs, use_tpu=True, engine=eng)
     counts = []
@@ -289,7 +302,7 @@ def test_the_span_counts_the_one_put(segs):
         assert not resp.exceptions
         span, = _dispatch_spans(resp.trace)
         counts.append(span["paramPuts"])
-    assert counts[1:] == [1, 0]
+    assert counts[1:] == [0, 0]
 
 
 def _dispatch_spans(tree):
@@ -315,13 +328,13 @@ def _batch_of(eng, segs, sqls):
         failpoints.disarm("server.dispatch.before")
 
 
-@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("devices", [1, 4, 8])
 def test_literals_and_batch_buckets_trace_nothing_new(segs, devices):
     """The layout is the plan's: it is in no cache key. Once a plan's
     single kernel and its batch buckets 2, 4 and 8 have compiled, other
     literals in batches of the same buckets compile nothing: on one
     device (the launch pool's path, held batches) and on a segments
-    mesh (GSPMD over the packed array's P(None, "segments"))."""
+    mesh (GSPMD places the host-stacked [B, K, S] argument itself)."""
     failpoints.clear()
     eng = TpuOperatorExecutor(devices=jax.devices()[:devices])
     sql = "SELECT SUM(m), COUNT(*) FROM t WHERE d BETWEEN {a} AND {b} " \
@@ -344,10 +357,12 @@ def test_literals_and_batch_buckets_trace_nothing_new(segs, devices):
     sizes = eng._dispatcher._metrics.timer("dispatch_batch_size").max_ms
     assert sizes >= 5, "no batch reached the bucket of 8"
     before = kernels.trace_count()
-    for shift in (1, 2):
+    retraced = eng._dispatcher._metrics.meter("kernel_retrace")
+    for shift in (1, 2):      # a second and a third batch of each bucket
         for n in (2, 4, 8, 3, 1):
             round_of(n, shift)
     assert kernels.trace_count() == before
+    assert eng._dispatcher._metrics.meter("kernel_retrace") == retraced
     # and each answer is the one the query gets alone, off the ring
     lone = TpuOperatorExecutor(devices=jax.devices()[:1])
     for q, got in want.items():

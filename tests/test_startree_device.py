@@ -26,6 +26,7 @@ from pinot_tpu.models import (DataType, FieldSpec, FieldType, Schema,
                               StarTreeIndexConfig, TableConfig, TableType)
 from pinot_tpu.ops import kernels
 from pinot_tpu.ops.engine import TpuOperatorExecutor
+from pinot_tpu.ops.plan_ir import batch_params
 from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.executor import QueryExecutor
 from pinot_tpu.segment.creator import SegmentCreator
@@ -218,7 +219,7 @@ class TestCoalesce:
             kern = launch.factory(b, False)
             with guard:
                 jax.block_until_ready(kern(
-                    launch.cols, (launch.params,) * b, launch.num_docs,
+                    launch.cols, batch_params([launch.params] * b), launch.num_docs,
                     D=launch.D, G=launch.G))
             b *= 2
 
