@@ -4,7 +4,12 @@ Reference parity: Druid's historical segment cache (`useCache` /
 `populateCache`, immutable segments only) mapped onto this repo's
 ImmutableSegment / consuming-segment split. Cached unit: ONE segment's
 aggregation / group-by / distinct partial for ONE plan fingerprint.
-Consuming (mutable) segments and upsert segments (live `valid_doc_ids`)
+A device GROUP BY folds its segments' partials before they leave the
+device (ops/engine.py `_group_fold`), so there the cached unit is the ONE
+partial of the whole segment batch, keyed by every member's name and
+version (`get_batch` / `put_batch`): the same segments asked the same
+plan again are served without the device. Consuming (mutable) segments
+and upsert segments (live `valid_doc_ids`)
 are never cached — the mutable tail always re-executes, which is exactly
 what keeps hybrid tables fresh while the immutable bulk is served from
 cache.
@@ -72,6 +77,8 @@ def segment_remote_key(key) -> Optional[str]:
     per-process counters — identical stamps on two instances would alias
     DIFFERENT segment contents, so only content-CRC versions are shared."""
     name, version, plan_fp = key
+    if isinstance(name, tuple):
+        return None  # a batch's folded partial stays process-local
     if not (isinstance(version, tuple) and version[0] == "crc"):
         return None
     return f"seg|{name}|crc:{version[1]}|{plan_fp}"
@@ -134,8 +141,31 @@ class SegmentResultCache:
     def get(self, segment: Any, plan_fp: str) -> Optional[Any]:
         if not self.enabled or not is_cacheable_segment(segment):
             return None
-        payload = self._cache.get(
-            (segment.name, segment_version(segment), plan_fp))
+        return self._get((segment.name, segment_version(segment), plan_fp))
+
+    @staticmethod
+    def _batch_key(segments, plan_fp: str):
+        """(names, versions, plan): a replaced member addresses another
+        key, as a replaced segment does."""
+        return (tuple(s.name for s in segments),
+                tuple(segment_version(s) for s in segments), plan_fp)
+
+    def get_batch(self, segments, plan_fp: str) -> Optional[Any]:
+        """The folded partial of exactly these segments, in this order,
+        or None."""
+        if not self.enabled or len(segments) < 2 \
+                or not all(is_cacheable_segment(s) for s in segments):
+            return None
+        return self._get(self._batch_key(segments, plan_fp))
+
+    def put_batch(self, segments, plan_fp: str, result: Any) -> bool:
+        if not self.enabled or len(segments) < 2 \
+                or not all(is_cacheable_segment(s) for s in segments):
+            return False
+        return self._put(self._batch_key(segments, plan_fp), result)
+
+    def _get(self, key) -> Optional[Any]:
+        payload = self._cache.get(key)
         if payload is None:
             return None
         # workload accounting: serving this partial cost the cache tier
@@ -149,6 +179,10 @@ class SegmentResultCache:
     def put(self, segment: Any, plan_fp: str, result: Any) -> bool:
         if not self.enabled or not is_cacheable_segment(segment):
             return False
+        return self._put(
+            (segment.name, segment_version(segment), plan_fp), result)
+
+    def _put(self, key, result: Any) -> bool:
         payload = self._encode(result)
         if payload is None:
             return False
@@ -158,8 +192,7 @@ class SegmentResultCache:
         slip = current_slip()
         if slip is not None:
             slip.add(cache_miss_bytes=len(payload))
-        return self._cache.put(
-            (segment.name, segment_version(segment), plan_fp), payload)
+        return self._cache.put(key, payload)
 
     def invalidate_segment(self, name: str, except_version=None) -> int:
         """Drop cached partials for the named segment. except_version
@@ -167,9 +200,14 @@ class SegmentResultCache:
         segment right after warmup populated the NEW version's entries,
         and a name-only purge would wipe that warmup work along with the
         stale version."""
-        return self._cache.invalidate(
-            lambda k: k[0] == name and (except_version is None
-                                        or k[1] != except_version))
+        def stale(k) -> bool:
+            if isinstance(k[0], tuple):  # a batch with this member
+                return any(n == name and (except_version is None
+                                          or v != except_version)
+                           for n, v in zip(k[0], k[1]))
+            return k[0] == name and (except_version is None
+                                     or k[1] != except_version)
+        return self._cache.invalidate(stale)
 
     def clear(self) -> None:
         self._cache.clear()

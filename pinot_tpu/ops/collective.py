@@ -1,4 +1,7 @@
-"""Collective broker merge: the cross-segment partial fold ON DEVICE.
+"""Collective broker merge: the cross-segment partial fold ON DEVICE,
+for ungrouped aggregations on an explicit mesh. (A GROUP BY's partials
+are folded inside the plain-jit kernel on every engine,
+kernels.fold_groups: one remap, one assembly.)
 
 Reference parity: the reference broker/server merge per-segment partials
 host-side (IndexedTable / the combine operators — SURVEY §2.7). On an
@@ -11,23 +14,12 @@ query returns ONE merged row instead of S per-segment rows.
 
 Layout contract (engine._assemble_merged is the only consumer):
 
-  no group-by: [sum(slot widths) + S]   — merged slots at the same
-               slot offsets _assemble uses (no leading matched column),
-               then the per-segment matched counts as an [S] tail (the
-               exact ExecutionStats the host fold would have summed).
-  group-by:    [G * n_slots + S]        — the merged [G, n_slots] group
-               block flattened row-major, then the same [S] matched tail.
-  batched:     [B, L] — batch axis leading, same L per member, so the
-               dispatch ring's split_packed contract holds unchanged.
-
-Group keys are GLOBAL: per-segment dictIds/compact codes are
-segment-local, so the engine factorizes a global key space once
-host-side (engine._merged_group_params) and ships tiny int32 remap
-params — `gmap` (compact: local code -> global index) or per-column
-`gmap<i>` + traced `gstride` (dense: local dictId -> global value index,
-mixed-radix over the UNION cardinalities). The kernels here only gather
-through those tables; changing segment composition re-uploads a few KB
-of params and never retraces.
+  one query: [sum(slot widths) + S]   — merged slots at the same
+             slot offsets _assemble uses (no leading matched column),
+             then the per-segment matched counts as an [S] tail (the
+             exact ExecutionStats the host fold would have summed).
+  batched:   [B, L] — batch axis leading, same L per member, so the
+             dispatch ring's split_packed contract holds unchanged.
 
 Merge semantics per slot ride kernels._DOC_COMBINE — combining partials
 across segments uses the same semiring as combining across doc shards
@@ -38,7 +30,6 @@ host fold is property-tested in tests/test_mesh_scaling.py.
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -50,43 +41,12 @@ from pinot_tpu.ops.kernels import note_trace, plan_fingerprint
 from pinot_tpu.ops.plan_ir import DevicePlan
 
 
-def _merged_plan(plan: DevicePlan) -> DevicePlan:
-    """Plan variant whose group keys come from cols['gkey'] (the
-    injected GLOBAL keys) regardless of how the original plan keyed:
-    group_compact reads gkey directly and num_groups=0 defers the group
-    count to the kernel's static G (the global pow2 pad)."""
-    if not plan.group_cols:
-        return plan
-    return dataclasses.replace(plan, group_compact=True, num_groups=0,
-                               group_strides=())
-
-
-def _global_keys(plan: DevicePlan, cols, params) -> jnp.ndarray:
-    """Shard-local [S_loc, D_loc] GLOBAL group indices via the host-
-    factorized remap params (engine._merged_group_params)."""
-    if plan.group_compact:
-        # local compact code -> global index: one gather per doc
-        return jnp.take_along_axis(params["gmap"], cols["gkey"], axis=-1)
-    keys = None
-    gstride = params["gstride"]  # [S, k] global mixed-radix strides
-    for ci, col in enumerate(plan.group_cols):
-        idx = jnp.take_along_axis(params[f"gmap{ci}"],
-                                  cols["ids:" + col], axis=-1)
-        term = idx * gstride[..., ci:ci + 1]
-        keys = term if keys is None else keys + term
-    return keys
-
-
-def _member_fn(plan: DevicePlan, doc_shards: int, has_docs: bool,
-               count_j):
+def _member_fn(plan: DevicePlan, doc_shards: int, has_docs: bool):
     """Per-member shard-local compute: slot partials reduced over the
     LOCAL segment axis (pure jnp — vmappable; collectives are applied by
     the caller AFTER any batching, so a batch pays one rendezvous).
     Returns (tuple of locally-reduced slot arrays, local matched [S_loc])."""
-    mplan = _merged_plan(plan)
-    grouped = bool(plan.group_cols)
-
-    def member(cols, params, num_docs, D, G):
+    def member(cols, params, num_docs, D):
         d_local = D // doc_shards
         if has_docs:
             doc_pos = (jax.lax.axis_index("docs") * d_local
@@ -96,20 +56,11 @@ def _member_fn(plan: DevicePlan, doc_shards: int, has_docs: bool,
         valid = doc_pos < num_docs[:, None]
         if plan.valid_mask:
             valid = valid & cols["vmask"]
-        if grouped:
-            kcols = dict(cols)
-            kcols["gkey"] = _global_keys(plan, cols, params)
-            slots, _ = kernels._compute_slots(mplan, kcols, params,
-                                              valid, G)
-            # the guaranteed unfiltered count slot sums to the per-seg
-            # matched count (every matched doc lands in exactly one key)
-            matched = jnp.sum(slots[count_j][1], axis=-1)
-        else:
-            slots, matched = kernels._compute_slots(plan, cols, params,
-                                                    valid, 0)
+        slots, matched = kernels._compute_slots(plan, cols, params,
+                                                valid, 0)
         # local fold over THIS shard's segments; axis 0 is the segment
         # axis for every slot shape here ([S_loc] scalar, [S_loc, w]
-        # sketch, [S_loc, G] grouped)
+        # sketch)
         locs = []
         for (op, _v, _f), (_o, s) in zip(plan.agg_ops, slots):
             kind = kernels._doc_combine(op)
@@ -124,7 +75,7 @@ def _member_fn(plan: DevicePlan, doc_shards: int, has_docs: bool,
     return member
 
 
-def _collect_pack(plan: DevicePlan, locs, axes, G: int):
+def _collect_pack(plan: DevicePlan, locs, axes):
     """One collective per slot over EVERY mesh axis, then pack into the
     module's merged layout (rank-agnostic: a leading batch axis rides
     along untouched — the reductions already happened per member)."""
@@ -137,9 +88,6 @@ def _collect_pack(plan: DevicePlan, locs, axes, G: int):
             merged.append(jax.lax.pmin(s, axes))
         else:
             merged.append(jax.lax.pmax(s, axes))
-    if plan.group_cols:
-        out = jnp.stack(merged, axis=-1)          # [..., G, n_slots]
-        return out.reshape(out.shape[:-2] + (G * len(plan.agg_ops),))
     parts = [s[..., None] if kernels.slot_width(op) == 1 else s
              for (op, _v, _f), s in zip(plan.agg_ops, merged)]
     return jnp.concatenate(parts, axis=-1)        # [..., sum(widths)]
@@ -149,15 +97,6 @@ def _mesh_geometry(mesh):
     axes = tuple(mesh.axis_names)
     shape = dict(zip(mesh.axis_names, mesh.devices.shape))
     return axes, "docs" in axes, shape.get("docs", 1)
-
-
-def _find_count_slot(plan: DevicePlan):
-    if not plan.group_cols:
-        return None
-    for j, (op, _v, fidx) in enumerate(plan.agg_ops):
-        if op == "count" and fidx is None:
-            return j
-    raise ValueError("grouped plan without an unfiltered count slot")
 
 
 def _matched_tail(matched, seg_shards: int, axes):
@@ -183,21 +122,20 @@ def _matched_tail(matched, seg_shards: int, axes):
 def make_merged_kernel(plan: DevicePlan, mesh):
     """Single-query collective merge: fn(cols, params, num_docs, D, G)
     -> ONE packed [L] row (layout in the module docstring). D is the
-    padded GLOBAL doc count; G the GLOBAL group pad (0 = no group-by)."""
+    padded GLOBAL doc count; G is 0 (the launch's signature)."""
     from jax.sharding import PartitionSpec as P
 
     axes, has_docs, doc_shards = _mesh_geometry(mesh)
     seg_shards = dict(zip(mesh.axis_names,
                           mesh.devices.shape))["segments"]
     fp = plan_fingerprint(plan)
-    count_j = _find_count_slot(plan)
-    member = _member_fn(plan, doc_shards, has_docs, count_j)
+    member = _member_fn(plan, doc_shards, has_docs)
 
     def local(cols, params, num_docs, D, G=0):
         # body runs at trace time: counts compiles
         note_trace("merged", fp, (int(num_docs.shape[-1]), D, G))
-        locs, matched = member(cols, params, num_docs, D, G)
-        flat = _collect_pack(plan, locs, axes, G)
+        locs, matched = member(cols, params, num_docs, D)
+        flat = _collect_pack(plan, locs, axes)
         tail = _matched_tail(matched, seg_shards, axes)
         return jnp.concatenate([flat, tail.astype(flat.dtype)], axis=-1)
 
@@ -241,8 +179,7 @@ def make_batched_merged_kernel(plan: DevicePlan, mesh, B: int,
     seg_shards = dict(zip(mesh.axis_names,
                           mesh.devices.shape))["segments"]
     fp = plan_fingerprint(plan)
-    count_j = _find_count_slot(plan)
-    member = _member_fn(plan, doc_shards, has_docs, count_j)
+    member = _member_fn(plan, doc_shards, has_docs)
     kind = "merged_batched_stacked" if stacked else "merged_batched"
 
     def local(cols, params, num_docs, D, G=0):
@@ -252,9 +189,9 @@ def make_batched_merged_kernel(plan: DevicePlan, mesh, B: int,
         idx = jnp.arange(B, dtype=jnp.int32)
         in_axes = (0 if stacked else None, 0, 0 if stacked else None, 0)
         locs, matched = jax.vmap(
-            lambda c, p, nd, _i: member(c, p, nd, D, G),
+            lambda c, p, nd, _i: member(c, p, nd, D),
             in_axes=in_axes)(cols, params, num_docs, idx)
-        flat = _collect_pack(plan, locs, axes, G)
+        flat = _collect_pack(plan, locs, axes)
         tail = _matched_tail(matched, seg_shards, axes)
         return jnp.concatenate([flat, tail.astype(flat.dtype)], axis=-1)
 

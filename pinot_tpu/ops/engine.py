@@ -19,6 +19,7 @@ Responsibilities:
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import math
 import threading
 import time
@@ -92,10 +93,13 @@ class TpuOperatorExecutor:
         passes its instance id)."""
         device_mod.configure_compile_cache()
         self._doc_axis = 1
-        #: collective broker merge engages only on an EXPLICIT mesh: the
-        #: implicit >1-device segments mesh below keeps per-segment
-        #: partials so the tier-2 segment cache and stacked-batch dedup
-        #: (both keyed per segment) work exactly as on one device
+        #: collective broker merge (shard_map + psum: an UNGROUPED
+        #: aggregation's partials become one row) engages only on an
+        #: EXPLICIT mesh; one device and the implicit >1-device segments
+        #: mesh below keep one result a segment there, which the tier-2
+        #: segment cache is keyed by. A GROUP BY's partials are folded
+        #: inside the plain-jit kernel on every engine (`_group_fold`;
+        #: GSPMD makes the all-reduce on a segments mesh)
         self._explicit_mesh = mesh is not None
         if mesh is not None:
             self._mesh = mesh
@@ -166,13 +170,14 @@ class TpuOperatorExecutor:
         self._ts_bucket_enabled = _cfg.get_bool(
             "pinot.server.timeseries.bucket.enabled", True)
         #: collective broker merge (ops/collective.py): on a mesh engine
-        #: the per-segment partial fold becomes one on-device
-        #: psum/pmin/pmax over the whole mesh; the host IndexedTable
+        #: an ungrouped aggregation's per-segment partial fold becomes
+        #: one on-device psum/pmin/pmax over the whole mesh; the host
         #: fold stays reachable as the escape hatch when this is off
         self._collective_merge = _cfg.get_bool(
             "pinot.server.mesh.collective.merge", True)
-        #: host-factorized global group-key remap params per
-        #: (segment batch, plan) — built once, re-used across queries
+        #: host-factorized global group-key remap params of the device
+        #: fold per (segment batch, group columns) — built once, re-used
+        #: across queries
         self._gmap_cache: "OrderedDict[tuple, Any]" = OrderedDict()
 
     # ------------------------------------------------------------------
@@ -358,13 +363,8 @@ class TpuOperatorExecutor:
                            _p, _m, B, stacked))
             dedup_factory = None  # sharded in_specs are per-member
         else:
-            kernel = kernels.compiled_kernel(plan)
+            kernel, factory, dedup_factory = self._plain_kernels(plan)
             batchable = isinstance(kernel, jax.stages.Wrapped)
-            factory = (lambda B, stacked, _p=plan:
-                       kernels.compiled_batched_kernel(_p, B, stacked))
-            dedup_factory = (lambda B, U, _p=plan:
-                             kernels.compiled_batched_dedup_kernel(
-                                 _p, B, U))
         info.planned()
         try:
             cols, params, S, S_real, D, G = self._stage(
@@ -375,14 +375,15 @@ class TpuOperatorExecutor:
                 dsp.end(outcome="hostFallback")
             return None
         staged_ts = self._staging_attrs(info, S=S, D=D, G=G)
-        # collective broker merge (ops/collective.py): fold the
-        # per-segment partials on device — one psum/pmin/pmax over
-        # the whole mesh — instead of shipping [S, ...] rows to the
-        # host IndexedTable fold. Any gate trips back to the
-        # per-segment launch below, metered by reason
+        # collective broker merge (ops/collective.py): fold an
+        # UNGROUPED aggregation's per-segment partials on device — one
+        # psum/pmin/pmax over the whole mesh — instead of shipping
+        # [S, ...] rows to the host fold. Any gate trips back to the
+        # per-segment launch below, metered by reason. A GROUP BY has
+        # its own fold further down, on every engine alike
         minfo = None
         if self._explicit_mesh and len(self.devices) > 1 \
-                and batchable:
+                and batchable and not plan.group_cols:
             if not self._collective_merge:
                 self._merge_fallback("disabled")
             else:
@@ -395,20 +396,27 @@ class TpuOperatorExecutor:
                     chaos = True
                 if not chaos:
                     try:
-                        params, minfo = self._merged_prepare(
-                            segments, plan, params, S_real, S, G, info)
+                        minfo = self._merged_prepare(segments, S_real, S)
                     except _MergeFallback as e:
                         self._merge_fallback(e.reason)
                     except Exception:  # noqa: BLE001 — never fail
                         self._merge_fallback("staging")  # the query
+        # one grouped result a query leaves the device: the per-segment
+        # partials of a GROUP BY are folded inside the kernel over a
+        # global key space (kernels.fold_groups), unless
+        # `kernels.group_fold` says host
+        if plan.group_cols and batchable:
+            folded = self._group_fold(segments, plan, params, S, G, info)
+            if folded is not None:
+                plan, params, minfo = folded
+                kernel, factory, dedup_factory = self._plain_kernels(plan)
         if slip is not None:
             slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("scan_served")
-        G_eff = G
         num_groups = plan.num_groups or G
-        if minfo is not None:
+        merged = minfo is not None and not plan.group_fold
+        if merged:
             self._meter("mesh_merge_served")
-            G_eff = num_groups = minfo["G"]
             kernel = collective.compiled_merged_kernel(plan, self._mesh)
             factory = (lambda B, stacked, _p=plan, _m=self._mesh:
                        collective.compiled_batched_merged_kernel(
@@ -420,18 +428,21 @@ class TpuOperatorExecutor:
             path = kernels.group_path(
                 num_groups, D // self._doc_axis, kernels._value_dtype(),
                 finite=not plan.nonfinite)
+            fold = "device" if plan.group_fold else "host"
             self._meter("group_path", path=path)
+            self._meter("group_fold", where=fold)
             if dsp is not None:
-                dsp.set(groupPath=path)
+                dsp.set(groupPath=path, groupKeySpace=num_groups,
+                        groupFold=fold)
         launch = Launch(
             # num_docs rides the packed parameters (plan_ir.PACK), a
             # host array here: the call's own argument, one transfer
-            call=lambda: kernel(cols, params, None, D=D, G=G_eff),
+            call=lambda: kernel(cols, params, None, D=D, G=G),
             plan=plan, cols=cols, params=params, num_docs=None,
-            D=D, G=G_eff,
+            D=D, G=G,
             batch_key=self._coalesce_key(
-                plan, segments, S, D, G_eff, cols, params, batchable,
-                merged=minfo is not None),
+                plan, segments, S, D, G, cols, params, batchable,
+                merged=merged),
             cols_key=self._cols_key(segments, plan),
             factory=factory, dedup_factory=dedup_factory,
             collective=self._needs_cpu_ordering(kernel),
@@ -440,6 +451,16 @@ class TpuOperatorExecutor:
             slip=slip, docs=sum(s.num_docs for s in segments),
             staged_ts=staged_ts)
         return plan, slots_of_fn, S_real, launch, minfo
+
+    @staticmethod
+    def _plain_kernels(plan: DevicePlan):
+        """(kernel, batched factory, dedup factory) of a plan on the
+        plain-jit route (no doc sharding, no collective merge)."""
+        return (kernels.compiled_kernel(plan),
+                lambda B, stacked: kernels.compiled_batched_kernel(
+                    plan, B, stacked),
+                lambda B, U: kernels.compiled_batched_dedup_kernel(
+                    plan, B, U))
 
     def _coalesce_key(self, plan, segments, S, D, G, cols, params,
                       batchable: bool, merged: bool = False):
@@ -461,11 +482,12 @@ class TpuOperatorExecutor:
         return (plan, batch_id(segments), D, G, mesh_sig)
 
     # ------------------------------------------------------------------
-    # collective broker merge (ops/collective.py)
+    # one result a batch: the collective merge (ops/collective.py,
+    # ungrouped) and the GROUP BY fold (kernels.fold_groups)
     # ------------------------------------------------------------------
     #: cap on the host-factorized group-remap params shipped per
-    #: (segment batch, plan) — past this the remap upload would rival
-    #: the partial rows it saves, so the host fold wins
+    #: (segment batch, group columns) — past this the remap upload would
+    #: rival the partial rows it saves, so the host fold wins
     GMAP_MAX_BYTES = 1 << 26
     GMAP_CACHE_ENTRIES = 64
 
@@ -474,12 +496,9 @@ class TpuOperatorExecutor:
         the host IndexedTable fold (labeled like startree_fallback)."""
         self._meter("mesh_merge_fallback", reason=reason)
 
-    def _merged_prepare(self, segments, plan: DevicePlan, params,
-                        S_real: int, S: int, G_local: int, info=None):
-        """Gate + group-key factorization for the collective merge.
-        Returns (params with the remap entries merged in beside the
-        host-side pack, minfo) or raises _MergeFallback(reason). Runs
-        after staging, outside the staging lock."""
+    def _merged_prepare(self, segments, S_real: int, S: int):
+        """Gate of the collective merge (ungrouped aggregations on an
+        explicit mesh): its minfo, or raises _MergeFallback(reason)."""
         if kernels._value_dtype() == jnp.float32:
             # merged counts/isum halves sum ACROSS segments: exactness
             # needs total docs < 2^24 and < 4096 real segments (the
@@ -487,35 +506,79 @@ class TpuOperatorExecutor:
             total = sum(int(seg.num_docs) for seg in segments)
             if total >= MAX_DOCS_PER_SEGMENT or S_real >= 4096:
                 raise _MergeFallback("precision")
-        if not plan.group_cols:
-            return params, {"S": S, "G": 0}
-        gparams, G_m, n_real, decode = self._merged_group_params(
-            segments, plan, S, G_local, info)
+        return {"S": S, "G": 0}
+
+    def _group_fold(self, segments, plan: DevicePlan, params, S: int,
+                    G_local: int, info):
+        """A GROUP BY's device fold, prepared: (the plan with its
+        `group_fold` set, params with the inverse remap tables beside
+        the pack, the assembly's info), or None where the per-segment
+        partials leave the device (`groupFold` = host): the doc-sharded
+        kernels (a shard_map body cannot take the fold's gathers over
+        its local segments alone), or a remap `kernels.group_fold`
+        turned down. Runs after
+        staging, outside the staging lock."""
+        if self._doc_axis > 1 or self._fold_where(plan) == "host":
+            return None  # the last: what the plan alone rules out
+        try:
+            gparams, G_out, decode = self._group_remap(
+                segments, plan, S, G_local, info)
+        except _MergeFallback:
+            return None
+        fold = (G_out,) if plan.group_compact else tuple(decode[1])
         params = dict(params)
         params.update(gparams)
-        return params, {"S": S, "G": G_m, "n_real": n_real,
-                        "decode": decode}
+        return (dataclasses.replace(plan, group_fold=fold), params,
+                {"G": G_out, "decode": decode})
 
-    def _merged_group_params(self, segments, plan: DevicePlan, S: int,
-                             G_local: int, info=None):
+    def _fold_where(self, plan: DevicePlan, out_groups: int = 0,
+                    remap_bytes: int = 0) -> str:
+        """`kernels.group_fold` against the engine's own caps."""
+        return kernels.group_fold(plan, out_groups, remap_bytes,
+                                  MAX_DEVICE_GROUPS, self.GMAP_MAX_BYTES)
+
+    def _group_remap(self, segments, plan: DevicePlan, S: int,
+                     G_local: int, info=None):
         """Factorize a GLOBAL group-key space once host-side: dictIds
-        and compact codes are segment-local, so the device can only
-        merge groups through a remap to shared indices. Compact plans
-        ship one [S, G_local] code->global table; dense plans ship
-        per-column [S, Cpad] dictId->union-index tables plus the traced
-        [S, k] global strides (mixed radix over UNION cardinalities —
-        stride changes re-upload KBs, never retrace). Cached per
-        (segment batch, plan); returns (params, G pad, real group
-        count, decode info for _assemble_merged). The staging lock is
-        held for the cache probe and the insert only: the factorization
-        and its puts run outside it."""
-        key = (batch_id(segments), plan, S, G_local)
+        and compact codes are segment-local, so the device can only fold
+        groups across segments through a remap to shared indices. The
+        fold gathers every GROUP's partial (kernels.fold_groups):
+        compact plans ship one [S, G] global->code table, dense plans
+        per-column [S, U] union-index->dictId tables, -1 where a segment
+        lacks the key; G is the union's key space with every digit
+        padded to a pow2 (one compiled shape for batches whose unions
+        differ by a few values), and `kernels.group_fold` decides
+        whether it is worth it.
+
+        Cached per (segment batch, group columns), so plans that differ
+        in their aggregates share it, a remap that was turned down too;
+        returns (params, G, decode info for _assemble_folded). The
+        staging lock is held for the cache probe and the insert only:
+        the factorization and its puts run outside it."""
+        key = (batch_id(segments), plan.group_cols, plan.group_strides,
+               plan.group_compact, S, G_local)
         with self._engine_lock:
             ent = self._gmap_cache.get(key)
             if ent is not None:
                 self._gmap_cache.move_to_end(key)
+                if isinstance(ent, str):
+                    # turned down once: not factorized again
+                    raise _MergeFallback(ent)
                 return ent
-        n_slots = max(len(plan.agg_ops), 1)
+        try:
+            ent = self._factorize_groups(segments, plan, S, info)
+        except _MergeFallback as e:
+            ent = e.reason
+        with self._engine_lock:
+            self._gmap_cache[key] = ent
+            while len(self._gmap_cache) > self.GMAP_CACHE_ENTRIES:
+                self._gmap_cache.popitem(last=False)
+        if isinstance(ent, str):
+            raise _MergeFallback(ent)
+        return ent
+
+    def _factorize_groups(self, segments, plan: DevicePlan, S: int, info):
+        """What a miss of `_group_remap`'s cache builds."""
         if plan.group_compact:
             per_seg = []
             for seg in segments:
@@ -528,69 +591,49 @@ class TpuOperatorExecutor:
                                 for i in range(table.shape[0])])
             union = sorted(set().union(*map(set, per_seg))) \
                 if per_seg else []
-            n_real = len(union)
-            G_m = _pow2(max(n_real, 1), floor=8)
-            if G_m > MAX_DEVICE_GROUPS \
-                    or S * G_m * n_slots * 8 > MAX_GROUP_RESULT_BYTES \
-                    or S * G_local * 4 > self.GMAP_MAX_BYTES:
-                raise _MergeFallback("groups")
             index = {t: i for i, t in enumerate(union)}
-            gmap = np.zeros((S, G_local), np.int32)
+            G_out = _pow2(max(len(union), 1), floor=8)
+            if self._fold_where(plan, G_out, S * G_out * 4) == "host":
+                raise _MergeFallback("groups")
+            ginv = np.full((S, G_out), -1, np.int32)
             for s, tuples in enumerate(per_seg):
                 for code, t in enumerate(tuples):
-                    gmap[s, code] = index[t]
-            gparams = {"gmap": self._put(gmap, info)}
-            decode = union  # global index -> key value tuple
-        else:
-            unions = []
-            per_col_vals = []
-            for colname in plan.group_cols:
-                vals = []
-                for seg in segments:
-                    card = max(
-                        int(seg.metadata.columns[colname].cardinality), 1)
-                    d = seg.data_source(colname).dictionary
-                    vals.append(np.asarray(
-                        d.get_values(np.arange(card))))
-                per_col_vals.append(vals)
-                unions.append(np.unique(np.concatenate(vals)))
-            cards = [len(u) for u in unions]
-            n_real = 1
-            for c in cards:
-                n_real *= max(c, 1)
-                if n_real > MAX_DEVICE_GROUPS:
-                    raise _MergeFallback("groups")
-            G_m = _pow2(max(n_real, 1), floor=8)
-            gbytes = sum(
-                S * _pow2(max(len(v) for v in vals), floor=8) * 4
-                for vals in per_col_vals)
-            if G_m > MAX_DEVICE_GROUPS \
-                    or S * G_m * n_slots * 8 > MAX_GROUP_RESULT_BYTES \
-                    or gbytes > self.GMAP_MAX_BYTES:
-                raise _MergeFallback("groups")
-            strides = []
-            st = n_real
-            for c in cards:
-                st //= max(c, 1)
-                strides.append(st)
-            gparams = {}
-            for ci, (union, vals) in enumerate(zip(unions,
-                                                   per_col_vals)):
-                Cpad = _pow2(max(len(v) for v in vals), floor=8)
-                gm = np.zeros((S, Cpad), np.int32)
-                for s, v in enumerate(vals):
-                    gm[s, :len(v)] = np.searchsorted(union, v)
-                gparams[f"gmap{ci}"] = self._put(gm, info)
-            gstride = np.ascontiguousarray(np.broadcast_to(
-                np.asarray(strides, np.int32), (S, len(strides))))
-            gparams["gstride"] = self._put(gstride, info)
-            decode = (tuple(strides), tuple(cards), tuple(unions))
-        ent = (gparams, G_m, n_real, decode)
-        with self._engine_lock:
-            self._gmap_cache[key] = ent
-            while len(self._gmap_cache) > self.GMAP_CACHE_ENTRIES:
-                self._gmap_cache.popitem(last=False)
-        return ent
+                    ginv[s, index[t]] = code
+            # decode: global index -> key value tuple
+            return {"ginv": self._put(ginv, info)}, G_out, union
+        unions = []
+        per_col_vals = []
+        for colname in plan.group_cols:
+            vals = []
+            for seg in segments:
+                card = max(
+                    int(seg.metadata.columns[colname].cardinality), 1)
+                d = seg.data_source(colname).dictionary
+                vals.append(np.asarray(d.get_values(np.arange(card))))
+            per_col_vals.append(vals)
+            unions.append(np.unique(np.concatenate(vals)))
+        # the key space in pow2 digits, as S, D and a compact G are
+        # bucketed: a window that slides over time-partitioned segments
+        # changes a union by a value or two, and the kernel's shape must
+        # not follow it. A padded digit is a group no segment has
+        cards = [_pow2(len(u), floor=8) for u in unions]
+        G_out = math.prod(cards)
+        if self._fold_where(plan, G_out,
+                            sum(S * c * 4 for c in cards)) == "host":
+            raise _MergeFallback("groups")
+        strides = []
+        st = G_out
+        for c in cards:
+            st //= c
+            strides.append(st)
+        gparams = {}
+        for ci, (union, vals) in enumerate(zip(unions, per_col_vals)):
+            gi = np.full((S, cards[ci]), -1, np.int32)
+            for s, v in enumerate(vals):
+                gi[s, np.searchsorted(union, v)] = \
+                    np.arange(len(v), dtype=np.int32)
+            gparams[f"ginv{ci}"] = self._put(gi, info)
+        return gparams, G_out, (tuple(strides), tuple(cards), tuple(unions))
 
     # ------------------------------------------------------------------
     # star-tree device leg (ops/startree_device.py)
@@ -1111,11 +1154,15 @@ class TpuOperatorExecutor:
         if prep is None:
             return None
         plan, slots_of_fn, S_real, launch, minfo = prep
+        if plan.group_fold:
+            return launch, lambda packed: self._assemble_folded(
+                segments, ctx, plan, packed, S_real, slots_of_fn, minfo,
+                launch.span)
         if minfo is not None:
             return launch, lambda packed: self._assemble_merged(
                 segments, ctx, plan, packed, S_real, slots_of_fn, minfo)
         return launch, lambda packed: self._assemble(
-            segments, ctx, plan, packed, S_real, slots_of_fn)
+            segments, ctx, plan, packed, S_real, slots_of_fn, launch.span)
 
     @staticmethod
     def _note_assemble(launch: Launch, t0: float, parent=None) -> None:
@@ -2470,7 +2517,8 @@ class TpuOperatorExecutor:
     # ------------------------------------------------------------------
     def _assemble(self, segments, ctx: QueryContext, plan: DevicePlan,
                   packed: np.ndarray, S_real: int,
-                  mappings: List[Dict[str, int]]) -> List[Any]:
+                  mappings: List[Dict[str, int]], span=None) -> List[Any]:
+        t0 = time.perf_counter()
         filter_cols = len(set(ctx.filter_columns()))
         # parity with executor_cpu: COUNT(*) materializes no column, so it
         # doesn't contribute to entries-scanned-post-filter
@@ -2535,7 +2583,23 @@ class TpuOperatorExecutor:
                             slots["hist_width"] = span / w
                     inters.append(fn.from_device_slots(slots))
                 results.append(AggregationResult(inters, stats))
+        if is_group:
+            self._note_groups(span, packed.nbytes,
+                              sum(len(r.groups) for r in results), t0)
         return results
+
+    def _note_groups(self, span, nbytes: int, present: int,
+                     t0: float) -> None:
+        """A grouped fetch's numbers, on the meter and (traced) on the
+        launch's DeviceDispatch span: bytes of group table fetched,
+        groups present in it (summed over the segments where the host
+        folds), and the time from the fetched slots to the group table
+        (inside the request's assembleMs)."""
+        self._meter("group_result_bytes", nbytes)
+        if span is not None:
+            span.set(groupResultBytes=int(nbytes), groupsPresent=present,
+                     groupDecodeMs=round(
+                         (time.perf_counter() - t0) * 1e3, 3))
 
     def _assemble_group(self, seg, s, ctx, plan, packed, count_j, mappings, stats):
         present = np.nonzero(packed[s, :, count_j] > 0)[0]
@@ -2588,32 +2652,15 @@ class TpuOperatorExecutor:
                          plan: DevicePlan, packed: np.ndarray,
                          S_real: int, mappings: List[Dict[str, int]],
                          minfo) -> List[Any]:
-        """ONE result covering the whole segment batch, from the
-        collective-merge kernel's packed row (layout documented in
+        """ONE AggregationResult covering the whole segment batch, from
+        the collective-merge kernel's packed row (layout documented in
         ops/collective.py). The [S] matched tail carries exactly the
         per-segment facts the host fold would have summed, so the
         ExecutionStats equal folding the per-segment path's stats."""
         S = minfo["S"]
-        matched_i = [int(round(float(m)))
-                     for m in np.asarray(packed[-S:][:S_real])]
-        total_matched = sum(matched_i)
-        filter_cols = len(set(ctx.filter_columns()))
-        n_valued_aggs = sum(
-            1 for node in ctx.aggregations
-            if node.args and not (isinstance(node.args[0], Identifier)
-                                  and node.args[0].name == "*"))
-        stats = ExecutionStats(
-            num_docs_scanned=total_matched,
-            num_entries_scanned_in_filter=(
-                sum(seg.num_docs for seg in segments[:S_real])
-                * filter_cols if ctx.filter is not None else 0),
-            num_entries_scanned_post_filter=total_matched * n_valued_aggs,
-            num_segments_processed=S_real,
-            num_segments_matched=sum(1 for m in matched_i if m),
-            total_docs=sum(seg.num_docs for seg in segments[:S_real]))
-        if plan.group_cols:
-            return [self._assemble_merged_group(ctx, plan, packed,
-                                                mappings, minfo, stats)]
+        stats = self._batch_stats(
+            segments, ctx, S_real,
+            [int(round(float(m))) for m in np.asarray(packed[-S:][:S_real])])
         widths = [kernels.slot_width(op) for op, _v, _f in plan.agg_ops]
         slot_offsets = np.concatenate(
             [[0], np.cumsum(widths)]).astype(int)
@@ -2643,35 +2690,72 @@ class TpuOperatorExecutor:
             inters.append(fn.from_device_slots(slots))
         return [AggregationResult(inters, stats)]
 
-    def _assemble_merged_group(self, ctx, plan: DevicePlan, packed,
-                               mappings, minfo, stats):
-        G = minfo["G"]
-        n_slots = len(plan.agg_ops)
-        gp = np.asarray(packed[:G * n_slots]).reshape(G, n_slots)
-        count_j = None
-        for j, (op, _vidx, fidx) in enumerate(plan.agg_ops):
-            if op == "count" and fidx is None:
-                count_j = j
-                break
-        assert count_j is not None  # _plan guarantees a count slot
-        present = np.nonzero(gp[:, count_j] > 0)[0]
-        present = present[present < minfo["n_real"]]
+    @staticmethod
+    def _batch_stats(segments, ctx: QueryContext, S_real: int,
+                     matched_i: List[int]) -> ExecutionStats:
+        """The ExecutionStats of a whole segment batch from its
+        per-segment matched counts: what folding the per-segment
+        results' stats would give."""
+        total_matched = sum(matched_i)
+        filter_cols = len(set(ctx.filter_columns()))
+        n_valued_aggs = sum(
+            1 for node in ctx.aggregations
+            if node.args and not (isinstance(node.args[0], Identifier)
+                                  and node.args[0].name == "*"))
+        total_docs = sum(seg.num_docs for seg in segments[:S_real])
+        return ExecutionStats(
+            num_docs_scanned=total_matched,
+            num_entries_scanned_in_filter=(
+                total_docs * filter_cols if ctx.filter is not None else 0),
+            num_entries_scanned_post_filter=total_matched * n_valued_aggs,
+            num_segments_processed=S_real,
+            num_segments_matched=sum(1 for m in matched_i if m),
+            total_docs=total_docs)
+
+    def _assemble_folded(self, segments, ctx: QueryContext,
+                         plan: DevicePlan, packed: np.ndarray, S_real: int,
+                         mappings: List[Dict[str, int]], minfo,
+                         span=None) -> List[Any]:
+        """ONE GroupByResult for the whole segment batch, from the
+        integer row `kernels.fold_groups` packed: the [n_slots, G] group
+        table over the global key space, then each segment's matched
+        count. Present groups are found, their keys decoded through the
+        remap's unions and every slot column converted once for the
+        batch, vectorised; what is left a group is one intermediate a
+        function."""
+        t0 = time.perf_counter()
+        G, n_slots = minfo["G"], len(plan.agg_ops)
+        row = np.asarray(packed)
+        table = row[:G * n_slots].reshape(n_slots, G)
+        stats = self._batch_stats(
+            segments, ctx, S_real,
+            row[G * n_slots:][:S_real].tolist())
+        present = np.flatnonzero(table[plan.agg_ops.index(
+            ("count", None, None))] > 0)  # _plan guarantees the slot
         decode = minfo["decode"]
         if plan.group_compact:
-            keys = [decode[g] for g in present]
+            keys = [decode[g] for g in present.tolist()]
         else:
             strides, cards, unions = decode
-            keys = [tuple(_py(unions[ci][(g // strides[ci]) % cards[ci]])
-                          for ci in range(len(plan.group_cols)))
-                    for g in present]
-        groups: Dict[tuple, list] = {}
-        for gi, g in enumerate(present):
-            inters = []
-            for fn, mapping in zip(ctx.agg_functions, mappings):
-                slots = {op: gp[g, j] for op, j in mapping.items()}
-                inters.append(fn.from_device_slots(slots))
-            groups[keys[gi]] = inters
-        return GroupByResult(groups, stats)
+            keys = list(zip(*(
+                unions[ci][(present // strides[ci]) % cards[ci]].tolist()
+                for ci in range(len(plan.group_cols))))) \
+                if len(present) else []
+        vdt = np.float64 if row.dtype.itemsize == 8 else np.float32
+        slot_vals = [
+            table[j, present].tolist() if op == "count"
+            else table[j, present].view(vdt).tolist()
+            for j, (op, _v, _f) in enumerate(plan.agg_ops)]
+        per_fn = []
+        for fn, mapping in zip(ctx.agg_functions, mappings):
+            ops = list(mapping)
+            cols = [slot_vals[mapping[op]] for op in ops]
+            per_fn.append([fn.from_device_slots(dict(zip(ops, vals)))
+                           for vals in zip(*cols)])
+        groups = {key: list(inters)
+                  for key, inters in zip(keys, zip(*per_fn))}
+        self._note_groups(span, table.nbytes, len(groups), t0)
+        return [GroupByResult(groups, stats)]
 
 
 def _isum_value(planes: np.ndarray) -> float:

@@ -116,6 +116,8 @@ def plan_fingerprint(plan: DevicePlan) -> str:
     meter. repr() of the frozen dataclass is deterministic and total."""
     text = repr(plan) + ("+nonfinite" if getattr(plan, "nonfinite", False)
                          else "")
+    if getattr(plan, "group_fold", ()):
+        text += f"+fold{plan.group_fold}"
     return hashlib.sha1(text.encode()).hexdigest()[:12]
 
 
@@ -373,6 +375,140 @@ def group_path(num_groups: int, docs: int, dtype, finite: bool) -> str:
             and finite and jnp.dtype(dtype) == jnp.float32:
         return "onehot2"
     return "scatter"
+
+
+def group_fold(plan: DevicePlan, out_groups: int = 0, remap_bytes: int = 0,
+               max_groups: int = 0, max_remap_bytes: int = 0) -> str:
+    """Where a GROUP BY's per-segment partials become one result —
+    'device' (`fold_groups`, inside the kernel: [G, slots] leaves the
+    device) | 'host' ([S, G, slots] leaves it and the engine builds a
+    result a segment) — from the plan and static sizes alone. The ONE
+    place that decides, as `group_path` is for the path: the engine asks
+    with the global key space its remap came to and the bytes of its
+    tables, against the caps it already holds every device group table
+    and every remap to (`MAX_DEVICE_GROUPS`, `GMAP_MAX_BYTES`), and
+    writes the word on the DeviceDispatch span (`groupFold`). A fused
+    time bucket is a digit of the key the remap does not carry; a key
+    space or a compacted plan's [S, G] table past the caps would cost
+    more than the partials it saves."""
+    if plan.tbucket or out_groups > max_groups \
+            or remap_bytes > max_remap_bytes:
+        return "host"
+    return "device"
+
+
+def _two_sum(a, b):
+    """a + b as (the rounded sum, what the rounding lost): Knuth's
+    TwoSum, exact for finite floats whatever their order."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def _sum_segments(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., S, G] float partials -> [..., G], summed over the segment
+    axis as a pairwise tree that carries each addition's rounding error
+    and adds the errors back once at the root: the result is the exact
+    sum rounded once (to a second-order term), where a plain f32 sum of
+    sixteen partials loses up to sixteen roundings (the host fold it
+    replaces added them in f64). A tree, not a loop over segments, so
+    that a sharded segment axis halves in place until it is shorter than
+    the mesh. A non-finite partial gives the plain sum (inf - inf in the
+    error term would read NaN)."""
+    S = x.shape[-2]
+    pad = (1 << max(S - 1, 0).bit_length()) - S
+    if pad:
+        x = jnp.concatenate(
+            [x, jnp.zeros(x.shape[:-2] + (pad, x.shape[-1]), x.dtype)],
+            axis=-2)
+    err = jnp.zeros_like(x)
+    while x.shape[-2] > 1:
+        half = x.shape[-2] // 2
+        pair = x.reshape(x.shape[:-2] + (half, 2, x.shape[-1]))
+        epair = err.reshape(pair.shape)
+        x, lost = _two_sum(pair[..., 0, :], pair[..., 1, :])
+        err = epair[..., 0, :] + epair[..., 1, :] + lost
+    total, err = x[..., 0, :], err[..., 0, :]
+    return jnp.where(jnp.isfinite(total) & jnp.isfinite(err),
+                     total + err, total)
+
+
+def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
+    """[S, G] per-segment partials a slot -> ONE packed integer row
+    [n_slots * G_out + S]: the [n_slots, G_out] group table over the
+    GLOBAL key space `plan.group_fold`, a slot after the other (slots
+    interleaved a group, `stack(axis=-1).reshape(-1)`, compiled for 66 s
+    at 131,072 groups on the v5e against under 1 s for this), then each
+    segment's matched count (the ExecutionStats the host fold would have
+    summed).
+
+    Dictionary ids and compacted codes are segment-local, so a global
+    group reads each segment's partial through the engine's remap
+    (`_group_remap`): a dense plan's `ginv<i>`
+    [S, U_i] tables give column i's local id of a union value (-1: the
+    segment's dictionary lacks it), recombined with the plan's own
+    strides; a compacted plan's `ginv` [S, G_out] gives the local code.
+    A gather a segment, then a reduction over the segment axis: on a
+    segments mesh GSPMD makes that one the exchange between chips.
+
+    Exactness: a count is an integer under 2^24 a segment (f32-exact)
+    and is summed as an integer, so a table past 2^24 rows a group
+    stays exact; sums add in the value dtype with the roundings carried
+    (`_sum_segments`); min / max fold with min / max. The row's dtype is
+    the integer as wide as the value dtype: counts ride it as integers,
+    every other slot bit-cast."""
+    bits = jnp.int64 if _value_dtype() == jnp.float64 else jnp.int32
+    if plan.group_compact:
+        idx = params["ginv"]
+        there = idx >= 0
+        idx = jnp.where(there, idx, 0)
+
+        def globally(s):
+            return jnp.take_along_axis(s, idx, axis=-1)
+    else:
+        # a dense key is mixed radix over the plan's own strides, so a
+        # segment's [G] partials are a [C_0, .., C_k] block: each digit
+        # is gathered along its own axis through its column's table,
+        # whole rows at a time (an element-wise gather over an iota of
+        # the key space compiled for a minute at 131,072 groups: XLA
+        # folded the iota's divisions as constants)
+        k = len(plan.group_fold)
+        local = [(plan.num_groups if ci == 0 else plan.group_strides[ci - 1])
+                 // plan.group_strides[ci] for ci in range(k)]
+        tables = [params[f"ginv{ci}"] for ci in range(k)]
+        S = tables[0].shape[0]
+        there = True
+        for ci, ids in enumerate(tables):
+            there = there & (ids >= 0).reshape(
+                (S,) + (1,) * ci + (-1,) + (1,) * (k - ci - 1))
+        there = jnp.broadcast_to(
+            there, (S,) + tuple(plan.group_fold)).reshape(S, -1)
+
+        def globally(s):
+            x = s.reshape((S,) + tuple(local))
+            for ci, ids in enumerate(tables):
+                x = jax.vmap(lambda xs, i, _a=ci: jnp.take(
+                    xs, i, axis=_a, mode="clip"))(
+                        x, jnp.maximum(ids, 0))
+            return x.reshape(S, -1)
+    folded = []
+    matched = None
+    for (op, _vidx, fidx), (_o, s) in zip(plan.agg_ops, slots):
+        g = globally(s)
+        if op == "count":
+            f = jnp.sum(jnp.where(there, g, 0).astype(bits), axis=-2)
+            if fidx is None and matched is None:
+                # every matched doc lands in exactly one group
+                matched = jnp.sum(s.astype(bits), axis=-1)
+        elif op == "min":
+            f = jnp.min(jnp.where(there, g, jnp.inf), axis=-2)
+        elif op == "max":
+            f = jnp.max(jnp.where(there, g, -jnp.inf), axis=-2)
+        else:
+            f = _sum_segments(jnp.where(there, g, 0))
+        folded.append(f if f.dtype == bits
+                      else jax.lax.bitcast_convert_type(f, bits))
+    return jnp.concatenate(folded + [matched], axis=-1)
 
 
 def _contribution(op: str, vals: Optional[jnp.ndarray],
@@ -857,7 +993,9 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
     sync the query would wait out in turn:
       no group-by: [S, 1 + n_slots]  (col 0 = matched doc count)
       group-by:    [S, G, n_slots]   (matched derived from the count
-                                      slot host-side)
+                                      slot host-side), or with
+                                      `plan.group_fold` ONE integer row
+                                      [n_slots * G_out + S] (`fold_groups`)
     Counts ride in the value dtype; exact while D < 2^24 (engine caps
     doc padding below that).
 
@@ -877,6 +1015,9 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
             # mirroring the host executor's `mask &= valid.to_mask()`
             valid = valid & cols["vmask"]
         slots, matched = _compute_slots(plan, cols, params, valid, G)
+        if plan.group_fold:
+            with jax.named_scope("fold"):
+                return fold_groups(plan, slots, params)
         with jax.named_scope("pack"):
             if plan.num_groups or G:
                 return jnp.stack([s for _, s in slots], axis=-1)

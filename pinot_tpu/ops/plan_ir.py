@@ -186,3 +186,11 @@ class DevicePlan:
     #: the repr, and in the fingerprint only when set: plans without it
     #: compile under the names they always had.
     nonfinite: bool = field(default=False, repr=False)
+    #: device fold of a GROUP BY (kernels.fold_groups): the GLOBAL key
+    #: space the kernel's ONE [G, slots] result is laid over, a dense
+    #: plan's union cardinality a group column, a compacted plan's one
+    #: union count. () = the per-segment partials leave the device as
+    #: [S, G, slots]. It follows from the staged segments' dictionaries,
+    #: so the engine sets it after staging (`_group_fold`); like
+    #: `nonfinite` out of the repr, and in the fingerprint only when set.
+    group_fold: Tuple[int, ...] = field(default=(), repr=False)

@@ -85,7 +85,14 @@ class QueryExecutor:
             plan_fp = ctx.fingerprint()
             with tracing.Scope("SegmentResultCache") as sc:
                 to_run = []
-                for s in selected:
+                # a device GROUP BY is cached as the ONE folded partial
+                # of its whole batch, every other shape a segment
+                folded = cache.get_batch(selected, plan_fp) \
+                    if self._use_tpu and ctx.group_by else None
+                if folded is not None:
+                    results.append(folded)
+                    cache_hits = len(selected)
+                for s in () if folded is not None else selected:
                     hit = cache.get(s, plan_fp)
                     if hit is not None:
                         results.append(hit)
@@ -186,6 +193,13 @@ class QueryExecutor:
                     and len(device_results_now) == len(device_candidates):
                 for s, r in zip(device_candidates, device_results_now):
                     cache.put(s, plan_fp, r)
+            elif plan_fp is not None and not remaining \
+                    and len(device_results_now) == 1 \
+                    and len(device_candidates) == len(selected):
+                # one result for the batch (the engine folded a GROUP
+                # BY's partials on the device), and the batch is all the
+                # query selected: the key `get_batch` will look under
+                cache.put_batch(selected, plan_fp, device_results_now[0])
         results.extend(host_results)
         # device fallbacks (shapes/columns the engine rejected) run last
         results.extend(run_host(list(remaining)))

@@ -190,8 +190,19 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
                     cur[i] = fn.merge(cur[i], inters[i])
 
     rows = []
+    # where every output and sort expression IS a group key, an
+    # aggregate or an alias of one (the plain report: 48,000 rows of it
+    # cost the broker more in hashing expressions than the device took),
+    # a row is picked by position; anything computed takes the bindings
+    direct = _direct_columns(ctx)
     for key, inters in merged.items():
         finals = [fn.extract_final(m) for fn, m in zip(ctx.agg_functions, inters)]
+        if direct is not None:
+            cols = (key, finals)
+            out_row = tuple(cols[w][i] for w, i in direct[0])
+            cols = (key, finals, out_row)
+            rows.append((tuple(cols[w][i] for w, i in direct[1]), out_row))
+            continue
         bindings: Dict[Expression, Any] = dict(zip(ctx.group_by, key))
         bindings.update(zip(ctx.agg_keys, finals))
         if ctx.having is not None and not eval_scalar(ctx.having, bindings):
@@ -231,6 +242,32 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
     return ResultTable(names, types, out)
 
 
+def _direct_columns(ctx: QueryContext):
+    """([(where, index) a select expression], [the same an ORDER BY
+    expression]) with where 0 = the group key, 1 = the aggregates'
+    finals, 2 = the output row (an alias), or None where HAVING or any
+    expression has to be evaluated."""
+    if ctx.having is not None:
+        return None
+    group_by, agg_keys = list(ctx.group_by), list(ctx.agg_keys)
+
+    def where(e):
+        if e in group_by:
+            return 0, group_by.index(e)
+        if e in agg_keys:
+            return 1, agg_keys.index(e)
+        return None
+
+    aliases = {Identifier(a): (2, i) for i, a in enumerate(ctx.aliases)
+               if a is not None}
+    select = [where(e) for e in ctx.select]
+    # an alias shadows a column of its name for ORDER BY
+    order = [aliases.get(e) or where(e) for e, _ in ctx.order_by]
+    if any(c is None for c in select + order):
+        return None
+    return select, order
+
+
 def _sort_limit_filled(ctx: QueryContext, names, filled_rows):
     """ORDER BY + OFFSET/LIMIT over gap-filled rows: sort keys re-derive
     from the output columns (select expressions + aliases)."""
@@ -250,6 +287,18 @@ def _sort_limit_filled(ctx: QueryContext, names, filled_rows):
 def _sorted_by_keys(rows, ascs: List[bool]):
     """Sort (sort_key, row) pairs honoring per-key direction."""
     import functools
+
+    # keys of one type a position (the usual answer) sort natively, a
+    # stable pass a key from the last to the first; a None or a mix of
+    # types raises and takes the comparator below, which orders those
+    # by their strings
+    try:
+        out = list(rows)
+        for i in reversed(range(len(ascs))):
+            out.sort(key=lambda r, _i=i: r[0][_i], reverse=not ascs[i])
+        return out
+    except TypeError:
+        pass
 
     def cmp(a, b):
         for i, asc in enumerate(ascs):

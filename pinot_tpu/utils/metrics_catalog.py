@@ -79,6 +79,12 @@ METRICS: Dict[str, str] = {
     "group_path":
         "device GROUP BYs by the way their additive slots run (label "
         "path=onehot|onehot2|scatter: kernels.group_path)",
+    "group_fold":
+        "device GROUP BYs by where their per-segment partials became one "
+        "result (label where=device|host: kernels.group_fold)",
+    "group_result_bytes":
+        "bytes of group table fetched from the device ([G, slots] a "
+        "folded query, [S, G, slots] where the host folds)",
     "scan_served":
         "queries staged for the device scan leg (agg, group-by, top-N, "
         "DISTINCT)",

@@ -160,6 +160,47 @@ def spans(tree, name: str) -> list:
     return out
 
 
+def test_a_grouped_query_is_folded_across_the_four_devices(ssb, served):
+    """ISSUE 35: the per-segment partials of a GROUP BY are folded inside
+    the kernel over a global key space; on the segments mesh the fold's
+    sum over the sharded segment axis is GSPMD's all-reduce. ONE result
+    comes back for the eight segments, equal to numpy's."""
+    from pinot_tpu.query.context import QueryContext
+    from pinot_tpu.query.reduce import reduce_results
+    from pinot_tpu.query.results import GroupByResult
+    from pinot_tpu.server.datatable import deserialize_results_ex
+    ex, engine = served
+    sql = ("SELECT SUM(lo_extendedprice), COUNT(*), MAX(lo_extendedprice), "
+           "lo_discount, lo_quantity FROM ssb WHERE lo_orderdate BETWEEN "
+           "19930101 AND 19940128 GROUP BY lo_discount, lo_quantity "
+           "ORDER BY lo_discount, lo_quantity LIMIT 1000 "
+           "OPTION(skipCache=true)")
+    results, exceptions, _stats, trace = deserialize_results_ex(
+        ex.execute("ssb_OFFLINE", sql, trace_ctx={
+            "traceId": "four-chip-grouped", "spanId": "1", "sampled": True}))
+    assert not exceptions
+    assert len(results) == 1 and isinstance(results[0], GroupByResult)
+    assert results[0].stats.num_segments_processed == len(DOCS)
+    span, = spans(trace, "DeviceDispatch")
+    assert "outcome" not in span and span["meshDevices"] == 4
+    assert span["groupFold"] == "device" and span["groupKeySpace"] == 11 * 50
+    want = {}
+    for c in ssb[1]:
+        keep = (c["lo_orderdate"] >= 19930101) & (c["lo_orderdate"] <= 19940128)
+        for d, q, p in zip(c["lo_discount"][keep], c["lo_quantity"][keep],
+                           c["lo_extendedprice"][keep]):
+            t = want.setdefault((int(d), int(q)), [0, 0, 0])
+            t[0] += int(p)
+            t[1] += 1
+            t[2] = max(t[2], int(p))
+    rows = reduce_results(QueryContext.from_sql(sql), results
+                          ).result_table.rows
+    got = [((int(r[3]), int(r[4])), [int(r[0]), int(r[1]), int(r[2])])
+           for r in rows]
+    assert got == sorted(want.items()) and len(got) > 500
+    assert results[0].stats.num_docs_scanned == sum(t[1] for t in want.values())
+
+
 # -- (b) budgets per chip ----------------------------------------------------
 KNOBS = {"pinot.server.hbm.cache.bytes": 1_000_000,
          "pinot.server.hbm.resident.bytes": 600_000,

@@ -329,6 +329,13 @@ def test_int_sum_is_served_by_the_pass(wide_segs):
     assert not plan.nonfinite
     assert span["groupPath"] == "onehot2" and span["G"] == 0
     assert meters == {"onehot": 0, "onehot2": 1, "scatter": 0}
+    # the pass's per-segment partials are folded before they leave the
+    # device: one [G, slots] table of f32-wide words, not one a segment
+    assert span["groupFold"] == "device" and plan.num_groups == CARD
+    assert span["groupKeySpace"] == CARD
+    # over the union's key space in pow2 digits (1,500 -> 2,048)
+    assert span["groupResultBytes"] == 2048 * len(plan.agg_ops) * 4
+    assert span["groupsPresent"] == CARD
     assert len(got) == len(want) == CARD
     for g, w in zip(got, want):
         assert g[:2] == w[:2]

@@ -318,8 +318,10 @@ class TestMeshCollectiveFailpoint:
     def test_armed_error_falls_back_to_host_fold(self, segs, host):
         engine = _mesh_engine(8, 2)
         device = QueryExecutor(segs, use_tpu=True, engine=engine)
-        sql = ("SELECT groupCol, COUNT(*), SUM(intCol) FROM testTable "
-               "GROUP BY groupCol ORDER BY groupCol LIMIT 50")
+        # ungrouped: the collective merge's shape (a GROUP BY is folded
+        # inside the plain kernel on every engine, kernels.fold_groups)
+        sql = ("SELECT COUNT(*), SUM(intCol), MAX(rawIntCol) FROM testTable "
+               "WHERE intCol < 900")
         with failpoints.armed("server.mesh.collective",
                               error=FailpointError("mesh chaos")):
             _assert_parity(device.execute(sql), host.execute(sql))
@@ -357,18 +359,15 @@ class TestShardMapOneHotScan:
     the parity segments above (700 docs) never reach it, so only a bench
     smoke leg used to."""
 
-    @pytest.mark.parametrize("merge", [True, False])
-    def test_small_group_by_over_scan_chunk(self, tmp_path, merge):
+    def test_small_group_by_over_scan_chunk(self, tmp_path):
         if len(jax.devices()) < 4:
             pytest.skip("needs 4 virtual devices")
         docs = 2 * kernels._ONEHOT_CHUNK - 91  # pads to 4096 per doc shard
         segs = build_segments(
             tmp_path, synthetic_schema(), synthetic_table_config(),
             [synthetic_columns(docs, seed=977 + i) for i in range(2)])
-        labels = {"leg": f"onehot-scan-{merge}"}
-        engine = _mesh_engine(
-            4, 2, labels=labels,
-            **{"pinot.server.mesh.collective.merge": merge})
+        labels = {"leg": "onehot-scan"}
+        engine = _mesh_engine(4, 2, labels=labels)
         sql = ("SELECT groupCol, COUNT(*), SUM(intCol), MAX(rawIntCol) "
                "FROM testTable WHERE intCol < 900 GROUP BY groupCol "
                "ORDER BY groupCol LIMIT 50")
@@ -380,8 +379,12 @@ class TestShardMapOneHotScan:
         traced = [e for e in kernels.trace_log()
                   if e["kind"] in ("sharded", "merged")][before:]
         assert traced, "group-by never reached a shard_map kernel"
+        # a doc-sharded GROUP BY leaves the device a result a segment:
+        # neither the collective merge nor the plain kernels' fold
         reg = engine._dispatcher._metrics
-        assert (reg.meter("mesh_merge_served", labels=labels) > 0) == merge
+        assert reg.meter("mesh_merge_served", labels=labels) == 0
+        assert reg.meter("group_fold",
+                         labels=dict(labels, where="host")) > 0
 
 
 # tier-1 smoke of the acceptance driver

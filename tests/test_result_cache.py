@@ -159,6 +159,44 @@ class TestSegmentCacheTier2:
             assert cache.stats.hits == hits0 + 2
             assert second.result_table.rows == first.result_table.rows
 
+    def test_a_device_group_by_is_cached_as_its_batch_s_one_partial(
+            self, tmp_path):
+        """The engine folds a GROUP BY's per-segment partials before they
+        leave the device, so what comes back is ONE result for the batch:
+        it is cached under the batch's key, and the same query asked
+        again (no skipCache) never reaches the device."""
+        segs = [_build(tmp_path, f"f{i}", [j % 5 for j in range(90)],
+                       [i + j for j in range(90)]) for i in range(3)]
+        cache = SegmentResultCache()
+        sql = ("SELECT d, SUM(m), COUNT(*) FROM t GROUP BY d ORDER BY d "
+               "LIMIT 10")
+        ex = QueryExecutor(segs, use_tpu=True, segment_cache=cache)
+        engine = ex.tpu_engine
+        served = lambda: engine._metrics.meter(  # noqa: E731
+            "scan_served", labels=dict(engine._labels or {}))
+        before = served()
+        cold = ex.execute(sql)
+        assert served() == before + 1
+        assert cache.stats.puts == 1 and len(cache) == 1
+        warm = ex.execute(sql)
+        assert served() == before + 1, "the repeat ran on the device"
+        assert cache.stats.hits == 1
+        assert warm.result_table.rows == cold.result_table.rows
+        host = QueryExecutor(segs, use_tpu=False).execute(sql)
+        assert warm.result_table.rows == host.result_table.rows
+        # skipCache neither reads nor fills it
+        ex.execute(sql + " OPTION(skipCache=true)")
+        assert served() == before + 2 and cache.stats.puts == 1
+        # other segments, another key: a subset is not served from it
+        sub = QueryExecutor(segs[:2], use_tpu=True, segment_cache=cache)
+        assert sub.execute(sql).result_table.rows \
+            != cold.result_table.rows
+        # a member replaced or removed takes the batch's entry with it
+        assert len(cache) == 2  # (f0, f1, f2) and (f0, f1)
+        assert cache.invalidate_segment("f1") == 2 and len(cache) == 0
+        assert cache.get_batch(segs, QueryContext.from_sql(
+            sql).fingerprint()) is None
+
     def test_mutable_segment_never_cached(self):
         mut = MutableSegment("t__0__0__1",
                              TableConfig("t", TableType.REALTIME), _schema())

@@ -530,6 +530,16 @@ def test_served_group_by_names_its_path_and_a_scan_does_not(cluster):
         11, dims["D"], kernels._value_dtype(), finite=True) == "scatter"
     assert scatters() == before + 1
     assert "# HELP pinot_tpu_server_group_path " in page()
+    # ISSUE 35: where the per-segment partials became one result, what was
+    # fetched, and the decode: inside assembleMs, so the phases tile as ever
+    assert grouped["groupFold"] == "device" and grouped["groupKeySpace"] == 11
+    assert 0 < grouped["groupResultBytes"] <= 11 * 8 * 8
+    assert 0 < grouped["groupsPresent"] <= 11
+    request, = _spans(_cluster_trace(cluster, "groupby", 42), "ServerRequest")
+    served, = _served(request)
+    assert 0 <= served["groupDecodeMs"] <= request["assembleMs"]
+    assert 'pinot_tpu_server_group_fold{' in page() \
+        and "# HELP pinot_tpu_server_group_result_bytes " in page()
 
 
 # -- the same phases on the profiler's clock ----------------------------------
