@@ -772,8 +772,15 @@ class BrokerRequestHandler:
                 # broker thread past the deadline
                 payload = fut.result(
                     timeout=max(0.0, deadline - time.time()) + 1.0)
+                t_wire = time.perf_counter()
                 server_results, server_exc, stats_extra, server_trace = \
                     datatable.deserialize_results_ex(payload)
+                if sp is not None:
+                    # the part of the scatter the broker can name: bytes
+                    # -> result columns (a grouped result's dict is built
+                    # where the reduce first reads `.groups`)
+                    sp.set(deserializeMs=round(
+                        (time.perf_counter() - t_wire) * 1e3, 3))
             except Exception as e:  # noqa: BLE001 — partial results
                 if sp is not None:
                     sp.end(error=f"{type(e).__name__}: {e}",

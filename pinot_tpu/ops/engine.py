@@ -50,7 +50,7 @@ from pinot_tpu.query.expressions import (
     Expression, Function, Identifier, Literal)
 from pinot_tpu.query.filter import resolve_predicate
 from pinot_tpu.query.results import (
-    AggregationResult, ExecutionStats, GroupByResult)
+    AggregationResult, CodedColumn, ExecutionStats, GroupByResult)
 from pinot_tpu.segment.loader import DataSource, ImmutableSegment
 from pinot_tpu.utils import accounting, tracing
 from pinot_tpu.utils.config import PinotConfiguration
@@ -599,8 +599,10 @@ class TpuOperatorExecutor:
             for s, tuples in enumerate(per_seg):
                 for code, t in enumerate(tuples):
                     ginv[s, index[t]] = code
-            # decode: global index -> key value tuple
-            return {"ginv": self._put(ginv, info)}, G_out, union
+            # decode: a column a group expression, global index -> value
+            return ({"ginv": self._put(ginv, info)}, G_out,
+                    [list(c) for c in zip(*union)]
+                    or [[] for _ in plan.group_cols])
         unions = []
         per_col_vals = []
         for colname in plan.group_cols:
@@ -2719,10 +2721,11 @@ class TpuOperatorExecutor:
         """ONE GroupByResult for the whole segment batch, from the
         integer row `kernels.fold_groups` packed: the [n_slots, G] group
         table over the global key space, then each segment's matched
-        count. Present groups are found, their keys decoded through the
-        remap's unions and every slot column converted once for the
-        batch, vectorised; what is left a group is one intermediate a
-        function."""
+        count. No Python a group: the present groups' slot words go to
+        each function as columns (`from_device_slot_columns`), and a key
+        column is the remap's union with the groups' indices into it
+        (`CodedColumn`); the result's `.groups` dict is built only where
+        somebody reads it."""
         t0 = time.perf_counter()
         G, n_slots = minfo["G"], len(plan.agg_ops)
         row = np.asarray(packed)
@@ -2734,28 +2737,25 @@ class TpuOperatorExecutor:
             ("count", None, None))] > 0)  # _plan guarantees the slot
         decode = minfo["decode"]
         if plan.group_compact:
-            keys = [decode[g] for g in present.tolist()]
+            key_columns = [CodedColumn(values, present) for values in decode]
         else:
             strides, cards, unions = decode
-            keys = list(zip(*(
-                unions[ci][(present // strides[ci]) % cards[ci]].tolist()
-                for ci in range(len(plan.group_cols))))) \
-                if len(present) else []
+            key_columns = [
+                CodedColumn(unions[ci],
+                            (present // strides[ci]) % cards[ci])
+                for ci in range(len(plan.group_cols))]
         vdt = np.float64 if row.dtype.itemsize == 8 else np.float32
-        slot_vals = [
-            table[j, present].tolist() if op == "count"
-            else table[j, present].view(vdt).tolist()
+        slot_cols = [
+            table[j, present] if op == "count"
+            else table[j, present].view(vdt)
             for j, (op, _v, _f) in enumerate(plan.agg_ops)]
-        per_fn = []
-        for fn, mapping in zip(ctx.agg_functions, mappings):
-            ops = list(mapping)
-            cols = [slot_vals[mapping[op]] for op in ops]
-            per_fn.append([fn.from_device_slots(dict(zip(ops, vals)))
-                           for vals in zip(*cols)])
-        groups = {key: list(inters)
-                  for key, inters in zip(keys, zip(*per_fn))}
-        self._note_groups(span, table.nbytes, len(groups), t0)
-        return [GroupByResult(groups, stats)]
+        value_columns = [
+            fn.from_device_slot_columns(
+                {op: slot_cols[j] for op, j in mapping.items()})
+            for fn, mapping in zip(ctx.agg_functions, mappings)]
+        self._note_groups(span, table.nbytes, len(present), t0)
+        return [GroupByResult(stats=stats, key_columns=key_columns,
+                              value_columns=value_columns)]
 
 
 def _isum_value(planes: np.ndarray) -> float:

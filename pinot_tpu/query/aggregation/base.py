@@ -78,6 +78,19 @@ class AggregationFunction:
         DeviceAggSpec.ops."""
         raise NotImplementedError
 
+    def from_device_slot_columns(self, slots: Dict[str, np.ndarray]) -> Any:
+        """`from_device_slots` for a whole result at once: slots maps
+        op-name -> [rows] array (a row a group). Returns the function's
+        value columns as `GroupByResult.value_columns` holds them: one
+        column whose rows are the intermediates, or a tuple of columns,
+        one a component of a tuple intermediate. This default loops
+        (an object column: any function keeps working); the plain
+        reductions override it with array arithmetic that gives, row for
+        row, the value and Python type `from_device_slots` gives."""
+        ops = list(slots)
+        return [self.from_device_slots(dict(zip(ops, vals)))
+                for vals in zip(*(slots[op] for op in ops))]
+
     # -- metadata -----------------------------------------------------------
     @property
     def result_name(self) -> str:
@@ -87,6 +100,22 @@ class AggregationFunction:
     @property
     def final_dtype(self) -> str:
         return "DOUBLE"
+
+
+def count_column(words: np.ndarray) -> np.ndarray:
+    """A device count slot's column as int64: `int(round(float(x)))` a
+    row (counts arrive as integer words from the fold, in the value
+    dtype elsewhere; both round half to even)."""
+    words = np.asarray(words)
+    if words.dtype.kind in "iu":
+        return words.astype(np.int64)
+    return np.rint(words).astype(np.int64)
+
+
+def float_column(words: np.ndarray) -> np.ndarray:
+    """A device sum / min / max slot's column as float64: `float(x)` a
+    row, the f32 or f64 word widened, never narrowed."""
+    return np.asarray(words).astype(np.float64)
 
 
 def scalar(v):

@@ -12,7 +12,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from pinot_tpu.query.aggregation.base import (
-    AggregationFunction, DeviceAggSpec, register)
+    AggregationFunction, DeviceAggSpec, count_column, float_column,
+    register)
 from pinot_tpu.query.aggregation.sketches import HyperLogLog, TDigest
 
 
@@ -49,6 +50,9 @@ class CountAggregation(AggregationFunction):
         # device counts arrive in the value dtype (single packed output)
         return int(round(float(slots["count"])))
 
+    def from_device_slot_columns(self, slots):
+        return count_column(slots["count"])
+
     @property
     def result_name(self):
         return "count(*)" if not self.args or str(self.args[0]) == "*" \
@@ -80,6 +84,9 @@ class SumAggregation(AggregationFunction):
     def from_device_slots(self, slots):
         return float(slots["sum"])
 
+    def from_device_slot_columns(self, slots):
+        return float_column(slots["sum"])
+
 
 @register
 class MinAggregation(AggregationFunction):
@@ -105,6 +112,9 @@ class MinAggregation(AggregationFunction):
     def from_device_slots(self, slots):
         return float(slots["min"])
 
+    def from_device_slot_columns(self, slots):
+        return float_column(slots["min"])
+
 
 @register
 class MaxAggregation(AggregationFunction):
@@ -129,6 +139,9 @@ class MaxAggregation(AggregationFunction):
 
     def from_device_slots(self, slots):
         return float(slots["max"])
+
+    def from_device_slot_columns(self, slots):
+        return float_column(slots["max"])
 
 
 @register
@@ -159,6 +172,9 @@ class AvgAggregation(AggregationFunction):
     def from_device_slots(self, slots):
         return (float(slots["sum"]), int(round(float(slots["count"]))))
 
+    def from_device_slot_columns(self, slots):
+        return (float_column(slots["sum"]), count_column(slots["count"]))
+
 
 @register
 class MinMaxRangeAggregation(AggregationFunction):
@@ -183,6 +199,9 @@ class MinMaxRangeAggregation(AggregationFunction):
 
     def from_device_slots(self, slots):
         return (float(slots["min"]), float(slots["max"]))
+
+    def from_device_slot_columns(self, slots):
+        return (float_column(slots["min"]), float_column(slots["max"]))
 
 
 @register

@@ -12,11 +12,46 @@ Layout: 4-byte magic 'PDT1', then a tagged value tree:
   D Decimal(str)  | t tuple | l list | S set | M dict
   A numpy array (dtype str, ndim, shape, raw bytes)
   H HyperLogLog (log2m + registers) | G TDigest (compression, means, weights)
-  R result container (shape tag + fields)
+  E ThetaSketch | K KLLSketch
+then per result a shape tag and its fields: 1 aggregation | 3 selection |
+4 distinct as tagged values, and
+
+  2 group-by, as COLUMNS (one wire form; `GroupByResult.columns()`
+    transposes a dict-built result when it is written):
+      stats (tagged tuple) | limit flag (T/F) | u32 rows
+      u32 key columns, then a column each
+      u32 functions, then for each: u32 arity, max(arity, 1) columns
+        (arity 0: the column's rows ARE the intermediates; k >= 1: the
+        intermediate is the tuple of the k columns' rows: AVG's
+        (sum, count), MINMAXRANGE's (min, max))
+    a column is written by what it holds, never by a knob or a name:
+      A  a numeric array (kind b/i/u/f), as the value tag above: raw bytes
+      C  a dictionary with ids: a values column (A, U or l) and an A of
+         int32 ids (a key column's distinct values once: 4,000 host
+         names, not 48,000)
+      U  strings: u32 count, count u32 byte lengths, then their utf-8
+         bytes end to end (only inside C; a plain string column is
+         written as C over its distinct strings)
+      l  anything else (sketches, Decimal, None, a list of Python
+         bools, mixed types, nested tuples): the tagged list above
+    A list of exactly-`int` rows travels as an int64 A, of exactly-
+    `float` rows as a float64 A: no value is narrowed, and the reader's
+    `tolist` gives the same Python types back. Row order is dict order.
+
+Nothing persists DataTable bytes across versions of the program (server
+and broker of one deployment speak it, and both result caches live in
+their process), so tag 2's layout changed in place when it became
+columnar; there is no row form left to fall back to.
+
+The reader checks every length against the buffer before it slices and
+builds arrays with `np.frombuffer` over a whitelisted dtype kind: a
+truncated or length-lying payload raises, nothing is ever unpickled.
 """
 from __future__ import annotations
 
+import math
 import struct
+from collections import Counter
 from decimal import Decimal
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -26,10 +61,24 @@ from pinot_tpu.utils import errorcodes
 from pinot_tpu.query.aggregation.sketches import (
     HyperLogLog, KLLSketch, TDigest, ThetaSketch)
 from pinot_tpu.query.results import (
-    AggregationResult, DistinctResult, ExecutionStats, GroupByResult,
-    SelectionResult)
+    AggregationResult, CodedColumn, DistinctResult, ExecutionStats,
+    GroupByResult, SelectionResult)
 
 MAGIC = b"PDT1"
+
+
+def _listed(col: Any) -> Any:
+    """A string or object array as the list of its values."""
+    if isinstance(col, np.ndarray) and col.dtype.kind in "UO":
+        return col.tolist()
+    return col
+
+
+def _all_of(col: Any, kind: type) -> bool:
+    """A non-empty list whose values are all exactly `kind` (a bool is
+    no int, a numpy scalar no float)."""
+    return isinstance(col, list) and bool(col) \
+        and set(map(type, col)) == {kind}
 
 _I64 = struct.Struct("<q")
 _F64 = struct.Struct("<d")
@@ -131,6 +180,48 @@ class _Writer:
         else:
             raise TypeError(f"unserializable value type {type(v)}")
 
+    def column(self, col: Any, forms: Counter):
+        """One column of a grouped result, in the form its content
+        allows (module docstring); `forms` counts the forms written."""
+        col = _listed(col)
+        if _all_of(col, str):
+            index: Dict[str, int] = {}
+            ids = np.fromiter(
+                (index.setdefault(x, len(index)) for x in col),
+                np.int32, len(col))
+            col = CodedColumn(list(index), ids)
+        if not isinstance(col, CodedColumn):
+            self._plain_column(col, forms)
+            return
+        forms["coded"] += 1
+        self.tag("C")
+        values = _listed(col.values)
+        if _all_of(values, str):
+            enc = [x.encode() for x in values]
+            self.tag("U")
+            self.u32(len(enc))
+            self.raw(np.fromiter(map(len, enc), "<u4", len(enc)).tobytes())
+            self.raw(b"".join(enc))
+        else:
+            self._plain_column(values, Counter())
+        self.value(np.asarray(col.ids).astype(np.int32, copy=False))
+
+    def _plain_column(self, col: Any, forms: Counter):
+        try:
+            if _all_of(col, int):
+                col = np.array(col, np.int64)
+            elif _all_of(col, float):
+                col = np.array(col, np.float64)
+        except OverflowError:  # an int past 64 bits: the tagged list
+            pass
+        if isinstance(col, np.ndarray) and col.ndim == 1 \
+                and col.dtype.kind in "biuf":
+            forms["array"] += 1
+            self.value(col)
+        else:
+            forms["list"] += 1
+            self.value(list(col))
+
     def bytes(self) -> bytes:
         return b"".join(self.parts)
 
@@ -146,9 +237,50 @@ class _Reader:
         return v
 
     def take(self, n: int) -> bytes:
+        if self.pos + n > len(self.buf):
+            raise ValueError(f"truncated DataTable: {n} bytes wanted at "
+                             f"{self.pos} of {len(self.buf)}")
         b = self.buf[self.pos:self.pos + n]
         self.pos += n
         return b
+
+    def column(self, rows: int) -> Any:
+        """One column of a grouped result (`_Writer.column`): an array,
+        a list or a CodedColumn of `rows` rows, or ValueError."""
+        if chr(self.buf[self.pos]) != "C":
+            return self._plain_column(rows)
+        self.pos += 1
+        values = self._strings() if chr(self.buf[self.pos]) == "U" \
+            else self._plain_column(None)
+        ids = self._plain_column(rows)
+        if not isinstance(ids, np.ndarray) or ids.dtype.kind != "i" or (
+                rows and not
+                0 <= int(ids.min()) <= int(ids.max()) < len(values)):
+            raise ValueError("bad dictionary ids in a group column")
+        return CodedColumn(values, ids)
+
+    def _strings(self) -> List[str]:
+        self.pos += 1
+        n = self.u32()
+        ends = np.cumsum(np.frombuffer(self.take(4 * n), "<u4"),
+                         dtype=np.int64).tolist()
+        blob = self.take(ends[-1] if ends else 0)
+        text = blob.decode()
+        if len(text) == len(blob):  # ASCII: a byte a character
+            return [text[a:b] for a, b in zip([0] + ends, ends)]
+        return [blob[a:b].decode() for a, b in zip([0] + ends, ends)]
+
+    def _plain_column(self, rows: Optional[int]) -> Any:
+        t = chr(self.buf[self.pos])
+        if t not in "Al":
+            raise ValueError(f"bad column tag {t!r} at {self.pos}")
+        col = self.value()
+        if t == "A" and (col.ndim != 1 or col.dtype.kind not in "biuf"):
+            raise ValueError("bad array in a group column")
+        if rows is not None and len(col) != rows:
+            raise ValueError(f"group column of {len(col)} rows, "
+                             f"{rows} stated")
+        return col
 
     def value(self) -> Any:
         t = chr(self.buf[self.pos])
@@ -187,7 +319,9 @@ class _Reader:
             dt = np.dtype(self.take(self.u32()).decode())
             ndim = self.u32()
             shape = tuple(self.u32() for _ in range(ndim))
-            n = int(np.prod(shape)) if shape else 1
+            if dt.kind not in "biufcmMSV" or dt.hasobject:
+                raise ValueError(f"bad array dtype {dt!r}")
+            n = math.prod(shape)
             arr = np.frombuffer(self.take(n * dt.itemsize), dtype=dt)
             return arr.reshape(shape).copy()
         if t == "H":
@@ -246,10 +380,13 @@ def deserialize_value(buf: bytes) -> Any:
 
 
 def serialize_results(results: List[Any], exceptions: List[dict] = (),
-                      extra_stats: Optional[ExecutionStats] = None) -> bytes:
+                      extra_stats: Optional[ExecutionStats] = None,
+                      metrics=None) -> bytes:
     """Server response: list of shape-tagged SegmentResults + exceptions +
     server-level stats (pruning counts survive even with zero results —
-    the reference carries these in DataTable metadata).
+    the reference carries these in DataTable metadata). `metrics` (a
+    MetricsRegistry) gets `group_block{form=array|coded|list}`, the
+    columns of grouped results written, by form.
 
     Layout note: a server-side span tree may be APPENDED to the returned
     bytes as one extra tagged value (ServerQueryExecutor.execute does
@@ -260,16 +397,27 @@ def serialize_results(results: List[Any], exceptions: List[dict] = (),
     w.value([_exc_tuple(e) for e in exceptions])
     w.value(_stats_tuple(extra_stats) if extra_stats is not None else None)
     w.u32(len(results))
+    forms: Counter = Counter()
     for r in results:
         if isinstance(r, AggregationResult):
             w.tag("1")
             w.value(r.intermediates)
             w.value(_stats_tuple(r.stats))
         elif isinstance(r, GroupByResult):
+            rows, key_columns, value_columns = r.columns()
             w.tag("2")
-            w.value(r.groups)
             w.value(_stats_tuple(r.stats))
-            w.value(r.num_groups_limit_reached)
+            w.value(bool(r.num_groups_limit_reached))
+            w.u32(rows)
+            w.u32(len(key_columns))
+            for col in key_columns:
+                w.column(col, forms)
+            w.u32(len(value_columns))
+            for col in value_columns:
+                parts = col if isinstance(col, tuple) else (col,)
+                w.u32(len(col) if isinstance(col, tuple) else 0)
+                for part in parts:
+                    w.column(part, forms)
         elif isinstance(r, SelectionResult):
             w.tag("3")
             w.value(r.rows)
@@ -282,6 +430,9 @@ def serialize_results(results: List[Any], exceptions: List[dict] = (),
             w.value(_stats_tuple(r.stats))
         else:
             raise TypeError(f"unserializable result {type(r)}")
+    if metrics is not None:
+        for form, n in forms.items():
+            metrics.add_meter("group_block", n, labels={"form": form})
     return w.bytes()
 
 
@@ -310,10 +461,21 @@ def deserialize_results_ex(buf: bytes) -> Tuple[
             inters = r.value()
             out.append(AggregationResult(inters, _stats_from(r.value())))
         elif tag == "2":
-            groups = r.value()
             stats = _stats_from(r.value())
-            out.append(GroupByResult(groups, stats,
-                                     num_groups_limit_reached=r.value()))
+            limit_reached = r.value()
+            rows = r.u32()
+            key_columns = [r.column(rows) for _ in range(r.u32())]
+            value_columns: List[Any] = []
+            for _ in range(r.u32()):
+                arity = r.u32()
+                value_columns.append(
+                    tuple(r.column(rows) for _ in range(arity))
+                    if arity else r.column(rows))
+            if rows and not key_columns:
+                raise ValueError("group rows without a key column")
+            out.append(GroupByResult(
+                stats=stats, num_groups_limit_reached=limit_reached,
+                key_columns=key_columns, value_columns=value_columns))
         elif tag == "3":
             rows = r.value()
             order_values = r.value()

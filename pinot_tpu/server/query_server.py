@@ -450,7 +450,7 @@ class ServerQueryExecutor:
                     # 4 bytes per entry is the storage-traffic cost
                     slip.add(rows_scanned=rows, bytes_scanned=4 * entries)
                 payload = datatable.serialize_results(
-                    results, extra_stats=prune_stats)
+                    results, extra_stats=prune_stats, metrics=metrics)
                 # the request's two ends on its ServerRequest span (no-op
                 # untraced): parse + segment acquire before the executor
                 # runs, DataTable bytes after it; the engine's phases on
@@ -458,7 +458,8 @@ class ServerQueryExecutor:
                 tracing.annotate(
                     parseMs=round((t_exec - slo_t0) * 1e3, 3),
                     serializeMs=round(
-                        (time.perf_counter() - t_done) * 1e3, 3))
+                        (time.perf_counter() - t_done) * 1e3, 3),
+                    serializeBytes=len(payload))
                 return payload
             finally:
                 TableDataManager.release_all(sdms)

@@ -85,6 +85,10 @@ METRICS: Dict[str, str] = {
     "group_result_bytes":
         "bytes of group table fetched from the device ([G, slots] a "
         "folded query, [S, G, slots] where the host folds)",
+    "group_block":
+        "columns of grouped results written to the DataTable, by the form "
+        "their content allowed (label form=array|coded|list: raw numeric "
+        "bytes, a dictionary with ids, the tagged list a value at a time)",
     "scan_served":
         "queries staged for the device scan leg (agg, group-by, top-N, "
         "DISTINCT)",

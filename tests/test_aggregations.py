@@ -249,3 +249,86 @@ class TestMVFamily:
         g = np.asarray(cols["flag"])
         want = sum(sum(t) for t, f in zip(cols["tags"], g) if f == 1)
         assert abs(r[0] - want) < 1e-6 * max(1.0, abs(want))
+
+
+class TestDeviceSlotColumns:
+    """`from_device_slot_columns` (a folded GROUP BY's whole result at
+    once) against a loop of `from_device_slots`, element for element and
+    type for type: counts past 2^24 from an integer word, sums / mins /
+    maxes from the f32 or f64 word widened."""
+
+    COUNTS = [0, 1, 360, (1 << 24) + 1, (1 << 31) - 1]
+    VALUES = [0.0, -2.5, 0.1, 16777217.0, 3.0e38]
+
+    @staticmethod
+    def rows_of(cols):
+        """What the result's `.groups` holds a row for this function."""
+        from pinot_tpu.query.results import column_values
+        if isinstance(cols, tuple):
+            return list(zip(*map(column_values, cols)))
+        return column_values(cols)
+
+    @staticmethod
+    def looped(fn, slots):
+        ops = list(slots)
+        return [fn.from_device_slots(dict(zip(ops, vals)))
+                for vals in zip(*(slots[op].tolist() for op in ops))]
+
+    @staticmethod
+    def kinds(v):
+        return tuple(map(type, v)) if isinstance(v, tuple) else type(v)
+
+    @pytest.mark.parametrize("words", [np.float32, np.float64])
+    @pytest.mark.parametrize("name,ops", [
+        ("count", ("count",)), ("sum", ("sum",)), ("min", ("min",)),
+        ("max", ("max",)), ("avg", ("sum", "count")),
+        ("minmaxrange", ("min", "max"))])
+    def test_vectorised_equals_the_loop(self, name, ops, words):
+        from pinot_tpu.query.aggregation.base import get_aggregation
+        from pinot_tpu.query.expressions import Identifier
+        fn = get_aggregation(name, (Identifier("m"),))
+        assert tuple(fn.device_spec.ops) == ops
+        ints = np.int32 if words == np.float32 else np.int64
+        values = np.array(self.VALUES, words)
+        slots = {op: np.array(self.COUNTS, ints) if op == "count"
+                 else values[::-1] if op == "max" else values for op in ops}
+        cols = fn.from_device_slot_columns(slots)
+        for col in cols if isinstance(cols, tuple) else (cols,):
+            assert isinstance(col, np.ndarray) and col.dtype in (
+                np.int64, np.float64)
+        got, want = self.rows_of(cols), self.looped(fn, slots)
+        assert got == want
+        assert [self.kinds(v) for v in got] == [self.kinds(v) for v in want]
+        if "count" in ops:
+            flat = [v[-1] if isinstance(v, tuple) else v for v in got]
+            assert flat[3] == (1 << 24) + 1 and type(flat[3]) is int
+
+    def test_a_count_in_the_value_dtype_rounds_as_the_loop_does(self):
+        from pinot_tpu.query.aggregation.base import get_aggregation
+        fn = get_aggregation("count", ())
+        words = np.array([0.0, 0.5, 1.5, 2.5, 359.9999, 16777216.0],
+                         np.float32)
+        got = fn.from_device_slot_columns({"count": words})
+        assert got.dtype == np.int64
+        assert got.tolist() == [fn.from_device_slots({"count": w})
+                                for w in words] == [0, 0, 2, 2, 360, 1 << 24]
+
+    def test_the_base_default_serves_a_sketch_and_a_tuple_function(self):
+        from pinot_tpu.query.aggregation.base import get_aggregation
+        from pinot_tpu.query.aggregation.sketches import HyperLogLog
+        from pinot_tpu.query.expressions import Identifier
+        hll = get_aggregation("distinctcounthll", (Identifier("m"),))
+        op, = hll.device_spec.ops
+        registers = np.zeros((3, 1 << hll._log2m()), np.uint8)
+        registers[1, 5] = 3
+        cols = hll.from_device_slot_columns({op: registers})
+        assert isinstance(cols, list) and len(cols) == 3
+        assert all(isinstance(h, HyperLogLog) for h in cols)
+        assert cols[1].registers[5] == 3 and not cols[0].registers.any()
+        var = get_aggregation("variance", (Identifier("m"),))
+        slots = {o: np.array([2.0, 3.0], np.float32)
+                 for o in var.device_spec.ops}
+        cols = var.from_device_slot_columns(slots)
+        assert cols == [var.from_device_slots(
+            {o: np.float32(v) for o in slots}) for v in (2.0, 3.0)]
+        assert isinstance(cols[0], tuple)

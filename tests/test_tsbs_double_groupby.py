@@ -216,6 +216,58 @@ def test_the_template_answers_as_the_reference_does(cell, layout, table,
         assert split >= 2  # short segments stop short of their last hour
 
 
+def block_meter(form: str) -> float:
+    from pinot_tpu.utils.metrics import get_registry
+    return get_registry("server").meter("group_block", labels={"form": form})
+
+
+def test_the_answer_crosses_to_the_broker_as_columns(cell, layout, table,
+                                                     served):
+    """ISSUE 36: the template's answer through a broker and the framed
+    TCP transport, over the server's warm engine: its payload writes
+    arrays and dictionaries with ids and not one tagged-list column, the
+    two ends of the wire are on the traced spans, and the rows are the
+    reference's."""
+    from pinot_tpu.cluster.mini import MiniCluster
+    config, mix = cell
+    segs, ref = table
+    _ex, engine = served
+    t, literals, sql = traffic.make_queries(
+        mix, config["table"], SEED, 1, 2, False)[1]
+    c = MiniCluster(num_servers=1, use_tpu=True)
+    c.servers[0].executor._engine = engine  # one device, kernels compiled
+    c.start()
+    try:
+        c.add_table(config["table"])
+        for seg in segs:
+            c.add_segment(config["table"], seg, server_idx=0)
+        before = {f: block_meter(f) for f in ("array", "coded", "list")}
+        resp = c.query("SET trace = true; " + sql)
+    finally:
+        c.servers[0].executor._engine = None  # the fixture's to close
+        c.stop()
+    assert not resp.exceptions, resp.exceptions
+    want = ref.answer(mix["templates"][t], literals)
+    assert [[float(r[0]), int(r[1]), int(r[2]), r[3]]
+            for r in resp.result_table.rows] \
+        == [[float(w[0]), w[1], w[2], w[3]] for w in want]
+    # ts_hour + ids, hostname once + ids; SUM as f64, COUNT as i64
+    assert block_meter("coded") - before["coded"] == 2
+    assert block_meter("array") - before["array"] == 2
+    assert block_meter("list") - before["list"] == 0
+    scatter, = spans(resp.trace, "ServerScatter")
+    request, = spans(scatter, "ServerRequest")
+    span, = spans(request, "DeviceDispatch")
+    assert span["groupFold"] == "device"
+    assert span["groupsPresent"] == len(want)
+    # 4 B an id and 8 B a value a column, a group; the host names once
+    assert 24 * len(want) < request["serializeBytes"] \
+        < 24 * len(want) + 80_000
+    assert 0 <= request["serializeMs"] < request["durationMs"]
+    assert 0 <= scatter["deserializeMs"] \
+        <= scatter["durationMs"] - request["durationMs"]
+
+
 def test_the_fold_is_metered(served):
     _ex, engine = served
     labels = dict(engine._labels or {})
