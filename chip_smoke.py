@@ -238,6 +238,30 @@ MAIN_QUERIES = [
 ]
 
 
+#: the grouped kernel a main query's launch has to meet (`groupPath`)
+GROUP_PATHS = {"groupby_onehot": "onehot", "groupby_scatter": "onehot2"}
+
+
+def group_attrs(resp: dict) -> dict:
+    """Which grouped kernel a query's launches ran, as its DeviceDispatch
+    spans say (`groupPath`: onehot | onehot2 | scatter; `groupFold`:
+    device | host; on a server of several chips `meshDevices` and what
+    the grouped program's collectives carry), so that a change of path
+    cannot hide behind a leg that silently ran another kernel. An
+    ungrouped query states nothing."""
+    out = {}
+    for d in spans(resp.get("traceInfo"), "DeviceDispatch"):
+        for span_key, key in (("groupPath", "group_path"),
+                              ("groupFold", "group_fold"),
+                              ("groupKeySpace", "group_key_space"),
+                              ("meshDevices", "mesh_devices"),
+                              ("meshExchangeBytes", "mesh_exchange_bytes"),
+                              ("meshGatherBytes", "mesh_gather_bytes")):
+            if span_key in d:
+                out.setdefault(key, []).append(d[span_key])
+    return out
+
+
 def check_main(name: str, rows: list, want: dict) -> dict:
     """Raises unless `rows` is the reference's answer; returns what the
     approximate answers were measured against."""
@@ -724,9 +748,14 @@ def drive(args, cluster, work, native, t_start) -> dict:
             "warm_compiles": series_delta(mid, after, "kernel_retrace"),
             "device_kernel_fetch_ms": spans(
                 resp["traceInfo"], "DeviceDispatch")[0].get("kernelMs"),
+            **group_attrs(resp),
             **accuracy,
         }
         print(f"chip_smoke: {json.dumps(entry)}", flush=True)
+        if name in GROUP_PATHS \
+                and entry.get("group_path") != [GROUP_PATHS[name]]:
+            raise SmokeFailure(f"{name}: groupPath {entry.get('group_path')}"
+                               f", not {GROUP_PATHS[name]}")
         if entry["warm_upload_bytes"] or entry["warm_compiles"]:
             raise SmokeFailure(f"{name}: warm repeats uploaded "
                                f"{entry['warm_upload_bytes']} bytes and "
@@ -760,7 +789,8 @@ def drive(args, cluster, work, native, t_start) -> dict:
         if not check(rows):
             raise SmokeFailure(f"{leg}: rows differ from numpy: {rows}")
         leg_results.append({"name": leg, "served_meter": meter,
-                            "device_served": True, "cold_ms": ms})
+                            "device_served": True, "cold_ms": ms,
+                            **group_attrs(_resp)})
         print(f"chip_smoke: {json.dumps(leg_results[-1])}", flush=True)
 
     counters = cluster.counters()

@@ -7,8 +7,10 @@ brokers, controllers and clients must not.
 from __future__ import annotations
 
 import importlib.metadata
+import math
 import os
-from typing import Any, Dict, Optional, Sequence
+import re
+from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
 import jaxlib
@@ -84,3 +86,43 @@ def device_line(report: Dict[str, Any]) -> str:
             f"libtpu={report['libtpu']} "
             f"compile_cache_dir={report['compile_cache_dir']} "
             f"native_lib={report['native_lib']}")
+
+
+#: bytes an element of an HLO primitive type
+_HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8,
+                 "u64": 8, "f64": 8}
+_HLO_DEF = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ([a-z]\w*)\[([\d,]*)\]")
+_HLO_COLLECTIVE = re.compile(
+    r" (all-reduce|all-gather|all-to-all|collective-permute|reduce-scatter)"
+    r"(?:-start)?\(")
+
+
+def collective_bytes(hlo: str) -> Tuple[int, int]:
+    """(bytes, the all-gathers' part of them) that ONE device hands to
+    the collectives of a compiled program each time it runs, read from
+    the program's own text (`Compiled.as_text()`, the module after
+    partitioning): the operands of every all-reduce, all-gather,
+    all-to-all, collective-permute and reduce-scatter, started or
+    synchronous. A program with no collective reads (0, 0)."""
+    sizes: Dict[str, int] = {}
+    for line in hlo.splitlines():
+        m = _HLO_DEF.match(line)
+        if m and m.group(2) in _HLO_ITEMSIZE:
+            sizes[m.group(1)] = _HLO_ITEMSIZE[m.group(2)] * math.prod(
+                int(d) for d in m.group(3).split(",") if d)
+    total = gathered = 0
+    for line in hlo.splitlines():
+        m = _HLO_COLLECTIVE.search(line)
+        if m is None:
+            continue
+        depth, end = 1, m.end()
+        while depth and end < len(line):
+            depth += {"(": 1, ")": -1}.get(line[end], 0)
+            end += 1
+        moved = sum(sizes.get(name, 0) for name in
+                    re.findall(r"%([\w.\-]+)", line[m.end():end]))
+        total += moved
+        if m.group(1) == "all-gather":
+            gathered += moved
+    return total, gathered

@@ -179,6 +179,9 @@ class TpuOperatorExecutor:
         #: fold per (segment batch, group columns) — built once, re-used
         #: across queries
         self._gmap_cache: "OrderedDict[tuple, Any]" = OrderedDict()
+        #: what a mesh engine's grouped programs exchange between chips,
+        #: a compiled program an entry (`_mesh_exchange`)
+        self._exchange_cache: Dict[tuple, Tuple[Optional[int], ...]] = {}
 
     # ------------------------------------------------------------------
     # capability check (structural)
@@ -434,6 +437,14 @@ class TpuOperatorExecutor:
             if dsp is not None:
                 dsp.set(groupPath=path, groupKeySpace=num_groups,
                         groupFold=fold)
+            if self._mesh is not None and batchable:
+                exchanged, gathered = self._mesh_exchange(
+                    kernel, S, D, G, cols, params)
+                if exchanged is not None:
+                    self._meter("mesh_exchange_bytes", exchanged)
+                if dsp is not None:
+                    dsp.set(meshExchangeBytes=exchanged,
+                            meshGatherBytes=gathered)
         launch = Launch(
             # num_docs rides the packed parameters (plan_ir.PACK), a
             # host array here: the call's own argument, one transfer
@@ -452,15 +463,40 @@ class TpuOperatorExecutor:
             staged_ts=staged_ts)
         return plan, slots_of_fn, S_real, launch, minfo
 
-    @staticmethod
-    def _plain_kernels(plan: DevicePlan):
+    def _plain_kernels(self, plan: DevicePlan):
         """(kernel, batched factory, dedup factory) of a plan on the
-        plain-jit route (no doc sharding, no collective merge)."""
-        return (kernels.compiled_kernel(plan),
+        plain-jit route (no doc sharding, no collective merge). The
+        engine's mesh rides every look-up (None on one device): GSPMD
+        partitions the body from the staged blocks' shardings, and the
+        one part it cannot split, the Pallas group-by pass, runs a shard
+        of the segment axis under a shard_map over that mesh."""
+        mesh = self._mesh
+        return (kernels.compiled_kernel(plan, mesh),
                 lambda B, stacked: kernels.compiled_batched_kernel(
-                    plan, B, stacked),
+                    plan, B, stacked, mesh),
                 lambda B, U: kernels.compiled_batched_dedup_kernel(
-                    plan, B, U))
+                    plan, B, U, mesh))
+
+    def _mesh_exchange(self, kernel, S, D, G, cols, params):
+        """(bytes, the all-gathers' part) a chip hands to the collectives
+        of a mesh engine's grouped program a launch, (None, None) where
+        the program cannot be read. Read ONCE a compiled program, from the
+        program itself (`device.collective_bytes`), when a query first
+        stages its shapes, and kept: lowering the kernel again with the
+        launch's own arguments meets jit's caches, so the read costs a
+        compile only where the launch behind it would have paid it. The
+        single-query program's: a coalesced batch of B runs another,
+        whose collectives carry B rows."""
+        key = (kernel, S, D, G, _shape_sig(cols, params))
+        if key not in self._exchange_cache:
+            try:
+                moved = device_mod.collective_bytes(
+                    kernel.lower(cols, params, None, D=D, G=G)
+                    .compile().as_text())
+            except Exception:  # noqa: BLE001 — an observation, no failure
+                moved = (None, None)
+            self._exchange_cache[key] = moved
+        return self._exchange_cache[key]
 
     def _coalesce_key(self, plan, segments, S, D, G, cols, params,
                       batchable: bool, merged: bool = False):

@@ -405,16 +405,20 @@ def _two_sum(a, b):
     return s, (a - (s - bb)) + (b - bb)
 
 
-def _sum_segments(x: jnp.ndarray) -> jnp.ndarray:
+def _sum_segments(x: jnp.ndarray, chips: Optional[str] = None) -> jnp.ndarray:
     """[..., S, G] float partials -> [..., G], summed over the segment
     axis as a pairwise tree that carries each addition's rounding error
     and adds the errors back once at the root: the result is the exact
     sum rounded once (to a second-order term), where a plain f32 sum of
     sixteen partials loses up to sixteen roundings (the host fold it
-    replaces added them in f64). A tree, not a loop over segments, so
-    that a sharded segment axis halves in place until it is shorter than
-    the mesh. A non-finite partial gives the plain sum (inf - inf in the
-    error term would read NaN)."""
+    replaces added them in f64). A non-finite partial gives the plain
+    sum (inf - inf in the error term would read NaN).
+
+    chips: the mesh axis the segments are sharded over, inside a
+    shard_map (`fold_groups`): x holds this chip's segments, the tree
+    runs over them, and the chips' roots and carried errors are added by
+    ONE all-reduce each, the exchange between chips. Those last
+    additions (three on four chips) round in f32 and are not carried."""
     S = x.shape[-2]
     pad = (1 << max(S - 1, 0).bit_length()) - S
     if pad:
@@ -429,11 +433,13 @@ def _sum_segments(x: jnp.ndarray) -> jnp.ndarray:
         x, lost = _two_sum(pair[..., 0, :], pair[..., 1, :])
         err = epair[..., 0, :] + epair[..., 1, :] + lost
     total, err = x[..., 0, :], err[..., 0, :]
+    if chips:
+        total, err = jax.lax.psum((total, err), chips)
     return jnp.where(jnp.isfinite(total) & jnp.isfinite(err),
                      total + err, total)
 
 
-def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
+def fold_groups(plan: DevicePlan, slots, params, mesh=None) -> jnp.ndarray:
     """[S, G] per-segment partials a slot -> ONE packed integer row
     [n_slots * G_out + S]: the [n_slots, G_out] group table over the
     GLOBAL key space `plan.group_fold`, a slot after the other (slots
@@ -448,8 +454,15 @@ def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
     [S, U_i] tables give column i's local id of a union value (-1: the
     segment's dictionary lacks it), recombined with the plan's own
     strides; a compacted plan's `ginv` [S, G_out] gives the local code.
-    A gather a segment, then a reduction over the segment axis: on a
-    segments mesh GSPMD makes that one the exchange between chips.
+    A gather a segment, then a reduction over the segment axis.
+
+    mesh: the segments mesh the partials and the tables are sharded
+    over. Each chip then folds its own segments (`_fold_shard` under a
+    shard_map: the gathers never leave the chip) and the chips' rows
+    are added by all-reduces, after which every chip holds the whole
+    row. Left to GSPMD the same body compiled, for a v5e:2x2, to an
+    all-reduce, three all-to-alls, nine collective-permutes and an
+    all-gather of the answer.
 
     Exactness: a count is an integer under 2^24 a segment (f32-exact)
     and is summed as an integer, so a table past 2^24 rows a group
@@ -457,9 +470,28 @@ def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
     (`_sum_segments`); min / max fold with min / max. The row's dtype is
     the integer as wide as the value dtype: counts ride it as integers,
     every other slot bit-cast."""
+    names = ["ginv"] if plan.group_compact \
+        else [f"ginv{ci}" for ci in range(len(plan.group_fold))]
+    arrs = [s for _op, s in slots]
+    tables = [params[n] for n in names]
+    if mesh is None:
+        return _fold_shard(plan, None, arrs, tables)
+    seg = jax.sharding.PartitionSpec("segments")
+    return jax.shard_map(
+        functools.partial(_fold_shard, plan, "segments"), mesh=mesh,
+        in_specs=(seg, seg), out_specs=jax.sharding.PartitionSpec(),
+    )(arrs, tables)
+
+
+def _fold_shard(plan: DevicePlan, chips: Optional[str], arrs,
+                tables) -> jnp.ndarray:
+    """`fold_groups` over the segments at hand: all of them (chips
+    None), or one chip's share inside a shard_map over the mesh axis
+    `chips`, where every reduction over segments ends in its
+    all-reduce."""
     bits = jnp.int64 if _value_dtype() == jnp.float64 else jnp.int32
     if plan.group_compact:
-        idx = params["ginv"]
+        idx, = tables
         there = idx >= 0
         idx = jnp.where(there, idx, 0)
 
@@ -475,7 +507,6 @@ def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
         k = len(plan.group_fold)
         local = [(plan.num_groups if ci == 0 else plan.group_strides[ci - 1])
                  // plan.group_strides[ci] for ci in range(k)]
-        tables = [params[f"ginv{ci}"] for ci in range(k)]
         S = tables[0].shape[0]
         there = True
         for ci, ids in enumerate(tables):
@@ -491,23 +522,37 @@ def fold_groups(plan: DevicePlan, slots, params) -> jnp.ndarray:
                     xs, i, axis=_a, mode="clip"))(
                         x, jnp.maximum(ids, 0))
             return x.reshape(S, -1)
+
+    def across(f, reduce):
+        return reduce(f, chips) if chips else f
+
     folded = []
     matched = None
-    for (op, _vidx, fidx), (_o, s) in zip(plan.agg_ops, slots):
+    for (op, _vidx, fidx), s in zip(plan.agg_ops, arrs):
         g = globally(s)
         if op == "count":
-            f = jnp.sum(jnp.where(there, g, 0).astype(bits), axis=-2)
+            f = across(jnp.sum(jnp.where(there, g, 0).astype(bits), axis=-2),
+                       jax.lax.psum)
             if fidx is None and matched is None:
                 # every matched doc lands in exactly one group
                 matched = jnp.sum(s.astype(bits), axis=-1)
         elif op == "min":
-            f = jnp.min(jnp.where(there, g, jnp.inf), axis=-2)
+            f = across(jnp.min(jnp.where(there, g, jnp.inf), axis=-2),
+                       jax.lax.pmin)
         elif op == "max":
-            f = jnp.max(jnp.where(there, g, -jnp.inf), axis=-2)
+            f = across(jnp.max(jnp.where(there, g, -jnp.inf), axis=-2),
+                       jax.lax.pmax)
         else:
-            f = _sum_segments(jnp.where(there, g, 0))
+            f = _sum_segments(jnp.where(there, g, 0), chips)
         folded.append(f if f.dtype == bits
                       else jax.lax.bitcast_convert_type(f, bits))
+    if chips:
+        # [S] from the chips' [S / n]: each lays its own stretch into
+        # zeros and the all-reduce fills in the others'
+        mine = jnp.arange(jax.lax.axis_size(chips))[:, None] \
+            == jax.lax.axis_index(chips)
+        matched = jax.lax.psum(
+            jnp.where(mine, matched[None, :], 0).reshape(-1), chips)
     return jnp.concatenate(folded + [matched], axis=-1)
 
 
@@ -532,13 +577,13 @@ def _contribution(op: str, vals: Optional[jnp.ndarray],
 
 def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
                     mask: jnp.ndarray, valid: jnp.ndarray,
-                    num_groups: int) -> jnp.ndarray:
+                    num_groups: int, mesh=None) -> jnp.ndarray:
     """[S, D] + keys [S, D] -> [S, G] per-group partials, one slot."""
     m = mask & valid
     safe_keys = jnp.where(m, keys, 0)
     if op in _ADDITIVE:
         contrib = _contribution(op, vals, m).astype(_value_dtype())
-        return _scatter_sum(contrib, safe_keys, num_groups)
+        return _scatter_sum(contrib, safe_keys, num_groups, mesh)
     if op == "min":
         init = jnp.full((vals.shape[0], num_groups), jnp.inf, dtype=vals.dtype)
         v = jnp.where(m, vals, jnp.inf)
@@ -551,7 +596,7 @@ def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
 
 
 def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
-                 num_groups: int) -> jnp.ndarray:
+                 num_groups: int, mesh=None) -> jnp.ndarray:
     """Sum one slot's contributions per group key, by `group_path`: a
     chunked one-hot matmul to ONEHOT_MAX_GROUPS groups (SURVEY.md §7:
     group-bys become one-hot/segment-sum scatter-adds), the factored
@@ -562,7 +607,7 @@ def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
     path = group_path(num_groups, D, dt, finite=contrib.dtype == jnp.bool_)
     if path == "onehot2":
         with jax.named_scope("onehot2"):
-            return _onehot2_sums([contrib], keys, num_groups)[0]
+            return _onehot2_sums([contrib], keys, num_groups, mesh)[0]
     contrib = contrib.astype(dt)
     if path == "onehot":
         nchunk = D // _ONEHOT_CHUNK
@@ -638,7 +683,7 @@ def _onehot2_kernel(keys_ref, planes_ref, out_ref, *, H: int):
 
 
 def _onehot2_sums(contribs: List[jnp.ndarray], keys: jnp.ndarray,
-                  num_groups: int) -> List[jnp.ndarray]:
+                  num_groups: int, mesh=None) -> List[jnp.ndarray]:
     """Per-group sums of several contributions in ONE pass over the docs:
     a factored one-hot matmul on the MXU.
 
@@ -663,8 +708,21 @@ def _onehot2_sums(contribs: List[jnp.ndarray], keys: jnp.ndarray,
     same loop is a dozen ops a tile: 2.4 M device events in the
     benchmark's 12 s traced window, which the profiler could not write
     out in 150 s, and three quarters slower (G = 7,000: 91 ms against 52).
-    Every other backend takes that loop: Pallas's interpreter cannot
-    type a kernel under shard_map."""
+    Every other backend takes that loop. Checked on jax 0.9.0 (ISSUE
+    37): `pallas_call(interpret=True)` inside a shard_map fails its
+    type check ("requires varying manual axes to match": the
+    interpreter's constants are not varying as the refs are); the TPU
+    interpret mode (`pltpu.force_tpu_interpret_mode`) runs there bit for
+    bit but not under `vmap` (`safe_zip`: the batched grid axis has no
+    `dimension_semantics`), while the chip's compiler takes the call
+    under `vmap`, `shard_map` and both.
+
+    mesh: the engine's segments mesh where keys and contributions are
+    sharded over one. GSPMD refuses a Mosaic kernel ("cannot be
+    automatically partitioned"), so the tiles, the Pallas call or the
+    loop alike, run under a shard_map over `segments`: each chip over
+    its own segments, no doc axis gathered, the [S, P * H, L] partials
+    sharded as the blocks are."""
     S, D = keys.shape
     L = _ONEHOT2_LANES
     H = -(-num_groups // (8 * L)) * 8  # f32 sublanes: the P pieces align
@@ -689,32 +747,49 @@ def _onehot2_sums(contribs: List[jnp.ndarray], keys: jnp.ndarray,
         pad = ((0, 0), (0, 0), (0, tail))
         planes, keys = jnp.pad(planes, pad), jnp.pad(keys, pad)
     n = (D + tail) // T
-    # under shard_map the result varies over the mesh as its inputs do
-    vma = jax.typeof(keys).vma | jax.typeof(planes).vma
-    if jax.default_backend() == "tpu":
-        acc = pl.pallas_call(
-            functools.partial(_onehot2_kernel, H=H),
-            out_shape=jax.ShapeDtypeStruct((S, P * H, L), jnp.float32,
-                                           vma=vma),
-            grid=(S, n),
-            in_specs=[pl.BlockSpec((None, 1, T), lambda s, t: (s, 0, t)),
-                      pl.BlockSpec((None, P, T), lambda s, t: (s, 0, t))],
-            out_specs=pl.BlockSpec((None, P * H, L), lambda s, t: (s, 0, 0)),
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary"),
-                vmem_limit_bytes=_ONEHOT2_VMEM_BYTES),
-            name="onehot2",
-        )(keys, planes)
-    else:
-        def tiles(x):  # [S, R, n * T] -> [n, S, R, T]
-            return x.reshape(S, -1, n, T).transpose(2, 0, 1, 3)
 
-        acc = jnp.zeros((S, P * H, L), jnp.float32)
+    def tile_sums(keys, planes):
+        """[S', 1, n * T] keys and [S', P, n * T] planes -> [S', P * H, L]:
+        every segment it is handed, S' = S or one shard's share of it."""
+        Sl = keys.shape[0]
+        # under shard_map the result varies over the mesh as its inputs do
+        vma = jax.typeof(keys).vma | jax.typeof(planes).vma
+        if jax.default_backend() == "tpu":
+            return pl.pallas_call(
+                functools.partial(_onehot2_kernel, H=H),
+                out_shape=jax.ShapeDtypeStruct((Sl, P * H, L), jnp.float32,
+                                               vma=vma),
+                grid=(Sl, n),
+                in_specs=[pl.BlockSpec((None, 1, T), lambda s, t: (s, 0, t)),
+                          pl.BlockSpec((None, P, T), lambda s, t: (s, 0, t))],
+                out_specs=pl.BlockSpec((None, P * H, L),
+                                       lambda s, t: (s, 0, 0)),
+                compiler_params=pltpu.CompilerParams(
+                    dimension_semantics=("parallel", "arbitrary"),
+                    vmem_limit_bytes=_ONEHOT2_VMEM_BYTES),
+                name="onehot2",
+            )(keys, planes)
+
+        def tiles(x):  # [S', R, n * T] -> [n, S', R, T]
+            return x.reshape(Sl, -1, n, T).transpose(2, 0, 1, 3)
+
+        acc = jnp.zeros((Sl, P * H, L), jnp.float32)
         if vma:  # scan's carry must enter with the type it leaves with
             acc = jax.lax.pcast(acc, tuple(vma), to="varying")
         add = jax.vmap(functools.partial(_onehot2_tile, H=H))
         acc, _ = jax.lax.scan(lambda a, kp: (a + add(*kp), None), acc,
                               (tiles(keys), tiles(planes)))
+        return acc
+
+    if mesh is None:
+        acc = tile_sums(keys, planes)
+    else:
+        # a Mosaic kernel is opaque to GSPMD ("cannot be automatically
+        # partitioned"): each chip runs it over its own segments, and
+        # the [S, P * H, L] partials stay sharded as the blocks are
+        seg = jax.sharding.PartitionSpec("segments")
+        acc = jax.shard_map(tile_sums, mesh=mesh, in_specs=(seg, seg),
+                            out_specs=seg)(keys, planes)
     sums = acc.reshape(S, P, H * L)[:, :, :num_groups]
     out, p = [], 0
     for w in widths:
@@ -864,7 +939,7 @@ def _hll_slot(op: str, cols, mask) -> jnp.ndarray:
     return _vmap_scatter(init, bucket, rank, "max")
 
 
-def _hist_slot(op: str, j: int, vals, params, mask) -> jnp.ndarray:
+def _hist_slot(op: str, j: int, vals, params, mask, mesh=None) -> jnp.ndarray:
     """Fixed-bucket histogram partials [S, B] over the value block:
     bucket = clip((v - lo) * scale) then a masked count a bucket (feeds
     TDigest centroids host-side, ref PercentileTDigestAggregationFunction)."""
@@ -873,18 +948,23 @@ def _hist_slot(op: str, j: int, vals, params, mask) -> jnp.ndarray:
     scale = params[f"slot{j}:hscale"][:, None]
     bucket = jnp.clip((vals - lo) * scale, 0, B - 1).astype(jnp.int32)
     bucket = jnp.where(mask, bucket, 0)
-    return _scatter_sum(mask, bucket, B)
+    return _scatter_sum(mask, bucket, B, mesh)
 
 
 # ---------------------------------------------------------------------------
 # Kernel assembly
 # ---------------------------------------------------------------------------
 
-def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
+def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0,
+                   mesh=None):
     """Shared kernel body: filter + values + per-slot reductions over a
     (possibly shard-local) [S, D] block. Returns
     ([(op, [S]- or [S, G]-array)], matched_count [S] or None).
-    G: group count for compact-key plans (plan.num_groups is 0 there)."""
+    G: group count for compact-key plans (plan.num_groups is 0 there).
+    mesh: the segments mesh a plain-jit kernel's blocks are sharded over
+    (None: one device, or already inside a shard_map): only the factored
+    one-hot pass, a GROUP BY's or a histogram slot's, asks for it
+    (`_onehot2_sums`)."""
     dt = _value_dtype()
     with jax.named_scope("filter"):
         if plan.filter_ir is not None:
@@ -944,7 +1024,7 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
                 # keys as they are: a masked row adds zeros wherever it
                 # lands, and a key outside [0, G) matches no group
                 sums = dict(zip(additive, _onehot2_sums(
-                    contribs, keys, num_groups)))
+                    contribs, keys, num_groups, mesh)))
         for j, (op, vidx, fidx) in enumerate(plan.agg_ops):
             if j in sums:
                 slots.append((op, sums[j]))
@@ -952,7 +1032,8 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
             with jax.named_scope("reduce:" + op):
                 vals = None if vidx is None else values[vidx]
                 slots.append((op, _grouped_reduce(
-                    op, vals, keys, slot_mask(fidx), valid, num_groups)))
+                    op, vals, keys, slot_mask(fidx), valid, num_groups,
+                    mesh)))
         return slots, None
     with jax.named_scope("reduce:matched"):
         matched = jnp.sum(mask & valid, axis=1).astype(dt)
@@ -962,7 +1043,8 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
             if op.startswith("hll:"):
                 slot = _hll_slot(op, cols, m & valid)
             elif op.startswith("hist:"):
-                slot = _hist_slot(op, j, values[vidx], params, m & valid)
+                slot = _hist_slot(op, j, values[vidx], params, m & valid,
+                                  mesh)
             elif op == "isum":
                 vi = _eval_value_int(plan.value_irs[vidx], cols)
                 slot = _isum_slot(vi, m & valid)
@@ -976,7 +1058,8 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0):
     return slots, matched
 
 
-def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
+def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = (),
+                mesh=None):
     """Build the traced kernel fn(cols, params, num_docs, D) -> packed array.
 
     cols:    dict of 'ids:<col>' int32 [S, D] / 'val:<col>' float [S, D]
@@ -1001,6 +1084,11 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
 
     kind/extra label this build's trace-log entries (the batched
     factories pass their own kind and batch bucket through).
+    mesh: the engine's segments mesh where its blocks are sharded over
+    one (GSPMD partitions the rest of the body from the inputs'
+    shardings; the Pallas pass, a GROUP BY's or a histogram slot's,
+    cannot be, and runs a shard under `shard_map`: `_onehot2_sums`; the
+    fold's reductions end in explicit all-reduces: `fold_groups`).
     """
     fp = plan_fingerprint(plan)
 
@@ -1014,10 +1102,10 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = ()):
             # rows drop out of every slot AND the matched count, exactly
             # mirroring the host executor's `mask &= valid.to_mask()`
             valid = valid & cols["vmask"]
-        slots, matched = _compute_slots(plan, cols, params, valid, G)
+        slots, matched = _compute_slots(plan, cols, params, valid, G, mesh)
         if plan.group_fold:
             with jax.named_scope("fold"):
-                return fold_groups(plan, slots, params)
+                return fold_groups(plan, slots, params, mesh)
         with jax.named_scope("pack"):
             if plan.num_groups or G:
                 return jnp.stack([s for _, s in slots], axis=-1)
@@ -1105,13 +1193,13 @@ def compiled_topn_kernel(plan: DevicePlan):
 
 
 @functools.lru_cache(maxsize=256)
-def compiled_kernel(plan: DevicePlan):
+def compiled_kernel(plan: DevicePlan, mesh=None):
     """jit-compiled kernel for a plan structure (shape specialization is
     handled inside jit's own cache; D is static because a filterless
     COUNT(*) stages no columns to infer it from; G is the compact-key
     group count — data-dependent, hence a static arg rather than plan
-    state)."""
-    return jax.jit(_named(make_kernel(plan),
+    state). mesh: see `make_kernel`; part of the cache's key."""
+    return jax.jit(_named(make_kernel(plan, mesh=mesh),
                           "agg_" + plan_fingerprint(plan)),
                    static_argnames=("D", "G"))
 
@@ -1262,9 +1350,10 @@ def _batched_name(prefix: str, B: int, stacked: bool,
             f"{plan_fingerprint(plan)}")
 
 
-def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
+def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False,
+                        mesh=None):
     kind = "batched_stacked" if stacked else "batched"
-    base = make_kernel(plan, kind=kind, extra=(B,))
+    base = make_kernel(plan, kind=kind, extra=(B,), mesh=mesh)
 
     if stacked:
         def fn(clist, plist, ndlist, D, G=0):
@@ -1286,13 +1375,15 @@ def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
 
 
 @functools.lru_cache(maxsize=256)
-def compiled_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False):
-    """One jit per (plan, batch-size bucket B, stacked?) — see the
-    factory note above. fn(cols|clist, plist, num_docs|ndlist, D, G)."""
-    return make_batched_kernel(plan, B, stacked)
+def compiled_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False,
+                            mesh=None):
+    """One jit per (plan, batch-size bucket B, stacked?, segments mesh)
+    — see the factory note above.
+    fn(cols|clist, plist, num_docs|ndlist, D, G)."""
+    return make_batched_kernel(plan, B, stacked, mesh)
 
 
-def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
+def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int, mesh=None):
     """Stacked-batch variant with SAME-COLS MEMBER GROUPING: members
     whose staged column blocks are identity-equal (same table/segments,
     different predicate literals — e.g. two dashboard queries of one
@@ -1306,7 +1397,7 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
     (B, U) buckets. Each vmapped member gathers its slot from the
     stacked uniques (dynamic_index on the leading axis), so device
     memory holds U copies of the data, not B."""
-    base = make_kernel(plan, kind="batched_dedup", extra=(B, U))
+    base = make_kernel(plan, kind="batched_dedup", extra=(B, U), mesh=mesh)
 
     def fn(clist, plist, ndlist, idx, D, G=0):
         cs, ns = map(stack_members, (clist, ndlist))
@@ -1323,10 +1414,11 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
 
 
 @functools.lru_cache(maxsize=256)
-def compiled_batched_dedup_kernel(plan: DevicePlan, B: int, U: int):
-    """One jit per (plan, B bucket, U bucket) —
+def compiled_batched_dedup_kernel(plan: DevicePlan, B: int, U: int,
+                                  mesh=None):
+    """One jit per (plan, B bucket, U bucket, segments mesh) —
     fn(clist[U], plist[B], ndlist[U], idx[B], D, G)."""
-    return make_batched_dedup_kernel(plan, B, U)
+    return make_batched_dedup_kernel(plan, B, U, mesh)
 
 
 def make_batched_topn_kernel(plan: DevicePlan, B: int,
@@ -1370,8 +1462,10 @@ def make_batched_sharded_kernel(plan: DevicePlan, mesh, B: int,
     """The batched kernel for doc-sharded mesh engines: vmap INSIDE
     shard_map — mesh axes outermost, batch axis innermost — so
     multi-device engines ride the same coalesce path instead of falling
-    off it (`vmap` OVER `shard_map` is unsupported; this nests the other
-    way). Each device computes its local [*, S_loc, D_loc] shard for all
+    off it. (Written when `vmap` OVER `shard_map` was unsupported; jax
+    0.9.0 batches one, checked at ISSUE 37, and the plain-jit mesh
+    kernels are vmapped over theirs. This nests the other way and stays:
+    its specs say where the batch axis lies.) Each device computes its local [*, S_loc, D_loc] shard for all
     B queries, then the whole batch pays ONE set of psum/pmin/pmax
     collectives over the stacked partials (reductions commute with the
     batch stack) instead of B per-query rendezvous — which also means

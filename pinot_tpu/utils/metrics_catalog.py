@@ -85,6 +85,10 @@ METRICS: Dict[str, str] = {
     "group_result_bytes":
         "bytes of group table fetched from the device ([G, slots] a "
         "folded query, [S, G, slots] where the host folds)",
+    "mesh_exchange_bytes":
+        "bytes a chip handed to the collectives of grouped programs on a "
+        "server of several chips (the fold's all-reduces; read once a "
+        "compiled program, added a launch)",
     "group_block":
         "columns of grouped results written to the DataTable, by the form "
         "their content allowed (label form=array|coded|list: raw numeric "

@@ -49,7 +49,7 @@ def segs(tmp_path):
 def test_dispatch_overlaps_across_threads(segs, monkeypatch):
     calls = []
 
-    def slow_compiled_kernel(plan):
+    def slow_compiled_kernel(plan, mesh=None):
         def kernel(cols, params, num_docs, D, G=0):
             calls.append(time.perf_counter())
             time.sleep(KERNEL_S)  # a dispatch in flight

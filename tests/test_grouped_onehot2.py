@@ -16,6 +16,7 @@ The suite runs with x64 ON, where the device sums are f64 and keep the
 scatter; what one chip runs (x64 off, f32) is entered with
 `jax.enable_x64(False)`.
 """
+import contextlib
 import math
 
 import numpy as np
@@ -219,15 +220,35 @@ def test_pallas_driver_equals_the_xla_loop(G, tail, monkeypatch):
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no TPU compiler here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
     return SingleDeviceSharding(topo.devices[0])
+
+
+@contextlib.contextmanager
+def _compiling_for_the_chip(monkeypatch):
+    """x64 off, the TPU's branch of `_onehot2_sums`, and no compile
+    cache (an entry written for a described chip cannot be read back)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with jax.enable_x64(False):
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            yield
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
 
 
 @pytest.mark.parametrize("G,ops", [
@@ -239,31 +260,158 @@ def test_pallas_driver_compiles_for_the_v5e(G, ops, one_chip, monkeypatch):
     """The chip's compiler takes the kernel at the benchmark's widths
     (16 segments of 8M docs) and at the widest plan the path function
     admits: tiling, VMEM and all. Nothing runs."""
-    from jax.experimental.compilation_cache import compilation_cache
     S, D = 16, 1 << 23
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    with jax.enable_x64(False):
-        jax.config.update("jax_enable_compilation_cache", False)
-        compilation_cache.reset_cache()
-        try:
-            kernel = jax.jit(kernels.make_kernel(_plan(G, ops)),
-                             static_argnames=("D", "G"))
-            compiled = kernel.lower(
-                {"ids:g": jax.ShapeDtypeStruct((S, D), jnp.int32,
-                                               sharding=one_chip),
-                 "val:v": jax.ShapeDtypeStruct((S, D), jnp.float32,
-                                               sharding=one_chip)},
-                {}, jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip),
-                D=D).compile()
-        finally:
-            jax.config.update("jax_enable_compilation_cache", True)
-            compilation_cache.reset_cache()
+    with _compiling_for_the_chip(monkeypatch):
+        kernel = jax.jit(kernels.make_kernel(_plan(G, ops)),
+                         static_argnames=("D", "G"))
+        compiled = kernel.lower(
+            {"ids:g": jax.ShapeDtypeStruct((S, D), jnp.int32,
+                                           sharding=one_chip),
+             "val:v": jax.ShapeDtypeStruct((S, D), jnp.float32,
+                                           sharding=one_chip)},
+            {}, jax.ShapeDtypeStruct((S,), jnp.int32, sharding=one_chip),
+            D=D).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 1 and "onehot2" in hlo
     assert "scatter" not in hlo
     # what a launch holds beside the table (planes, keys, contributions)
     # stays under half the chip
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
+# -- four chips: the pass a shard, the fold's all-reduces ------------------------
+def _q2_plan():
+    """SSB Q2.x as the engine plans it once the fold is set: d_year (7)
+    x p_brand1 (1,000) = 7,000 local keys, SUM + COUNT, a filter leaf,
+    folded over the union's key space in pow2 digits (8 x 1,024)."""
+    return DevicePlan(
+        filter_ir=("leaf", 0), leaves=(DeviceLeaf("vrange", "f"),),
+        value_irs=(("col", "v"),),
+        agg_ops=(("sum", 0, None), ("count", None, None)),
+        group_cols=("y", "b"), group_strides=(1000, 1), num_groups=7000,
+        raw_cols=("f", "v"), group_fold=(8, 1024))
+
+
+def _q2_args(S, D, put):
+    """(cols, params, num_docs) of `_q2_plan` as a four-chip server
+    stages them: every [S, ...] array sharded over `segments`.
+    put(shape, dtype, spec) makes one."""
+    seg, blk = ("segments",), ("segments", None)
+    cols = {"ids:y": put((S, D), jnp.int8, blk),
+            "ids:b": put((S, D), jnp.int16, blk),
+            "val:v": put((S, D), jnp.float32, blk),
+            "val:f": put((S, D), jnp.float32, blk)}
+    params = {"leaf0:lo": put((S,), jnp.float32, seg),
+              "leaf0:hi": put((S,), jnp.float32, seg),
+              "ginv0": put((S, 8), jnp.int32, blk),
+              "ginv1": put((S, 1024), jnp.int32, blk)}
+    return cols, params, put((S,), jnp.int32, seg)
+
+
+def test_mesh_kernel_runs_the_pallas_pass_a_shard(monkeypatch):
+    """On a segments mesh the TPU's branch is ONE `pallas_call` named
+    onehot2 INSIDE a shard_map over `segments` (GSPMD refuses a Mosaic
+    kernel: "cannot be automatically partitioned"), and the fold a second
+    shard_map whose reductions end in psums; without a mesh neither
+    shard_map is there and the body is the one chip's, as it was."""
+    from jax.sharding import Mesh, PartitionSpec as P
+    if len(jax.devices()) < 4:
+        pytest.skip("needs 4 virtual devices")
+    mesh = Mesh(np.array(jax.devices()[:4]), ("segments",))
+    S, D = 8, CH
+    args = _q2_args(S, D, lambda shape, dt, _spec: jnp.zeros(shape, dt))
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def jaxpr_of(mesh):
+        kernel = kernels.make_kernel(_q2_plan(), mesh=mesh)
+        return jax.make_jaxpr(lambda *a: kernel(*a, D=D))(*args)
+
+    with jax.enable_x64(False):
+        on_mesh, alone = jaxpr_of(mesh), jaxpr_of(None)
+    assert "shard_map" not in str(alone)
+    assert str(alone).count("pallas_call[") == 1
+    maps = [e for e in on_mesh.jaxpr.eqns if e.primitive.name == "shard_map"]
+    assert len(maps) == 2
+    for eqn in maps:
+        assert eqn.params["mesh"].axis_names == ("segments",)
+        assert set(eqn.params["in_specs"]) == {P("segments")}
+    pass_, fold = (str(e.params["jaxpr"]) for e in maps)
+    assert pass_.count("pallas_call[") == 1 and "name=onehot2" in pass_
+    assert "scan" not in pass_ and "psum" not in pass_
+    # the pass's partials leave their shard_map as they entered: a shard
+    assert maps[0].params["out_specs"] == (P("segments"),)
+    # the fold: every reduction over segments ends in its all-reduce and
+    # the row leaves held whole by every chip
+    assert "pallas_call" not in fold and fold.count("psum") >= 3
+    assert "all_gather" not in fold and "all_to_all" not in fold
+    assert maps[1].params["out_specs"] == (P(),)
+    assert str(on_mesh).count("pallas_call[") == 1
+
+
+def _mesh_shapes(topo, S, D):
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(topo.devices), ("segments",))
+    return mesh, _q2_args(S, D, lambda shape, dt, spec: jax.ShapeDtypeStruct(
+        shape, dt, sharding=NamedSharding(mesh, P(*spec))))
+
+
+def _assert_a_shard_a_chip(compiled, rows):
+    """The compiled four-chip program: the Mosaic kernel is in it, no
+    collective but the fold's all-reduces (so no all-gather, least of all
+    of an operand with a doc axis), and a chip's temporaries are its own
+    shard's planes, keys and contributions (one chip compiled alone for
+    8 such segments reads 1,073,838,592 B a query)."""
+    from pinot_tpu.ops import device
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 1 and "onehot2" in hlo
+    for op in ("all-gather", "all-to-all", "collective-permute",
+               "reduce-scatter"):
+        assert f" {op}(" not in hlo and f" {op}-start(" not in hlo, op
+    # f32 sums + carried errors, i32 counts, the segments' matched docs
+    assert device.collective_bytes(hlo) == (
+        rows * 4 * (3 * 8192 + 32), 0)
+    assert compiled.memory_analysis().temp_size_in_bytes < rows * (
+        (2 << 30) + (1 << 20))
+
+
+def test_mesh_kernel_compiles_for_the_v5e_2x2(topo, monkeypatch):
+    """ISSUE 37's lowering: the engine's own kernel for a four-device
+    segments mesh, the described v5e:2x2, 8 segments a chip x 2^23 docs,
+    G = 7,000, SUM + COUNT, folded. Nothing runs."""
+    mesh, (cols, params, num_docs) = _mesh_shapes(topo, 32, 1 << 23)
+    with _compiling_for_the_chip(monkeypatch):
+        compiled = kernels.make_kernel(_q2_plan(), mesh=mesh)
+        compiled = jax.jit(compiled, static_argnames=("D", "G")).lower(
+            cols, params, num_docs, D=1 << 23).compile()
+    _assert_a_shard_a_chip(compiled, rows=1)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 << 30
+
+
+@pytest.mark.parametrize("variant", ["broadcast", "stacked", "dedup"])
+def test_mesh_batched_kernels_compile_for_the_v5e_2x2(topo, monkeypatch,
+                                                      variant):
+    """Every variant the dispatch ring can pick for such a plan on a
+    mesh is `vmap` OVER the same body, so over both shard_maps and the
+    Pallas call: jax 0.9.0 batches all three, and the chip's compiler
+    takes the result (B = 2; the dedup variant B = 2 over U = 2)."""
+    mesh, (cols, params, num_docs) = _mesh_shapes(topo, 32, 1 << 23)
+    plist = {k: (v, v) for k, v in params.items()}
+    with _compiling_for_the_chip(monkeypatch):
+        if variant == "broadcast":
+            kernel = kernels.make_batched_kernel(_q2_plan(), 2, False, mesh)
+            lowered = kernel.lower(cols, plist, num_docs, D=1 << 23)
+        elif variant == "stacked":
+            kernel = kernels.make_batched_kernel(_q2_plan(), 2, True, mesh)
+            lowered = kernel.lower((cols, cols), plist,
+                                   (num_docs, num_docs), D=1 << 23)
+        else:
+            kernel = kernels.make_batched_dedup_kernel(
+                _q2_plan(), 2, 2, mesh)
+            lowered = kernel.lower(
+                (cols, cols), plist, (num_docs, num_docs),
+                jax.ShapeDtypeStruct((2,), jnp.int32), D=1 << 23)
+        compiled = lowered.compile()
+    _assert_a_shard_a_chip(compiled, rows=2)
 
 
 # -- a served GROUP BY ---------------------------------------------------------
