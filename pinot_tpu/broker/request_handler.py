@@ -1037,7 +1037,7 @@ class BrokerRequestHandler:
 
         self._phase("reduce", ctx.table)
         with tracing.Scope("BrokerReduce", servers=responded):
-            resp = reduce_results(ctx, results)
+            resp = reduce_results(ctx, results, self._metrics)
         for extra in server_stats:
             resp.stats.merge(extra)
         resp.exceptions = exceptions
@@ -1160,7 +1160,7 @@ class StreamingMixin:
             finally:
                 if self._selector is not None:
                     self._selector.record_end(server, time.time() - t0)
-        resp = reduce_results(ctx, results)
+        resp = reduce_results(ctx, results, self._metrics)
         for s in extra_stats:
             resp.stats.merge(s)
         resp.exceptions = exceptions

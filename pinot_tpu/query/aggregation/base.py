@@ -6,6 +6,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Type
 
 import numpy as np
 
+from pinot_tpu.query.results import column_values
+
 
 @dataclass(frozen=True)
 class DeviceAggSpec:
@@ -70,6 +72,22 @@ class AggregationFunction:
 
     def extract_final(self, intermediate: Any) -> Any:
         return intermediate
+
+    def final_column(self, value_column: Any) -> Any:
+        """`extract_final` for a whole `GroupByResult.value_columns`
+        entry at once: a column (ndarray or list) whose rows, as
+        `column_values` gives them, are the finals. Where extract_final
+        is the identity (COUNT, SUM, MIN, MAX, ...) that is the column
+        itself; otherwise this default loops (a list: any function keeps
+        working), and AVG / MINMAXRANGE override it with array
+        arithmetic that gives, row for row, extract_final's value and
+        Python type."""
+        if type(self).extract_final is AggregationFunction.extract_final:
+            return value_column
+        inters = zip(*map(column_values, value_column)) \
+            if isinstance(value_column, tuple) \
+            else column_values(value_column)
+        return [self.extract_final(v) for v in inters]
 
     # -- device path --------------------------------------------------------
     def from_device_slots(self, slots: Dict[str, Any]) -> Any:

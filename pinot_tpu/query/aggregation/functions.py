@@ -23,6 +23,12 @@ def _masked(values: np.ndarray, mask: np.ndarray) -> np.ndarray:
     return values[mask]
 
 
+def _f64(col) -> bool:
+    """col is a float64 ndarray: array arithmetic on it gives, row for
+    row, what Python floats give."""
+    return isinstance(col, np.ndarray) and col.dtype == np.float64
+
+
 def _grouped_bincount(keys, num_groups, mask, weights=None):
     k = keys[mask]
     w = None if weights is None else weights[mask]
@@ -175,6 +181,14 @@ class AvgAggregation(AggregationFunction):
     def from_device_slot_columns(self, slots):
         return (float_column(slots["sum"]), count_column(slots["count"]))
 
+    def final_column(self, value_column):
+        s, c = value_column if isinstance(value_column, tuple) else (0, 0)
+        if not (_f64(s) and isinstance(c, np.ndarray)
+                and c.dtype.kind in "iu"):
+            return super().final_column(value_column)
+        out = np.full(len(s), -np.inf)
+        return np.divide(s, c, out=out, where=c != 0)
+
 
 @register
 class MinMaxRangeAggregation(AggregationFunction):
@@ -202,6 +216,13 @@ class MinMaxRangeAggregation(AggregationFunction):
 
     def from_device_slot_columns(self, slots):
         return (float_column(slots["min"]), float_column(slots["max"]))
+
+    def final_column(self, value_column):
+        lo, hi = value_column if isinstance(value_column, tuple) else (0, 0)
+        if not (_f64(lo) and _f64(hi)):
+            return super().final_column(value_column)
+        with np.errstate(invalid="ignore"):  # inf - inf: nan, as floats do
+            return hi - lo
 
 
 @register

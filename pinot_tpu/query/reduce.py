@@ -18,8 +18,9 @@ from pinot_tpu.query.context import QueryContext
 from pinot_tpu.query.expressions import (
     Expression, Function, Identifier, Literal, extract_aggregations)
 from pinot_tpu.query.results import (
-    AggregationResult, DistinctResult, ExecutionStats, GroupByResult,
-    SelectionResult)
+    AggregationResult, CodedColumn, DistinctResult, ExecutionStats,
+    GroupByResult, SelectionResult)
+from pinot_tpu.utils import tracing
 
 
 @dataclass
@@ -139,15 +140,18 @@ def eval_scalar(expr: Expression, bindings: Dict[Expression, Any]) -> Any:
 # Reducers
 # ---------------------------------------------------------------------------
 
-def reduce_results(ctx: QueryContext, results: Sequence[Any]) -> BrokerResponse:
+def reduce_results(ctx: QueryContext, results: Sequence[Any],
+                   metrics=None) -> BrokerResponse:
     """Merge SegmentResults (from any mix of servers/paths) into the final
-    BrokerResponse (ref BrokerReduceService.reduceOnDataTable)."""
+    BrokerResponse (ref BrokerReduceService.reduceOnDataTable). `metrics`
+    (the broker's MetricsRegistry) gets `broker_reduce{path=}` a GROUP
+    BY."""
     resp = BrokerResponse()
     results = [r for r in results if r is not None]
     for r in results:
         resp.stats.merge(r.stats)
     if ctx.is_group_by_query:
-        resp.result_table = _reduce_group_by(ctx, results, resp)
+        resp.result_table = _reduce_group_by(ctx, results, resp, metrics)
     elif ctx.is_aggregation_query:
         resp.result_table = _reduce_aggregation(ctx, results)
     elif ctx.is_distinct_query:
@@ -176,11 +180,105 @@ def _reduce_aggregation(ctx: QueryContext, results: List[AggregationResult]) -> 
 
 
 def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
-                     resp: BrokerResponse) -> ResultTable:
-    # IndexedTable-style merge (ref GroupByDataTableReducer)
-    merged: Dict[Tuple, List[Any]] = {}
+                     resp: BrokerResponse, metrics=None) -> ResultTable:
     for r in results:
         resp.num_groups_limit_reached |= r.num_groups_limit_reached
+    names = ctx.result_column_names()
+    types = [_result_type(e, ctx) for e in ctx.select]
+    # where every output and sort expression IS a group key, an
+    # aggregate or an alias of one (the plain report: 48,000 rows of it
+    # cost the broker more in hashing expressions than the device took),
+    # a row is picked by position; anything computed takes the bindings
+    direct = _direct_columns(ctx)
+    held = [r for r in results if r.num_rows]
+    rows = None
+    if direct is not None and "gapfillTimeCol" not in ctx.options \
+            and len(held) == 1 and held[0].key_columns is not None:
+        rows = _columnar_rows(ctx, held[0], direct)
+    path = "rows" if rows is None else "columns"
+    tracing.annotate(reducePath=path,
+                     reduceRows=sum(r.num_rows for r in results))
+    if metrics is not None:
+        metrics.add_meter("broker_reduce", labels={"path": path})
+    if rows is None:
+        rows = _merged_rows(ctx, results, direct, names, types)
+    return ResultTable(names, types, rows)
+
+
+def _columnar_rows(ctx: QueryContext, r: GroupByResult,
+                   direct) -> Optional[List[Tuple]]:
+    """The answer's rows from ONE result held as columns, a whole column
+    at a time: finals by `final_column`, ORDER BY as one stable lexsort
+    over ranks, OFFSET/LIMIT sliced from the permutation, and Python
+    values made only for the rows kept. The same rows, value for value,
+    type for type and in order, as `_merged_rows` gives; None where an
+    ORDER BY column has no rank in Python's own order."""
+    select, order = direct
+    finals = [fn.final_column(col)
+              for fn, col in zip(ctx.agg_functions, r.value_columns)]
+    cols = (r.key_columns, finals)
+    out = [cols[w][i] for w, i in select]
+    keys = []
+    for (w, i), (_, asc) in zip(order, ctx.order_by):
+        rank = _rank(out[i] if w == 2 else cols[w][i])
+        if rank is None:
+            return None
+        # negated for DESC: ties keep row order, as the stable reverse
+        # sort of the rows path keeps them
+        keys.append(rank if asc else -rank)
+    perm = np.lexsort(keys[::-1]) if keys else np.arange(r.num_rows)
+    kept = perm[ctx.offset:ctx.offset + ctx.limit]
+    return list(zip(*(_take(c, kept) for c in out)))
+
+
+def _rank(col) -> Optional[np.ndarray]:
+    """Each row's rank among the column's distinct values in Python's
+    own order, equal values of equal rank; None where Python would not
+    order the column that way (a NaN, a None, types that do not
+    compare)."""
+    if isinstance(col, CodedColumn):
+        # only the values some row holds: a union of 1,000 names ranked
+        # whole cost more than the seven rows an answer may have
+        table = np.bincount(col.ids)
+        used = np.flatnonzero(table)
+        ranks = _rank(col.values[used] if isinstance(col.values, np.ndarray)
+                      else [col.values[i] for i in used.tolist()])
+        if ranks is None:
+            return None
+        table[used] = ranks
+        return table[col.ids]
+    if isinstance(col, np.ndarray):
+        if col.dtype.kind not in "biuf" \
+                or (col.dtype.kind == "f" and np.isnan(col).any()):
+            return None
+        return np.unique(col, return_inverse=True)[1]
+    try:
+        distinct = set(col)
+        if None in distinct or any(v != v for v in distinct):
+            return None
+        rank = {v: i for i, v in enumerate(sorted(distinct))}
+    except TypeError:
+        return None
+    return np.fromiter(map(rank.__getitem__, col), np.int64, len(col))
+
+
+def _take(col, idx: np.ndarray) -> list:
+    """A result column's rows at `idx` as Python values, as
+    `column_values` gives them."""
+    if isinstance(col, CodedColumn):
+        return _take(col.values, col.ids[idx])
+    if isinstance(col, np.ndarray):
+        return col[idx].tolist()
+    return [col[i] for i in idx.tolist()]
+
+
+def _merged_rows(ctx: QueryContext, results: List[GroupByResult], direct,
+                 names: List[str], types: List[str]) -> List[Tuple]:
+    """The rows path: any number of results of any form, merged a group
+    at a time into a dict (ref GroupByDataTableReducer's IndexedTable),
+    then HAVING, post-aggregation, gapfill, sort and limit."""
+    merged: Dict[Tuple, List[Any]] = {}
+    for r in results:
         for key, inters in r.groups.items():
             cur = merged.get(key)
             if cur is None:
@@ -190,11 +288,6 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
                     cur[i] = fn.merge(cur[i], inters[i])
 
     rows = []
-    # where every output and sort expression IS a group key, an
-    # aggregate or an alias of one (the plain report: 48,000 rows of it
-    # cost the broker more in hashing expressions than the device took),
-    # a row is picked by position; anything computed takes the bindings
-    direct = _direct_columns(ctx)
     for key, inters in merged.items():
         finals = [fn.extract_final(m) for fn, m in zip(ctx.agg_functions, inters)]
         if direct is not None:
@@ -218,8 +311,6 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
         sort_key = tuple(eval_scalar(e, bindings) for e, _ in ctx.order_by)
         rows.append((sort_key, out_row))
 
-    names = ctx.result_column_names()
-    types = [_result_type(e, ctx) for e in ctx.select]
     if "gapfillTimeCol" in ctx.options:
         # fill BEFORE sort/limit so ordering + limit apply to the filled
         # series (ref GapfillProcessor running inside the reducer)
@@ -228,9 +319,7 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
         filled = maybe_gapfill(ctx, pre)
         if filled is not pre:  # options were valid and fill applied
             try:
-                return ResultTable(
-                    names, types,
-                    _sort_limit_filled(ctx, names, filled.rows))
+                return _sort_limit_filled(ctx, names, filled.rows)
             except (ValueError, KeyError):
                 # ORDER BY references something not reconstructible from
                 # the output row (e.g. an unselected column): fall back
@@ -238,8 +327,7 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
                 pass
     if ctx.order_by:
         rows = _sorted_by_keys(rows, [asc for _, asc in ctx.order_by])
-    out = [r for _, r in rows][ctx.offset:ctx.offset + ctx.limit]
-    return ResultTable(names, types, out)
+    return [r for _, r in rows][ctx.offset:ctx.offset + ctx.limit]
 
 
 def _direct_columns(ctx: QueryContext):

@@ -103,14 +103,20 @@ class GroupByResult:
                 else {key: [] for key in keys})
         return self._groups
 
+    @property
+    def num_rows(self) -> int:
+        """Groups held, counted without building or transposing."""
+        if self._groups is not None:
+            return len(self._groups)
+        first = self.key_columns[0] if self.key_columns else ()
+        return len(first.ids if isinstance(first, CodedColumn) else first)
+
     def columns(self) -> Tuple[int, List[Any], List[Any]]:
         """(rows, key columns, value columns). A dict-built result is
         transposed: plain lists, and a function whose intermediates are
         all tuples of one length >= 1 splits into its components."""
         if self.key_columns is not None:
-            first = self.key_columns[0] if self.key_columns else ()
-            return (len(first.ids if isinstance(first, CodedColumn)
-                        else first), self.key_columns, self.value_columns)
+            return self.num_rows, self.key_columns, self.value_columns
         groups = self._groups
         if not groups:
             return 0, [], []
@@ -135,9 +141,8 @@ class GroupByResult:
                 == other.num_groups_limit_reached)
 
     def __repr__(self) -> str:  # never builds the dict
-        rows = len(self._groups) if self._groups is not None \
-            else self.columns()[0]
-        return (f"GroupByResult({rows} groups, stats={self.stats!r}, "
+        return (f"GroupByResult({self.num_rows} groups, "
+                f"stats={self.stats!r}, "
                 f"num_groups_limit_reached="
                 f"{self.num_groups_limit_reached!r})")
 

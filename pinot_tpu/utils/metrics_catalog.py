@@ -34,6 +34,10 @@ METRICS: Dict[str, str] = {
     "hedge_wasted": "hedge attempts the primary beat",
     "hedge_split": "hedges split across replicas (partial layouts)",
     "slow_queries": "queries at/over the slow-query threshold",
+    "broker_reduce":
+        "GROUP BY answers reduced, by path (label path=columns|rows: one "
+        "result held as columns, sorted and sliced a whole column at a "
+        "time, or the dict merge a group at a time)",
     # -- server query path ------------------------------------------------
     "queries": "queries executed by this server",
     "queries_killed": "queries stopped by deadline/cancel",

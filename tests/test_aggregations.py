@@ -332,3 +332,49 @@ class TestDeviceSlotColumns:
         assert cols == [var.from_device_slots(
             {o: np.float32(v) for o in slots}) for v in (2.0, 3.0)]
         assert isinstance(cols[0], tuple)
+
+    @pytest.mark.parametrize("name,ops", [
+        ("count", ("count",)), ("sum", ("sum",)), ("min", ("min",)),
+        ("max", ("max",)), ("avg", ("sum", "count")),
+        ("minmaxrange", ("min", "max"))])
+    def test_final_column_equals_extract_final_a_row(self, name, ops):
+        """ISSUE 38: the broker's finals a whole column at a time give,
+        row for row, `extract_final`'s value and Python type: AVG's
+        `-inf` where the count is 0, MINMAXRANGE's `nan` from inf - inf."""
+        from pinot_tpu.query.aggregation.base import get_aggregation
+        from pinot_tpu.query.expressions import Identifier
+        from pinot_tpu.query.results import column_values
+        fn = get_aggregation(name, (Identifier("m"),))
+        values = np.array(self.VALUES + [np.inf, -np.inf], np.float64)
+        slots = {op: np.array(self.COUNTS + [2, 0], np.int64) if op == "count"
+                 else values[::-1] if op == "max" else values for op in ops}
+        if name == "minmaxrange":
+            slots["max"][-1] = np.inf  # [inf, inf]: inf - inf
+        cols = fn.from_device_slot_columns(slots)
+        final = fn.final_column(cols)
+        assert isinstance(final, np.ndarray)
+        got = column_values(final)
+        want = [fn.extract_final(v) for v in self.rows_of(cols)]
+        assert repr(got) == repr(want)
+        assert list(map(type, got)) == list(map(type, want))
+        if name == "avg":
+            assert got[0] == got[-1] == -np.inf  # counts of 0
+        # a column the wire brought as a list takes the base's loop
+        listed = tuple(map(column_values, cols)) if isinstance(cols, tuple) \
+            else column_values(cols)
+        assert repr(column_values(fn.final_column(listed))) == repr(want)
+
+    def test_final_column_loops_extract_final_for_a_sketch(self):
+        from pinot_tpu.query.aggregation.base import get_aggregation
+        from pinot_tpu.query.expressions import Identifier
+        hll = get_aggregation("distinctcounthll", (Identifier("m"),))
+        op, = hll.device_spec.ops
+        registers = np.zeros((3, 1 << hll._log2m()), np.uint8)
+        registers[1, 5] = 3
+        registers[2, :7] = 1
+        cols = hll.from_device_slot_columns({op: registers})
+        got = hll.final_column(cols)
+        assert isinstance(got, list)
+        assert got == [hll.extract_final(h) for h in cols]
+        assert list(map(type, got)) == [type(hll.extract_final(cols[0]))] * 3
+        assert got[0] == 0 < got[1] < got[2]

@@ -169,6 +169,7 @@ def test_a_grouped_query_is_folded_across_the_four_devices(ssb, served):
     from pinot_tpu.query.reduce import reduce_results
     from pinot_tpu.query.results import GroupByResult
     from pinot_tpu.server.datatable import deserialize_results_ex
+    from pinot_tpu.utils.metrics import MetricsRegistry
     ex, engine = served
     sql = ("SELECT SUM(lo_extendedprice), COUNT(*), MAX(lo_extendedprice), "
            "lo_discount, lo_quantity FROM ssb WHERE lo_orderdate BETWEEN "
@@ -193,8 +194,11 @@ def test_a_grouped_query_is_folded_across_the_four_devices(ssb, served):
             t[0] += int(p)
             t[1] += 1
             t[2] = max(t[2], int(p))
-    rows = reduce_results(QueryContext.from_sql(sql), results
+    broker = MetricsRegistry("broker")
+    rows = reduce_results(QueryContext.from_sql(sql), results, broker
                           ).result_table.rows
+    # ISSUE 38: the broker finishes the one folded result as columns
+    assert broker.meter("broker_reduce", labels={"path": "columns"}) == 1
     got = [((int(r[3]), int(r[4])), [int(r[0]), int(r[1]), int(r[2])])
            for r in rows]
     assert got == sorted(want.items()) and len(got) > 500

@@ -259,6 +259,10 @@ def test_the_answer_crosses_to_the_broker_as_columns(cell, layout, table,
     request, = spans(scatter, "ServerRequest")
     span, = spans(request, "DeviceDispatch")
     assert span["groupFold"] == "device"
+    # ISSUE 38: the broker finishes the one folded result as columns
+    reduced, = spans(resp.trace, "BrokerReduce")
+    assert (reduced["reducePath"], reduced["reduceRows"]) \
+        == ("columns", len(want))
     assert span["groupsPresent"] == len(want)
     # 4 B an id and 8 B a value a column, a group; the host names once
     assert 24 * len(want) < request["serializeBytes"] \
