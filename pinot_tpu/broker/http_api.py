@@ -67,13 +67,15 @@ class BrokerHttpServer:
                     self.send_response(400)
                     self.end_headers()
                     return
-                resp = broker.handler.handle(sql)
-                body = json.dumps(resp.to_dict(), default=str).encode()
+                resp, body = broker.handler.handle_encoded(sql)
                 self.send_response(200)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(body)))
                 self.end_headers()
                 self.wfile.write(body)
+                # a large answer's rows take milliseconds to free: once
+                # it is written, outside the client's wait
+                del resp
 
         self._server = ThreadingHTTPServer((host, port), _Handler)
         self.port = self._server.server_address[1]

@@ -119,6 +119,7 @@ class BrokerRequestHandler:
         self.result_cache = result_cache
         from pinot_tpu.utils.metrics import get_registry
         self._metrics = get_registry("broker")
+        tracing.install_gc_probe("broker")
         #: pruned-to-zero memo (cache/broker_cache.py NegativeResultCache)
         #: — independent of the whole-result cache and on by default
         from pinot_tpu.cache.broker_cache import NegativeResultCache
@@ -303,16 +304,37 @@ class BrokerRequestHandler:
         broker trace store (tail-based capture) and emit a structured
         slow-query log line even with trace=false. With
         pinot.trace.enabled=false none of this machinery exists."""
+        return self._serve(sql, encode=False)[0]
+
+    def handle_encoded(self, sql: str) -> Tuple[BrokerResponse, bytes]:
+        """handle() for the HTTP edge: the response and its JSON body. The
+        result table is encoded while the request's tree is still open,
+        under a `BrokerEncode` span (`responseBytes`: the encoded table's
+        length) that lands in the same answer's traceInfo; the envelope
+        is written round it once the tree is closed. Byte for byte
+        `json.dumps(resp.to_dict(), default=str)`; `timeUsedMs` still
+        ends after the reduce. The response comes back with the body so
+        that the caller frees its rows after the write, not before."""
+        resp, table = self._serve(sql, encode=True)
+        return resp, resp.encode(table)
+
+    def _serve(self, sql: str, encode: bool):
+        """(response, its encoded result table or None)."""
         if not self._trace_enabled:
             resp = self._handle_inner(sql)
             self._meter_response(resp)
-            return resp
+            return resp, resp.encode_table() if encode else None
         rt = tracing.RequestTrace(sampled=False)
         inflight = trace_store.get_inflight("broker")
         inflight.begin(rt.trace_id, sql=sql, trace_id=rt.trace_id)
+        table = None
         try:
             with rt:
                 resp = self._handle_inner(sql)
+                if encode:
+                    with tracing.Scope("BrokerEncode") as span:
+                        table = resp.encode_table()
+                        span.set(responseBytes=len(table))
         finally:
             inflight.end(rt.trace_id)
         self._meter_response(resp)
@@ -340,7 +362,7 @@ class BrokerRequestHandler:
                     partialResult=bool(resp.partial_result),
                     exceptions=len(resp.exceptions or []))
                 self._metrics.add_meter("slow_queries")
-        return resp
+        return resp, table
 
     def _meter_response(self, resp) -> None:
         """Per-response counters the SLO error-rate burn reads

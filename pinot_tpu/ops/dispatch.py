@@ -252,6 +252,14 @@ def phase_annotation(phase: str, span):
                                         trace_id=span.trace_id or "")
 
 
+def gc_annotation(generation: int):
+    """`jax.profiler.TraceAnnotation("pinot:gc")` round one collection
+    of the server process (tracing.GcProbe enters it at the collection's
+    start and leaves it at its stop), tagged with its generation: the
+    collector on the host plane above the device's idle gaps."""
+    return jax.profiler.TraceAnnotation("pinot:gc", generation=generation)
+
+
 def start_copy(out) -> None:
     """Queue the device->host copy of a just-launched result behind its
     kernel, as `np.asarray` on the unready array used to: the explicit

@@ -92,6 +92,9 @@ class TpuOperatorExecutor:
         metrics_labels: labels for the dispatcher's metrics (the server
         passes its instance id)."""
         device_mod.configure_compile_cache()
+        # the collector's pauses on this process's spans and, in a
+        # running profile, as `pinot:gc` annotations
+        tracing.install_gc_probe("server", dispatch_mod.gc_annotation)
         self._doc_axis = 1
         #: collective broker merge (shard_map + psum: an UNGROUPED
         #: aggregation's partials become one row) engages only on an
@@ -1087,17 +1090,13 @@ class TpuOperatorExecutor:
 
     def _chip_attrs(self, dsp) -> None:
         """A mesh engine's traced DeviceDispatch: how many devices the
-        launch spans and what each chip's allocator holds and has held
-        at most (`memory_stats()`, None where the backend keeps none),
-        read once the staging lock is released, so no other query waits
-        for it; plus the bytes moved chip to chip at block assembly
-        since start-up. One device: nothing is set."""
+        launch spans and the bytes moved chip to chip at block assembly
+        since start-up (what each chip holds is on /metrics as
+        `hbm_resident_bytes{device=}` / `hbm_cache_bytes{device=}`). One
+        device: nothing is set."""
         if len(self.devices) < 2:
             return
-        stats = [d.memory_stats() or {} for d in self.devices]
         dsp.set(meshDevices=len(self.devices),
-                chipBytesInUse=[m.get("bytes_in_use") for m in stats],
-                chipPeakBytes=[m.get("peak_bytes_in_use") for m in stats],
                 crossChipBytes=self.stager.cross_chip_bytes)
 
     def _staging_attrs(self, info, **dims) -> float:

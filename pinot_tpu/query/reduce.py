@@ -8,6 +8,7 @@ PostAggregationHandler, SelectionDataTableReducer, DistinctDataTableReducer).
 """
 from __future__ import annotations
 
+import json
 import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -60,8 +61,27 @@ class BrokerResponse:
     stale_result: bool = False
 
     def to_dict(self) -> dict:
+        return {"resultTable": (self.result_table.to_dict()
+                                if self.result_table else None),
+                **self._envelope()}
+
+    def encode_table(self) -> bytes:
+        """The result table as the HTTP body carries it (JSON; `null`
+        where there is none): the broker's encode, timed on its own."""
+        return json.dumps(self.result_table.to_dict()
+                          if self.result_table else None,
+                          default=str).encode()
+
+    def encode(self, table: bytes) -> bytes:
+        """The HTTP body round a table from encode_table(): byte for byte
+        `json.dumps(self.to_dict(), default=str)`, whose first key is
+        resultTable and whose other keys are never empty."""
+        rest = json.dumps(self._envelope(), default=str).encode()
+        return b"".join((b'{"resultTable": ', table, b", ", rest[1:]))
+
+    def _envelope(self) -> dict:
+        """Every key of to_dict() but resultTable, in order."""
         d = {
-            "resultTable": self.result_table.to_dict() if self.result_table else None,
             "exceptions": self.exceptions,
             "numServersQueried": self.num_servers_queried,
             "numServersResponded": self.num_servers_responded,

@@ -98,6 +98,7 @@ class ServerQueryExecutor:
             self._trace_enabled = True
             self._slow_threshold_ms = 0.0
             self._trace_capacity = None
+        tracing.install_gc_probe("server")
         #: latency-SLO target — queries over it bump the slo_latency_bad
         #: counter the burn-rate watchdog reads as windowed deltas
         self._slo_p99_ms = (config.get_float("pinot.slo.query.p99.ms")

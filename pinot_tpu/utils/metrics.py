@@ -14,7 +14,7 @@ import random
 import threading
 import time
 from collections import defaultdict
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 _Key = Tuple[str, Tuple[Tuple[str, str], ...]]
 
@@ -97,6 +97,20 @@ class MetricsRegistry:
         #: the stored trace at /debug/traces/<id>
         self._exemplars: Dict[_Key, str] = {}
         self._lock = threading.Lock()
+        #: called before each read of the whole registry, outside its
+        #: lock: sources that cannot write as things happen (the
+        #: collector's probe, tracing.GcProbe) hand over what they hold
+        self._feeds: List[Callable[[], None]] = []
+
+    def add_feed(self, feed: Callable[[], None]) -> None:
+        with self._lock:
+            self._feeds.append(feed)
+
+    def _pull_feeds(self) -> None:
+        with self._lock:
+            feeds = list(self._feeds)
+        for feed in feeds:
+            feed()
 
     # -- write side ---------------------------------------------------------
     def add_meter(self, name: str, value: float = 1,
@@ -192,6 +206,7 @@ class MetricsRegistry:
         identity); timers collapse to count/sum/max plus the reservoir
         quantiles. Taken under the registry lock: one sample is
         internally consistent."""
+        self._pull_feeds()
         with self._lock:
             counters = {f"{n}{_fmt(ls)}": v
                         for (n, ls), v in self._meters.items()}
@@ -219,6 +234,7 @@ class MetricsRegistry:
         `# HELP` rides beside it from the metric-name catalog
         (utils/metrics_catalog.py) for every cataloged family."""
         from pinot_tpu.utils.metrics_catalog import METRICS
+        self._pull_feeds()
         out: List[str] = []
         prefix = f"pinot_tpu_{self.role}_"
         typed: set = set()

@@ -212,6 +212,10 @@ METRICS: Dict[str, str] = {
     "cluster_instances_live": "instances the last sweep verdicted live",
     "cluster_instances_degraded":
         "instances the last sweep verdicted degraded",
+    "gc_pause_ms":
+        "pauses of the Python collector in this process (ms), by "
+        "generation (label generation=0|1|2); the same pauses are "
+        "charged to the spans they stop (gcPauseMs)",
     # -- self-healing maintenance (PR 18) ---------------------------------
     "segments_missing_replicas":
         "segments below their configured replication (label table=; "

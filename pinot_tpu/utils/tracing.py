@@ -30,16 +30,28 @@ the jax profiler's xplane all stamp with on one machine, so that one
 integer lays client, broker, server and device on one axis. Stamps
 inside a span (``launchNs``/``readyNs`` on DeviceDispatch) are the same
 clock.
+
+The collector is charged to the spans it stops: once ``install_gc_probe``
+has run in a process, a span that closes gets ``gcPauseMs`` /
+``gcCollections`` for the collections inside its interval (absent when
+there were none). A collection holds the interpreter, so every span open
+at that moment was stopped by it.
 """
 from __future__ import annotations
 
+import bisect
+import collections
 import contextlib
 import contextvars
+import gc
 import threading
 import time
 import uuid
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional
+from operator import itemgetter
+from typing import Any, Callable, Dict, List, Optional
+
+from pinot_tpu.utils.metrics import get_registry
 
 _current: contextvars.ContextVar[Optional["TraceNode"]] = \
     contextvars.ContextVar("pinot_tpu_trace", default=None)
@@ -149,8 +161,13 @@ class SpanHandle:
             if attrs:
                 self.node.attrs.update(attrs)
             if self.node.duration_ms == 0.0 and self.node.start_ms:
-                self.node.duration_ms = \
-                    time.perf_counter() * 1000.0 - self.node.start_ms
+                end_ms = time.perf_counter() * 1000.0
+                self.node.duration_ms = end_ms - self.node.start_ms
+                probe = _gc_probe
+                if probe is not None \
+                        and probe.last_stop_ms > self.node.start_ms:
+                    self.node.attrs.update(
+                        probe.attrs(self.node.start_ms, end_ms))
 
     def set(self, **attrs) -> None:
         with _tree_lock:
@@ -220,9 +237,15 @@ class Scope:
 
     def __exit__(self, *exc):
         if self._active:
-            self.node.duration_ms = \
-                time.perf_counter() * 1000.0 - self.node.start_ms
+            end_ms = time.perf_counter() * 1000.0
+            self.node.duration_ms = end_ms - self.node.start_ms
             _current.reset(self._token)
+            probe = _gc_probe
+            if probe is not None and probe.last_stop_ms > self.node.start_ms:
+                paused = probe.attrs(self.node.start_ms, end_ms)
+                if paused:
+                    with _tree_lock:
+                        self.node.attrs.update(paused)
 
 
 class RequestTrace:
@@ -251,10 +274,20 @@ class RequestTrace:
         return self
 
     def __exit__(self, *exc):
-        self.root.duration_ms = \
-            time.perf_counter() * 1000.0 - self.root.start_ms
+        root = self.root
+        end_ms = time.perf_counter() * 1000.0
+        root.duration_ms = end_ms - root.start_ms
         _current.reset(self._token)
         _request.reset(self._req_token)
+        probe = _gc_probe
+        if probe is not None:
+            # the root also says where the process's running total stood,
+            # so pauses between requests show as the difference, and lists
+            # each long pause with its wall-clock stamp
+            paused = probe.attrs(root.start_ms, end_ms, root.start_ns)
+            with _tree_lock:
+                root.attrs.update(paused, gcTotalMs=round(probe.total_ms, 3))
+            probe.feed()
 
     def handle(self) -> SpanHandle:
         return SpanHandle(self.root, self.trace_id)
@@ -313,3 +346,130 @@ def annotate(**attrs) -> None:
     if node is not None:
         with _tree_lock:
             node.attrs.update(attrs)
+
+
+# -- the collector -------------------------------------------------------------
+#: a pause at least this long is listed by itself on its request's root
+GC_LONG_MS = 10.0
+#: collections the ring keeps (cut back to this many at twice as many): a
+#: span that outlives them is charged only the newest
+_GC_RING = 4096
+
+_stop_ms = itemgetter(1)
+
+
+class GcProbe:
+    """The process's one `gc.callbacks` entry. Each collection's
+    generation, start and stop (ms on the spans' perf_counter clock) go
+    into a bounded ring that closing spans bisect, its length into the
+    running total and into `pending`, which the role's registry takes in
+    as `gc_pause_ms{generation=}` when it is read or a request closes.
+    The callback takes no lock and calls no registry: a collection can
+    start anywhere, a registry's own lock held by the same thread.
+    `annotation(generation)`, where given, is a context manager entered
+    at the start and left at the stop (the server's `pinot:gc`)."""
+
+    def __init__(self, role: str,
+                 annotation: Optional[Callable[[int], Any]] = None):
+        self.role = role
+        self.annotation = annotation
+        #: (start_ms, stop_ms, generation), oldest first; replaced when
+        #: cut, never cut in place, so a reader's reference stays whole
+        self.ring: List[tuple] = []
+        #: the newest collection's stop: a span that opened after it was
+        #: stopped by none, which is all a closing span asks most often
+        self.last_stop_ms = float("-inf")
+        self.total_ms = 0.0
+        self.pending: collections.deque = collections.deque(maxlen=_GC_RING)
+        self._start_ms = 0.0
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        now = time.perf_counter() * 1000.0
+        gen = info["generation"]
+        if phase == "start":
+            self._start_ms = now
+            if self.annotation is not None:
+                opened = self.annotation(gen)
+                opened.__enter__()
+                self._open = opened
+            return
+        opened, self._open = self._open, None
+        if opened is not None:
+            opened.__exit__(None, None, None)
+        start = self._start_ms
+        ring = self.ring
+        if len(ring) >= 2 * _GC_RING:
+            ring = self.ring = ring[-_GC_RING:]
+        ring.append((start, now, gen))
+        self.last_stop_ms = now
+        self.total_ms += now - start
+        self.pending.append((gen, now - start))
+
+    def attrs(self, start_ms: float, end_ms: float,
+              start_ns: int = 0) -> dict:
+        """`gcPauseMs` / `gcCollections` for the pauses inside [start_ms,
+        end_ms], {} where none fell there. Given the span's `start_ns` (a
+        root's), also `gcByGeneration` ([n0, n1, n2]) and, where there
+        were any, `gcLongPauses`: [generation, startNs, ms] of each pause
+        of GC_LONG_MS or more."""
+        ring = self.ring
+        paused, gens, long_pauses = 0.0, [0, 0, 0], []
+        for k in range(bisect.bisect_right(ring, start_ms, key=_stop_ms),
+                       len(ring)):
+            s, e, gen = ring[k]
+            if s >= end_ms:
+                break
+            paused += min(e, end_ms) - max(s, start_ms)
+            gens[gen] += 1
+            if start_ns and e - s >= GC_LONG_MS:
+                long_pauses.append(
+                    [gen, start_ns + int((s - start_ms) * 1e6),
+                     round(e - s, 3)])
+        n = sum(gens)
+        if not n:
+            return {}
+        out = {"gcPauseMs": round(paused, 3), "gcCollections": n}
+        if start_ns:
+            out["gcByGeneration"] = gens
+        if long_pauses:
+            out["gcLongPauses"] = long_pauses
+        return out
+
+    def feed(self) -> None:
+        """Hand the pauses since the last feed to the role's registry."""
+        pending = self.pending
+        if not pending:
+            return
+        reg = get_registry(self.role)
+        while True:
+            try:
+                gen, ms = pending.popleft()
+            except IndexError:
+                return
+            reg.add_timing("gc_pause_ms", ms,
+                           labels={"generation": str(gen)})
+
+
+_gc_probe: Optional[GcProbe] = None
+_gc_install_lock = threading.Lock()
+
+
+def install_gc_probe(role: str,
+                     annotation: Optional[Callable[[int], Any]] = None
+                     ) -> GcProbe:
+    """The process's one collector probe, installed on the first call and
+    returned by every later one, which registers nothing more: the first
+    role's registry keeps it, and an annotation given later is taken
+    where there was none."""
+    global _gc_probe
+    with _gc_install_lock:
+        probe = _gc_probe
+        if probe is None:
+            probe = GcProbe(role, annotation)
+            get_registry(role).add_feed(probe.feed)
+            gc.callbacks.append(probe)
+            _gc_probe = probe
+        elif annotation is not None and probe.annotation is None:
+            probe.annotation = annotation
+        return probe

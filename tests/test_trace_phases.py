@@ -425,9 +425,13 @@ def test_untraced_query_makes_no_span_stamp_or_annotation(
             n: getattr(time, n) for n in dir(time) if not n.startswith("_")})
         clock.time_ns = counted(f"{mod.__name__}.time_ns", time.time_ns)
         monkeypatch.setattr(mod, "time", clock)
-    monkeypatch.setattr(
-        jax.profiler, "TraceAnnotation",
-        counted("TraceAnnotation", jax.profiler.TraceAnnotation))
+    trace_annotation = jax.profiler.TraceAnnotation
+
+    def annotation(name, **kw):
+        if name != "pinot:gc":  # the collector's pauses, not the query's
+            calls.append("TraceAnnotation")
+        return trace_annotation(name, **kw)
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", annotation)
     monkeypatch.setattr(tracing.TraceNode, "__init__", counted(
         "TraceNode", tracing.TraceNode.__init__))
     engine = TpuOperatorExecutor()
@@ -469,6 +473,7 @@ def test_phase_annotations_are_tagged_with_the_trace_id(scan_segs,
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorded)
     tree = _traced(scan_segs, TpuOperatorExecutor(),
                    SCAN_LEGS["agg"].format(lit=9))
+    seen = [(name, kw) for name, kw in seen if name != "pinot:gc"]
     assert [name for name, _kw in seen] == [
         "pinot:lock_wait", "pinot:staging", "pinot:launch",
         "pinot:device_wait", "pinot:d2h"]
@@ -577,7 +582,7 @@ def annotation_offsets_ns(segs, profile_dir, queries: int = 12) -> dict:
     for plane in ProfileData.from_file(found).planes:
         for line in plane.lines:
             for ev in line.events:
-                if not ev.name.startswith("pinot:"):
+                if not ev.name.startswith("pinot:") or ev.name == "pinot:gc":
                     continue
                 trace_id = {k: v for k, v in ev.stats}.get("trace_id")
                 offsets.setdefault(ev.name, [])
