@@ -323,7 +323,7 @@ class BrokerRequestHandler:
         if not self._trace_enabled:
             resp = self._handle_inner(sql)
             self._meter_response(resp)
-            return resp, resp.encode_table() if encode else None
+            return resp, self._encode_table(resp) if encode else None
         rt = tracing.RequestTrace(sampled=False)
         inflight = trace_store.get_inflight("broker")
         inflight.begin(rt.trace_id, sql=sql, trace_id=rt.trace_id)
@@ -333,8 +333,9 @@ class BrokerRequestHandler:
                 resp = self._handle_inner(sql)
                 if encode:
                     with tracing.Scope("BrokerEncode") as span:
-                        table = resp.encode_table()
-                        span.set(responseBytes=len(table))
+                        table = self._encode_table(resp)
+                        span.set(responseBytes=len(table),
+                                 encodePath=resp.encode_path)
         finally:
             inflight.end(rt.trace_id)
         self._meter_response(resp)
@@ -363,6 +364,12 @@ class BrokerRequestHandler:
                     exceptions=len(resp.exceptions or []))
                 self._metrics.add_meter("slow_queries")
         return resp, table
+
+    def _encode_table(self, resp: BrokerResponse) -> bytes:
+        """resp.encode_table(), metered `broker_encode{path=}`."""
+        self._metrics.add_meter("broker_encode",
+                                labels={"path": resp.encode_path})
+        return resp.encode_table()
 
     def _meter_response(self, resp) -> None:
         """Per-response counters the SLO error-rate burn reads

@@ -24,16 +24,84 @@ from pinot_tpu.query.results import (
 from pinot_tpu.utils import tracing
 
 
-@dataclass
 class ResultTable:
-    columns: List[str]
-    column_types: List[str]
-    rows: List[Tuple]
+    """The answer's table, built from its rows or, by the GROUP BY
+    reduce's columns path (`held`), from its output columns and `kept`,
+    the indices of the rows kept in order. Such a table makes its
+    rows, Python tuples, the first time `rows` is read, and keeps them;
+    `to_json` writes its JSON from the columns."""
+
+    def __init__(self, columns: List[str], column_types: List[str],
+                 rows: Optional[List[Tuple]]):
+        self.columns = columns
+        self.column_types = column_types
+        self._rows = rows
+        self.data: Optional[List[Any]] = None  # columns where held
+        self.kept: Optional[np.ndarray] = None
+
+    @classmethod
+    def held(cls, columns: List[str], column_types: List[str],
+             data: List[Any], kept: np.ndarray) -> "ResultTable":
+        table = cls(columns, column_types, None)
+        table.data, table.kept = data, kept
+        return table
+
+    @property
+    def rows(self) -> List[Tuple]:
+        if self._rows is None:
+            self._rows = list(zip(*(_take(c, self.kept) for c in self.data)))
+        return self._rows
+
+    def __repr__(self) -> str:
+        return (f"ResultTable(columns={self.columns!r}, "
+                f"column_types={self.column_types!r}, rows={self.rows!r})")
 
     def to_dict(self) -> dict:
         return {"dataSchema": {"columnNames": self.columns,
                                "columnDataTypes": self.column_types},
                 "rows": [list(r) for r in self.rows]}
+
+    def to_json(self) -> str:
+        """`json.dumps(self.to_dict(), default=str)`, byte for byte; a
+        table held as columns writes its rows without making them."""
+        if self.data is None:
+            return json.dumps(self.to_dict(), default=str)
+        schema = {"columnNames": self.columns,
+                  "columnDataTypes": self.column_types}
+        head = _to_json({"dataSchema": schema, "rows": []})
+        return head[:-len("[]}")] + self.rows_json() + "}"
+
+    def rows_json(self) -> str:
+        """The rows of a table held as columns as `to_json` writes them,
+        made a column at a time: each column's kept rows become JSON
+        texts (a numeric column in one `json.dumps`, a coded one a
+        distinct value once), joined row by row as strings, so no
+        container is made a row."""
+        if not len(self.kept):
+            return "[]"
+        texts = [_json_texts(c, self.kept) for c in self.data]
+        return "[[" + "], [".join(map(", ".join, zip(*texts))) + "]]"
+
+
+#: `json.dumps(obj, default=str)` without making an encoder a call
+_to_json = json.JSONEncoder(default=str).encode
+
+
+def _json_texts(col, idx: np.ndarray) -> List[str]:
+    """The JSON text of each of a column's rows at `idx`, as
+    `json.dumps(..., default=str)` writes each value of `_take`."""
+    if isinstance(col, CodedColumn):
+        # only the values some kept row holds, each encoded once
+        ids = col.ids[idx]
+        counts = np.bincount(ids)
+        used = np.flatnonzero(counts)
+        table = np.empty(len(counts), object)
+        table[used] = _json_texts(col.values, used)
+        return table[ids].tolist()
+    if isinstance(col, np.ndarray) and col.dtype.kind in "biuf":
+        # no number, NaN, Infinity, true or false holds the separator
+        return _to_json(col[idx].tolist())[1:-1].split(", ")
+    return list(map(_to_json, _take(col, idx)))
 
 
 @dataclass
@@ -65,12 +133,18 @@ class BrokerResponse:
                                 if self.result_table else None),
                 **self._envelope()}
 
+    @property
+    def encode_path(self) -> str:
+        """`columns` where encode_table() writes a table held as
+        columns, `rows` where it dumps to_dict()."""
+        t = self.result_table
+        return "rows" if t is None or t.data is None else "columns"
+
     def encode_table(self) -> bytes:
         """The result table as the HTTP body carries it (JSON; `null`
         where there is none): the broker's encode, timed on its own."""
-        return json.dumps(self.result_table.to_dict()
-                          if self.result_table else None,
-                          default=str).encode()
+        return (self.result_table.to_json() if self.result_table
+                else "null").encode()
 
     def encode(self, table: bytes) -> bytes:
         """The HTTP body round a table from encode_table(): byte for byte
@@ -211,28 +285,30 @@ def _reduce_group_by(ctx: QueryContext, results: List[GroupByResult],
     # a row is picked by position; anything computed takes the bindings
     direct = _direct_columns(ctx)
     held = [r for r in results if r.num_rows]
-    rows = None
+    table = None
     if direct is not None and "gapfillTimeCol" not in ctx.options \
             and len(held) == 1 and held[0].key_columns is not None:
-        rows = _columnar_rows(ctx, held[0], direct)
-    path = "rows" if rows is None else "columns"
+        table = _columnar_table(ctx, held[0], direct, names, types)
+    path = "rows" if table is None else "columns"
     tracing.annotate(reducePath=path,
                      reduceRows=sum(r.num_rows for r in results))
     if metrics is not None:
         metrics.add_meter("broker_reduce", labels={"path": path})
-    if rows is None:
-        rows = _merged_rows(ctx, results, direct, names, types)
-    return ResultTable(names, types, rows)
+    if table is None:
+        table = ResultTable(names, types,
+                            _merged_rows(ctx, results, direct, names, types))
+    return table
 
 
-def _columnar_rows(ctx: QueryContext, r: GroupByResult,
-                   direct) -> Optional[List[Tuple]]:
-    """The answer's rows from ONE result held as columns, a whole column
-    at a time: finals by `final_column`, ORDER BY as one stable lexsort
-    over ranks, OFFSET/LIMIT sliced from the permutation, and Python
-    values made only for the rows kept. The same rows, value for value,
-    type for type and in order, as `_merged_rows` gives; None where an
-    ORDER BY column has no rank in Python's own order."""
+def _columnar_table(ctx: QueryContext, r: GroupByResult, direct,
+                    names: List[str], types: List[str]
+                    ) -> Optional[ResultTable]:
+    """The answer from ONE result held as columns, a whole column at a
+    time: finals by `final_column`, ORDER BY as one stable lexsort over
+    ranks, OFFSET/LIMIT sliced from the permutation; a table held as
+    the output columns and the rows kept, whose rows are, value for
+    value, type for type and in order, those `_merged_rows` gives. None
+    where an ORDER BY column has no rank in Python's own order."""
     select, order = direct
     finals = [fn.final_column(col)
               for fn, col in zip(ctx.agg_functions, r.value_columns)]
@@ -247,8 +323,8 @@ def _columnar_rows(ctx: QueryContext, r: GroupByResult,
         # sort of the rows path keeps them
         keys.append(rank if asc else -rank)
     perm = np.lexsort(keys[::-1]) if keys else np.arange(r.num_rows)
-    kept = perm[ctx.offset:ctx.offset + ctx.limit]
-    return list(zip(*(_take(c, kept) for c in out)))
+    return ResultTable.held(names, types, out,
+                            perm[ctx.offset:ctx.offset + ctx.limit])
 
 
 def _rank(col) -> Optional[np.ndarray]:

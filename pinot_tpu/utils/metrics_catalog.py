@@ -38,6 +38,10 @@ METRICS: Dict[str, str] = {
         "GROUP BY answers reduced, by path (label path=columns|rows: one "
         "result held as columns, sorted and sliced a whole column at a "
         "time, or the dict merge a group at a time)",
+    "broker_encode":
+        "result tables encoded for the HTTP body, by path (label "
+        "path=columns|rows: a table held as columns written a column at a "
+        "time, or its rows dumped)",
     # -- server query path ------------------------------------------------
     "queries": "queries executed by this server",
     "queries_killed": "queries stopped by deadline/cancel",

@@ -1,5 +1,5 @@
 """ISSUE 38: the broker finishes ONE grouped result held as columns a whole
-column at a time (`reduce._columnar_rows`), and gives the rows the dict
+column at a time (`reduce._columnar_table`), and gives the rows the dict
 path gives on the same content, value for value, Python type for type
 and in the same order. Every case reduces the columnar result and a
 `GroupByResult(groups)` with the same groups, compares their rows by

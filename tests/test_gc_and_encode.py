@@ -10,11 +10,13 @@ import json
 import time
 import urllib.request
 
+import numpy as np
 import pytest
 
 from pinot_tpu.cluster.mini import MiniCluster
 from pinot_tpu.ops import dispatch
 from pinot_tpu.query.reduce import BrokerResponse, ResultTable
+from pinot_tpu.query.results import CodedColumn
 from pinot_tpu.utils import tracing
 from pinot_tpu.utils.metrics import get_registry
 from tests.queries.harness import (
@@ -178,12 +180,30 @@ def _post(cluster, sql: str) -> bytes:
         return r.read()
 
 
+NAMES = ["ts_hour", "hostname", "sum(usage_user)", "count(*)"]
+TYPES = ["LONG", "STRING", "DOUBLE", "LONG"]
+
+
 def _grouped(rows):
-    return BrokerResponse(
-        result_table=ResultTable(
-            ["ts_hour", "hostname", "sum(usage_user)", "count(*)"],
-            ["LONG", "STRING", "DOUBLE", "LONG"], rows),
-        time_used_ms=12.5, num_servers_queried=1, num_servers_responded=1)
+    return _answer(ResultTable(NAMES, TYPES, rows))
+
+
+def _answer(table):
+    return BrokerResponse(result_table=table, time_used_ms=12.5,
+                          num_servers_queried=1, num_servers_responded=1)
+
+
+def _held(n: int):
+    """`tsbs_dgb1_c1`'s answer as the columns path holds it: n of
+    48,000 rows (hour, a host of 4,000, a sum, a count), in a shuffled
+    order."""
+    i = np.arange(48_000)
+    hosts = CodedColumn([f"host_{h}" for h in range(4000)],
+                        (i % 4000).astype(np.int16))
+    kept = np.random.default_rng(5).permutation(48_000)[:n]
+    return _answer(ResultTable.held(
+        NAMES, TYPES, [458_000 + i // 4000, hosts, (i % 101) * 360.5,
+                       np.full(48_000, 360)], kept))
 
 
 ANSWERS = {
@@ -193,6 +213,8 @@ ANSWERS = {
     "48000_rows": lambda: _grouped(
         [(458_000 + i // 4000, f"host_{i % 4000}", float(i % 101) * 360.5,
           360) for i in range(48_000)]),
+    "held_zero_rows": lambda: _held(0),
+    "held_48000_rows": lambda: _held(48_000),
 }
 GROUP_SQL = ("SELECT groupCol, SUM(intCol) FROM testTable GROUP BY groupCol "
              "ORDER BY groupCol LIMIT 100")
@@ -211,9 +233,20 @@ def test_the_http_body_is_the_dict_dumped_byte_for_byte(
     body = _post(http_cluster, GROUP_SQL)
     assert resp.trace is None
     assert body == json.dumps(resp.to_dict(), default=str).encode()
+    assert resp.encode_path == ("columns" if answer.startswith("held")
+                                else "rows")
 
 
-def test_a_traced_http_answer_names_its_encode(http_cluster, monkeypatch):
+ENCODED = {"columns": GROUP_SQL,
+           "rows": "SELECT COUNT(*), SUM(intCol) FROM testTable"}
+
+
+@pytest.mark.parametrize("path", sorted(ENCODED))
+def test_a_traced_http_answer_names_its_encode(http_cluster, monkeypatch,
+                                               path):
+    """The span times whichever encoder the table's form takes: a GROUP
+    BY's table held as columns writes `rows_json`, an aggregation's
+    rows-built table dumps `to_dict`."""
     sent = []
     encode = BrokerResponse.encode
 
@@ -221,13 +254,16 @@ def test_a_traced_http_answer_names_its_encode(http_cluster, monkeypatch):
         sent.append((self, table))
         return encode(self, table)
     monkeypatch.setattr(BrokerResponse, "encode", spy)
-    to_dict = ResultTable.to_dict
+    encoder = {"columns": "rows_json", "rows": "to_dict"}[path]
+    fast = getattr(ResultTable, encoder)
 
-    def slow_to_dict(self):
+    def slow(self):
         time.sleep(0.2)
-        return to_dict(self)
-    monkeypatch.setattr(ResultTable, "to_dict", slow_to_dict)
-    body = _post(http_cluster, "SET trace = true; " + GROUP_SQL)
+        return fast(self)
+    monkeypatch.setattr(ResultTable, encoder, slow)
+    meters = http_cluster.broker._metrics
+    before = meters.meter("broker_encode", labels={"path": path})
+    body = _post(http_cluster, "SET trace = true; " + ENCODED[path])
     (resp, table), = sent
     assert body == json.dumps(resp.to_dict(), default=str).encode()
     answer = json.loads(body)
@@ -235,6 +271,8 @@ def test_a_traced_http_answer_names_its_encode(http_cluster, monkeypatch):
     tree = answer["traceInfo"]
     enc, = _spans(tree, "BrokerEncode")
     assert enc in tree["children"]
+    assert enc["encodePath"] == resp.encode_path == path
+    assert meters.meter("broker_encode", labels={"path": path}) == before + 1
     assert enc["responseBytes"] == len(table) == len(
         json.dumps(resp.result_table.to_dict(), default=str).encode())
     # timeUsedMs still ends after the reduce: the encode lies after it,
