@@ -734,8 +734,8 @@ class KernelDispatcher:
                     clock.ready()
                     with phase_annotation("d2h", span):
                         packed = np.asarray(out)
+                    kernel_ms = (time.monotonic() - t0) * 1e3
                     clock.copied([launch])
-                kernel_ms = (time.monotonic() - t0) * 1e3
             finally:
                 self._busy_end()
                 self._meter_traces()
@@ -1053,9 +1053,9 @@ class KernelDispatcher:
                 it.future.set_exception(e)
             self._meter_traces()
             return
+        kernel_ms = (time.monotonic() - t0) * 1e3
         clock.launched()
-        self._finish(live, out, batched, (time.monotonic() - t0) * 1e3,
-                     clock)
+        self._finish(live, out, batched, kernel_ms, clock)
 
     def _finish(self, live: List[Launch], out, batched: bool,
                 kernel_ms: float, clock) -> None:
@@ -1066,6 +1066,11 @@ class KernelDispatcher:
         as they do kernelMs (ring path: the launch call, plus the wait
         on the collective path, where d2hMs also holds the hand-off
         to the fetch pool) and fetchMs (the rest: device wait + copy).
+        Each of the two ends on a clock read taken just BEFORE the
+        clock's own (`launched`, `copied`), so kernelMs + fetchMs never
+        exceed launchMs + deviceWaitMs + d2hMs, however long the thread
+        waits between the reads; the busy bookkeeping after the copy is
+        in neither.
         The busy interval (opened at launch) closes when the
         fetch lands — and BEFORE the futures resolve: a caller woken by
         its result must observe an idle dispatcher, or its next lone
@@ -1081,6 +1086,7 @@ class KernelDispatcher:
                 clock.ready()
             with phase_annotation("d2h", span):
                 arr = np.asarray(out)
+            fetch_ms = (time.monotonic() - t0) * 1e3
             clock.copied(live)
         except BaseException as e:  # noqa: BLE001
             self._busy_end()
@@ -1091,7 +1097,6 @@ class KernelDispatcher:
             return
         self._busy_end()
         self._meter_traces()
-        fetch_ms = (time.monotonic() - t0) * 1e3
         for it in live:
             if it.span is not None:
                 it.span.set(kernelMs=round(kernel_ms, 3),

@@ -431,15 +431,19 @@ class TpuOperatorExecutor:
         if num_groups:
             # which way the additive slots of this GROUP BY run: the
             # kernel builder's own decision, asked with one shard's shapes
+            shard_docs = D // self._doc_axis
             path = kernels.group_path(
-                num_groups, D // self._doc_axis, kernels._value_dtype(),
+                num_groups, shard_docs, kernels._value_dtype(),
                 finite=not plan.nonfinite)
             fold = "device" if plan.group_fold else "host"
+            scattered = kernels.scatter_rows(plan, num_groups, S, D,
+                                             shard_docs)
             self._meter("group_path", path=path)
             self._meter("group_fold", where=fold)
+            self._meter("scatter_rows", scattered)
             if dsp is not None:
                 dsp.set(groupPath=path, groupKeySpace=num_groups,
-                        groupFold=fold)
+                        groupFold=fold, scatterRows=scattered)
             if self._mesh is not None and batchable:
                 exchanged, gathered = self._mesh_exchange(
                     kernel, S, D, G, cols, params)

@@ -377,6 +377,25 @@ def group_path(num_groups: int, docs: int, dtype, finite: bool) -> str:
     return "scatter"
 
 
+def scatter_rows(plan: DevicePlan, num_groups: int, S: int, D: int,
+                 docs: int) -> int:
+    """Rows x additive slots a grouped launch over [S, D] hands to XLA's
+    scatter-add, from static shapes alone (padding included), routed as
+    `_compute_slots` and `_scatter_sum` route them: none where
+    `group_path` sends every additive slot to `onehot2`; else each
+    slot's contributions reach `_scatter_sum` in the value dtype, so a
+    whole [S, D] a slot on `scatter` and, on `onehot`, the docs past its
+    last whole chunk. docs: one shard's, as `group_path` is asked."""
+    dt = _value_dtype()
+    if group_path(num_groups, docs, dt, finite=not plan.nonfinite) \
+            == "onehot2":
+        return 0
+    slots = sum(1 for op, _v, _f in plan.agg_ops if op in _ADDITIVE)
+    if group_path(num_groups, docs, dt, finite=False) == "onehot":
+        return S * (D // docs) * (docs % _ONEHOT_CHUNK) * slots
+    return S * D * slots
+
+
 def group_fold(plan: DevicePlan, out_groups: int = 0, remap_bytes: int = 0,
                max_groups: int = 0, max_remap_bytes: int = 0) -> str:
     """Where a GROUP BY's per-segment partials become one result —

@@ -93,6 +93,10 @@ METRICS: Dict[str, str] = {
     "group_result_bytes":
         "bytes of group table fetched from the device ([G, slots] a "
         "folded query, [S, G, slots] where the host folds)",
+    "scatter_rows":
+        "rows x additive slots device GROUP BYs handed to XLA's scatter-add "
+        "(static shapes, padding included; 0 a launch whose slots all run "
+        "one-hot: kernels.scatter_rows)",
     "mesh_exchange_bytes":
         "bytes a chip handed to the collectives of grouped programs on a "
         "server of several chips (the fold's all-reduces; read once a "

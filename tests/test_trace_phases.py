@@ -171,6 +171,31 @@ def test_inline_dispatch_carries_every_wait(legs, leg):
 def test_ring_dispatch_carries_every_wait(legs, leg):
     """Four clients at once: launches ride the ring (single or
     coalesced), and each member's own span gets the batch's values."""
+    ring = _ring_dispatches(legs, leg)
+    assert all(d["batchSize"] >= 1 for d in ring)
+
+
+def test_the_ring_s_bookkeeping_after_the_copy_is_no_fetch_time(
+        legs, monkeypatch):
+    """The fetch ends where the copy ends: the busy bookkeeping after it
+    (the ring's lock, contended under load) is in neither `kernelMs` nor
+    `fetchMs`, so their sum stays within launch + device wait + copy
+    however long that lock takes."""
+    busy_end = dispatch_mod.KernelDispatcher._busy_end
+
+    def slow_busy_end(self):
+        time.sleep(0.002)
+        busy_end(self)
+    monkeypatch.setattr(dispatch_mod.KernelDispatcher, "_busy_end",
+                        slow_busy_end)
+    for d in _ring_dispatches(legs, "topn"):
+        assert d["kernelMs"] + d["fetchMs"] <= \
+            d["launchMs"] + d["deviceWaitMs"] + d["d2hMs"] + 0.005, d
+
+
+def _ring_dispatches(legs, leg) -> list:
+    """The served spans of the first round of four concurrent clients
+    that reached the ring, each checked by `_check_dispatch`."""
     engine = TpuOperatorExecutor()
     segs, sql = _sql(legs, leg, 1)
     _traced(segs, engine, sql)  # warm
@@ -202,7 +227,7 @@ def test_ring_dispatch_carries_every_wait(legs, leg):
         if ring:
             break
     assert ring, "four concurrent clients never reached the ring"
-    assert all(d["batchSize"] >= 1 for d in ring)
+    return ring
 
 
 def test_a_launch_that_traced_a_kernel_says_so(scan_segs):
