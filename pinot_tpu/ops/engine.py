@@ -420,6 +420,7 @@ class TpuOperatorExecutor:
             slip.add(transfer_bytes=info.xfer_bytes)
         self._meter("scan_served")
         num_groups = plan.num_groups or G
+        scatter = None
         merged = minfo is not None and not plan.group_fold
         if merged:
             self._meter("mesh_merge_served")
@@ -436,14 +437,14 @@ class TpuOperatorExecutor:
                 num_groups, shard_docs, kernels._value_dtype(),
                 finite=not plan.nonfinite)
             fold = "device" if plan.group_fold else "host"
-            scattered = kernels.scatter_rows(plan, num_groups, S, D,
-                                             shard_docs)
+            # what reaches XLA's scatter-add follows from the rows the
+            # segments keep, which the fetched result carries
+            scatter = (plan, num_groups, S, D, shard_docs, path)
             self._meter("group_path", path=path)
             self._meter("group_fold", where=fold)
-            self._meter("scatter_rows", scattered)
             if dsp is not None:
                 dsp.set(groupPath=path, groupKeySpace=num_groups,
-                        groupFold=fold, scatterRows=scattered)
+                        groupFold=fold)
             if self._mesh is not None and batchable:
                 exchanged, gathered = self._mesh_exchange(
                     kernel, S, D, G, cols, params)
@@ -468,7 +469,7 @@ class TpuOperatorExecutor:
             site_ctx={"table": ctx.table, "mode": "agg"}, span=dsp,
             slip=slip, docs=sum(s.num_docs for s in segments),
             staged_ts=staged_ts)
-        return plan, slots_of_fn, S_real, launch, minfo
+        return plan, slots_of_fn, S_real, launch, minfo, scatter
 
     def _plain_kernels(self, plan: DevicePlan):
         """(kernel, batched factory, dedup factory) of a plan on the
@@ -1194,16 +1195,17 @@ class TpuOperatorExecutor:
                                  parent_span=parent_span, slip=slip)
         if prep is None:
             return None
-        plan, slots_of_fn, S_real, launch, minfo = prep
+        plan, slots_of_fn, S_real, launch, minfo, scatter = prep
         if plan.group_fold:
             return launch, lambda packed: self._assemble_folded(
                 segments, ctx, plan, packed, S_real, slots_of_fn, minfo,
-                launch.span)
+                launch.span, scatter)
         if minfo is not None:
             return launch, lambda packed: self._assemble_merged(
                 segments, ctx, plan, packed, S_real, slots_of_fn, minfo)
         return launch, lambda packed: self._assemble(
-            segments, ctx, plan, packed, S_real, slots_of_fn, launch.span)
+            segments, ctx, plan, packed, S_real, slots_of_fn, launch.span,
+            scatter)
 
     @staticmethod
     def _note_assemble(launch: Launch, t0: float, parent=None) -> None:
@@ -2339,7 +2341,7 @@ class TpuOperatorExecutor:
 
     def _meter(self, name: str, value: float = 1, **labels: str) -> None:
         """labels: the `reason=` of a `*_fallback` meter, the `path=` of
-        `group_path`."""
+        `group_path`, the `cap=` of `scatter_compact`."""
         if self._metrics is None:
             return
         if labels:
@@ -2558,7 +2560,8 @@ class TpuOperatorExecutor:
     # ------------------------------------------------------------------
     def _assemble(self, segments, ctx: QueryContext, plan: DevicePlan,
                   packed: np.ndarray, S_real: int,
-                  mappings: List[Dict[str, int]], span=None) -> List[Any]:
+                  mappings: List[Dict[str, int]], span=None,
+                  scatter=None) -> List[Any]:
         t0 = time.perf_counter()
         filter_cols = len(set(ctx.filter_columns()))
         # parity with executor_cpu: COUNT(*) materializes no column, so it
@@ -2585,9 +2588,11 @@ class TpuOperatorExecutor:
                     break
             assert count_j is not None  # _plan guarantees a count slot
         results = []
+        kept = []
         for s, seg in enumerate(segments[:S_real]):
             if is_group:
                 matched = int(round(float(packed[s, :, count_j].sum())))
+                kept.append(matched)
             else:
                 matched = int(round(float(packed[s, 0])))
             stats = ExecutionStats(
@@ -2627,7 +2632,31 @@ class TpuOperatorExecutor:
         if is_group:
             self._note_groups(span, packed.nbytes,
                               sum(len(r.groups) for r in results), t0)
+            self._note_scatter(span, scatter, kept)
         return results
+
+    def _note_scatter(self, span, scatter, kept: List[int]) -> None:
+        """A grouped launch's scatter work, once its result is here: the
+        rung the kernel chose (`kernels.compact_cap` on the most rows a
+        segment kept, from the counts the result carries; a coalesced
+        member reads its own, where the launch ran the batch's largest)
+        and the rows x additive slots it handed XLA's scatter-add
+        (`kernels.scatter_rows`). Meters `scatter_rows` and, on
+        `scatter`, `scatter_compact{cap=}`; span `scatterRows` and
+        `scatterCap` (0: the full scatter)."""
+        if scatter is None:
+            return
+        plan, num_groups, S, D, docs, path = scatter
+        cap = kernels.compact_cap(docs, max(kept, default=0)) \
+            if path == "scatter" else 0
+        rows = kernels.scatter_rows(plan, num_groups, S, D, docs, cap)
+        self._meter("scatter_rows", rows)
+        if path == "scatter":
+            self._meter("scatter_compact", cap=str(cap))
+        if span is not None:
+            span.set(scatterRows=rows)
+            if path == "scatter":
+                span.set(scatterCap=cap)
 
     def _note_groups(self, span, nbytes: int, present: int,
                      t0: float) -> None:
@@ -2756,7 +2785,7 @@ class TpuOperatorExecutor:
     def _assemble_folded(self, segments, ctx: QueryContext,
                          plan: DevicePlan, packed: np.ndarray, S_real: int,
                          mappings: List[Dict[str, int]], minfo,
-                         span=None) -> List[Any]:
+                         span=None, scatter=None) -> List[Any]:
         """ONE GroupByResult for the whole segment batch, from the
         integer row `kernels.fold_groups` packed: the [n_slots, G] group
         table over the global key space, then each segment's matched
@@ -2769,9 +2798,8 @@ class TpuOperatorExecutor:
         G, n_slots = minfo["G"], len(plan.agg_ops)
         row = np.asarray(packed)
         table = row[:G * n_slots].reshape(n_slots, G)
-        stats = self._batch_stats(
-            segments, ctx, S_real,
-            row[G * n_slots:][:S_real].tolist())
+        kept = row[G * n_slots:][:S_real].tolist()
+        stats = self._batch_stats(segments, ctx, S_real, kept)
         present = np.flatnonzero(table[plan.agg_ops.index(
             ("count", None, None))] > 0)  # _plan guarantees the slot
         decode = minfo["decode"]
@@ -2793,6 +2821,7 @@ class TpuOperatorExecutor:
                 {op: slot_cols[j] for op, j in mapping.items()})
             for fn, mapping in zip(ctx.agg_functions, mappings)]
         self._note_groups(span, table.nbytes, len(present), t0)
+        self._note_scatter(span, scatter, kept)
         return [GroupByResult(stats=stats, key_columns=key_columns,
                               value_columns=value_columns)]
 

@@ -48,12 +48,27 @@ _ONEHOT_CHUNK = 4096
 # its 52.3 ms at G = 7,000 in the kernel (about 90% of the MXU's bf16 peak),
 # and crosses the scatter near 530,000. The bound sits where it still
 # wins 2x, with room for a plan of more planes: its work grows with P,
-# the scatter's with slots.
+# the scatter's with slots. The sweep kept every row: the scatter hands
+# XLA only the rows the filter keeps (`_compacting_slots`), so its cost
+# is the kept rows' and the crossing near 530,000 holds for an
+# unfiltered GROUP BY alone; a selective one leaves the scatter cheaper
+# well below the bound, which stays where the one-hot pass is sure.
 ONEHOT2_MAX_GROUPS = 1 << 18
 _ONEHOT2_LANES = 128
 _ONEHOT2_CHUNK = 8192          # docs a segment under which: scatter
 _ONEHOT2_TILE_ELEMS = 1 << 21  # left-operand elements of one matmul
 _ONEHOT2_VMEM_BYTES = 64 << 20
+
+# a `scatter` GROUP BY hands XLA's scatter-add each segment's first `cap`
+# kept rows, cap the smallest rung that holds the launch's largest kept
+# count (`compact_rung`), where a shard has COMPACT_MIN_DOCS docs or more:
+# under it the whole scatter is a few ms and the ladder's three more
+# compiled branches are not worth their compile
+COMPACT_MIN_DOCS = 1 << 16
+_COMPACT_SHIFTS = (9, 6, 3)   # the rungs: D / 512, D / 64, D / 8
+_COMPACT_LANES = 128          # rows a tile of the count tree
+_COMPACT_WALK_ELEMS = 1 << 25  # [S, slots, lanes] elements a walk step
+_KEY = "key"                  # the group key among the compacted columns
 
 # ---------------------------------------------------------------------------
 # trace (recompile) accounting: kernel bodies run at TRACE time only, so a
@@ -378,14 +393,15 @@ def group_path(num_groups: int, docs: int, dtype, finite: bool) -> str:
 
 
 def scatter_rows(plan: DevicePlan, num_groups: int, S: int, D: int,
-                 docs: int) -> int:
+                 docs: int, cap: int = 0) -> int:
     """Rows x additive slots a grouped launch over [S, D] hands to XLA's
-    scatter-add, from static shapes alone (padding included), routed as
-    `_compute_slots` and `_scatter_sum` route them: none where
-    `group_path` sends every additive slot to `onehot2`; else each
-    slot's contributions reach `_scatter_sum` in the value dtype, so a
-    whole [S, D] a slot on `scatter` and, on `onehot`, the docs past its
-    last whole chunk. docs: one shard's, as `group_path` is asked."""
+    scatter-add (padding included), routed as `_compute_slots` and
+    `_scatter_sum` route them: none where `group_path` sends every
+    additive slot to `onehot2`; on `onehot` the docs past its last whole
+    chunk; on `scatter` each shard's `cap` compacted rows a segment, or
+    its whole docs where cap is 0 (the full scatter). docs: one shard's,
+    as `group_path` is asked; cap: the rung the launch ran, which the
+    device chose from the kept counts (`compact_cap`)."""
     dt = _value_dtype()
     if group_path(num_groups, docs, dt, finite=not plan.nonfinite) \
             == "onehot2":
@@ -393,7 +409,45 @@ def scatter_rows(plan: DevicePlan, num_groups: int, S: int, D: int,
     slots = sum(1 for op, _v, _f in plan.agg_ops if op in _ADDITIVE)
     if group_path(num_groups, docs, dt, finite=False) == "onehot":
         return S * (D // docs) * (docs % _ONEHOT_CHUNK) * slots
-    return S * D * slots
+    return S * (D // docs) * (cap or docs) * slots
+
+
+def compacts(plan: DevicePlan, num_groups: int, docs: int) -> bool:
+    """Whether a grouped launch whose shards hold `docs` docs a segment
+    compacts its kept rows before the scatter: its path is `scatter`
+    (`group_path`, the one place that decides) and the shard has rungs."""
+    return bool(num_groups) and bool(compact_rungs(docs)) and group_path(
+        num_groups, docs, _value_dtype(),
+        finite=not plan.nonfinite) == "scatter"
+
+
+def compact_rungs(docs: int) -> Tuple[int, ...]:
+    """The capacities a segment's kept rows are compacted to, smallest
+    first: () where a shard of `docs` docs does not compact (under
+    COMPACT_MIN_DOCS, or not the power of two every doc bucket is)."""
+    if docs < COMPACT_MIN_DOCS or docs & (docs - 1):
+        return ()
+    return tuple(docs >> s for s in _COMPACT_SHIFTS)
+
+
+def compact_rung(docs: int, most_kept):
+    """The index into `compact_rungs(docs)` of the smallest rung that
+    holds `most_kept` rows, len(rungs) past the top one (the full
+    scatter). The ONE function that chooses: the kernel calls it on a
+    traced count (its `lax.switch` index), the engine on the counts the
+    fetched result carries (`compact_cap`)."""
+    rung = 0
+    for cap in compact_rungs(docs):
+        rung = rung + (most_kept > cap)
+    return rung
+
+
+def compact_cap(docs: int, most_kept: int) -> int:
+    """The rows a segment a launch handed the scatter: the rung
+    `compact_rung` chose, 0 for the full scatter."""
+    rungs = compact_rungs(docs)
+    i = int(compact_rung(docs, most_kept))
+    return rungs[i] if i < len(rungs) else 0
 
 
 def group_fold(plan: DevicePlan, out_groups: int = 0, remap_bytes: int = 0,
@@ -597,7 +651,13 @@ def _contribution(op: str, vals: Optional[jnp.ndarray],
 def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
                     mask: jnp.ndarray, valid: jnp.ndarray,
                     num_groups: int, mesh=None) -> jnp.ndarray:
-    """[S, D] + keys [S, D] -> [S, G] per-group partials, one slot."""
+    """[S, N] + keys [S, N] -> [S, G] per-group partials, one slot, over
+    the rows it is given: an additive slot by `_scatter_sum`'s path, MIN
+    and MAX by XLA's scatter; a masked row adds 0 (MIN / MAX: their
+    identity) at key 0. A `scatter` GROUP BY of a large shard hands it
+    its kept rows alone (`_compacting_slots`): N is then the rung's
+    capacity, not the segment's docs, and the scatter costs what the
+    filter keeps."""
     m = mask & valid
     safe_keys = jnp.where(m, keys, 0)
     if op in _ADDITIVE:
@@ -612,6 +672,152 @@ def _grouped_reduce(op: str, vals: Optional[jnp.ndarray], keys: jnp.ndarray,
         v = jnp.where(m, vals, -jnp.inf)
         return _vmap_scatter(init, safe_keys, v, "max")
     raise ValueError(f"unknown grouped reduction {op}")
+
+
+def _lane_prefix(x: jnp.ndarray) -> jnp.ndarray:
+    """[..., L] of small counts (each at most L, so exact in bf16) -> the
+    inclusive prefix along the last axis, int32: one product with a 0/1
+    triangle on the MXU, exact in its f32 accumulation."""
+    L = x.shape[-1]
+    lanes = jnp.arange(L)
+    tri = (lanes[:, None] <= lanes[None, :]).astype(jnp.bfloat16)
+    return jnp.einsum("...l,lm->...m", x.astype(jnp.bfloat16), tri,
+                      preferred_element_type=jnp.float32).astype(jnp.int32)
+
+
+def _kept_tree(kept: jnp.ndarray):
+    """The count tree of a [S, D] kept mask (D a power of two, at least
+    COMPACT_MIN_DOCS): (tiles [S, D / L, L], the mask as rows of L;
+    within [S, D / L^2, L] int32, the inclusive prefix of the tiles'
+    counts within each group of L tiles; top [S, D / L^2] int32, the
+    inclusive prefix of the groups' counts, whose last column is the
+    segment's kept count). One reduction over the lanes of the mask, the
+    rest on counts: no sort, no scatter, no prefix over a whole
+    segment."""
+    S, D = kept.shape
+    L = _COMPACT_LANES
+    tiles = kept.reshape(S, D // L, L)
+    count = jnp.sum(tiles, axis=-1, dtype=jnp.int32)          # [S, D / L]
+    within = _lane_prefix(count.reshape(S, -1, L))
+    return tiles, within, jnp.cumsum(within[:, :, -1], axis=1)
+
+
+def _rows_at(table: jnp.ndarray, idx: jnp.ndarray) -> jnp.ndarray:
+    """table [S, n, w], idx [S, c] -> [S, c, w]: whole rows of each
+    segment's own table (a gather of w-wide slices)."""
+    return jax.vmap(lambda t, i: t[i])(table, idx)
+
+
+def _kept_rows(tree, cap: int, cols):
+    """({name: [S, cap]} each [S, D] column of `cols` at each segment's
+    r-th kept row for r < cap, in order; live [S, cap] bool, whether the
+    segment has an r-th row: a slot past its count holds row 0's values,
+    and is not live).
+
+    Each slot walks the count tree down: the groups' prefix, compared
+    with its rank, says which group of L tiles holds the row, and the
+    rank drops by the rows before it; that group's row of tile prefixes
+    says the tile, and the tile's own L rows of the mask, as a prefix,
+    the lane. Every column is then read as the same tile's L-wide row and
+    that lane picked out (on the v5e a row gather costs what a gather of
+    one element does, ~10 ns an index; `take_along_axis` over [S, D] took
+    twice that). In chunks that keep a step's [S, chunk, L] under
+    _COMPACT_WALK_ELEMS."""
+    tiles, within, top = tree
+    S = top.shape[0]
+    L = _COMPACT_LANES
+    chunk = min(cap, max(L, _pow2_floor(_COMPACT_WALK_ELEMS // (S * L))))
+    rows = {k: v.reshape(S, -1, L) for k, v in cols.items()}
+    lanes = jnp.arange(L, dtype=jnp.int32)
+
+    def descend(r, prefix):
+        """(the group each rank lies in, the rank within it), prefix
+        [S, c or 1, w] the inclusive prefix over the groups' counts."""
+        below = prefix <= r[..., None]
+        return (jnp.sum(below, axis=-1, dtype=jnp.int32),
+                r - jnp.max(jnp.where(below, prefix, 0), axis=-1))
+
+    def pick(row, lane):
+        hit = lanes == lane[..., None]
+        if row.dtype == jnp.bool_:
+            return jnp.any(hit & row, axis=-1)
+        return jnp.sum(jnp.where(hit, row, 0), axis=-1, dtype=row.dtype)
+
+    def walk(start):
+        r = start + jnp.broadcast_to(
+            jnp.arange(chunk, dtype=jnp.int32), (S, chunk))
+        live = r < top[:, -1:]
+        group, r = descend(r, top[:, None, :])
+        tile, r = descend(r, _rows_at(
+            within, jnp.minimum(group, within.shape[1] - 1)))
+        tile = jnp.where(live, group * L + tile, 0)
+        lane, _r = descend(r, _lane_prefix(_rows_at(tiles, tile)))
+        lane = jnp.where(live, lane, 0)
+        return {k: pick(_rows_at(v, tile), lane)
+                for k, v in rows.items()}, live
+
+    if chunk == cap:
+        return walk(0)
+    picked, live = jax.lax.map(
+        walk, jnp.arange(cap // chunk, dtype=jnp.int32) * chunk)
+
+    def whole(x):  # [cap / chunk, S, chunk] -> [S, cap]
+        return x.transpose(1, 0, 2).reshape(S, cap)
+
+    return {k: whole(v) for k, v in picked.items()}, whole(live)
+
+
+def _compacting_slots(plan: DevicePlan, cols, params, keys: jnp.ndarray,
+                      kept: jnp.ndarray, num_groups: int, rung=None):
+    """Every slot of a `scatter` GROUP BY, each segment's kept rows
+    compacted first: [(op, [S, G])], what the full scatter gives.
+
+    keys [S, D]: the group keys (`_group_keys`); kept [S, D]: the rows
+    the filter, the time gate and validity keep. The rung (`compact_rung`
+    of the largest kept count of the launch; the batched and sharded
+    factories pass the whole launch's) picks ONE branch of a
+    `lax.switch`: at rung cap the count tree finds each segment's first
+    cap kept rows and reads the key and every staged column there into
+    [S, cap] (`_kept_rows`; what no slot reads XLA drops: the key's own
+    columns, the main filter's), and FILTER masks, values and the
+    scatters run over those; past the top rung the full [S, D] scatter
+    runs as it always did. The key is gathered as one column, not as the
+    columns it is made of: on the v5e a gather costs ~20 ns an index,
+    the key's pass over the block a few ms. No row is dropped: a slot
+    past a segment's count is not live, and adds 0 (MIN / MAX: their
+    identity) at key 0."""
+    rungs = compact_rungs(kept.shape[1])
+    with jax.named_scope("compact"):
+        tree = _kept_tree(kept)
+        if rung is None:
+            rung = compact_rung(kept.shape[1], jnp.max(tree[2][:, -1]))
+
+    def slots_of(rows, keys, live):
+        masks = [_eval_filter(ir, plan, rows, params)
+                 for ir in plan.agg_filter_irs]
+        values = [None if ir is None else _eval_value(ir, rows, params)
+                  for ir in plan.value_irs]
+        out = []
+        for op, vidx, fidx in plan.agg_ops:
+            # G > ONEHOT_MAX_GROUPS wherever a shard compacts, so every
+            # slot takes XLA's scatter here, at the full size as well
+            with jax.named_scope("reduce:" + op):
+                out.append(_grouped_reduce(
+                    op, None if vidx is None else values[vidx], keys,
+                    live if fidx is None else masks[fidx], live,
+                    num_groups))
+        return tuple(out)
+
+    def compacted(cap):
+        def branch():
+            with jax.named_scope(f"compact{cap}"):
+                rows, live = _kept_rows(tree, cap, {**cols, _KEY: keys})
+            return slots_of(rows, rows.pop(_KEY), live)
+        return branch
+
+    outs = jax.lax.switch(rung, [compacted(c) for c in rungs]
+                          + [lambda: slots_of(cols, keys, kept)])
+    return [(op, s) for (op, _v, _f), s in zip(plan.agg_ops, outs)]
 
 
 def _scatter_sum(contrib: jnp.ndarray, keys: jnp.ndarray,
@@ -974,8 +1180,49 @@ def _hist_slot(op: str, j: int, vals, params, mask, mesh=None) -> jnp.ndarray:
 # Kernel assembly
 # ---------------------------------------------------------------------------
 
+def _filter_mask(plan: DevicePlan, cols, params, shape) -> jnp.ndarray:
+    """[S, N] rows the plan's WHERE keeps (every row without one)."""
+    if plan.filter_ir is not None:
+        return _eval_filter(plan.filter_ir, plan, cols, params)
+    return jnp.ones(shape, dtype=bool)
+
+
+def _group_keys(plan: DevicePlan, cols, params, shape):
+    """(keys [S, N] int32, the fused time bucket's gate [S, N] or None)
+    of a GROUP BY's rows (shape [S, N]): the compacted code, or the
+    mixed-radix key over the plan's strides, with the time bucket as its
+    lowest digit."""
+    if plan.group_compact:
+        keys = cols["gkey"]
+    else:
+        keys = jnp.zeros(shape, dtype=jnp.int32)
+        for col, stride in zip(plan.group_cols, plan.group_strides):
+            keys = keys + cols["ids:" + col] * jnp.int32(stride)
+    if not plan.tbucket:
+        return keys, None
+    # fused time bucket: floor((t - start) / step) from the (hi, lo)
+    # raw64 planes becomes the key's lowest digit; out-of-window rows
+    # gate out of every slot (their wrapped deltas never reach the
+    # scatter)
+    tcol, count_pad = plan.tbucket
+    b, gate = timeseries_device.bucket_ids(
+        cols["valhi:" + tcol], cols["vallo:" + tcol],
+        params["tb:shi"], params["tb:slo"],
+        params["tb:step"], params["tb:count"], count_pad)
+    return keys + b, gate
+
+
+def _kept_counts(plan: DevicePlan, cols, params, valid) -> jnp.ndarray:
+    """[S] int32: the rows of each segment a GROUP BY keeps (filter,
+    time gate, validity), what its unfiltered COUNT slot sums to."""
+    mask = _filter_mask(plan, cols, params, valid.shape) & valid
+    if plan.tbucket:
+        mask = mask & _group_keys(plan, cols, params, valid.shape)[1]
+    return jnp.sum(mask, axis=1, dtype=jnp.int32)
+
+
 def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0,
-                   mesh=None):
+                   mesh=None, rung=None):
     """Shared kernel body: filter + values + per-slot reductions over a
     (possibly shard-local) [S, D] block. Returns
     ([(op, [S]- or [S, G]-array)], matched_count [S] or None).
@@ -983,13 +1230,21 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0,
     mesh: the segments mesh a plain-jit kernel's blocks are sharded over
     (None: one device, or already inside a shard_map): only the factored
     one-hot pass, a GROUP BY's or a histogram slot's, asks for it
-    (`_onehot2_sums`)."""
+    (`_onehot2_sums`).
+    rung: a `scatter` GROUP BY's compaction rung where the caller chose
+    it for a whole launch (`compact_rung`; the batched and sharded
+    factories), None to choose it from these segments."""
     dt = _value_dtype()
+    num_groups = plan.num_groups or G
     with jax.named_scope("filter"):
-        if plan.filter_ir is not None:
-            mask = _eval_filter(plan.filter_ir, plan, cols, params)
-        else:
-            mask = jnp.ones(valid.shape, dtype=bool)
+        mask = _filter_mask(plan, cols, params, valid.shape)
+    if compacts(plan, num_groups, valid.shape[1]):
+        with jax.named_scope("group_keys"):
+            keys, gate = _group_keys(plan, cols, params, valid.shape)
+        kept = mask & valid if gate is None else mask & gate & valid
+        return _compacting_slots(plan, cols, params, keys, kept,
+                                 num_groups, rung), None
+    with jax.named_scope("filter"):
         # per-aggregation FILTER (WHERE ...) masks AND into the main mask
         # per slot (ref FilteredAggregationOperator)
         agg_masks = [_eval_filter(ir, plan, cols, params)
@@ -1002,27 +1257,11 @@ def _compute_slots(plan: DevicePlan, cols, params, valid, G: int = 0,
                           else _eval_value(ir, cols, params))
 
     slots = []
-    num_groups = plan.num_groups or G
     if num_groups:
         with jax.named_scope("group_keys"):
-            if plan.group_compact:
-                keys = cols["gkey"]
-            else:
-                keys = jnp.zeros(valid.shape, dtype=jnp.int32)
-                for col, stride in zip(plan.group_cols, plan.group_strides):
-                    keys = keys + cols["ids:" + col] * jnp.int32(stride)
-            if plan.tbucket:
-                # fused time bucket: floor((t - start) / step) from the
-                # (hi, lo) raw64 planes becomes the key's lowest digit;
-                # out-of-window rows gate out of every slot (their wrapped
-                # deltas never reach the scatter)
-                tcol, count_pad = plan.tbucket
-                b, tgate = timeseries_device.bucket_ids(
-                    cols["valhi:" + tcol], cols["vallo:" + tcol],
-                    params["tb:shi"], params["tb:slo"],
-                    params["tb:step"], params["tb:count"], count_pad)
-                keys = keys + b
-                mask = mask & tgate
+            keys, gate = _group_keys(plan, cols, params, valid.shape)
+            if gate is not None:
+                mask = mask & gate
         def slot_mask(fidx):
             return mask if fidx is None else mask & agg_masks[fidx]
 
@@ -1111,17 +1350,14 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = (),
     """
     fp = plan_fingerprint(plan)
 
-    def kernel(cols, params, num_docs, D, G=0):
+    def kernel(cols, params, num_docs, D, G=0, rung=None):
         params, num_docs = unpack_params(plan, params, num_docs)
         # body runs at trace time: counts compiles
         note_trace(kind, fp, (*extra, int(num_docs.shape[-1]), D, G))
-        valid = jnp.arange(D, dtype=jnp.int32)[None, :] < num_docs[:, None]
-        if plan.valid_mask:
-            # upsert validDocIds ride as a staged bool block: superseded
-            # rows drop out of every slot AND the matched count, exactly
-            # mirroring the host executor's `mask &= valid.to_mask()`
-            valid = valid & cols["vmask"]
-        slots, matched = _compute_slots(plan, cols, params, valid, G, mesh)
+        valid = _valid_rows(plan, cols, num_docs,
+                            jnp.arange(D, dtype=jnp.int32)[None, :])
+        slots, matched = _compute_slots(plan, cols, params, valid, G, mesh,
+                                        rung)
         if plan.group_fold:
             with jax.named_scope("fold"):
                 return fold_groups(plan, slots, params, mesh)
@@ -1131,6 +1367,36 @@ def make_kernel(plan: DevicePlan, kind: str = "agg", extra: tuple = (),
             return _pack_flat(matched, slots)
 
     return kernel
+
+
+def _valid_rows(plan: DevicePlan, cols, num_docs, doc_pos) -> jnp.ndarray:
+    """[S, D] bool: the rows at doc_pos [1, D] (a shard's own, under a
+    docs mesh axis) that are real docs of their segment, less the
+    superseded rows where upsert validDocIds ride as a staged bool block
+    (they drop out of every slot AND the matched count, exactly mirroring
+    the host executor's `mask &= valid.to_mask()`)."""
+    valid = doc_pos < num_docs[:, None]
+    if plan.valid_mask:
+        valid = valid & cols["vmask"]
+    return valid
+
+
+def _launch_rung(plan: DevicePlan, D: int, G: int, over_members):
+    """ONE compaction rung for a batched launch, from the most rows any
+    segment of any member keeps; None where the plan does not compact at
+    these shapes. Under `vmap` a member's own rung would be a batched
+    `lax.switch` index, which runs every branch and selects: the full
+    scatter always. over_members(f) maps f(cols, params, num_docs) over
+    the members as the launch does."""
+    if not compacts(plan, plan.num_groups or G, D):
+        return None
+
+    def most_kept(cols, params, num_docs):
+        params, num_docs = unpack_params(plan, params, num_docs)
+        return jnp.max(_kept_counts(plan, cols, params, _valid_rows(
+            plan, cols, num_docs, jnp.arange(D, dtype=jnp.int32)[None, :])))
+
+    return compact_rung(D, jnp.max(over_members(most_kept)))
 
 
 def _pack_flat(matched, slots):
@@ -1245,15 +1511,34 @@ def _shard_one(plan: DevicePlan, doc_pos, G: int):
     the batched (vmap-inside-shard_map) sharded kernels so the slot
     semantics live in exactly one place. Returns the slot arrays in
     plan.agg_ops order, with the matched count appended for non-grouped
-    plans (a pytree vmap can carry)."""
-    def one(cols, params, num_docs):
-        valid = doc_pos < num_docs[:, None]
-        if plan.valid_mask:
-            valid = valid & cols["vmask"]  # shard-local [S_loc, D_loc]
-        slots, matched = _compute_slots(plan, cols, params, valid, G)
+    plans (a pytree vmap can carry). rung: `_shard_rung`'s."""
+    def one(cols, params, num_docs, rung=None):
+        slots, matched = _compute_slots(
+            plan, cols, params, _valid_rows(plan, cols, num_docs, doc_pos),
+            G, rung=rung)
         arrs = tuple(s for _, s in slots)
         return arrs if (plan.num_groups or G) else arrs + (matched,)
     return one
+
+
+def _shard_rung(plan: DevicePlan, doc_pos, G: int, over_members):
+    """ONE compaction rung for a launch over a (segments x docs) mesh,
+    inside its shard_map: from each segment's kept count summed over the
+    docs axis, the most over segments (and members: over_members(f) maps
+    f(cols, params, num_docs) as the launch does), as the host reads the
+    counts back, so a shard's cap holds its own share. None where the
+    plan does not compact a shard."""
+    d_local = doc_pos.shape[1]
+    if not compacts(plan, plan.num_groups or G, d_local):
+        return None
+
+    def kept(cols, params, num_docs):
+        return _kept_counts(plan, cols, params,
+                            _valid_rows(plan, cols, num_docs, doc_pos))
+
+    counts = jax.lax.psum(over_members(kept), "docs")
+    return compact_rung(d_local,
+                        jax.lax.pmax(jnp.max(counts), "segments"))
 
 
 def _shard_combine_pack(plan: DevicePlan, outs, G: int):
@@ -1306,7 +1591,9 @@ def make_sharded_kernel(plan: DevicePlan, mesh):
         d_local = D // doc_shards
         doc_pos = (jax.lax.axis_index("docs") * d_local
                    + jnp.arange(d_local, dtype=jnp.int32))[None, :]
-        outs = _shard_one(plan, doc_pos, G)(cols, params, num_docs)
+        rung = _shard_rung(plan, doc_pos, G,
+                           lambda f: f(cols, params, num_docs))
+        outs = _shard_one(plan, doc_pos, G)(cols, params, num_docs, rung)
         return _shard_combine_pack(plan, outs, G)
 
     def col_spec(name):
@@ -1378,16 +1665,19 @@ def make_batched_kernel(plan: DevicePlan, B: int, stacked: bool = False,
         def fn(clist, plist, ndlist, D, G=0):
             cs, ns = map(stack_members, (clist, ndlist))
             ps = stack_params(plist)
-            return jax.vmap(
-                lambda c, p, nd: base(c, p, nd, D=D, G=G))(cs, ps, ns)
+            rung = _launch_rung(plan, D, G, lambda f: jax.vmap(f)(cs, ps, ns))
+            return jax.vmap(lambda c, p, nd: base(
+                c, p, nd, D=D, G=G, rung=rung))(cs, ps, ns)
     else:
         def fn(cols, plist, num_docs, D, G=0):
             ps = stack_params(plist)
             # the index array keeps vmap fed when a filterless plan has
             # EMPTY per-query params (vmap rejects an all-empty pytree)
             idx = jnp.arange(B, dtype=jnp.int32)
-            return jax.vmap(
-                lambda p, _i: base(cols, p, num_docs, D=D, G=G))(ps, idx)
+            rung = _launch_rung(plan, D, G, lambda f: jax.vmap(
+                lambda p, _i: f(cols, p, num_docs))(ps, idx))
+            return jax.vmap(lambda p, _i: base(
+                cols, p, num_docs, D=D, G=G, rung=rung))(ps, idx)
 
     return jax.jit(_named(fn, _batched_name("batched", B, stacked, plan)),
                    static_argnames=("D", "G"))
@@ -1422,10 +1712,14 @@ def make_batched_dedup_kernel(plan: DevicePlan, B: int, U: int, mesh=None):
         cs, ns = map(stack_members, (clist, ndlist))
         ps = stack_params(plist)
         pick = jax.tree_util.tree_map
-        return jax.vmap(
-            lambda p, i: base(
-                pick(lambda c: c[i], cs), p, pick(lambda n: n[i], ns),
-                D=D, G=G))(ps, idx)
+
+        def members(f):
+            return jax.vmap(lambda p, i: f(
+                pick(lambda c: c[i], cs), p, pick(lambda n: n[i], ns)))(
+                    ps, idx)
+
+        rung = _launch_rung(plan, D, G, members)
+        return members(lambda c, p, nd: base(c, p, nd, D=D, G=G, rung=rung))
 
     return jax.jit(_named(fn, f"batched_b{B}_dedup{U}_"
                               f"{plan_fingerprint(plan)}"),
@@ -1510,8 +1804,13 @@ def make_batched_sharded_kernel(plan: DevicePlan, mesh, B: int,
         one = _shard_one(plan, doc_pos, G)
         idx = jnp.arange(B, dtype=jnp.int32)
         in_axes = (0 if stacked else None, 0, 0 if stacked else None, 0)
-        outs = jax.vmap(lambda c, p, nd, _i: one(c, p, nd),
-                        in_axes=in_axes)(cols, params, num_docs, idx)
+
+        def members(f):
+            return jax.vmap(lambda c, p, nd, _i: f(c, p, nd),
+                            in_axes=in_axes)(cols, params, num_docs, idx)
+
+        rung = _shard_rung(plan, doc_pos, G, members)
+        outs = members(lambda c, p, nd: one(c, p, nd, rung))
         return _shard_combine_pack(plan, outs, G)
 
     def fn(cols, plist, num_docs, D, G=0):

@@ -95,8 +95,13 @@ METRICS: Dict[str, str] = {
         "folded query, [S, G, slots] where the host folds)",
     "scatter_rows":
         "rows x additive slots device GROUP BYs handed to XLA's scatter-add "
-        "(static shapes, padding included; 0 a launch whose slots all run "
-        "one-hot: kernels.scatter_rows)",
+        "(padding included; on the scatter path each segment's compacted "
+        "rung of kept rows, or its whole docs past the top rung; 0 a launch "
+        "whose slots all run one-hot: kernels.scatter_rows)",
+    "scatter_compact":
+        "device GROUP BYs on the scatter path by the rows a segment their "
+        "kept rows were compacted to before the scatter (label cap=, 0 the "
+        "full scatter: kernels.compact_cap)",
     "mesh_exchange_bytes":
         "bytes a chip handed to the collectives of grouped programs on a "
         "server of several chips (the fold's all-reduces; read once a "

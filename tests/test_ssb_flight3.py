@@ -8,7 +8,8 @@ ONEHOT2_MAX_GROUPS: the XLA scatter-add, folded on the device into a
 pow2 table of 524,288 slots. Every answer has to be the rows
 `benchmark/reference.py` gives (keys, order, exact COUNT; the f32 SUM
 within the configuration's limit), and the launch's span has to name
-the scatter's work (`scatterRows`). The reference reads `c_nation = N`
+the scatter's work: each segment's kept rows compacted to a rung
+(`scatterCap`, `scatterRows`). The reference reads `c_nation = N`
 as a range of cities, which holds only because a city is its nation's
 name cut or padded to 9 characters plus a digit: pinned here."""
 import copy
@@ -169,10 +170,23 @@ def test_q3_2_answers_as_the_reference_does(cell, table, served):
             assert span["groupFold"] == "device"
             assert span["groupsPresent"] == len(want)
             assert span["groupResultBytes"] == FOLDED * SLOTS * 4
-            # every padded row of every padded segment, a slot each
-            assert span["scatterRows"] == span["S"] * span["D"] * SLOTS
+            # each segment's kept rows compacted to the rung that holds
+            # the most any segment kept, a slot each
+            cap = kernels.compact_cap(span["D"], max(
+                kept_rows(made, nation) for made in table[2]))
+            assert cap == span["D"] >> 9
+            assert span["scatterCap"] == cap
+            assert span["scatterRows"] == span["S"] * cap * SLOTS
             assert span["S"] >= len(DOCS) and span["D"] >= max(DOCS)
     assert worst <= limit, worst
+
+
+def kept_rows(made, nation: str) -> int:
+    """Rows of one segment Q3.2 keeps: both nations N, 1992-1997."""
+    year = made["d_year"][0].astype(int)
+    return int(((made["c_nation"][0] == nation)
+                & (made["s_nation"][0] == nation)
+                & (year >= 1992) & (year <= 1997)).sum())
 
 
 def test_scatter_rows_is_metered(cell, served):
@@ -185,6 +199,48 @@ def test_scatter_rows_is_metered(cell, served):
     _resp, _results, (span,) = ask(ex, config["table"], sql)
     assert engine._metrics.meter("scatter_rows", labels=labels) - before \
         == span["scatterRows"] > 0
+
+
+def test_the_compaction_rung_is_metered(cell, served):
+    config, mix = cell
+    ex, engine, _dm = served
+    _t, _lit, sql = traffic.make_queries(
+        mix, config["table"], SEED, 3, 1, False)[0]
+    labels = dict(engine._labels or {})
+    _resp, _results, (span,) = ask(ex, config["table"], sql)
+    cap = span["scatterCap"]
+    assert cap > 0
+    before = engine._metrics.meter("scatter_compact",
+                                   labels=dict(labels, cap=str(cap)))
+    ask(ex, config["table"], sql)
+    assert engine._metrics.meter("scatter_compact",
+                                 labels=dict(labels, cap=str(cap))) \
+        - before == 1
+
+
+def test_a_query_that_keeps_every_row_takes_the_full_scatter(cell, table,
+                                                              served):
+    """Q3.2's GROUP BY with no WHERE keeps every row, past the top rung:
+    the full scatter, `scatterCap` 0 and every padded row of every padded
+    segment a slot; the counts as numpy's."""
+    config, _mix = cell
+    _segs, _ref, made_all = table
+    ex, _engine, _dm = served
+    sql = (f"SELECT SUM(lo_revenue) AS revenue, COUNT(*), c_city, s_city, "
+           f"d_year FROM {config['table']} GROUP BY c_city, s_city, d_year "
+           f"ORDER BY COUNT(*) DESC, c_city, s_city, d_year LIMIT 5")
+    resp, _results, (span,) = ask(ex, config["table"], sql)
+    assert span["groupPath"] == "scatter" and span["scatterCap"] == 0
+    assert span["scatterRows"] == span["S"] * span["D"] * SLOTS
+    counts = {}
+    for made in made_all:
+        for key in zip(made["c_city"][0], made["s_city"][0],
+                       made["d_year"][0].astype(int)):
+            counts[key] = counts.get(key, 0) + 1
+    top = sorted(counts.items(), key=lambda kv: (-kv[1], kv[0]))[:5]
+    assert [(int(r[1]), r[2], r[3], int(r[4]))
+            for r in resp.result_table.rows] \
+        == [(n, c, s, y) for (c, s, y), n in top]
 
 
 def test_a_flight_2_query_hands_the_scatter_nothing(served, tmp_path_factory):
@@ -219,23 +275,37 @@ PLAN = DevicePlan(
     group_cols=("a",), group_strides=(1,), num_groups=6)
 
 
-@pytest.mark.parametrize("G,D,nonfinite,rows", [
-    (KEY_SPACE, 1 << 23, False, 16 * (1 << 23) * 2),  # Q3.2: scatter
-    (7000, 1 << 15, False, 0),                         # onehot2
-    (7000, 1 << 15, True, 16 * (1 << 15) * 2),   # Inf/NaN keep the scatter
-    (7000, 4096, False, 16 * 4096 * 2),          # under onehot2's chunk
-    (175, 1 << 15, False, 0),                          # onehot, whole chunks
-    (175, 3 * 4096 + 100, False, 16 * 100 * 2),        # onehot's tail
-    (175, 1000, False, 16 * 1000 * 2),           # under onehot's chunk
+@pytest.mark.parametrize("G,D,nonfinite,kept,rows", [
+    # Q3.2: about 10,970 kept rows a segment, on the D / 512 rung
+    (KEY_SPACE, 1 << 23, False, 10970, 16 * 16384 * 2),
+    (KEY_SPACE, 1 << 23, False, 16384, 16 * 16384 * 2),      # rung edge
+    (KEY_SPACE, 1 << 23, False, 16385, 16 * 131072 * 2),     # next rung
+    (KEY_SPACE, 1 << 23, False, 1 << 20, 16 * (1 << 20) * 2),  # top rung
+    (KEY_SPACE, 1 << 23, False, (1 << 20) + 1,
+     16 * (1 << 23) * 2),                           # past it: the full scatter
+    (KEY_SPACE, 1 << 15, False, 5, 16 * (1 << 15) * 2),  # under the minimum
+    (7000, 1 << 15, False, 5, 0),                          # onehot2
+    (7000, 1 << 15, True, 5, 16 * (1 << 15) * 2),  # Inf/NaN keep the scatter
+    (7000, 1 << 16, True, 5, 16 * 128 * 2),        # ... and compact
+    (7000, 4096, False, 5, 16 * 4096 * 2),         # under onehot2's chunk
+    (175, 1 << 15, False, 5, 0),                           # onehot, whole chunks
+    (175, 3 * 4096 + 100, False, 5, 16 * 100 * 2),         # onehot's tail
+    (175, 1000, False, 5, 16 * 1000 * 2),          # under onehot's chunk
 ])
-def test_scatter_rows_follows_the_kernel_s_own_routing(G, D, nonfinite, rows):
+def test_scatter_rows_follows_the_kernel_s_own_routing(G, D, nonfinite, kept,
+                                                       rows):
     """Rows x additive slots (MAX scatters too, but not by adding), as
-    `group_path` routes them, from shapes alone."""
+    `group_path` routes them and, on `scatter`, as the kernel compacts
+    them: the rung `compact_cap` reads from the most rows a segment
+    kept."""
     plan = dataclasses.replace(PLAN, num_groups=G, nonfinite=nonfinite)
     with jax.enable_x64(False):
-        assert kernels.scatter_rows(plan, G, 16, D, D) == rows
         path = kernels.group_path(G, D, kernels._value_dtype(),
                                   finite=not nonfinite)
+        cap = kernels.compact_cap(D, kept) if path == "scatter" else 0
+        assert kernels.scatter_rows(plan, G, 16, D, D, cap) == rows
+        assert kernels.compacts(plan, G, D) == (path == "scatter"
+                                                and D >= 1 << 16)
     assert (path == "onehot2") <= (rows == 0)
 
 
