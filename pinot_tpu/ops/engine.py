@@ -411,7 +411,7 @@ class TpuOperatorExecutor:
         # partials of a GROUP BY are folded inside the kernel over a
         # global key space (kernels.fold_groups), unless
         # `kernels.group_fold` says host
-        if plan.group_cols and batchable:
+        if (plan.group_cols or plan.tbucket) and batchable:
             folded = self._group_fold(segments, plan, params, S, G, info)
             if folded is not None:
                 plan, params, minfo = folded
@@ -421,6 +421,10 @@ class TpuOperatorExecutor:
         self._meter("scan_served")
         num_groups = plan.num_groups or G
         scatter = None
+        # the fused time bucket of this query's window: how its keys
+        # decode (`_assemble*`), and what the span and meter say
+        bucket = timeseries_device.plan_bucket(
+            ctx.group_by[0], ctx.filter, segments) if plan.tbucket else None
         merged = minfo is not None and not plan.group_fold
         if merged:
             self._meter("mesh_merge_served")
@@ -445,6 +449,12 @@ class TpuOperatorExecutor:
             if dsp is not None:
                 dsp.set(groupPath=path, groupKeySpace=num_groups,
                         groupFold=fold)
+            if bucket is not None:
+                unit = timeseries_device.bucket_label(bucket)
+                self._meter("time_bucket", unit=unit, fold=fold)
+                if dsp is not None:
+                    dsp.set(timeBucket=unit, bucketCount=bucket.count,
+                            bucketPad=bucket.count_pad)
             if self._mesh is not None and batchable:
                 exchanged, gathered = self._mesh_exchange(
                     kernel, S, D, G, cols, params)
@@ -469,7 +479,7 @@ class TpuOperatorExecutor:
             site_ctx={"table": ctx.table, "mode": "agg"}, span=dsp,
             slip=slip, docs=sum(s.num_docs for s in segments),
             staged_ts=staged_ts)
-        return plan, slots_of_fn, S_real, launch, minfo, scatter
+        return plan, slots_of_fn, S_real, launch, minfo, scatter, bucket
 
     def _plain_kernels(self, plan: DevicePlan):
         """(kernel, batched factory, dedup factory) of a plan on the
@@ -569,7 +579,9 @@ class TpuOperatorExecutor:
                 segments, plan, S, G_local, info)
         except _MergeFallback:
             return None
-        fold = (G_out,) if plan.group_compact else tuple(decode[1])
+        # a fused time bucket's digit is the fold's last, carried unmapped
+        fold = (G_out,) if plan.group_compact \
+            else tuple(decode[1]) + plan.tbucket[1:]
         params = dict(params)
         params.update(gparams)
         return (dataclasses.replace(plan, group_fold=fold), params,
@@ -661,9 +673,11 @@ class TpuOperatorExecutor:
         # the key space in pow2 digits, as S, D and a compact G are
         # bucketed: a window that slides over time-partitioned segments
         # changes a union by a value or two, and the kernel's shape must
-        # not follow it. A padded digit is a group no segment has
+        # not follow it. A padded digit is a group no segment has. A
+        # fused time bucket's digit (count_pad, a pow2 already) stays
+        # the lowest: the remap carries it as it is
         cards = [_pow2(len(u), floor=8) for u in unions]
-        G_out = math.prod(cards)
+        G_out = math.prod(cards) * math.prod(plan.tbucket[1:])
         if self._fold_where(plan, G_out,
                             sum(S * c * 4 for c in cards)) == "host":
             raise _MergeFallback("groups")
@@ -1195,17 +1209,17 @@ class TpuOperatorExecutor:
                                  parent_span=parent_span, slip=slip)
         if prep is None:
             return None
-        plan, slots_of_fn, S_real, launch, minfo, scatter = prep
+        plan, slots_of_fn, S_real, launch, minfo, scatter, bucket = prep
         if plan.group_fold:
             return launch, lambda packed: self._assemble_folded(
                 segments, ctx, plan, packed, S_real, slots_of_fn, minfo,
-                launch.span, scatter)
+                launch.span, scatter, bucket)
         if minfo is not None:
             return launch, lambda packed: self._assemble_merged(
                 segments, ctx, plan, packed, S_real, slots_of_fn, minfo)
         return launch, lambda packed: self._assemble(
             segments, ctx, plan, packed, S_real, slots_of_fn, launch.span,
-            scatter)
+            scatter, bucket)
 
     @staticmethod
     def _note_assemble(launch: Launch, t0: float, parent=None) -> None:
@@ -1443,21 +1457,31 @@ class TpuOperatorExecutor:
                         return None
                 hll_cols.add(col)
 
+        # a fused time bucket reads its column's split planes, so a raw
+        # filter leaf on that column reads them too: no 'val:' block of
+        # it is staged (x64 would make the leaf an f64 range, and a small
+        # int under f32 staging an f32 one)
+        planes_only = set(hll_cols)
+        if ctx.group_by and not isinstance(ctx.group_by[0], Identifier):
+            shape = timeseries_device.extract_bucket(ctx.group_by[0])
+            if shape is not None:
+                planes_only.add(shape[0])
+
         # filter IR FIRST: leaves fill in build order, so the main filter's
         # leaves precede agg-filter leaves (staging resolves in this order)
         leaves: List[DeviceLeaf] = []
         filter_ir = None
-        hll64 = frozenset(hll_cols)
+        force64 = frozenset(planes_only)
         if ctx.filter is not None:
             filter_ir = self._build_filter_ir(ctx.filter, segments, leaves,
-                                              classify, force64=hll64)
+                                              classify, force64=force64)
             if filter_ir is None:
                 return None
 
         #: columns that stage as split planes carry NO 'val:' block — they
         #: cannot feed value IRs (the whole query falls back instead)
         raw64 = {lf.column for lf in leaves
-                 if lf.kind == "vrange64"} | hll_cols
+                 if lf.kind == "vrange64"} | planes_only
 
         # per-aggregation FILTER (WHERE ...) trees, deduplicated
         agg_filter_irs: List[tuple] = []
@@ -1471,7 +1495,7 @@ class TpuOperatorExecutor:
                 agg_fidx.append(fidx_of_filter[f])
                 continue
             ir = self._build_filter_ir(f, segments, leaves, classify,
-                                       force64=hll64)
+                                       force64=force64)
             if ir is None:
                 return None
             fidx_of_filter[f] = len(agg_filter_irs)
@@ -1630,7 +1654,7 @@ class TpuOperatorExecutor:
             adds_nonfinite(op, vidx) for op, vidx, _f in agg_ops)
 
         raw64 = {lf.column for lf in leaves
-                 if lf.kind == "vrange64"} | hll_cols
+                 if lf.kind == "vrange64"} | planes_only
         if tbucket:
             # the bucket kernel reads the timestamp's (hi, lo) planes
             # regardless of how its range leaf classified
@@ -2561,7 +2585,7 @@ class TpuOperatorExecutor:
     def _assemble(self, segments, ctx: QueryContext, plan: DevicePlan,
                   packed: np.ndarray, S_real: int,
                   mappings: List[Dict[str, int]], span=None,
-                  scatter=None) -> List[Any]:
+                  scatter=None, bucket=None) -> List[Any]:
         t0 = time.perf_counter()
         filter_cols = len(set(ctx.filter_columns()))
         # parity with executor_cpu: COUNT(*) materializes no column, so it
@@ -2605,7 +2629,8 @@ class TpuOperatorExecutor:
                 total_docs=seg.num_docs)
             if is_group:
                 results.append(self._assemble_group(
-                    seg, s, ctx, plan, packed, count_j, mappings, stats))
+                    seg, s, ctx, plan, packed, count_j, mappings, stats,
+                    bucket))
             else:
                 inters = []
                 for fn, mapping in zip(ctx.agg_functions, mappings):
@@ -2671,7 +2696,8 @@ class TpuOperatorExecutor:
                      groupDecodeMs=round(
                          (time.perf_counter() - t0) * 1e3, 3))
 
-    def _assemble_group(self, seg, s, ctx, plan, packed, count_j, mappings, stats):
+    def _assemble_group(self, seg, s, ctx, plan, packed, count_j, mappings,
+                        stats, bucket=None):
         present = np.nonzero(packed[s, :, count_j] > 0)[0]
 
         dicts = [seg.data_source(c).dictionary for c in plan.group_cols]
@@ -2701,16 +2727,15 @@ class TpuOperatorExecutor:
             present = present[valid]
             ids_per_col = [ids[valid] for ids in ids_per_col]
             if buckets is not None:
-                buckets = buckets[valid]
+                buckets = timeseries_device.bucket_keys(
+                    bucket, buckets[valid])
 
         key_cols = [d.get_values(ids) for d, ids in zip(dicts, ids_per_col)]
         groups: Dict[tuple, list] = {}
         for gi, g in enumerate(present):
             key = tuple(_py(col[gi]) for col in key_cols)
             if buckets is not None:
-                # host parity: floor() over the f64 division yields a
-                # float group key
-                key = (float(buckets[gi]),) + key
+                key = (_py(buckets[gi]),) + key
             inters = []
             for fn, mapping in zip(ctx.agg_functions, mappings):
                 slots = {op: packed[s, g, j] for op, j in mapping.items()}
@@ -2785,7 +2810,7 @@ class TpuOperatorExecutor:
     def _assemble_folded(self, segments, ctx: QueryContext,
                          plan: DevicePlan, packed: np.ndarray, S_real: int,
                          mappings: List[Dict[str, int]], minfo,
-                         span=None, scatter=None) -> List[Any]:
+                         span=None, scatter=None, bucket=None) -> List[Any]:
         """ONE GroupByResult for the whole segment batch, from the
         integer row `kernels.fold_groups` packed: the [n_slots, G] group
         table over the global key space, then each segment's matched
@@ -2811,6 +2836,13 @@ class TpuOperatorExecutor:
                 CodedColumn(unions[ci],
                             (present // strides[ci]) % cards[ci])
                 for ci in range(len(plan.group_cols))]
+            if bucket is not None:
+                # the fused time bucket leads the GROUP BY and is the
+                # key's lowest digit
+                pad = plan.tbucket[1]
+                key_columns.insert(0, CodedColumn(
+                    timeseries_device.bucket_keys(bucket, np.arange(pad)),
+                    present % pad))
         vdt = np.float64 if row.dtype.itemsize == 8 else np.float32
         slot_cols = [
             table[j, present] if op == "count"

@@ -460,12 +460,11 @@ def group_fold(plan: DevicePlan, out_groups: int = 0, remap_bytes: int = 0,
     with the global key space its remap came to and the bytes of its
     tables, against the caps it already holds every device group table
     and every remap to (`MAX_DEVICE_GROUPS`, `GMAP_MAX_BYTES`), and
-    writes the word on the DeviceDispatch span (`groupFold`). A fused
-    time bucket is a digit of the key the remap does not carry; a key
+    writes the word on the DeviceDispatch span (`groupFold`). A key
     space or a compacted plan's [S, G] table past the caps would cost
-    more than the partials it saves."""
-    if plan.tbucket or out_groups > max_groups \
-            or remap_bytes > max_remap_bytes:
+    more than the partials it saves. A fused time bucket folds like any
+    other key: its digit means the same in every segment of a launch."""
+    if out_groups > max_groups or remap_bytes > max_remap_bytes:
         return "host"
     return "device"
 
@@ -527,7 +526,10 @@ def fold_groups(plan: DevicePlan, slots, params, mesh=None) -> jnp.ndarray:
     [S, U_i] tables give column i's local id of a union value (-1: the
     segment's dictionary lacks it), recombined with the plan's own
     strides; a compacted plan's `ginv` [S, G_out] gives the local code.
-    A gather a segment, then a reduction over the segment axis.
+    A gather a segment, then a reduction over the segment axis. A fused
+    time bucket, the key's lowest digit (`plan.group_fold`'s last), is
+    the same bucket in every segment (start and step are the query's):
+    it is carried as it is, with no table.
 
     mesh: the segments mesh the partials and the tables are sharded
     over. Each chip then folds its own segments (`_fold_shard` under a
@@ -544,7 +546,7 @@ def fold_groups(plan: DevicePlan, slots, params, mesh=None) -> jnp.ndarray:
     the integer as wide as the value dtype: counts ride it as integers,
     every other slot bit-cast."""
     names = ["ginv"] if plan.group_compact \
-        else [f"ginv{ci}" for ci in range(len(plan.group_fold))]
+        else [f"ginv{ci}" for ci in range(len(plan.group_cols))]
     arrs = [s for _op, s in slots]
     tables = [params[n] for n in names]
     if mesh is None:
@@ -576,17 +578,20 @@ def _fold_shard(plan: DevicePlan, chips: Optional[str], arrs,
         # is gathered along its own axis through its column's table,
         # whole rows at a time (an element-wise gather over an iota of
         # the key space compiled for a minute at 131,072 groups: XLA
-        # folded the iota's divisions as constants)
-        k = len(plan.group_fold)
+        # folded the iota's divisions as constants). The last axis is
+        # the fused time bucket's digit (1 without one), never remapped
+        k = len(plan.group_cols)
         local = [(plan.num_groups if ci == 0 else plan.group_strides[ci - 1])
                  // plan.group_strides[ci] for ci in range(k)]
-        S = tables[0].shape[0]
+        local.append(plan.group_strides[-1] if k else plan.num_groups)
+        S = arrs[0].shape[0]
         there = True
         for ci, ids in enumerate(tables):
             there = there & (ids >= 0).reshape(
-                (S,) + (1,) * ci + (-1,) + (1,) * (k - ci - 1))
+                (S,) + (1,) * ci + (-1,) + (1,) * (k - ci))
         there = jnp.broadcast_to(
-            there, (S,) + tuple(plan.group_fold)).reshape(S, -1)
+            there, (S,) + tuple(plan.group_fold[:k]) + (local[-1],)
+        ).reshape(S, -1)
 
         def globally(s):
             x = s.reshape((S,) + tuple(local))

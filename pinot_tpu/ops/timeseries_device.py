@@ -12,6 +12,12 @@ computes `b = (t - start) // step` in i32 from those planes, and `b`
 becomes the LOWEST digit of the composite group key — the engine's
 successive-division strides then decode it for free.
 
+`DATETRUNC('<unit>', t)` over epoch milliseconds is the same bucket
+where the unit has a fixed width in UTC (MILLISECOND .. WEEK): start is the
+window's lower bound truncated to the unit, step the unit's width, and
+the key decodes to the absolute `start + b * step`. MONTH, QUARTER and
+YEAR vary in length and stay host expressions.
+
 start / step / count are PARAMS (per-segment i32 cells), not plan
 fields: a dashboard's sliding window changes `start` every refresh, and
 only `count_pad` — the pow2 bucket of the window's bucket count — is
@@ -27,7 +33,9 @@ from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from pinot_tpu.query import transform
 from pinot_tpu.query.expressions import Function, Identifier, Literal
 
 #: timestamps stage as (v >> 24, v & 0xFFFFFF) i32 planes — exact while
@@ -49,6 +57,9 @@ class BucketSpec(NamedTuple):
     step: int
     count: int      # buckets actually addressed by the window
     count_pad: int  # pow2 bucket -> the plan's static group width
+    #: DATETRUNC's unit (its keys are `start + b * step`, LONG), or None
+    #: for the leaf's floor((t - start) / step) (its keys are b)
+    unit: Optional[str] = None
 
 
 def _pow2(n: int, floor: int = 8) -> int:
@@ -68,10 +79,25 @@ def _int_lit(e) -> Optional[int]:
     return int(v) if v.is_integer() else None
 
 
-def extract_bucket(e) -> Optional[Tuple[str, int, int]]:
-    """(col, start, step) when `e` is exactly
-    floor((Identifier - int) / int) with a positive step — the shape
-    the time-series leaf SQL emits; None otherwise."""
+def extract_bucket(e) -> Optional[Tuple[str, Optional[int], int,
+                                        Optional[str]]]:
+    """(col, start, step, unit) of a group key the fused bucket serves,
+    or None:
+      * floor((Identifier - int) / int) with a positive step, the shape
+        the time-series leaf SQL emits (unit None);
+      * DATETRUNC('<unit>', Identifier) over epoch milliseconds with a
+        unit of fixed width in UTC (`transform.DATETRUNC_UNITS`: MONTH
+        and longer stay on the host). Its start is not in the key: it is
+        the filter window's lower bound truncated to the unit
+        (`plan_bucket`), so start is None here."""
+    if isinstance(e, Function) and e.name in transform.DATETRUNC_NAMES \
+            and len(e.args) == 2 and isinstance(e.args[0], Literal) \
+            and isinstance(e.args[1], Identifier):
+        unit = str(e.args[0].value).upper()
+        step = transform.DATETRUNC_UNITS.get(unit)
+        if step is None:
+            return None
+        return e.args[1].name, None, step, unit
     if not (isinstance(e, Function) and e.name == "floor"
             and len(e.args) == 1):
         return None
@@ -87,7 +113,7 @@ def extract_bucket(e) -> Optional[Tuple[str, int, int]]:
     step = _int_lit(step_e)
     if start is None or step is None or step <= 0:
         return None
-    return sub.args[0].name, start, step
+    return sub.args[0].name, start, step, None
 
 
 def extract_window(flt, col: str) -> Optional[Tuple[int, int]]:
@@ -121,16 +147,21 @@ def extract_window(flt, col: str) -> Optional[Tuple[int, int]]:
 def plan_bucket(group_expr, flt, segments) -> Optional[BucketSpec]:
     """Admit the first group-by expression as a fused device time
     bucket, or None (the query stays on whatever path it had). Checks:
-    the floor shape, an int timestamp column bounded in [0, 2^55) on
-    every segment, a filter window starting at/after `start` (so the
-    kernel's delta is never negative for surviving rows), and the
-    window fitting the exact-i32 envelope."""
+    a shape `extract_bucket` admits, an int timestamp column bounded in
+    [0, 2^55) on every segment, a filter window starting at/after
+    `start` (so the kernel's delta is never negative for surviving rows;
+    DATETRUNC's start is that lower bound truncated to its unit), and
+    the window fitting the exact-i32 envelope."""
     shape = extract_bucket(group_expr)
     if shape is None:
         return None
-    col, start, step = shape
+    col, start, step, unit = shape
     win = extract_window(flt, col)
-    if win is None or win[0] < start:
+    if win is None:
+        return None
+    if unit is not None:
+        start = int(transform.datetrunc_ms(unit, win[0]))
+    if win[0] < start:
         return None
     for seg in segments:
         m = seg.metadata.columns.get(col)
@@ -143,7 +174,24 @@ def plan_bucket(group_expr, flt, segments) -> Optional[BucketSpec]:
     if window >= MAX_WINDOW:
         return None
     count = window // step + 1
-    return BucketSpec(col, start, step, count, _pow2(count))
+    return BucketSpec(col, start, step, count, _pow2(count), unit)
+
+
+def bucket_keys(spec: BucketSpec, b) -> np.ndarray:
+    """The group keys of bucket ids `b`: DATETRUNC's absolute
+    `start + b * step` (int64 ms), or the leaf's bucket id itself as the
+    host's floor() over an f64 division gives it (float64)."""
+    b = np.asarray(b, dtype=np.int64)
+    if spec.unit is None:
+        return b.astype(np.float64)
+    return spec.start + b * spec.step
+
+
+def bucket_label(spec: BucketSpec) -> str:
+    """What `timeBucket` and the `time_bucket{unit=}` meter say: the
+    DATETRUNC unit, or FLOOR for the leaf's floor((t - start) / step)
+    (its step is a literal of any size: no label)."""
+    return spec.unit or "FLOOR"
 
 
 def leaf_params(spec: BucketSpec, S: int):

@@ -21,6 +21,7 @@ from pinot_tpu.query.expressions import (
 from pinot_tpu.query.results import (
     AggregationResult, CodedColumn, DistinctResult, ExecutionStats,
     GroupByResult, SelectionResult)
+from pinot_tpu.query.transform import DATETRUNC_NAMES, datetrunc
 from pinot_tpu.utils import tracing
 
 
@@ -205,6 +206,8 @@ _SCALAR_FUNCS = {
     "and": lambda *xs: all(xs),
     "or": lambda *xs: any(xs),
     "not": lambda a: not a,
+    **dict.fromkeys(DATETRUNC_NAMES, lambda unit, v, *input_unit: int(
+        datetrunc(unit, v, *input_unit))),
 }
 
 
@@ -544,6 +547,8 @@ def _result_type(e: Expression, ctx: QueryContext) -> str:
     from pinot_tpu.query.aggregation import get_aggregation, is_aggregation
     if isinstance(e, Function) and is_aggregation(e.name):
         return get_aggregation(e.name, e.args).final_dtype
+    if isinstance(e, Function) and e.name in DATETRUNC_NAMES:
+        return "LONG"
     if isinstance(e, Function):
         return "DOUBLE"
     return "UNKNOWN"

@@ -247,6 +247,120 @@ def _cast(expr: Function, p: ColumnProvider):
     raise ValueError(f"unsupported cast target {tname}")
 
 
+_DAY_MS = 86_400_000
+
+#: the function's names: upstream's registry drops the underscore
+DATETRUNC_NAMES = ("datetrunc", "date_trunc")
+
+#: DATETRUNC's units (pinot-common DateTimeUtils.getTimestampField), each
+#: with its width in milliseconds where that is fixed in UTC; MONTH,
+#: QUARTER and YEAR vary in length (None)
+DATETRUNC_UNITS: Dict[str, Any] = {
+    "MILLISECOND": 1, "SECOND": 1000, "MINUTE": 60_000,
+    "HOUR": 3_600_000, "DAY": _DAY_MS, "WEEK": 7 * _DAY_MS,
+    "MONTH": None, "QUARTER": None, "YEAR": None,
+}
+
+#: ISO weeks start on Monday; 1970-01-01 was a Thursday, so the week
+#: grid sits 3 days before the epoch
+_WEEK_ORIGIN_MS = -3 * _DAY_MS
+
+#: java.util.concurrent.TimeUnit, in milliseconds: a value in one of
+#: these is what DATETRUNC's third argument names
+_INPUT_UNITS_MS = {
+    "MILLISECONDS": 1, "SECONDS": 1000, "MINUTES": 60_000,
+    "HOURS": 3_600_000, "DAYS": _DAY_MS,
+}
+_INPUT_UNITS_PER_MS = {"MICROSECONDS": 1000, "NANOSECONDS": 1_000_000}
+
+
+def datetrunc_unit(unit: Any) -> str:
+    """The DATETRUNC unit a literal names, upper case; ValueError for any
+    other."""
+    name = str(unit).upper()
+    if name not in DATETRUNC_UNITS:
+        raise ValueError(f"DATETRUNC: unsupported unit {unit!r}; one of "
+                         f"{', '.join(DATETRUNC_UNITS)}")
+    return name
+
+
+def datetrunc_ms(unit: str, millis) -> np.ndarray:
+    """Epoch milliseconds (UTC) truncated to the start of their `unit`,
+    as int64: floor for a unit of fixed width (before 1970 too), the
+    calendar's month, quarter or year otherwise."""
+    t = np.asarray(millis, dtype=np.int64)
+    unit = datetrunc_unit(unit)
+    width = DATETRUNC_UNITS[unit]
+    if width is not None:
+        origin = _WEEK_ORIGIN_MS if unit == "WEEK" else 0
+        return (t - origin) // width * width + origin
+    months = t.astype("datetime64[ms]").astype("datetime64[M]")
+    if unit == "YEAR":
+        months = months.astype("datetime64[Y]").astype("datetime64[M]")
+    elif unit == "QUARTER":
+        m = months.astype(np.int64)
+        months = (m // 3 * 3).astype("datetime64[M]")
+    return months.astype("datetime64[ms]").astype(np.int64)
+
+
+def _datetrunc_values(values) -> np.ndarray:
+    """DATETRUNC's time argument as int64: a value of a non-integer
+    dtype has to hold a whole number (no NaN, no fraction)."""
+    arr = np.asarray(values)
+    if arr.dtype.kind in "iu":
+        return arr.astype(np.int64)
+    if arr.dtype.kind == "b" or arr.dtype.kind not in "fO":
+        raise ValueError(f"DATETRUNC: time value of type {arr.dtype} is "
+                         "not a number of the input time unit")
+    as_float = arr.astype(np.float64)
+    if not np.isfinite(as_float).all() \
+            or (as_float != np.floor(as_float)).any():
+        raise ValueError("DATETRUNC: time value is not a whole number of "
+                         "the input time unit")
+    return as_float.astype(np.int64)
+
+
+def _to_java_div(v: np.ndarray, per: int) -> np.ndarray:
+    """v / per toward zero, as java's TimeUnit.convert to a coarser
+    unit."""
+    return np.where(v < 0, -((-v) // per), v // per)
+
+
+def datetrunc(unit: Any, values, input_unit: Any = "MILLISECONDS"
+              ) -> np.ndarray:
+    """DATETRUNC(unit, value[, inputTimeUnit]) (pinot-common
+    DateTimeFunctions.dateTrunc): the value in `input_unit` since the
+    epoch, truncated in UTC to the start of its `unit`, in the input
+    unit again, as int64. From a unit no finer than milliseconds the
+    way back is exact: a truncation to a unit at least as wide as the
+    input's is a whole number of input units, and one to a finer unit
+    leaves the value as it was."""
+    unit = datetrunc_unit(unit)
+    v = _datetrunc_values(values)
+    name = str(input_unit).upper()
+    if name in _INPUT_UNITS_MS:
+        per = _INPUT_UNITS_MS[name]
+        return datetrunc_ms(unit, v * per) // per
+    if name in _INPUT_UNITS_PER_MS:
+        per = _INPUT_UNITS_PER_MS[name]
+        return datetrunc_ms(unit, _to_java_div(v, per)) * per
+    raise ValueError(f"DATETRUNC: unsupported input time unit "
+                     f"{input_unit!r}; one of "
+                     f"{', '.join([*_INPUT_UNITS_MS, *_INPUT_UNITS_PER_MS])}")
+
+
+def _datetrunc(expr: Function, p: ColumnProvider):
+    if len(expr.args) not in (2, 3) or not all(
+            isinstance(a, Literal) for a in expr.args[:1] + expr.args[2:]):
+        raise ValueError(
+            "DATETRUNC takes ('<unit>', <time value>[, '<input time unit>'])"
+            " with literal units; the time zone and output unit forms are "
+            "not supported")
+    out = datetrunc(expr.args[0].value, evaluate(expr.args[1], p),
+                    *(a.value for a in expr.args[2:]))
+    return out if out.ndim else int(out)
+
+
 def _clpdecode(expr: Function, p: ColumnProvider):
     """clpDecode(logtypeCol, dictVarsCol, encodedVarsCol) -> message strings
     (ref CLPDecodeTransformFunction, used with clp-log ingestion where the
@@ -261,6 +375,7 @@ def _clpdecode(expr: Function, p: ColumnProvider):
 
 _SPECIAL: Dict[str, Callable] = {
     "clpdecode": _clpdecode,
+    **dict.fromkeys(DATETRUNC_NAMES, _datetrunc),
     "case": _case,
     "concat": _concat,
     "substr": _substr,

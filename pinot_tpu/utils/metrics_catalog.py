@@ -139,6 +139,10 @@ METRICS: Dict[str, str] = {
         "leaf group-bys whose time bucket fused into the device "
         "group-by kernel (ops/timeseries_device.py) instead of the "
         "host expression path",
+    "time_bucket":
+        "device GROUP BYs whose leading key is the fused time bucket, by "
+        "unit and fold (labels unit=SECOND..WEEK for DATETRUNC, FLOOR for "
+        "the leaf's floor((t - start) / step); fold=device|host)",
     "mesh_merge_served":
         "mesh queries whose cross-segment partial merge ran as ONE "
         "on-device collective (no host IndexedTable fold)",

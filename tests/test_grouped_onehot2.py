@@ -279,6 +279,46 @@ def test_pallas_driver_compiles_for_the_v5e(G, ops, one_chip, monkeypatch):
     assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
 
 
+def test_fused_time_bucket_and_its_fold_compile_for_the_v5e(one_chip,
+                                                          monkeypatch):
+    """The tsbs_ts_dgb1_c1 cell's kernel as the engine plans it: the
+    hour of DATETRUNC('HOUR', ts) computed from the raw timestamp's
+    (hi, lo) planes as the key's lowest digit (16 padded hours) under
+    4,000 hosts, G = 64,000 on the Pallas pass, 4 segments of 2^23 docs
+    folded over 4,096 x 16 = 65,536 global keys. Nothing runs."""
+    from pinot_tpu.ops.plan_ir import PACK, pack_layout
+    S, D = 4, 1 << 23
+    plan = DevicePlan(
+        filter_ir=("and", ("leaf", 0), ("leaf", 1)),
+        leaves=(DeviceLeaf("vrange64", "ts"), DeviceLeaf("vrange64", "ts")),
+        value_irs=(("col", "usage_user"),),
+        agg_ops=(("sum", 0, None), ("count", None, None)),
+        group_cols=("hostname",), group_strides=(16,), num_groups=64000,
+        dict_cols=("hostname",), raw_cols=("usage_user",),
+        raw64_cols=("ts",), tbucket=("ts", 16), group_fold=(4096, 16))
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    with _compiling_for_the_chip(monkeypatch):
+        kernel = jax.jit(kernels.make_kernel(plan),
+                         static_argnames=("D", "G"))
+        compiled = kernel.lower(
+            {"ids:hostname": shape((S, D), jnp.int16),
+             "val:usage_user": shape((S, D), jnp.float32),
+             "valhi:ts": shape((S, D), jnp.int32),
+             "vallo:ts": shape((S, D), jnp.int32)},
+            {PACK: shape((len(pack_layout(plan)), S), jnp.int32),
+             "ginv0": shape((S, 4096), jnp.int32)}, None, D=D).compile()
+    hlo = compiled.as_text()
+    assert hlo.count("tpu_custom_call") >= 1 and "onehot2" in hlo
+    assert "scatter" not in hlo
+    # one folded row leaves: 2 slots x 65,536 keys, then S matched counts
+    out, = jax.tree_util.tree_leaves(compiled.out_info)
+    assert out.shape == (2 * 65536 + S,) and out.dtype == jnp.int32
+    assert compiled.memory_analysis().temp_size_in_bytes < 8 << 30
+
+
 # -- four chips: the pass a shard, the fold's all-reduces ------------------------
 def _q2_plan():
     """SSB Q2.x as the engine plans it once the fold is set: d_year (7)

@@ -351,8 +351,10 @@ def test_the_fold_s_caps_are_the_engine_s_own_and_hold_at_their_edge():
     assert kernels.group_fold(plan, max_g + 1, 0, max_g, max_b) == "host"
     assert kernels.group_fold(plan, 0, max_b + 1, max_g, max_b) == "host"
     import dataclasses
+    # a fused time bucket folds as any key does, under the same caps
     fused = dataclasses.replace(plan, tbucket=("ts", 8))
-    assert kernels.group_fold(fused, 0, 0, max_g, max_b) == "host"
+    assert kernels.group_fold(fused, max_g, max_b, max_g, max_b) == "device"
+    assert kernels.group_fold(fused, max_g + 1, 0, max_g, max_b) == "host"
 
 
 def test_a_remap_past_the_cap_keeps_the_per_segment_route(cell, table,
