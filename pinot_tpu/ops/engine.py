@@ -451,9 +451,11 @@ class TpuOperatorExecutor:
                         groupFold=fold)
             if bucket is not None:
                 unit = timeseries_device.bucket_label(bucket)
-                self._meter("time_bucket", unit=unit, fold=fold)
+                self._meter("time_bucket", unit=unit, fold=fold,
+                            width=str(bucket.width))
                 if dsp is not None:
                     dsp.set(timeBucket=unit, bucketCount=bucket.count,
+                            bucketWidth=bucket.width,
                             bucketPad=bucket.count_pad)
             if self._mesh is not None and batchable:
                 exchanged, gathered = self._mesh_exchange(
@@ -579,9 +581,10 @@ class TpuOperatorExecutor:
                 segments, plan, S, G_local, info)
         except _MergeFallback:
             return None
-        # a fused time bucket's digit is the fold's last, carried unmapped
+        # a fused time bucket's digit is the fold's last: the window's
+        # count_pad buckets, each segment's shifted by its origin
         fold = (G_out,) if plan.group_compact \
-            else tuple(decode[1]) + plan.tbucket[1:]
+            else tuple(decode[1]) + plan.tbucket[2:]
         params = dict(params)
         params.update(gparams)
         return (dataclasses.replace(plan, group_fold=fold), params,
@@ -612,7 +615,7 @@ class TpuOperatorExecutor:
         staging lock is held for the cache probe and the insert only:
         the factorization and its puts run outside it."""
         key = (batch_id(segments), plan.group_cols, plan.group_strides,
-               plan.group_compact, S, G_local)
+               plan.group_compact, plan.tbucket, S, G_local)
         with self._engine_lock:
             ent = self._gmap_cache.get(key)
             if ent is not None:
@@ -674,10 +677,11 @@ class TpuOperatorExecutor:
         # bucketed: a window that slides over time-partitioned segments
         # changes a union by a value or two, and the kernel's shape must
         # not follow it. A padded digit is a group no segment has. A
-        # fused time bucket's digit (count_pad, a pow2 already) stays
-        # the lowest: the remap carries it as it is
+        # fused time bucket's digit (the window's count_pad, a pow2
+        # already) stays the lowest: the kernel shifts each segment's
+        # onto it, with no table
         cards = [_pow2(len(u), floor=8) for u in unions]
-        G_out = math.prod(cards) * math.prod(plan.tbucket[1:])
+        G_out = math.prod(cards) * math.prod(plan.tbucket[2:])
         if self._fold_where(plan, G_out,
                             sum(S * c * 4 for c in cards)) == "host":
             raise _MergeFallback("groups")
@@ -1581,8 +1585,10 @@ class TpuOperatorExecutor:
                 # leading floor((t - start) / step): the fused device
                 # time-bucket leg (supports() admitted the shape; the
                 # window/metadata admission happens here). The bucket id
-                # becomes the key's LOWEST digit, so count_pad seeds the
-                # mixed radix ahead of the tag cardinalities.
+                # becomes the key's LOWEST digit, so its width (what the
+                # segments span, as a dictionary digit is what they
+                # hold) seeds the mixed radix ahead of the tag
+                # cardinalities.
                 tb_spec = timeseries_device.plan_bucket(
                     gb[0], ctx.filter, segments)
                 if tb_spec is None:
@@ -1592,7 +1598,7 @@ class TpuOperatorExecutor:
                     # the timestamp stages ONLY as split planes once the
                     # bucket leg claims it — it can't also feed a value IR
                     return None
-                tbucket = (tb_spec.col, tb_spec.count_pad)
+                tbucket = (tb_spec.col, tb_spec.width, tb_spec.count_pad)
                 gb = gb[1:]
             card_pads = []
             for g in gb:
@@ -1606,7 +1612,7 @@ class TpuOperatorExecutor:
                            for seg in segments)
                 group_cols.append(col)
                 card_pads.append(max(card, 1))
-            num_groups = tb_spec.count_pad if tb_spec is not None else 1
+            num_groups = tb_spec.width if tb_spec is not None else 1
             for c in card_pads:
                 num_groups *= c
             if tb_spec is not None and num_groups > MAX_DEVICE_GROUPS:
@@ -2084,15 +2090,18 @@ class TpuOperatorExecutor:
         rows[NUM_DOCS] = np.zeros(S, dtype=np.int32)
         rows[NUM_DOCS][:S_real] = [s.num_docs for s in segments]
         if plan.tbucket:
-            # fused time-bucket cells: start's (hi, lo) planes + step +
-            # live bucket count — the ONLY things that change across a
-            # dashboard's sliding refresh window (pkey carries group_by
-            # above: same filter + different bucket expr must not alias)
+            # fused time-bucket cells, a segment from its own origin
+            # bucket: the origin's (hi, lo) planes + step + live bucket
+            # count + the origin's offset — the ONLY things that change
+            # across a dashboard's sliding refresh window (pkey carries
+            # group_by above: same filter + different bucket expr must
+            # not alias)
             spec = timeseries_device.plan_bucket(
                 ctx.group_by[0], ctx.filter, segments)
-            if spec is None or spec.count_pad != plan.tbucket[1]:
+            if spec is None \
+                    or (spec.width, spec.count_pad) != plan.tbucket[1:]:
                 raise _NotStageable()
-            rows.update(timeseries_device.leaf_params(spec, S))
+            rows.update(timeseries_device.leaf_params(spec, segments, S))
         # histogram sketch slots: bucket bounds from segment metadata
         # (missing min/max -> host fallback)
         for j, (op, vidx, _fidx) in enumerate(plan.agg_ops):
@@ -2719,8 +2728,9 @@ class TpuOperatorExecutor:
                 rem = rem % stride
             if plan.tbucket:
                 # the fused time bucket is the key's lowest digit: after
-                # peeling every tag stride, the remainder IS the bucket
-                buckets = rem
+                # peeling every tag stride, the remainder is the bucket
+                # counted from this segment's origin
+                buckets = timeseries_device.origin(bucket, seg) + rem
             valid = np.ones(len(present), dtype=bool)
             for ids, card in zip(ids_per_col, cards):
                 valid &= ids < card
@@ -2838,8 +2848,8 @@ class TpuOperatorExecutor:
                 for ci in range(len(plan.group_cols))]
             if bucket is not None:
                 # the fused time bucket leads the GROUP BY and is the
-                # key's lowest digit
-                pad = plan.tbucket[1]
+                # key's lowest digit, on the window's axis
+                pad = plan.tbucket[2]
                 key_columns.insert(0, CodedColumn(
                     timeseries_device.bucket_keys(bucket, np.arange(pad)),
                     present % pad))

@@ -463,7 +463,7 @@ def group_fold(plan: DevicePlan, out_groups: int = 0, remap_bytes: int = 0,
     writes the word on the DeviceDispatch span (`groupFold`). A key
     space or a compacted plan's [S, G] table past the caps would cost
     more than the partials it saves. A fused time bucket folds like any
-    other key: its digit means the same in every segment of a launch."""
+    other key: its digit is shifted onto the window's axis on the way."""
     if out_groups > max_groups or remap_bytes > max_remap_bytes:
         return "host"
     return "device"
@@ -527,9 +527,12 @@ def fold_groups(plan: DevicePlan, slots, params, mesh=None) -> jnp.ndarray:
     segment's dictionary lacks it), recombined with the plan's own
     strides; a compacted plan's `ginv` [S, G_out] gives the local code.
     A gather a segment, then a reduction over the segment axis. A fused
-    time bucket, the key's lowest digit (`plan.group_fold`'s last), is
-    the same bucket in every segment (start and step are the query's):
-    it is carried as it is, with no table.
+    time bucket, the key's lowest digit, counts from each segment's own
+    origin bucket: global bucket j of the window's axis
+    (`plan.group_fold`'s last, count_pad) reads local digit
+    j - tb:off[s], and is absent where that falls outside [0, width),
+    the same whole-row gather with its index made in the kernel from
+    the [S] offsets instead of a host table.
 
     mesh: the segments mesh the partials and the tables are sharded
     over. Each chip then folds its own segments (`_fold_shard` under a
@@ -547,6 +550,8 @@ def fold_groups(plan: DevicePlan, slots, params, mesh=None) -> jnp.ndarray:
     every other slot bit-cast."""
     names = ["ginv"] if plan.group_compact \
         else [f"ginv{ci}" for ci in range(len(plan.group_cols))]
+    if plan.tbucket:
+        names.append("tb:off")  # [S], sharded as the tables are
     arrs = [s for _op, s in slots]
     tables = [params[n] for n in names]
     if mesh is None:
@@ -579,7 +584,10 @@ def _fold_shard(plan: DevicePlan, chips: Optional[str], arrs,
         # whole rows at a time (an element-wise gather over an iota of
         # the key space compiled for a minute at 131,072 groups: XLA
         # folded the iota's divisions as constants). The last axis is
-        # the fused time bucket's digit (1 without one), never remapped
+        # the fused time bucket's digit (1 without one): shifted from
+        # the segment's origin onto the window's axis
+        if plan.tbucket:
+            *tables, off = tables
         k = len(plan.group_cols)
         local = [(plan.num_groups if ci == 0 else plan.group_strides[ci - 1])
                  // plan.group_strides[ci] for ci in range(k)]
@@ -589,8 +597,16 @@ def _fold_shard(plan: DevicePlan, chips: Optional[str], arrs,
         for ci, ids in enumerate(tables):
             there = there & (ids >= 0).reshape(
                 (S,) + (1,) * ci + (-1,) + (1,) * (k - ci))
+        last = local[-1]
+        if plan.tbucket:
+            # global bucket j of a segment is its local digit j - off[s]
+            last = plan.group_fold[-1]
+            at = jnp.arange(last, dtype=jnp.int32)[None, :] - off[:, None]
+            there = there & ((at >= 0) & (at < local[-1])).reshape(
+                (S,) + (1,) * k + (last,))
+            at = jnp.clip(at, 0, local[-1] - 1)
         there = jnp.broadcast_to(
-            there, (S,) + tuple(plan.group_fold[:k]) + (local[-1],)
+            there, (S,) + tuple(plan.group_fold[:k]) + (last,)
         ).reshape(S, -1)
 
         def globally(s):
@@ -599,6 +615,9 @@ def _fold_shard(plan: DevicePlan, chips: Optional[str], arrs,
                 x = jax.vmap(lambda xs, i, _a=ci: jnp.take(
                     xs, i, axis=_a, mode="clip"))(
                         x, jnp.maximum(ids, 0))
+            if plan.tbucket:
+                x = jax.vmap(lambda xs, i: jnp.take(
+                    xs, i, axis=-1, mode="clip"))(x, at)
             return x.reshape(S, -1)
 
     def across(f, reduce):
@@ -1205,15 +1224,17 @@ def _group_keys(plan: DevicePlan, cols, params, shape):
             keys = keys + cols["ids:" + col] * jnp.int32(stride)
     if not plan.tbucket:
         return keys, None
-    # fused time bucket: floor((t - start) / step) from the (hi, lo)
-    # raw64 planes becomes the key's lowest digit; out-of-window rows
+    # fused time bucket: floor((t - origin) / step) from the (hi, lo)
+    # raw64 planes, counted from the segment's own origin bucket
+    # (`timeseries_device.origin`), becomes the key's lowest digit, as
+    # wide as the most buckets a segment spans; out-of-window rows
     # gate out of every slot (their wrapped deltas never reach the
     # scatter)
-    tcol, count_pad = plan.tbucket
+    tcol, width, _pad = plan.tbucket
     b, gate = timeseries_device.bucket_ids(
         cols["valhi:" + tcol], cols["vallo:" + tcol],
         params["tb:shi"], params["tb:slo"],
-        params["tb:step"], params["tb:count"], count_pad)
+        params["tb:step"], params["tb:count"], width)
     return keys + b, gate
 
 

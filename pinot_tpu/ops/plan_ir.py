@@ -34,7 +34,7 @@ Value IR (aggregation inputs / in-kernel transforms):
 
 Packed parameters: every [S]-shaped parameter of a plan (the leaf bounds
 above except 'lut' and 'clp', the 'hist:' slots' bucket bounds, the time
-bucket's four cells, and num_docs) is staged as ONE int32 [K, S] array
+bucket's five cells, and num_docs) is staged as ONE int32 [K, S] array
 under params[PACK]. It stays a HOST (numpy) array until the launch: a
 lone launch hands it to the jit'd kernel as an argument, a batch's B
 packs are stacked on the host into one [B, K, S] array
@@ -75,7 +75,7 @@ def pack_layout(plan) -> Tuple[Tuple[str, str], ...]:
     rows = [(NUM_DOCS, "i")]
     if plan.tbucket:
         rows += [(f"tb:{cell}", "i")
-                 for cell in ("shi", "slo", "step", "count")]
+                 for cell in ("shi", "slo", "step", "count", "off")]
     for j, (op, _vidx, _fidx) in enumerate(plan.agg_ops):
         if op.startswith("hist:"):
             rows += [(f"slot{j}:hlo", "f"), (f"slot{j}:hscale", "f")]
@@ -172,12 +172,15 @@ class DevicePlan:
     #: `mask &= valid.to_mask()` makes them (SURVEY §2.3)
     valid_mask: bool = False
     #: device time-bucket leg (ops/timeseries_device.py): (ts_col,
-    #: count_pad) — floor((t - start) / step) fused into the group key
-    #: as its LOWEST digit (count_pad is the pow2 bucket of the window's
-    #: bucket count, so it multiplies into num_groups ahead of the tag
-    #: radices). start/step/count ride params ('tb:*' i32 cells), NOT
-    #: the plan, so a dashboard's sliding refresh window re-stages four
-    #: scalar rows instead of retracing the kernel.
+    #: width, count_pad) — floor((t - origin) / step) fused into the
+    #: group key as its LOWEST digit, counted from each segment's own
+    #: origin bucket: width is the most buckets one segment of the batch
+    #: spans (it multiplies into num_groups ahead of the tag radices),
+    #: count_pad the pow2 bucket of the window's bucket count (the
+    #: fold's digit, the window's axis). start/step/count/origin ride
+    #: params ('tb:*' i32 cells), NOT the plan, so a dashboard's sliding
+    #: refresh window re-stages five scalar rows instead of retracing
+    #: the kernel.
     tbucket: Tuple = ()
     #: True: an additive slot of this GROUP BY may add an Inf or a NaN (a
     #: FLOAT/DOUBLE column, a `div`, or a power that can overflow f32).

@@ -18,11 +18,20 @@ window's lower bound truncated to the unit, step the unit's width, and
 the key decodes to the absolute `start + b * step`. MONTH, QUARTER and
 YEAR vary in length and stay host expressions.
 
-start / step / count are PARAMS (per-segment i32 cells), not plan
-fields: a dashboard's sliding window changes `start` every refresh, and
-only `count_pad` — the pow2 bucket of the window's bucket count — is
-baked into the plan, so steady-state refreshes re-stage four scalar
-param rows and never retrace.
+The kernel's bucket digit is as wide as the most buckets one segment of
+the batch spans (`BucketSpec.width`, from each segment's own time range
+in its metadata), not as the window: each segment keys its rows from
+its own first bucket of the window, `off` buckets in, and the fold (or
+the host, past the remap cap) shifts the digit back onto the window's
+axis of `count_pad` buckets, the pow2 bucket of the window's count. A
+batch with a segment that spans `count_pad` buckets or more gets
+width == count_pad, the digit the window alone would give. Both widths
+are plan fields; start / step / count / origin are PARAMS (per-segment
+i32 cells): a dashboard's sliding window changes `start`
+every refresh, and the width, fixed by the segments' ranges and by where
+start lies within a step (DATETRUNC's start is always on the unit; a
+leaf's moves by whole steps), does not, so steady-state refreshes
+re-stage five scalar param rows and never retrace.
 
 No `kernels` import here: kernels.py imports this module (one-way, the
 same direction as its clp_device import).
@@ -49,6 +58,10 @@ MAX_WINDOW = (1 << 31) - (1 << 24)
 
 _SHIFT = 1 << 24
 
+#: bound on bucket_ids' f32 quotient estimate before its i32 conversion
+#: (far past any width, far inside i32)
+_EST_MAX = float(1 << 30)
+
 
 class BucketSpec(NamedTuple):
     """Host-side admission result for one leaf query's bucket leg."""
@@ -56,7 +69,12 @@ class BucketSpec(NamedTuple):
     start: int
     step: int
     count: int      # buckets actually addressed by the window
-    count_pad: int  # pow2 bucket -> the plan's static group width
+    #: pow2 bucket of `count`: the window's axis, the fold's bucket digit
+    count_pad: int
+    #: the kernel's bucket digit: the most buckets of the window's grid
+    #: one segment of the batch spans, at most count_pad
+    width: int
+    lo: int         # the window's lower bound (inclusive)
     #: DATETRUNC's unit (its keys are `start + b * step`, LONG), or None
     #: for the leaf's floor((t - start) / step) (its keys are b)
     unit: Optional[str] = None
@@ -151,7 +169,12 @@ def plan_bucket(group_expr, flt, segments) -> Optional[BucketSpec]:
     [0, 2^55) on every segment, a filter window starting at/after
     `start` (so the kernel's delta is never negative for surviving rows;
     DATETRUNC's start is that lower bound truncated to its unit), and
-    the window fitting the exact-i32 envelope."""
+    the window fitting the exact-i32 envelope.
+
+    The width is the most buckets of the window's grid one segment's
+    whole [min, max] spans, not that range clipped to the window: a
+    batch keeps one width, and one compiled kernel, while the window
+    slides over it."""
     shape = extract_bucket(group_expr)
     if shape is None:
         return None
@@ -163,18 +186,32 @@ def plan_bucket(group_expr, flt, segments) -> Optional[BucketSpec]:
         start = int(transform.datetrunc_ms(unit, win[0]))
     if win[0] < start:
         return None
+    span = 1
     for seg in segments:
         m = seg.metadata.columns.get(col)
         if m is None or m.data_type.np_dtype.kind not in "iu" \
                 or m.min_value is None or m.max_value is None:
             return None
-        if int(m.min_value) < 0 or int(m.max_value) >= MAX_TS:
+        lo, hi = int(m.min_value), int(m.max_value)
+        if lo < 0 or hi >= MAX_TS:
             return None
+        span = max(span, (hi - start) // step - (lo - start) // step + 1)
     window = win[1] - 1 - start
     if window >= MAX_WINDOW:
         return None
     count = window // step + 1
-    return BucketSpec(col, start, step, count, _pow2(count), unit)
+    count_pad = _pow2(count)
+    return BucketSpec(col, start, step, count, count_pad,
+                      min(span, count_pad), win[0], unit)
+
+
+def origin(spec: BucketSpec, seg) -> int:
+    """The bucket of the window's grid that segment `seg` keys its rows
+    from: the one its first instant inside the window falls in (at most
+    `count`, where the segment lies past the window and keys nothing).
+    Its rows' bucket digit counts from there."""
+    first = max(int(seg.metadata.columns[spec.col].min_value), spec.lo)
+    return min((first - spec.start) // spec.step, spec.count)
 
 
 def bucket_keys(spec: BucketSpec, b) -> np.ndarray:
@@ -194,16 +231,22 @@ def bucket_label(spec: BucketSpec) -> str:
     return spec.unit or "FLOOR"
 
 
-def leaf_params(spec: BucketSpec, S: int):
-    """The four per-segment i32 param cells the kernel reads: start's
-    (hi, lo) planes, step, and the live bucket count. Imported lazily by
-    the engine's _stage; numpy-side only."""
-    import numpy as np
+def leaf_params(spec: BucketSpec, segments, S: int):
+    """The five per-segment i32 param cells the kernel reads, a segment
+    from its own origin bucket (`origin`; 0 on padded slots): the
+    origin instant's (hi, lo) planes, step, the buckets left to it in
+    the window (at most the width), and the origin's offset into the
+    window's grid, which the fold shifts the bucket digit by. Numpy
+    only: the engine packs them (`_resolve_params`)."""
+    off = np.zeros(S, np.int64)
+    off[:len(segments)] = [origin(spec, seg) for seg in segments]
+    first = spec.start + off * spec.step
     return {
-        "tb:shi": np.full(S, spec.start >> 24, np.int32),
-        "tb:slo": np.full(S, spec.start & 0xFFFFFF, np.int32),
+        "tb:shi": (first >> 24).astype(np.int32),
+        "tb:slo": (first & 0xFFFFFF).astype(np.int32),
         "tb:step": np.full(S, spec.step, np.int32),
-        "tb:count": np.full(S, spec.count, np.int32),
+        "tb:count": np.minimum(spec.count - off, spec.width).astype(np.int32),
+        "tb:off": off.astype(np.int32),
     }
 
 
@@ -211,18 +254,32 @@ def leaf_params(spec: BucketSpec, S: int):
 # Traced bucket math (called from kernels._compute_slots)
 # ---------------------------------------------------------------------------
 
-def bucket_ids(vhi, vlo, shi, slo, step, count, count_pad: int):
-    """(bucket ids clipped to [0, count_pad), in-window gate) from the
+def bucket_ids(vhi, vlo, shi, slo, step, count, width: int):
+    """(bucket ids clipped to [0, width), in-window gate) from the
     staged (hi, lo) timestamp planes. delta reconstructs exactly in i32
     for every row the window filter keeps; out-of-window rows may wrap,
     but the gate (and the query's own t-range conjuncts) zero their
-    contribution before the scatter."""
+    contribution before the scatter.
+
+    floor(delta / step) is exact without an integer divide, which the
+    TPU has no unit for (XLA's expansion of an i32 divide by a runtime
+    divisor took ~35 s to compile a v5e kernel over 2^23 docs, where
+    the rest of it takes ~1 s): an f32 estimate, three roundings of at
+    most 2^-24 each, is within one of the quotient below 2^21, and one
+    exact i32 correction each way makes it the quotient. A width is at
+    most MAX_DEVICE_GROUPS = 2^20 buckets, so every row the gate passes
+    gets its exact bucket, and a row past 2^21 buckets stays past the
+    width and the gate."""
     delta = (vhi - shi[:, None]) * jnp.int32(_SHIFT) + (vlo - slo[:, None])
-    b = jnp.floor_divide(delta, step[:, None])
+    step = step[:, None]
+    est = delta.astype(jnp.float32) * (1 / step.astype(jnp.float32))
+    b = jnp.floor(jnp.clip(est, -_EST_MAX, _EST_MAX)).astype(jnp.int32)
+    r = delta - b * step
+    b = b - (r < 0) + (r >= step)
     gate = (delta >= 0) & (b < count[:, None])
-    return jnp.clip(b, 0, count_pad - 1).astype(jnp.int32), gate
+    return jnp.clip(b, 0, width - 1).astype(jnp.int32), gate
 
 
 #: standalone jit entry so tests (and the purity checker's traced-fn
 #: sweep) exercise the bucket math without a full kernel launch
-compiled_bucket_ids = jax.jit(bucket_ids, static_argnames=("count_pad",))
+compiled_bucket_ids = jax.jit(bucket_ids, static_argnames=("width",))

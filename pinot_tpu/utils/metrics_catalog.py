@@ -141,8 +141,10 @@ METRICS: Dict[str, str] = {
         "host expression path",
     "time_bucket":
         "device GROUP BYs whose leading key is the fused time bucket, by "
-        "unit and fold (labels unit=SECOND..WEEK for DATETRUNC, FLOOR for "
-        "the leaf's floor((t - start) / step); fold=device|host)",
+        "unit, fold and kernel width (labels unit=SECOND..WEEK for "
+        "DATETRUNC, FLOOR for the leaf's floor((t - start) / step); "
+        "fold=device|host; width= the bucket digit the kernel ran, the "
+        "most buckets one segment of the batch spans)",
     "mesh_merge_served":
         "mesh queries whose cross-segment partial merge ran as ONE "
         "on-device collective (no host IndexedTable fold)",

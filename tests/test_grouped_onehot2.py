@@ -283,9 +283,11 @@ def test_fused_time_bucket_and_its_fold_compile_for_the_v5e(one_chip,
                                                           monkeypatch):
     """The tsbs_ts_dgb1_c1 cell's kernel as the engine plans it: the
     hour of DATETRUNC('HOUR', ts) computed from the raw timestamp's
-    (hi, lo) planes as the key's lowest digit (16 padded hours) under
-    4,000 hosts, G = 64,000 on the Pallas pass, 4 segments of 2^23 docs
-    folded over 4,096 x 16 = 65,536 global keys. Nothing runs."""
+    (hi, lo) planes as the key's lowest digit, 5 hours wide (what a
+    segment of 4.5 hours spans) under 4,000 hosts, G = 20,000 on the
+    Pallas pass, 4 segments of 2^23 docs folded over 4,096 x 16 = 65,536
+    global keys, each segment's hours shifted onto the window's 16 by
+    its origin. Nothing runs."""
     from pinot_tpu.ops.plan_ir import PACK, pack_layout
     S, D = 4, 1 << 23
     plan = DevicePlan(
@@ -293,9 +295,9 @@ def test_fused_time_bucket_and_its_fold_compile_for_the_v5e(one_chip,
         leaves=(DeviceLeaf("vrange64", "ts"), DeviceLeaf("vrange64", "ts")),
         value_irs=(("col", "usage_user"),),
         agg_ops=(("sum", 0, None), ("count", None, None)),
-        group_cols=("hostname",), group_strides=(16,), num_groups=64000,
+        group_cols=("hostname",), group_strides=(5,), num_groups=20000,
         dict_cols=("hostname",), raw_cols=("usage_user",),
-        raw64_cols=("ts",), tbucket=("ts", 16), group_fold=(4096, 16))
+        raw64_cols=("ts",), tbucket=("ts", 5, 16), group_fold=(4096, 16))
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)

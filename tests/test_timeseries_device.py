@@ -6,8 +6,9 @@
     tolerance of the host expression-column leaf, across aggregations
     and the full transform set; served leaves meter
     `timeseries_leaf_device`
-  * retraces — start/step/count ride staged params (only count_pad is
-    in the plan): a sliding dashboard refresh causes ZERO retraces
+  * retraces — start/step/count ride staged params (only the bucket
+    digit's widths are in the plan): a sliding dashboard refresh causes
+    ZERO retraces
   * simpleql parens — stage splitting is paren-depth aware: a where()
     predicate like `host = 'a(1)' AND floor(x / 2) > 1` stays ONE stage
     with its argument string verbatim (the old `[^)]*` regex stopped at

@@ -352,7 +352,7 @@ def test_the_fold_s_caps_are_the_engine_s_own_and_hold_at_their_edge():
     assert kernels.group_fold(plan, 0, max_b + 1, max_g, max_b) == "host"
     import dataclasses
     # a fused time bucket folds as any key does, under the same caps
-    fused = dataclasses.replace(plan, tbucket=("ts", 8))
+    fused = dataclasses.replace(plan, tbucket=("ts", 5, 8))
     assert kernels.group_fold(fused, max_g, max_b, max_g, max_b) == "device"
     assert kernels.group_fold(fused, max_g + 1, 0, max_g, max_b) == "host"
 

@@ -5,7 +5,8 @@ unequal docs in time order, `ts` a raw LONG of epoch milliseconds. The
 cell's template groups by `DATETRUNC('HOUR', ts), hostname`: the device
 computes the hour from ts's (hi, lo) planes inside the grouped kernel
 (ops/timeseries_device.py) and folds the 3-4 surviving segments into one
-`[G, slots]` table, the bucket digit carried unmapped. Each answer has to
+`[G, slots]` table, each segment's bucket digit (4-5 hours wide: what a
+segment spans) shifted onto the window's 16. Each answer has to
 be the host executor's and `benchmark/reference.py`'s, for windows that a
 segment boundary splits, through the server's executor and through a
 broker; the spans have to say so. Also: `fleet_ts` makes ts and the
@@ -97,8 +98,10 @@ def served(cell, table):
 
 
 def bucket_meter(engine, fold: str) -> float:
-    return engine._metrics.meter("time_bucket", labels=dict(
-        engine._labels or {}, unit="HOUR", fold=fold))
+    """The HOUR bucket's meter, over every width of the window's 16."""
+    return sum(engine._metrics.meter("time_bucket", labels=dict(
+        engine._labels or {}, unit="HOUR", fold=fold, width=str(w)))
+        for w in range(1, 17))
 
 
 def spans(tree, name: str) -> list:
@@ -162,6 +165,7 @@ def test_the_template_is_served_on_the_device_as_host_and_reference_answer(
     queries = traffic.make_queries(mix, config["table"], SEED, 1, 6, False)
     assert len({q[1]["w"][0] for q in queries}) > 4  # several windows
     split = 0
+    widths = set()
     for t, literals, sql in queries:
         template = mix["templates"][t]
         resp, results, dispatches = ask(ex, config, sql)
@@ -185,15 +189,24 @@ def test_the_template_is_served_on_the_device_as_host_and_reference_answer(
         split += sum(1 for m in in_time[1:] if m % HOUR)
         span, = dispatches
         assert "outcome" not in span, "fell back to the host"
+        # the kernel's bucket digit is the most hours a segment spans:
+        # 5 of a full segment's 4.5 hours, 4 or 5 of these few ticks;
+        # the window's 12 hours pad to 16 on the fold's axis
+        width = max(int(s.metadata.columns["ts"].max_value) // HOUR
+                    - int(s.metadata.columns["ts"].min_value) // HOUR + 1
+                    for s in touched)
+        assert width in (4, 5)
+        widths.add(width)
         assert (span["groupFold"], span["timeBucket"], span["bucketCount"],
-                span["bucketPad"]) == ("device", "HOUR", 12, 16)
+                span["bucketWidth"], span["bucketPad"]) \
+            == ("device", "HOUR", 12, width, 16)
         assert span["groupPath"] == "onehot2"
-        assert span["groupKeySpace"] == 16 * max(
+        assert span["groupKeySpace"] == width * max(
             s.metadata.columns["hostname"].cardinality for s in touched)
         assert span["groupsPresent"] == len(want)
         # the fold's table: 4,096 hosts x 16 hours, SUM and COUNT, 4 B
         assert span["groupResultBytes"] == 4096 * 16 * 2 * 4
-    assert split >= 2
+    assert split >= 2 and 5 in widths
     assert bucket_meter(engine, "device") - engine.buckets_before \
         == len(queries)
     assert bucket_meter(engine, "host") == 0
